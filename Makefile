@@ -21,11 +21,12 @@ test:
 		--continue-on-collection-errors -p no:cacheprovider
 
 # bench regression gate (docs/perf-attribution.md): run bench.py
-# fresh and diff it against the newest checked-in BENCH_r*.json with
+# fresh and diff it against the newest match of BENCH_HISTORY (a glob
+# of BENCH_r*.json results; the repo keeps none of its own) with
 # noise-aware per-metric bands; non-zero exit on regression. Known,
 # accepted regressions go in bench-waivers.json with a reason.
 benchgate:
-	$(PYTHON) scripts/perfgate.py --run
+	$(PYTHON) scripts/perfgate.py --run --history '$(BENCH_HISTORY)'
 
 # fleet simulator smoke (docs/simulation.md): the autoscale scenario
 # (diurnal + flash-crowd trace through the real controller on virtual

@@ -30,6 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from .. import device
 from ..models import llama
 from ..models.config import ModelConfig
 from .sampling import sample, spec_verify
@@ -477,7 +478,7 @@ class InferenceEngine:
                     "paged KV supports standard rmsnorm GQA models; "
                     "MLA/MoE/sliding-window/parallel-block/layernorm/"
                     "sink models use the dense cache")
-            if jax.devices()[0].platform == "tpu" and (
+            if device.on_tpu() and (
                     self.kv_block % 128 or cfg.head_dim % 128
                     or cfg.num_heads < 8):
                 # outside the Pallas kernel's coverage every layer
@@ -556,13 +557,13 @@ class InferenceEngine:
                      temperature, top_k, top_p, key, adapter,
                      bucket: int):
             cache = llama.KVCache.create(cfg_, 1, bucket)
+            # last REAL token's logits only (right padding occupies
+            # the tail): the head runs on that one row
             logits, new_cache = llama.forward(params, cfg_, padded,
                                               cache=cache,
-                                              adapter_ids=adapter)
-            # last REAL token's logits (right padding occupies the tail)
-            last = jnp.take_along_axis(
-                logits, (true_len - 1)[:, None, None], axis=1)[:, 0]
-            tok = sample(last, key, temperature, top_k, top_p)
+                                              adapter_ids=adapter,
+                                              logits_at=true_len - 1)
+            tok = sample(logits[:, 0], key, temperature, top_k, top_p)
             return tok[0], new_cache.k, new_cache.v
 
         @functools.partial(jax.jit,
@@ -586,10 +587,9 @@ class InferenceEngine:
                 prefix_v[:, :, :keep], (0, 0, 0, 0, 0))
             cache = llama.KVCache(k=k0, v=v0, index=prefix_len)
             logits, new_cache = llama.forward(params, cfg_, padded,
-                                              cache=cache)
-            last = jnp.take_along_axis(
-                logits, (suffix_len - 1)[:, None, None], axis=1)[:, 0]
-            tok = sample(last, key, temperature, top_k, top_p)
+                                              cache=cache,
+                                              logits_at=suffix_len - 1)
+            tok = sample(logits[:, 0], key, temperature, top_k, top_p)
             # (suffix prefill stays base-model-only: adapter requests
             # bypass the prefix cache — their KV depends on the
             # adapter, so shared-prefix reuse would be wrong)
@@ -652,10 +652,9 @@ class InferenceEngine:
             cache = llama.KVCache.create(cfg_, 1, bucket)
             logits, new_cache = llama.forward(params, cfg_, padded,
                                               cache=cache,
-                                              adapter_ids=adapter)
-            last = jnp.take_along_axis(
-                logits, (true_len - 1)[:, None, None], axis=1)[:, 0]
-            last = jnp.where(mask, last, -jnp.inf)
+                                              adapter_ids=adapter,
+                                              logits_at=true_len - 1)
+            last = jnp.where(mask, logits[:, 0], -jnp.inf)
             tok = sample(last, key, temperature, top_k, top_p)
             return tok[0], new_cache.k, new_cache.v
 
@@ -1202,6 +1201,10 @@ class InferenceEngine:
             from ..perf.ledger import ProgramLedger
             ledger = ProgramLedger()
         self.ledger = ledger
+        if ledger.mode != "off":
+            # an accelerator with no published peaks fails the engine
+            # here, at start, not inside a swallowed capture
+            ledger.device_spec()
         self._weight_bytes: Optional[int] = None
         self._param_count: Optional[int] = None
 

@@ -84,11 +84,7 @@ def init_from_env(env=None) -> Optional[DistContext]:
         # a multi-process CPU group (dev/CI topologies) needs an
         # explicit collectives implementation; the default "none"
         # rejects every cross-process computation
-        try:
-            jax.config.update(
-                "jax_cpu_collectives_implementation", "gloo")
-        except (AttributeError, ValueError):
-            pass
+        jax.config.update("jax_cpu_collectives_implementation", "gloo")
     jax.distributed.initialize(coordinator_address=coord,
                                num_processes=num, process_id=pid)
     log.info("joined jax.distributed rendezvous %s as process %d/%d "

@@ -2591,8 +2591,15 @@ class Scheduler:
         # per-STEP time (the queue-wait estimator and step histogram
         # stay per-token): a K-chunk dispatch amortizes over K steps
         dt_step = dt / n_steps
-        self._ewma_step_s = dt_step if self._ewma_step_s is None \
-            else 0.9 * self._ewma_step_s + 0.1 * dt_step
+        # a program's FIRST dispatch includes its compilation — tens
+        # of seconds on the chip, not a service time. One such sample
+        # held the queue-wait estimate over the admission cap and an
+        # idle, freshly started server answered 429 (first chip run)
+        led = getattr(self.engine, "ledger", None)
+        cost = led.last_dispatch() if led is not None else None
+        if cost is None or cost["dispatches"] > 1:
+            self._ewma_step_s = dt_step if self._ewma_step_s is None \
+                else 0.9 * self._ewma_step_s + 0.1 * dt_step
         self._h_decode_step.observe(dt_step)
         if n_steps > 1:
             self._ph_device_loop.observe(dt)

@@ -251,12 +251,16 @@ def build_parser() -> argparse.ArgumentParser:
     return p
 
 
-def _load_params_cfg(args, dtype):
+def _load_params_cfg(args, dtype, mesh=None):
     """Shared load path: checkpoint (or random init) + LoRA merge.
 
     Returns a NUMPY param tree for the checkpoint path — device
     placement is the caller's job (single-device asarray, or
     shard_params for tp>1 so the full tree never lands on one chip).
+    Random weights are made on the device; with `mesh` (tp>1) they
+    are born sharded for the same reason, and are the same values
+    (the seed and the partitionable threefry decide them, not the
+    layout).
     """
     import jax
 
@@ -274,7 +278,18 @@ def _load_params_cfg(args, dtype):
             from ..models.config import tiny_test
             cfg = tiny_test()
         cfg = cfg.replace(dtype=dtype)
-        params = llama.init_params(jax.random.PRNGKey(0), cfg)
+
+        def init():
+            return llama.init_params(jax.random.PRNGKey(0), cfg)
+
+        # one program for the whole tree: run leaf by leaf, a 4 B
+        # model took 91 s to initialise on the chip and kept float32
+        # copies of its largest leaves beside them
+        shardings = None
+        if mesh is not None:
+            from ..parallel.sharding import param_shardings
+            shardings = param_shardings(jax.eval_shape(init), mesh)
+        params = jax.jit(init, out_shardings=shardings)()
         log.info("initialized random weights: %.2fM params",
                  llama.param_count(params) / 1e6)
         return params, cfg
@@ -318,7 +333,6 @@ def load_engine(args, dist=None):
 
     ledger = ProgramLedger(mode=getattr(args, "ledger_mode", "auto"))
     dtype = jnp.bfloat16 if args.dtype == "bfloat16" else jnp.float32
-    params, cfg = _load_params_cfg(args, dtype)
     if dist is not None and args.tp <= 1:
         # multi-host slice: tp spans every chip of every host by
         # default (the LWS north-star layout, e.g. v5e-16 = 4x4)
@@ -326,6 +340,11 @@ def load_engine(args, dist=None):
         args.tp = jax.device_count()
         log.info("multi-host: tp=%d over %d processes", args.tp,
                  dist.num_processes)
+    mesh = None
+    if args.tp > 1:
+        from ..parallel.mesh import MeshConfig, build_mesh
+        mesh = build_mesh(MeshConfig(tp=args.tp))
+    params, cfg = _load_params_cfg(args, dtype, mesh)
     if cfg.is_moe and args.tp == 1:
         # single-device serving uses the ragged grouped-GEMM dispatch;
         # tp>1 keeps the dense path (shardable through plain GSPMD)
@@ -363,6 +382,7 @@ def load_engine(args, dist=None):
         # on one device first would OOM exactly the models tp serves
         from .sharded import ShardedInferenceEngine
         return ShardedInferenceEngine(params, cfg, tp=args.tp,
+                                      mesh=mesh,
                                       max_slots=args.max_slots,
                                       max_seq=max_seq,
                                       prefix_cache_bytes=args.prefix_cache_mb << 20,
@@ -613,6 +633,8 @@ def main(argv=None) -> int:
     logging.basicConfig(level=logging.INFO,
                         format="%(asctime)s %(name)s %(message)s")
     args = build_parser().parse_args(argv)
+    from .. import device
+    cache_dir = device.enable_compile_cache()
     if args.faults:
         from .. import faults
         faults.install(args.faults)
@@ -648,6 +670,11 @@ def main(argv=None) -> int:
     from . import multihost
     dist = multihost.init_from_env()
     control_port = args.control_port or multihost.CONTROL_PORT
+    # named before any weight is loaded, so a launcher that expected
+    # another platform can stop here
+    dev = device.identity()
+    log.info("device: platform=%s kind=%s count=%d (compile cache %s)",
+             dev["platform"], dev["kind"], dev["count"], cache_dir)
 
     from .scheduler import Scheduler
     from .server import EngineServer
@@ -783,6 +810,8 @@ def main(argv=None) -> int:
                               class_wait_caps=class_wait_caps,
                               priority_scheduling=not
                               args.no_priority_scheduling)
+    log.info("device memory after load, GB per device: %s",
+             device.memory_gb())
     tok = load_tokenizer(args.model_dir)
     name = args.model_name or args.model_dir.rstrip("/").rsplit("/", 1)[-1]
     # measured weight-fetch throughput from the published fetch
@@ -792,7 +821,7 @@ def main(argv=None) -> int:
     from ..modelagent import weightplane
     fetch_bps = weightplane.published_fetch_bps(args.model_dir)
     server = EngineServer(scheduler, tokenizer=tok, model_name=name,
-                          fetch_bps=fetch_bps,
+                          fetch_bps=fetch_bps, device=dev,
                           host=args.host, port=args.port,
                           embedder=embedder, pd_prefill=pd_prefill,
                           request_log=(reqlog if reqlog is not None

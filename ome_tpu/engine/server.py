@@ -66,8 +66,14 @@ class EngineServer:
                  registry: Optional[Registry] = None,
                  request_log=None, profile_dir: Optional[str] = None,
                  debug_endpoints: bool = False,
-                 fetch_bps: Optional[float] = None):
+                 fetch_bps: Optional[float] = None,
+                 device: Optional[dict] = None):
         self.scheduler = scheduler
+        # {platform, kind, count} of the accelerator THIS process
+        # serves on, as JAX reports it (ome_tpu/device.identity): a
+        # caller reading /health learns the device from the process
+        # that holds it, never from its own guess
+        self.device = device
         self.tokenizer = tokenizer or load_tokenizer()
         self.model_name = model_name
         # measured weight-fetch throughput from the published fetch
@@ -184,6 +190,8 @@ class EngineServer:
                         # regression, visible without a metrics scrape
                         "degradations": getattr(
                             sched, "degradations", {}),
+                        "device": outer.device,
+                        "engine": outer._engine_facts(),
                         "uptime_s": round(
                             time.time() - outer.started_at, 1)})
                 elif self.path == "/ready":
@@ -815,6 +823,18 @@ class EngineServer:
             "output_tokens": n,
             "finish_reason": outcome or req.finish_reason,
         })
+
+    def _engine_facts(self) -> dict:
+        """What the engine was actually built with, for /health: a
+        paged pool that fell back to the dense slab, or a tp width,
+        shows here and not only in the start-up log."""
+        eng = getattr(self.scheduler, "engine", None)
+        paged = bool(getattr(eng, "kv_block", 0))
+        return {"tp": getattr(eng, "tp", 1),
+                "paged_kv": paged,
+                "kv_blocks": eng.kv_blocks if paged else 0,
+                "kv_quantized": bool(getattr(eng, "kv_quantized",
+                                             False))}
 
     def begin_drain(self):
         """Flip this replica to draining: /ready answers 503 (the

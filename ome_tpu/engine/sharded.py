@@ -12,7 +12,12 @@ SURVEY.md §2.9; here TP is GSPMD over the mesh's "tp" axis):
     after the o-projection and MLP down-projection (ride ICI);
   * prefill/insert/decode are the same three compiled programs as the
     single-chip InferenceEngine — GSPMD propagates shardings from the
-    committed inputs, so the host-side scheduler code is unchanged.
+    committed inputs, so the host-side scheduler code is unchanged;
+  * the Pallas attention kernels run per device under shard_map over
+    "tp" (ops/attention.heads_sharded_over): GSPMD cannot partition a
+    Mosaic kernel. The int4 matmul kernel is not wrapped and stays
+    off under tp (XLA dequant), which /debug/programs lists as a
+    decline.
 
 This is what the LWS multi-host contract (controllers/reconcilers/
 multinode.py) targets: the same engine, mesh spanning hosts.
@@ -20,14 +25,12 @@ multinode.py) targets: the same engine, mesh spanning hosts.
 
 from __future__ import annotations
 
-from contextlib import nullcontext as _nullcontext
+import contextlib
 from typing import List, Optional
 
-import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..models import llama
 from ..models.config import ModelConfig
 from ..parallel.mesh import MeshConfig, build_mesh
 from ..parallel.sharding import shard_params
@@ -64,40 +67,42 @@ class ShardedInferenceEngine(InferenceEngine):
                          lora_slots=lora_slots, lora_rank=lora_rank,
                          ledger=ledger)
 
-    # tp-sharded weights must not hit the un-partitioned int4 Pallas
-    # kernel (GSPMD would replicate + all-gather the packed weight per
-    # step); the gate is a contextvar scoped around THIS engine's
-    # traces so tp=1 engines in the same process keep the fused path
-    def _no_int4_kernel(self):
+    @contextlib.contextmanager
+    def _tp_trace(self):
+        """Scope the two per-trace kernel decisions of a sharded
+        engine (contextvars, so tp=1 engines in the same process are
+        untouched): attention kernels run per device on their own
+        heads, and the un-partitioned int4 matmul kernel stays off —
+        GSPMD would replicate it and all-gather the packed weight
+        every step."""
+        from ..ops.attention import heads_sharded_over
         from ..ops.int4_matmul import kernel_disabled
-        return kernel_disabled() if self.tp > 1 else _nullcontext()
+        with heads_sharded_over(self.mesh), kernel_disabled():
+            yield
+
+    # every op that can trace a program runs inside the scope; GSPMD
+    # propagates the committed shardings (KV head-sharded, tokens and
+    # lengths replicated) through each of them, fori_loop carries and
+    # the multi-token verify forward included
 
     def prefill(self, *a, **kw):
-        with self._no_int4_kernel():
+        with self._tp_trace():
             return super().prefill(*a, **kw)
 
     def insert(self, *a, **kw):
-        with self._no_int4_kernel():
+        with self._tp_trace():
             return super().insert(*a, **kw)
 
     def decode(self, *a, **kw):
-        with self._no_int4_kernel():
+        with self._tp_trace():
             return super().decode(*a, **kw)
 
     def verify(self, *a, **kw):
-        # speculative verify is the same dense multi-token forward
-        # GSPMD already propagates shardings through (tokens/drafts
-        # replicated, KV head-sharded) — only the int4-kernel gate
-        # needs the decode treatment
-        with self._no_int4_kernel():
+        with self._tp_trace():
             return super().verify(*a, **kw)
 
     def decode_multi(self, *a, **kw):
-        # the fori_loop carry keeps the committed shardings (KV
-        # head-sharded, tokens/lengths replicated) — GSPMD propagates
-        # them through every iteration, so only the int4-kernel gate
-        # needs the decode treatment here too
-        with self._no_int4_kernel():
+        with self._tp_trace():
             return super().decode_multi(*a, **kw)
 
     def _kv_sharding(self) -> NamedSharding:
@@ -113,16 +118,19 @@ class ShardedInferenceEngine(InferenceEngine):
         return NamedSharding(self.mesh, P())
 
     def new_state(self) -> DecodeState:
+        # zeros are born sharded (`device=`): building the full
+        # [L, B, S, K, Dh] slab on one device and moving it would need
+        # the whole cache to fit on that device first
         cfg = self.cfg
         L, B, S = cfg.num_layers, self.max_slots, self.max_seq
         base = (L, B, S, cfg.kv_cache_heads)
         kv = self._kv_sharding()
         rep = self._replicated()
         return DecodeState(
-            k=jax.device_put(
-                jnp.zeros(base + (cfg.kv_cache_k_dim,), cfg.dtype), kv),
-            v=jax.device_put(
-                jnp.zeros(base + (cfg.kv_cache_v_dim,), cfg.dtype), kv),
-            lengths=jax.device_put(jnp.zeros((B,), jnp.int32), rep),
-            tokens=jax.device_put(jnp.zeros((B,), jnp.int32), rep),
-            adapters=jax.device_put(jnp.zeros((B,), jnp.int32), rep))
+            k=jnp.zeros(base + (cfg.kv_cache_k_dim,), cfg.dtype,
+                        device=kv),
+            v=jnp.zeros(base + (cfg.kv_cache_v_dim,), cfg.dtype,
+                        device=kv),
+            lengths=jnp.zeros((B,), jnp.int32, device=rep),
+            tokens=jnp.zeros((B,), jnp.int32, device=rep),
+            adapters=jnp.zeros((B,), jnp.int32, device=rep))

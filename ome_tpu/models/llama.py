@@ -712,6 +712,7 @@ def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
             positions: Optional[jax.Array] = None,
             cache: Optional[KVCache] = None,
             adapter_ids: Optional[jax.Array] = None,
+            logits_at: Optional[jax.Array] = None,
             ) -> Tuple[jax.Array, Optional[KVCache]]:
     """Run the decoder.
 
@@ -720,6 +721,12 @@ def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
     cache (serving decode/chunked prefill); without, plain causal prefill.
     `adapter_ids` ([B] int32) selects each row's LoRA adapter slot when
     the params carry multi-adapter factor stacks (engine/core.py).
+    `logits_at` ([B] int32) asks for the logits of ONE row per
+    sequence: the hidden state is cut to that row before the final
+    norm and head (both act row by row, so the mathematics is the
+    same) and logits come back [B, 1, vocab] — a serving prefill
+    samples from one row, and [1, S, vocab] in f32 is gigabytes at a
+    150k vocabulary.
     Returns (logits [B, S, vocab], updated cache or None).
     """
     B, S = tokens.shape
@@ -778,6 +785,8 @@ def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
         else:
             new_cache = None
 
+    if logits_at is not None:
+        x = jnp.take_along_axis(x, logits_at[:, None, None], axis=1)
     return _final_logits(params, cfg, x), new_cache
 
 

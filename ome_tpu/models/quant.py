@@ -27,11 +27,13 @@ at use by models/llama.py's weight accessor `_w`.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import logging
 from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 
 @dataclasses.dataclass
@@ -107,6 +109,14 @@ def _unpack4(q: jax.Array, s: jax.Array, axis: int) -> jax.Array:
     return out.reshape(pre + (K,) + post)
 
 
+# The three quantizers are jitted (contract_axes, a tuple, is static):
+# run op by op, one [36, 2560, 9728] leaf keeps four float32 copies of
+# itself alive at once, 14 GB, and quantizing a 4 B model on a 16 GB
+# chip runs out of memory at load. Fused, a leaf is read twice in its
+# own dtype and only the quantized tensor is written.
+
+
+@functools.partial(jax.jit, static_argnames=("contract_axes",))
 def quantize_tensor(w: jax.Array, contract_axes) -> QTensor:
     """Per-output-channel symmetric int8: scales span `contract_axes`
     (the dims the matmul sums over), so each output channel gets its
@@ -119,6 +129,7 @@ def quantize_tensor(w: jax.Array, contract_axes) -> QTensor:
     return QTensor(q=q, s=s, bits=8)
 
 
+@functools.partial(jax.jit, static_argnames=("contract_axes",))
 def quantize_tensor_fp8(w: jax.Array, contract_axes) -> QTensor:
     """Per-output-channel scaled float8_e4m3: same byte footprint as
     int8 but a floating 4-bit mantissa — the v6e-native weight format
@@ -133,14 +144,15 @@ def quantize_tensor_fp8(w: jax.Array, contract_axes) -> QTensor:
     return QTensor(q=q, s=s, bits=8)
 
 
+@functools.partial(jax.jit, static_argnames=("contract_axes", "group"))
 def quantize_tensor_int4(w: jax.Array, contract_axes,
                          group: int = 128) -> QTensor:
     """Groupwise symmetric int4, concat-packed along the first
     contraction axis. Falls back to one group when the axis doesn't
     split evenly into even-sized groups."""
+    w = jnp.asarray(w)
     axis = contract_axes[0]
-    w32 = jnp.asarray(w, jnp.float32)
-    K = w32.shape[axis]
+    K = w.shape[axis]
     if K % group == 0 and group % 2 == 0:
         n_groups = K // group
     elif K % 2 == 0:
@@ -148,19 +160,40 @@ def quantize_tensor_int4(w: jax.Array, contract_axes,
     else:
         raise ValueError(f"int4 needs an even packing dim, got {K}")
     gsize = K // n_groups
-    pre, post = w32.shape[:axis], w32.shape[axis + 1:]
-    wg = w32.reshape(pre + (n_groups, gsize) + post)
+    pre, post = w.shape[:axis], w.shape[axis + 1:]
+    # reshapes and slices stay in the leaf's own dtype and float32
+    # begins inside the fused passes: converting first made XLA keep
+    # a float32 copy of the leaf (3.6 GB for one stacked MLP
+    # projection of a 4 B model; tests/test_aot_tpu_compile.py)
+    wg = w.reshape(pre + (n_groups, gsize) + post)
     # scales span the group slice plus the OTHER contraction dims
     other = tuple(a + 1 if a > axis else a
                   for a in contract_axes[1:])
-    amax = jnp.max(jnp.abs(wg), axis=(axis + 1,) + other, keepdims=True)
+    amax = jnp.max(jnp.abs(wg.astype(jnp.float32)),
+                   axis=(axis + 1,) + other, keepdims=True)
     s = jnp.maximum(amax, 1e-8) / 7.0
-    qg = jnp.clip(jnp.round(wg / s), -7, 7).astype(jnp.int8)
-    qfull = qg.reshape(pre + (K,) + post)
-    lo, hi = jnp.split(qfull, 2, axis=axis)       # halves of the AXIS
-    packed = (hi << 4) | (lo & 0x0F)              # [., K/2, .]
+
+    def quantized(x, scale):
+        return jnp.clip(jnp.round(x.astype(jnp.float32) / scale),
+                        -7, 7).astype(jnp.int8)
+
+    if n_groups % 2 == 0:
+        # each nibble half is a run of whole groups: quantize the two
+        # runs where they lie and pack the second over the first
+        half = n_groups // 2
+        lo, hi = (quantized(
+            lax.slice_in_dim(wg, i * half, (i + 1) * half, axis=axis),
+            lax.slice_in_dim(s, i * half, (i + 1) * half, axis=axis))
+            for i in (0, 1))
+    else:
+        # one group (or an odd number): the halves cut through a
+        # group, so quantize whole and split the result
+        lo, hi = jnp.split(
+            quantized(wg, s).reshape(pre + (K,) + post), 2, axis=axis)
+    packed = ((hi << 4) | (lo & 0x0F)).reshape(
+        pre + (K // 2,) + post)                   # [., K/2, .]
     s = jnp.squeeze(s, axis=axis + 1)             # [., n_groups, .(1s)]
-    return QTensor(q=packed, s=s, bits=4, axis=axis - w32.ndim)
+    return QTensor(q=packed, s=s, bits=4, axis=axis - w.ndim)
 
 
 # contraction axes per stacked-layer leaf ([L, ...]; axis 0 = layer)

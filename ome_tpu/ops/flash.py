@@ -232,13 +232,11 @@ def flash_decode_quantized(q: jax.Array, kq: jax.Array, vq: jax.Array,
 
     Experimental building block, NOT wired into the engine: the KV
     read is the second-largest term in the decode step's HBM budget
-    after the weights (bench.py breakdown) and int8 halves it, but on
-    v5e the in-kernel int8->bf16 convert costs more than the halved
-    read saves (measured 8.8 vs 8.3 ms on the attention microbench —
-    BASELINE.md round-4 notes). Wire behind a --kv-cache-dtype flag
-    on chips where that trade flips; until then it ships
-    numerics-tested (tests/test_ops.py) but unreachable from serving
-    (r4 advisor low #4: the docstring must not claim otherwise).
+    after the weights and int8 halves it; whether the in-kernel
+    int8->bf16 convert costs more than the halved read saves is not
+    measured on the chip. It ships numerics-tested (tests/test_ops.py)
+    but unreachable from serving (the paged pool's --kv-dtype int8 is
+    the served int8 KV path).
     """
     B, Sq, H, D = q.shape
     assert Sq == 1

@@ -36,6 +36,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .. import device
+from . import note_decline
 from .flash import M_INIT, _decode_block_range, _decode_kernel
 
 
@@ -237,19 +239,24 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                     v_scale: Optional[jax.Array] = None,
                     backend: Optional[str] = None) -> jax.Array:
     """Dispatching entry: Pallas on TPU, XLA elsewhere (same contract
-    as ops/attention.attention). int8 pools pass k_scale/v_scale."""
+    as ops/attention.attention). int8 pools pass k_scale/v_scale.
+    Interpret mode runs only when asked for by name
+    ("pallas_interpret")."""
     import os
     if backend is None:
         backend = os.environ.get("OME_ATTN_BACKEND")
-    on_tpu = jax.devices()[0].platform == "tpu"
-    if backend in (None, "pallas", "pallas_interpret") and \
-            (on_tpu or backend is not None):
+    if backend is None:
+        backend = "pallas" if device.on_tpu() else "xla"
+    if backend in ("pallas", "pallas_interpret"):
         out = paged_flash_decode(
             q, k_pool, v_pool, table, kv_len, scale, logit_softcap,
             k_scale=k_scale, v_scale=v_scale,
-            interpret=(backend == "pallas_interpret" or not on_tpu))
+            interpret=(backend == "pallas_interpret"))
         if out is not None:
             return out
+        note_decline("paged_flash_decode",
+                     f"q{tuple(q.shape)} pool{tuple(k_pool.shape)} "
+                     f"outside the kernel's coverage")
     return paged_attention_xla(q, k_pool, v_pool, table, kv_len,
                                scale, logit_softcap,
                                k_scale=k_scale, v_scale=v_scale)

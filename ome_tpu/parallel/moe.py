@@ -23,7 +23,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..compat import shard_map
 from ..models.config import ModelConfig
 
 
@@ -68,7 +67,7 @@ def moe_mlp_ragged_ep(x: jax.Array, lp, cfg: ModelConfig, mesh: Mesh,
         out = lax.psum(out, axis)
         return out.reshape(B, S, D).astype(x.dtype)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(), P(), P(axis), P(axis), P(axis)),
         out_specs=P(),

@@ -30,7 +30,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..compat import shard_map
 
 M_INIT = -1.0e30
 
@@ -120,6 +119,6 @@ def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array,
             .astype(q.dtype)
 
     spec_q = P(None, axis, None, None)
-    return shard_map(local, mesh=mesh,
-                     in_specs=(spec_q, spec_q, spec_q),
-                     out_specs=spec_q, check_vma=False)(q, k, v)
+    return jax.shard_map(local, mesh=mesh,
+                         in_specs=(spec_q, spec_q, spec_q),
+                         out_specs=spec_q, check_vma=False)(q, k, v)

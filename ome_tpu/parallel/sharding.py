@@ -82,6 +82,15 @@ def param_specs(params: Dict[str, Any], pipeline: bool = False) -> Dict[str, Any
     return out
 
 
+def param_shardings(params, mesh: Mesh):
+    """NamedSharding pytree matching an unquantized `params` tree (or
+    its `jax.eval_shape`) — the out_shardings that make random weights
+    sharded from birth."""
+    return jax.tree.map(lambda spec: NamedSharding(mesh, spec),
+                        param_specs(params),
+                        is_leaf=lambda x: isinstance(x, P))
+
+
 def shard_params(params, mesh: Mesh, pipeline: bool = False):
     from ..models.quant import QTensor
 

@@ -5,8 +5,10 @@ model; the compiler already knows the truth. When the engine
 dispatches a program for the first time (one ledger entry per
 (program, static-args) pair — the jit compile key), the ledger asks
 the AOT path for it: `fn.lower(...).compile()` then
-`cost_analysis()` (FLOPs, bytes accessed) and `memory_analysis()`
-(argument/output/temp bytes). Off-TPU — where a second CPU compile
+`cost_analysis()` (FLOPs, bytes accessed), `memory_analysis()`
+(argument/output/temp bytes), how many Mosaic kernels the compiled
+program holds (`tpu_custom_call`) and which kernels declined while it
+was traced (ops/__init__.py). Off-TPU — where a second CPU compile
 of a production-sized model would be pure waste and the analysis is
 not the one serving runs — the ledger degrades to the analytic
 byte model the quantizer already maintains (models/quant.py
@@ -34,14 +36,25 @@ import time
 from collections import OrderedDict
 from typing import Dict, List, Optional
 
-# Per-chip HBM bandwidth (GB/s) and bf16 peak (TFLOP/s) by
-# generation; bench.py imports these so the offline and online
-# rooflines can never disagree about the device spec. CPU entries
-# keep the ratios defined in dev environments.
+from .. import device
+from ..ops import collect_declines
+
+# Published per-chip peaks, keyed by a substring of `device_kind`:
+# HBM bandwidth (GB/s) and bf16 peak (TFLOP/s). Source: Google Cloud
+# documentation, the "TPU v5e" page (197 TFLOP/s bf16, 819 GB/s, 16 GB
+# HBM; a v5e reports device_kind "TPU v5 lite") and its sibling pages
+# for v4, v5p and v6e. bench.py imports these so the offline and
+# online rooflines can never disagree about the device spec. An
+# accelerator that is not in the table is an error, never "a v5e".
 DEVICE_HBM_GBPS = {"v5 lite": 819.0, "v5e": 819.0, "v5p": 2765.0,
-                   "v6e": 1640.0, "v4": 1228.0, "cpu": 50.0}
+                   "v6e": 1640.0, "v4": 1228.0}
 DEVICE_PEAK_TFLOPS = {"v5 lite": 197.0, "v5e": 197.0, "v5p": 459.0,
-                      "v6e": 918.0, "v4": 275.0, "cpu": 0.2}
+                      "v6e": 918.0, "v4": 275.0}
+# The CPU row is not a measurement of anything: tier-1 builds ledger
+# entries for tiny programs on the CPU backend and the roofline
+# arithmetic needs a divisor. Whatever is priced from it carries
+# `platform: "cpu"` and is never printed under a device metric's name.
+_CPU_SPEC = {"hbm_gbps": 50.0, "peak_tflops": 0.2}
 
 LEDGER_MODES = ("auto", "full", "model", "off")
 
@@ -50,29 +63,26 @@ log = logging.getLogger("ome.perf.ledger")
 
 def device_spec(device=None) -> Dict[str, object]:
     """{kind, platform, hbm_gbps, peak_tflops} for `device` (default:
-    jax.devices()[0]). Matching mirrors bench.py's table lookup:
-    substring on device_kind, platform-keyed fallback."""
+    jax.devices()[0]; a process with no backend raises there). An
+    accelerator whose device_kind matches no table key raises
+    LookupError: pricing it as some other chip would put a wrong
+    roofline under a true device name."""
     import jax
     if device is None:
-        try:
-            device = jax.devices()[0]
-        except Exception:  # pragma: no cover - no backend at all
-            return {"kind": "unknown", "platform": "unknown",
-                    "hbm_gbps": DEVICE_HBM_GBPS["cpu"],
-                    "peak_tflops": DEVICE_PEAK_TFLOPS["cpu"]}
-    kind = str(getattr(device, "device_kind",
-                       getattr(device, "platform", "cpu"))).lower()
-    platform = str(getattr(device, "platform", "cpu"))
-
-    def _lookup(table):
-        for key, val in table.items():
-            if key in kind:
-                return val
-        return table["cpu" if platform == "cpu" else "v5e"]
-
-    return {"kind": kind, "platform": platform,
-            "hbm_gbps": _lookup(DEVICE_HBM_GBPS),
-            "peak_tflops": _lookup(DEVICE_PEAK_TFLOPS)}
+        device = jax.devices()[0]
+    platform = str(device.platform)
+    kind = str(device.device_kind).lower()
+    if platform == "cpu":
+        return {"kind": kind, "platform": platform, **_CPU_SPEC}
+    for key in DEVICE_HBM_GBPS:
+        if key in kind:
+            return {"kind": kind, "platform": platform,
+                    "hbm_gbps": DEVICE_HBM_GBPS[key],
+                    "peak_tflops": DEVICE_PEAK_TFLOPS[key]}
+    raise LookupError(
+        f"no published peaks for device_kind {device.device_kind!r} "
+        f"(platform {platform!r}); add it to perf/ledger.py with its "
+        f"source")
 
 
 def roofline_ms(flops: float, bytes_moved: float, hbm_gbps: float,
@@ -82,11 +92,6 @@ def roofline_ms(flops: float, bytes_moved: float, hbm_gbps: float,
     mem_s = bytes_moved / max(hbm_gbps * 1e9, 1e-9)
     compute_s = flops / max(peak_tflops * 1e12, 1e-9)
     return max(mem_s, compute_s) * 1000.0
-
-
-def _on_tpu() -> bool:
-    from ..ops.int4_matmul import _on_tpu_device
-    return _on_tpu_device()
 
 
 class ProgramLedger:
@@ -153,7 +158,7 @@ class ProgramLedger:
     def _resolved_mode(self) -> str:
         if self.mode != "auto":
             return self.mode
-        return "full" if _on_tpu() else "model"
+        return "full" if device.on_tpu() else "model"
 
     def capture(self, name: str, static_desc: str, fn, args,
                 static_kwargs: Dict[str, object],
@@ -201,7 +206,12 @@ class ProgramLedger:
             "argument_bytes": None,
             "output_bytes": None,
             "temp_bytes": None,
+            # Mosaic custom calls in the compiled text, and the
+            # kernels that declined while it was traced (full mode)
+            "mosaic_calls": None,
+            "kernel_declines": None,
             "device": spec["kind"],
+            "platform": spec["platform"],
             "dispatches": 0,
             "captured_unix": time.time(),
         }
@@ -217,10 +227,12 @@ class ProgramLedger:
         analytic-model numbers in place (never break a dispatch over
         observability)."""
         try:
-            lowered = fn.lower(*args, **static_kwargs)
+            with collect_declines() as declines:
+                lowered = fn.lower(*args, **static_kwargs)
         except Exception as e:
             self._warn_once("lower", entry["program"], e)
             return
+        entry["kernel_declines"] = sorted(set(declines))
         ca = None
         try:
             ca = lowered.cost_analysis()
@@ -249,6 +261,11 @@ class ProgramLedger:
             ma = compiled.memory_analysis()
         except Exception:
             ma = None
+        try:
+            entry["mosaic_calls"] = compiled.as_text().count(
+                "tpu_custom_call")
+        except Exception:
+            pass
         if ma is not None:
             entry["argument_bytes"] = int(
                 getattr(ma, "argument_size_in_bytes", 0))

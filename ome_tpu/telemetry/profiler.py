@@ -40,8 +40,10 @@ def capture(out_dir: str, seconds: float = 1.0, ledger=None) -> dict:
         raise ValueError(
             f"seconds must be in (0, {MAX_SECONDS:g}], got {seconds}")
     import jax
-    platform = jax.default_backend()
-    if platform != "tpu":
+
+    from .. import device
+    platform = jax.devices()[0].platform
+    if not device.on_tpu():
         result = {"captured": False, "platform": platform,
                   "note": "profiler capture is a no-op off-TPU"}
         if ledger is not None:

@@ -1,0 +1,178 @@
+#!/usr/bin/env python
+"""Compile the engine's whole programs for a described v5e, no chip.
+
+The chip's compiler is installed here (libtpu) and compiles for a TPU
+that is described, not attached (on-chip-measurement guide, section
+2). This hands the engine's own jitted programs shapes at Qwen3-4B's
+full size on a described v5e:2x2 and prints, per program, what the
+compiler says: compile seconds, argument / output / temporary bytes on
+each device, Mosaic custom calls, collectives. It refuses what does
+not fit the chip's memory and what cannot be partitioned, which is
+what a chip run would otherwise find on chip time. Nothing runs: these
+are facts about programs, never timings.
+
+    python scripts/aot_programs.py                      # one chip, paged
+    python scripts/aot_programs.py --kv-blocks 240      # refused: HBM
+    python scripts/aot_programs.py --quantization int4 --kv-dtype int8
+    python scripts/aot_programs.py --tp 4               # dense, sharded
+
+One process at a time can hold libtpu (/tmp/libtpu_lockfile).
+"""
+
+from __future__ import annotations
+
+import argparse
+import contextlib
+import json
+import os
+import re
+import sys
+import time
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+import numpy as np  # noqa: E402
+from jax.sharding import (Mesh, NamedSharding,  # noqa: E402
+                          PartitionSpec as P, SingleDeviceSharding)
+
+from chip_smoke import QWEN3_4B  # noqa: E402
+from ome_tpu import device  # noqa: E402
+from ome_tpu.engine.core import DecodeState, InferenceEngine  # noqa: E402
+from ome_tpu.models import llama  # noqa: E402
+from ome_tpu.models.config import ModelConfig  # noqa: E402
+from ome_tpu.perf.ledger import ProgramLedger  # noqa: E402
+
+COLLECTIVES = ("all-reduce", "all-gather", "all-to-all",
+               "collective-permute", "reduce-scatter")
+
+
+def report(name: str, lowered) -> None:
+    t0 = time.time()
+    compiled = lowered.compile()
+    ma, text = compiled.memory_analysis(), compiled.as_text()
+    print(json.dumps({
+        "program": name, "compile_s": round(time.time() - t0, 1),
+        "arg_gb": round(ma.argument_size_in_bytes / 1e9, 3),
+        "out_gb": round(ma.output_size_in_bytes / 1e9, 3),
+        "temp_gb": round(ma.temp_size_in_bytes / 1e9, 3),
+        "mosaic_calls": text.count("tpu_custom_call"),
+        "collectives": {c: len(re.findall(rf"\b{c}(-start)?\(", text))
+                        for c in COLLECTIVES}}), flush=True)
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
+    ap.add_argument("--layers", type=int, default=36)
+    ap.add_argument("--slots", type=int, default=16)
+    ap.add_argument("--max-seq", type=int, default=2048)
+    ap.add_argument("--kv-blocks", type=int, default=198)
+    ap.add_argument("--kv-dtype", default="bf16")
+    ap.add_argument("--quantization", default="none")
+    ap.add_argument("--tp", type=int, default=1, choices=(1, 4))
+    ap.add_argument("--buckets", default="64,2048")
+    args = ap.parse_args()
+
+    # code that asks the device still sees the CPU here; steering it
+    # is this script's job, not an option of the program
+    device.on_tpu = lambda: True
+    from jax.experimental import topologies
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    cfg = ModelConfig.from_hf_config(
+        dict(QWEN3_4B, num_hidden_layers=args.layers))
+
+    def init():
+        p = llama.init_params(jax.random.PRNGKey(0), cfg)
+        if args.quantization != "none":
+            from ome_tpu.models.quant import quantize_params
+            p = quantize_params(p, mode=args.quantization)
+        return p
+
+    shapes = jax.eval_shape(init)
+    scope = contextlib.nullcontext()
+    if args.tp == 1:
+        one = SingleDeviceSharding(topo.devices[0])
+        rep = kv_sh = one
+        shardings = jax.tree.map(lambda _: one, shapes)
+    else:
+        from ome_tpu.ops.attention import heads_sharded_over
+        from ome_tpu.parallel.sharding import param_shardings
+        mesh = Mesh(np.array(topo.devices).reshape(1, 1, 4),
+                    ("dp", "pp", "tp"))
+        rep = NamedSharding(mesh, P())
+        kv_sh = NamedSharding(mesh, P(None, None, None, "tp", None))
+        shardings = param_shardings(shapes, mesh)
+        scope = heads_sharded_over(mesh)
+
+    def S(shape, dtype, sharding=rep):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    params = jax.tree.map(lambda l, s: S(l.shape, l.dtype, s),
+                          shapes, shardings)
+    paged = args.tp == 1
+    eng = InferenceEngine(
+        params, cfg, max_slots=args.slots, max_seq=args.max_seq,
+        kv_block=128 if paged else 0,
+        kv_blocks=args.kv_blocks if paged else None,
+        kv_dtype=args.kv_dtype if paged else None,
+        ledger=ProgramLedger("off"))
+    L, B, Kh, Dh = cfg.num_layers, args.slots, cfg.num_kv_heads, \
+        cfg.head_dim
+    i32, f32 = jnp.int32, jnp.float32
+    if paged:
+        quantized = args.kv_dtype == "int8"
+        pool = S((L, args.kv_blocks, 128, Kh, Dh),
+                 jnp.int8 if quantized else cfg.dtype)
+        scale = S((L, args.kv_blocks, Kh, 128), f32) \
+            if quantized else None
+        state = DecodeState(k=pool, v=pool, lengths=S((B,), i32),
+                            tokens=S((B,), i32),
+                            adapters=S((B,), i32),
+                            k_scale=scale, v_scale=scale)
+    else:
+        slab = S((L, B, args.max_seq, Kh, Dh), cfg.dtype, kv_sh)
+        state = DecodeState(k=slab, v=slab, lengths=S((B,), i32),
+                            tokens=S((B,), i32),
+                            adapters=S((B,), i32))
+    key = S((2,), jnp.uint32)
+
+    def sampling(n):
+        return S((n,), f32), S((n,), i32), S((n,), f32)
+
+    scalar = S((), i32)
+    with scope:
+        for b in (int(x) for x in args.buckets.split(",")):
+            report(f"prefill[bucket={b}]", eng._prefill_fn.lower(
+                params, S((1, b), i32), S((1,), i32), *sampling(1),
+                key, S((1,), i32), bucket=b))
+            kv = S((L, 1, b, Kh, Dh), cfg.dtype, kv_sh)
+            if paged:
+                report(f"insert_paged[bucket={b}]",
+                       eng._insert_paged_fn.lower(
+                           state, kv, kv, S((-(-b // 128),), i32),
+                           scalar, scalar, scalar, scalar, bucket=b))
+            else:
+                report(f"insert[bucket={b}]", eng._insert_fn.lower(
+                    state, kv, kv, scalar, scalar, scalar, scalar,
+                    bucket=b))
+        if paged:
+            table = S((B, eng.max_blocks), i32)
+            report("decode_paged", eng._decode_paged_fn.lower(
+                params, state, table, *sampling(B), key))
+            report("decode_multi_paged[n=4]",
+                   eng._decode_multi_paged_fn.lower(
+                       params, state, table, *sampling(B), key,
+                       S((B,), i32), S((B, 4), i32), n=4))
+        else:
+            report("decode", eng._decode_fn.lower(
+                params, state, *sampling(B), key))
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -1,6 +1,13 @@
 #!/usr/bin/env python
-"""Bench regression gate: diff fresh bench.py output against the
-checked-in BENCH_r*.json history and fail on real regressions.
+"""Bench regression gate: diff fresh bench.py output against a
+history of BENCH_r*.json results (--history GLOB, or one --baseline
+file) and fail on real regressions. The repo keeps no history of its
+own any more (ROADMAP C6: the driver's paired runs and
+PERF_LEDGER.jsonl are the gate); tests pass a fixture.
+
+This script imports no JAX and must stay so: it runs bench.py as a
+child process, and a parent that has touched JAX holds the chip the
+child needs.
 
 The BENCH files record best-of-N numbers per round, so run-to-run
 noise is already partly squeezed out — but not gone. The gate is
@@ -24,10 +31,10 @@ program variant from the newest round's breakdowns) — the calibration
 artifact the fleet capacity simulator consumes (ROADMAP item 6).
 
 Usage:
-  python scripts/perfgate.py                      # fresh bench vs history
-  python scripts/perfgate.py --bench-json out.json
-  python scripts/perfgate.py --check-only         # validate history only
-  make benchgate
+  python scripts/perfgate.py --history 'runs/BENCH_r*.json' --run
+  python scripts/perfgate.py --history GLOB --bench-json out.json
+  python scripts/perfgate.py --history GLOB --check-only
+  make benchgate BENCH_HISTORY='runs/BENCH_r*.json'
 
 Exit codes: 0 pass, 1 regression, 2 usage/configuration error.
 """
@@ -288,10 +295,10 @@ def main(argv=None) -> int:
                     help="with --run: also save the fresh result here")
     ap.add_argument("--baseline", default=None,
                     help="explicit baseline JSON (default: newest "
-                         "BENCH_r*.json in the repo root)")
-    ap.add_argument("--history",
-                    default=os.path.join(REPO, "BENCH_r*.json"),
-                    help="history glob used when --baseline is unset")
+                         "match of --history)")
+    ap.add_argument("--history", default=None, metavar="GLOB",
+                    help="history glob of BENCH_r*.json results, used "
+                         "when --baseline is unset")
     ap.add_argument("--waivers",
                     default=os.path.join(REPO, "bench-waivers.json"),
                     help="waiver file (JSON list of {metric, reason}); "
@@ -309,8 +316,12 @@ def main(argv=None) -> int:
     try:
         if args.baseline:
             base_path, base = args.baseline, load_bench(args.baseline)
-        else:
+        elif args.history:
             base_path, base = newest_history(args.history)
+        else:
+            print("perfgate: need --history or --baseline",
+                  file=sys.stderr)
+            return 2
         if base is None:
             print(f"perfgate: no baseline matches {args.history}",
                   file=sys.stderr)

@@ -1,0 +1,188 @@
+"""Main-path kernels through the chip's own compiler, without the chip.
+
+libtpu is installed here and compiles for a TPU that is described, not
+attached (on-chip-measurement guide, section 2). Interpret mode cannot
+see what Mosaic refuses — a scale block of 10 sublanes, a kernel GSPMD
+cannot partition — so the kernels the serve path runs at Qwen3-4B
+widths (32 heads / 8 KV heads, head_dim 128, hidden 2560, MLP 9728,
+KV block 128) are compiled here for a described v5e:2x2. A compile
+that passes is not a chip run; it says the kernel is accepted, nothing
+about its results or its speed.
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
+
+from ome_tpu import device
+from ome_tpu.models.quant import QTensor
+from ome_tpu.ops import attention as attn_ops
+from ome_tpu.ops import flash, int4_matmul, paged
+
+B, H, K, D, BS = 16, 32, 8, 128, 128   # slots, heads, KV heads, dims
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu, or it cannot describe a v5e
+        pytest.skip(f"cannot describe a v5e:2x2 topology: {e}")
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _no_compile_cache():
+    """A compile for a described chip is written to the persistent
+    cache but cannot be read back without the chip (the next one
+    warns and recompiles), so the cache is off around this module."""
+    from jax.experimental.compilation_cache import compilation_cache
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def one_chip(topo):
+    sharding = SingleDeviceSharding(topo.devices[0])
+
+    def struct(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+    return struct
+
+
+def _compile(fn, *args):
+    return jax.jit(fn).lower(*args).compile()
+
+
+@pytest.mark.parametrize("pool_dtype", ["bf16", "int8"])
+def test_paged_flash_decode(one_chip, pool_dtype):
+    n_blocks, max_blocks = 257, 16
+    dt = jnp.int8 if pool_dtype == "int8" else jnp.bfloat16
+    pool = one_chip((n_blocks, BS, K, D), dt)
+    scale = (one_chip((n_blocks, K, BS), jnp.float32)
+             if pool_dtype == "int8" else None)
+
+    def f(q, kp, vp, table, kv_len, ks, vs):
+        out = paged.paged_flash_decode(q, kp, vp, table, kv_len,
+                                       k_scale=ks, v_scale=vs)
+        assert out is not None, "kernel declined the serve-path shape"
+        return out
+
+    c = _compile(f, one_chip((B, 1, H, D), jnp.bfloat16), pool, pool,
+                 one_chip((B, max_blocks), jnp.int32),
+                 one_chip((B,), jnp.int32), scale, scale)
+    assert "tpu_custom_call" in c.as_text()
+
+
+def test_flash_decode_dense_slab(one_chip):
+    S = 4096
+    kv = one_chip((B, S, K, D), jnp.bfloat16)
+
+    def f(q, k, v, lo, hi):
+        out = flash._flash_decode(q, k, v, lo, hi, D ** -0.5, None,
+                                  False)
+        assert out is not None
+        return out
+
+    c = _compile(f, one_chip((B, 1, H, D), jnp.bfloat16), kv, kv,
+                 one_chip((B,), jnp.int32), one_chip((B,), jnp.int32))
+    assert "tpu_custom_call" in c.as_text()
+
+
+@pytest.mark.parametrize("S", [512, 2048])
+def test_flash_prefill(one_chip, S):
+    kv = one_chip((1, S, K, D), jnp.bfloat16)
+
+    def f(q, k, v, base, kv_hi):
+        out = flash._flash_prefill(q, k, v, base, kv_hi, D ** -0.5,
+                                   None, None, False)
+        assert out is not None
+        return out
+
+    c = _compile(f, one_chip((1, S, H, D), jnp.bfloat16), kv, kv,
+                 one_chip((1,), jnp.int32), one_chip((1,), jnp.int32))
+    assert "tpu_custom_call" in c.as_text()
+
+
+@pytest.mark.parametrize("k,n", [(2560, 9728), (9728, 2560),
+                                 (4096, 14336)])
+def test_int4_matmul_compiles_or_declines(one_chip, monkeypatch, k, n):
+    """K=2560 and K=9728 are the widths Mosaic refused before the
+    scale blocks were re-viewed (10 and 38 groups a half); whatever a
+    later change does to the block choice, the kernel either compiles
+    or declines with None — it never raises at trace time, because
+    that would crash `--quantization int4` instead of falling back."""
+    monkeypatch.setattr(device, "on_tpu", lambda: True)
+    group = 128
+    declined = []
+
+    def f(x, q, s):
+        y = int4_matmul.int4_matmul(
+            x, QTensor(q=q, s=s, bits=4, axis=-2))
+        if y is None:
+            declined.append(True)
+            return x
+        return y
+
+    c = _compile(f, one_chip((B, k), jnp.bfloat16),
+                 one_chip((k // 2, n), jnp.int8),
+                 one_chip((k // group, n), jnp.float32))
+    assert declined or "tpu_custom_call" in c.as_text()
+
+
+@pytest.mark.parametrize("sq,skv", [(1, 2048), (2048, 2048)])
+def test_tp4_attention_under_engine_shardings(topo, monkeypatch, sq,
+                                              skv):
+    """The sharded engine's layout: q on heads, the cache on KV heads,
+    over the "tp" axis of a (dp, pp, tp) mesh. GSPMD refuses to
+    partition a Mosaic kernel, so attention() runs it per device under
+    shard_map — and because heads mix nothing, the program holds the
+    kernel and NO collective."""
+    monkeypatch.setattr(device, "on_tpu", lambda: True)
+    import numpy as np
+    mesh = Mesh(np.array(topo.devices).reshape(1, 1, 4),
+                ("dp", "pp", "tp"))
+    heads = NamedSharding(mesh, P(None, None, "tp", None))
+    rep = NamedSharding(mesh, P())
+
+    def f(q, k, v, positions, kv_len):
+        with attn_ops.heads_sharded_over(mesh):
+            return attn_ops.attention(q, k, v, positions=positions,
+                                      kv_len=kv_len, backend="pallas")
+
+    b = B if sq == 1 else 1       # decode batch, or one prefill
+    c = _compile(
+        f, jax.ShapeDtypeStruct((b, sq, H, D), jnp.bfloat16,
+                                sharding=heads),
+        *[jax.ShapeDtypeStruct((b, skv, K, D), jnp.bfloat16,
+                               sharding=heads)] * 2,
+        jax.ShapeDtypeStruct((b, sq), jnp.int32, sharding=rep),
+        jax.ShapeDtypeStruct((b,), jnp.int32, sharding=rep))
+    text = c.as_text()
+    assert "tpu_custom_call" in text
+    for collective in ("all-reduce", "all-gather", "all-to-all",
+                       "collective-permute", "reduce-scatter"):
+        assert collective not in text, collective
+
+
+def test_int4_quantizer_keeps_no_float32_copy(one_chip):
+    """`--quantization int4` quantizes on the device, beside the
+    weights it is replacing. One stacked MLP projection of Qwen3-4B is
+    1.8 GB in bf16; the quantizer used to hold float32 copies of it
+    (3.6 GB each), which does not fit a 16 GB chip that already holds
+    the 8 GB model."""
+    from ome_tpu.models.quant import quantize_tensor_int4
+    w = one_chip((36, 2560, 9728), jnp.bfloat16)
+    c = quantize_tensor_int4.lower(w, (1,), group=128).compile()
+    assert c.memory_analysis().temp_size_in_bytes < 256 << 20

@@ -23,11 +23,15 @@ def _check(x, w, contract_axes, group):
                                atol=2e-2 * np.abs(want).max())
 
 
-def test_kernel_matches_dequant_gate_layout():
-    # w_gate-style [K, N], pack axis leading
+@pytest.mark.parametrize("k", [1024, 2560, 4864])
+def test_kernel_matches_dequant_gate_layout(k):
+    # w_gate-style [K, N], pack axis leading. K=2560 (Qwen3-4B's
+    # hidden) has 10 scale groups a nibble half in one k-step, 4864
+    # has 19 in steps of one: neither is a multiple of 8 sublanes,
+    # which the scale blocks' [half*steps, groups, N] view allows
     rng = np.random.default_rng(0)
-    _check(rng.standard_normal((16, 1024), dtype=np.float32),
-           rng.standard_normal((1024, 512), dtype=np.float32),
+    _check(rng.standard_normal((16, k), dtype=np.float32),
+           rng.standard_normal((k, 256), dtype=np.float32),
            contract_axes=(0,), group=128)
 
 

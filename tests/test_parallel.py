@@ -8,7 +8,6 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from ome_tpu.compat import set_mesh
 from ome_tpu.models import config as cfgs
 from ome_tpu.models import llama
 from ome_tpu.parallel import pipeline, sharding
@@ -76,7 +75,7 @@ class TestPipelineEquivalence:
 
         staged = sharding.stack_to_stages(params, 2)
         staged = sharding.shard_params(staged, mesh8, pipeline=True)
-        with set_mesh(mesh8):
+        with jax.set_mesh(mesh8):
             out = jax.jit(lambda p, t: pipeline.pipeline_forward(
                 p, cfg, t, pp=2, num_microbatches=2, mesh=mesh8))(staged, tokens)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref_logits),
@@ -90,7 +89,7 @@ class TestPipelineEquivalence:
         ref_logits, _ = llama.forward(params, cfg, tokens)
         staged = sharding.stack_to_stages(params, 2)
         staged = sharding.shard_params(staged, mesh8, pipeline=True)
-        with set_mesh(mesh8):
+        with jax.set_mesh(mesh8):
             out = jax.jit(lambda p, t: pipeline.pipeline_forward(
                 p, cfg, t, pp=2, num_microbatches=4, mesh=mesh8))(staged, tokens)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref_logits),
@@ -114,7 +113,7 @@ class TestPipelineEquivalence:
         ref_logits, _ = llama.forward(params, cfg, tokens)
         staged = sharding.stack_to_stages(params, 2)
         staged = sharding.shard_params(staged, mesh8, pipeline=True)
-        with set_mesh(mesh8):
+        with jax.set_mesh(mesh8):
             out = jax.jit(lambda p, t: pipeline.pipeline_forward(
                 p, cfg, t, pp=2, num_microbatches=2, mesh=mesh8))(staged,
                                                                   tokens)
@@ -138,7 +137,7 @@ class TestTrainStep:
         mesh_cfg = MeshConfig(dp=2, pp=2, tp=2)
         train_step, init_state = train_step_lib.make_train_step(
             cfg, mesh8, mesh_cfg, num_microbatches=4, lr=1e-2)
-        with set_mesh(mesh8):
+        with jax.set_mesh(mesh8):
             params, opt_state = init_state(jax.random.PRNGKey(0))
             tokens = jax.random.randint(jax.random.PRNGKey(1), (8, 16), 0,
                                         cfg.vocab_size)

@@ -76,6 +76,37 @@ class TestLedger:
         assert spec["platform"] == "cpu"
         assert spec["hbm_gbps"] > 0 and spec["peak_tflops"] > 0
 
+    def test_unknown_accelerator_is_an_error(self):
+        # a chip that is not in the table is never priced as a v5e
+        class Dev:
+            platform, device_kind = "tpu", "TPU v9 imaginary"
+        with pytest.raises(LookupError, match="no published peaks"):
+            device_spec(Dev())
+
+        class V5e:
+            platform, device_kind = "tpu", "TPU v5 lite"
+        spec = device_spec(V5e())
+        assert (spec["hbm_gbps"], spec["peak_tflops"]) == (819.0, 197.0)
+
+    def test_full_entry_names_platform_kernels_and_declines(self):
+        import jax.numpy as jnp
+
+        from ome_tpu.ops import note_decline
+
+        @jax.jit
+        def f(a):
+            note_decline("some_kernel", "shape not covered")
+            return a + 1
+
+        led = ProgramLedger(mode="full")
+        entry = led.capture("p", "", f, (jnp.ones((8,)),), {},
+                            {"flops": 1.0, "bytes": 1.0})
+        # priced from the CPU row: says so, and holds no Mosaic call
+        assert entry["platform"] == "cpu"
+        assert entry["mosaic_calls"] == 0
+        assert entry["kernel_declines"] == [
+            "some_kernel: shape not covered"]
+
     def test_capture_model_fallback_off_tpu(self):
         # mode "auto" resolves to the analytic model off-TPU — the
         # acceptance path for TPU-less CI: no second compile, no crash
@@ -381,6 +412,42 @@ class TestRooflineOnline:
         sched.update_gauges()
         assert sched.registry.get("ome_engine_hbm_bytes_in_use") > 0
 
+    def test_compile_dispatch_stays_out_of_the_queue_wait_estimate(
+            self):
+        """A program's first dispatch includes its compilation. On the
+        chip that one sample (tens of seconds) held the estimate over
+        the admission cap and an idle server answered 429; the
+        estimator takes a program's step times from its second
+        dispatch on."""
+        class SlowFirstLedger:
+            mode = "model"
+            entry = {"dispatches": 0, "bytes": 1.0, "expected_ms": 1.0,
+                     "program": "decode"}
+
+            def last_dispatch(self):
+                return self.entry
+
+        eng = FakeEngine(max_slots=1, decode_s=0.001)
+        eng.ledger = SlowFirstLedger()
+        decode = eng.decode
+
+        def compile_then_decode(*a, **kw):
+            eng.ledger.entry["dispatches"] += 1
+            if eng.ledger.entry["dispatches"] == 1:
+                time.sleep(0.3)          # the "compilation"
+            return decode(*a, **kw)
+
+        eng.decode = compile_then_decode
+        sched = Scheduler(eng)
+        req = Request(id="r1", prompt_ids=[1], max_new_tokens=6)
+        sched.submit(req)
+        deadline = time.monotonic() + 30
+        while not req.done.is_set() and time.monotonic() < deadline:
+            sched.step()
+        assert req.done.is_set()
+        assert sched._ewma_step_s is not None
+        assert sched._ewma_step_s < 0.1
+
 
 # -- profiler ride-along ---------------------------------------------
 
@@ -399,7 +466,16 @@ class TestProfilerLedger:
 # -- perfgate --------------------------------------------------------
 
 
+# the shape of one bench.py result in perfgate's wrapper — a fixture
+# for the gate's arithmetic, not a record of any run
+HISTORY = os.path.join(REPO, "tests", "data", "bench_history",
+                       "BENCH_r*.json")
+HISTORY_R05 = HISTORY.replace("*", "05")
+
+
 def _gate(*args):
+    if "--history" not in args:
+        args = ("--history", HISTORY, *args)
     return subprocess.run(
         [sys.executable, PERFGATE, *args],
         capture_output=True, text=True, cwd=REPO, timeout=120)
@@ -412,7 +488,7 @@ class TestPerfgate:
         assert "check-only OK" in r.stdout
 
     def test_identical_rerun_passes(self, tmp_path):
-        base = json.load(open(os.path.join(REPO, "BENCH_r05.json")))
+        base = json.load(open(HISTORY_R05))
         fresh = tmp_path / "fresh.json"
         fresh.write_text(json.dumps(base))
         r = _gate("--bench-json", str(fresh))
@@ -420,7 +496,7 @@ class TestPerfgate:
         assert "perfgate: pass" in r.stdout
 
     def test_decode_regression_fails(self, tmp_path):
-        base = json.load(open(os.path.join(REPO, "BENCH_r05.json")))
+        base = json.load(open(HISTORY_R05))
         base["parsed"]["value"] *= 0.9  # synthetic 10% decode loss
         fresh = tmp_path / "fresh.json"
         fresh.write_text(json.dumps(base))
@@ -429,7 +505,7 @@ class TestPerfgate:
         assert "REGRESSION" in r.stdout and "value" in r.stdout
 
     def test_waiver_downgrades_to_warning(self, tmp_path):
-        base = json.load(open(os.path.join(REPO, "BENCH_r05.json")))
+        base = json.load(open(HISTORY_R05))
         base["parsed"]["value"] *= 0.9
         fresh = tmp_path / "fresh.json"
         fresh.write_text(json.dumps(base))
@@ -442,7 +518,7 @@ class TestPerfgate:
         assert "WAIVED: accepted for ISSUE-12" in r.stdout
 
     def test_improvement_never_fails(self, tmp_path):
-        base = json.load(open(os.path.join(REPO, "BENCH_r05.json")))
+        base = json.load(open(HISTORY_R05))
         base["parsed"]["value"] *= 1.5
         base["parsed"]["prefill_ms_batch32x128"] *= 0.5
         fresh = tmp_path / "fresh.json"
@@ -465,7 +541,7 @@ class TestPerfgate:
         the ^composition. bands and export to the cost table: a cell
         losing throughput regresses; its fitted cost ships to the
         fleet simulator as a composed_* program."""
-        base = json.load(open(os.path.join(REPO, "BENCH_r05.json")))
+        base = json.load(open(HISTORY_R05))
         base["parsed"]["composition"] = {
             "cells": {"spec4_k4_d1": {
                 "tokens_per_sec": 5000.0, "accept_rate": 0.8,

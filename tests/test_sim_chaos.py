@@ -268,7 +268,8 @@ class TestSpawnAndWarmup:
         perfgate = _ilu.module_from_spec(spec)
         spec.loader.exec_module(perfgate)
         parsed = json.loads(
-            (REPO / "BENCH_r05.json").read_text())["parsed"]
+            (REPO / "tests" / "data" / "bench_history"
+             / "BENCH_r05.json").read_text())["parsed"]
         parsed = dict(parsed, warmup_ms=1234.5)
         table = perfgate.cost_table(parsed, "BENCH_r05.json")
         assert table["warmup_ms"] == 1234.5

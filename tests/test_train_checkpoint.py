@@ -6,7 +6,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ome_tpu.compat import set_mesh
 from ome_tpu.models.config import tiny_test
 from ome_tpu.parallel.mesh import MeshConfig, build_mesh
 from ome_tpu.train import step as ts
@@ -31,7 +30,7 @@ def _setup(mesh_cfg):
 def test_save_restore_resume_identical(tmp_path):
     mc = MeshConfig(dp=2, tp=2)
     mesh, train_step, init_state, tokens, targets = _setup(mc)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         params, opt = init_state(jax.random.PRNGKey(0))
         for step_i in range(2):
             params, opt, loss = train_step(params, opt, tokens, targets)
@@ -53,14 +52,14 @@ def test_save_restore_resume_identical(tmp_path):
 def test_restore_onto_different_mesh(tmp_path):
     mc_a = MeshConfig(dp=4, tp=1)
     mesh, train_step, init_state, tokens, targets = _setup(mc_a)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         params, opt = init_state(jax.random.PRNGKey(0))
         params, opt, loss_a = train_step(params, opt, tokens, targets)
         save_train_state(str(tmp_path / "c"), 1, params, opt)
 
     mc_b = MeshConfig(dp=1, tp=2)
     mesh_b, train_step_b, init_state_b, tokens_b, targets_b = _setup(mc_b)
-    with set_mesh(mesh_b):
+    with jax.set_mesh(mesh_b):
         p_like, o_like = init_state_b(jax.random.PRNGKey(1))
         _, params_b, opt_b = restore_train_state(str(tmp_path / "c"),
                                                  p_like, o_like)
@@ -73,7 +72,7 @@ def test_restore_onto_different_mesh(tmp_path):
 
 def _continue_once(mc, tmp_path):
     mesh, train_step, init_state, tokens, targets = _setup(mc)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         p_like, o_like = init_state(jax.random.PRNGKey(2))
         _, params, opt = restore_train_state(str(tmp_path / "c"),
                                              p_like, o_like)
