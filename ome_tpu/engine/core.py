@@ -33,9 +33,15 @@ from jax import lax
 from .. import device
 from ..models import llama
 from ..models.config import ModelConfig
-from .sampling import sample, spec_verify
+from ..telemetry.scopes import scoped
+from . import sampling
 
 Params = llama.Params
+
+# every sampling call site of the programs below traces under the
+# `sample` phase (telemetry/scopes.py)
+sample = scoped("sample")(sampling.sample)
+spec_verify = scoped("sample")(sampling.spec_verify)
 
 log = logging.getLogger("ome.engine.core")
 
@@ -553,6 +559,7 @@ class InferenceEngine:
         cfg_ = cfg
 
         @functools.partial(jax.jit, static_argnames=("bucket",))
+        @scoped("prefill")
         def _prefill(params, padded: jax.Array, true_len: jax.Array,
                      temperature, top_k, top_p, key, adapter,
                      bucket: int):
@@ -568,6 +575,7 @@ class InferenceEngine:
 
         @functools.partial(jax.jit,
                            static_argnames=("total_bucket", "keep"))
+        @scoped("prefill")
         def _prefill_suffix(params, prefix_k, prefix_v,
                             prefix_len: jax.Array, padded: jax.Array,
                             suffix_len: jax.Array, temperature, top_k,
@@ -597,6 +605,7 @@ class InferenceEngine:
 
         @functools.partial(jax.jit, donate_argnums=(0,),
                            static_argnames=("bucket",))
+        @scoped("insert")
         def _insert(state: DecodeState, kv_k, kv_v, slot: jax.Array,
                     true_len: jax.Array, token: jax.Array,
                     adapter: jax.Array, bucket: int):
@@ -612,6 +621,7 @@ class InferenceEngine:
                 adapters=state.adapters.at[slot].set(adapter))
 
         @functools.partial(jax.jit, donate_argnums=(1,))
+        @scoped("decode")
         def _decode(params, state: DecodeState, temperature, top_k, top_p,
                     key) -> Tuple[DecodeState, jax.Array]:
             cache = llama.KVCache(k=state.k, v=state.v, index=state.lengths)
@@ -625,6 +635,7 @@ class InferenceEngine:
                                adapters=state.adapters), toks
 
         @functools.partial(jax.jit, donate_argnums=(1,))
+        @scoped("decode")
         def _decode_masked(params, state: DecodeState, temperature,
                            top_k, top_p, key, mask,
                            ) -> Tuple[DecodeState, jax.Array]:
@@ -644,6 +655,7 @@ class InferenceEngine:
                                adapters=state.adapters), toks
 
         @functools.partial(jax.jit, static_argnames=("bucket",))
+        @scoped("prefill")
         def _prefill_masked(params, padded, true_len, temperature,
                             top_k, top_p, key, mask, adapter,
                             bucket: int):
@@ -663,6 +675,7 @@ class InferenceEngine:
 
         @functools.partial(jax.jit, donate_argnums=(0,),
                            static_argnames=("bucket",))
+        @scoped("insert")
         def _insert_paged(state: DecodeState, kv_k, kv_v,
                           block_ids: jax.Array, slot: jax.Array,
                           true_len: jax.Array, token: jax.Array,
@@ -711,6 +724,7 @@ class InferenceEngine:
                 k_scale=ksc, v_scale=vsc)
 
         @functools.partial(jax.jit, donate_argnums=(1,))
+        @scoped("decode")
         def _decode_paged(params, state: DecodeState, table,
                           temperature, top_k, top_p, key):
             cache = llama.PagedKVCache(k=state.k, v=state.v,
@@ -728,6 +742,7 @@ class InferenceEngine:
                                v_scale=nc.v_scale), toks
 
         @functools.partial(jax.jit, donate_argnums=(1,))
+        @scoped("decode")
         def _decode_masked_paged(params, state: DecodeState, table,
                                  temperature, top_k, top_p, key, mask):
             cache = llama.PagedKVCache(k=state.k, v=state.v,
@@ -809,6 +824,7 @@ class InferenceEngine:
 
         @functools.partial(jax.jit, donate_argnums=(1,),
                            static_argnames=("n",))
+        @scoped("decode")
         def _decode_multi(params, state: DecodeState, temperature,
                           top_k, top_p, key, budget, stop_ids,
                           n: int):
@@ -835,6 +851,7 @@ class InferenceEngine:
 
         @functools.partial(jax.jit, donate_argnums=(1,),
                            static_argnames=("n",))
+        @scoped("decode")
         def _decode_multi_paged(params, state: DecodeState, table,
                                 temperature, top_k, top_p, key,
                                 budget, stop_ids, n: int):
@@ -860,6 +877,7 @@ class InferenceEngine:
 
         @functools.partial(jax.jit, donate_argnums=(1,),
                            static_argnames=("n",))
+        @scoped("decode")
         def _decode_multi_masked(params, state: DecodeState,
                                  temperature, top_k, top_p, key,
                                  budget, stop_ids, mask, n: int):
@@ -880,6 +898,7 @@ class InferenceEngine:
 
         @functools.partial(jax.jit, donate_argnums=(1,),
                            static_argnames=("n",))
+        @scoped("decode")
         def _decode_multi_masked_paged(params, state: DecodeState,
                                        table, temperature, top_k,
                                        top_p, key, budget, stop_ids,
@@ -901,6 +920,7 @@ class InferenceEngine:
 
         @functools.partial(jax.jit, donate_argnums=(1,),
                            static_argnames=("k",))
+        @scoped("verify")
         def _verify(params, state: DecodeState, drafts, draft_len,
                     temperature, top_k, top_p, key, k: int):
             """Speculative verify: one forward over [last_token,
@@ -927,6 +947,7 @@ class InferenceEngine:
 
         @functools.partial(jax.jit, donate_argnums=(1,),
                            static_argnames=("k",))
+        @scoped("verify")
         def _verify_paged(params, state: DecodeState, table, drafts,
                           draft_len, temperature, top_k, top_p, key,
                           k: int):
@@ -955,6 +976,7 @@ class InferenceEngine:
 
         @functools.partial(jax.jit, donate_argnums=(1,),
                            static_argnames=("k",))
+        @scoped("verify")
         def _verify_masked(params, state: DecodeState, drafts,
                            draft_len, temperature, top_k, top_p, key,
                            mask, k: int):
@@ -983,6 +1005,7 @@ class InferenceEngine:
 
         @functools.partial(jax.jit, donate_argnums=(1,),
                            static_argnames=("k",))
+        @scoped("verify")
         def _verify_masked_paged(params, state: DecodeState, table,
                                  drafts, draft_len, temperature,
                                  top_k, top_p, key, mask, k: int):
@@ -1020,6 +1043,7 @@ class InferenceEngine:
             return tab.at[row].set(bits)
 
         @functools.partial(jax.jit, donate_argnums=(1,))
+        @scoped("decode")
         def _decode_masked_idx(params, state: DecodeState, temperature,
                                top_k, top_p, key, mtab, midx,
                                ) -> Tuple[DecodeState, jax.Array]:
@@ -1038,6 +1062,7 @@ class InferenceEngine:
                                adapters=state.adapters), toks
 
         @functools.partial(jax.jit, donate_argnums=(1,))
+        @scoped("decode")
         def _decode_masked_idx_paged(params, state: DecodeState, table,
                                      temperature, top_k, top_p, key,
                                      mtab, midx):
@@ -1058,6 +1083,7 @@ class InferenceEngine:
 
         @functools.partial(jax.jit, donate_argnums=(1,),
                            static_argnames=("n",))
+        @scoped("decode")
         def _decode_multi_masked_idx(params, state: DecodeState,
                                      temperature, top_k, top_p, key,
                                      budget, stop_ids, mtab, midx,
@@ -1078,6 +1104,7 @@ class InferenceEngine:
 
         @functools.partial(jax.jit, donate_argnums=(1,),
                            static_argnames=("n",))
+        @scoped("decode")
         def _decode_multi_masked_idx_paged(params, state: DecodeState,
                                            table, temperature, top_k,
                                            top_p, key, budget,
@@ -1100,6 +1127,7 @@ class InferenceEngine:
 
         @functools.partial(jax.jit, donate_argnums=(1,),
                            static_argnames=("k",))
+        @scoped("verify")
         def _verify_masked_idx(params, state: DecodeState, drafts,
                                draft_len, temperature, top_k, top_p,
                                key, mtab, midx, k: int):
@@ -1128,6 +1156,7 @@ class InferenceEngine:
 
         @functools.partial(jax.jit, donate_argnums=(1,),
                            static_argnames=("k",))
+        @scoped("verify")
         def _verify_masked_idx_paged(params, state: DecodeState, table,
                                      drafts, draft_len, temperature,
                                      top_k, top_p, key, mtab, midx,

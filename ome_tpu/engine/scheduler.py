@@ -41,6 +41,7 @@ a liveness probe should restart the pod on). Status is tri-state:
 from __future__ import annotations
 
 import collections
+import contextlib
 import itertools
 import math
 import os
@@ -50,6 +51,7 @@ import time
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -59,6 +61,7 @@ from ..priority import class_wait_caps as _wait_caps_table
 from ..priority import class_weights as _weights_table
 from ..telemetry import Registry
 from ..telemetry.flight import FlightRecorder
+from ..telemetry.scopes import ADMIT_PREFILL, SCHED_PHASES, SCHED_PREFIX
 from ..telemetry.tracing import Span, SpanContext, coerce_span_log, \
     new_trace
 from . import spec as spec_drafter
@@ -127,6 +130,39 @@ class _MultiStep:
         # — the drain attributes program/expected_ms on the
         # decode_chunk span when present
         self.cost = cost
+
+
+class _Dispatched:
+    """What the drain needs to time one dispatched step from its
+    completion: the step number its `sched.*` spans carry, the
+    dispatch START (monotonic), how many decode iterations it fused,
+    and the slow-step event's context (host gap before it, mask
+    seconds, the ledger entry of its program)."""
+
+    __slots__ = ("n", "t_dispatch", "k_steps", "gap_s", "mask_s",
+                 "entry")
+
+    def __init__(self, n, t_dispatch, k_steps, gap_s, mask_s, entry):
+        self.n = n
+        self.t_dispatch = t_dispatch
+        self.k_steps = k_steps
+        self.gap_s = gap_s
+        self.mask_s = mask_s
+        self.entry = entry
+
+
+class _Phase:
+    """The two timestamps of one `Scheduler._phase` block."""
+
+    __slots__ = ("t0", "t1")
+
+    def __init__(self):
+        self.t0 = time.monotonic()
+        self.t1 = self.t0
+
+    @property
+    def dt(self) -> float:
+        return self.t1 - self.t0
 
 
 class StepPlan:
@@ -400,6 +436,10 @@ class Request:
     scheduled_at: Optional[float] = None
     first_token_at: Optional[float] = None
     finished_at: Optional[float] = None
+    # host-observed seconds of this request's FIRST prefill call (a
+    # preempted request prefills again on resume; that is not added):
+    # the request log's `prefill_s`, None if it never prefilled
+    prefill_s: Optional[float] = None
     # one-shot observer the scheduler installs at submit(); must not
     # block or take scheduler locks (finish() may run under them)
     on_finish: Optional[object] = None
@@ -624,9 +664,16 @@ class Scheduler:
             collections.deque()
         # pipelined decode: dispatched-but-not-yet-read steps, each a
         # (device tokens, slot-occupancy snapshot, generation
-        # snapshot) triple; _drain_inflight is the ONLY place these
-        # tokens are fetched to the host
+        # snapshot, _Dispatched) tuple; _drain_inflight is the ONLY
+        # place these tokens are fetched to the host
         self._inflight: "collections.deque[tuple]" = collections.deque()
+        # dispatch counter: the `step` attribute that joins a step's
+        # sched.dispatch span, its device module and its drain spans
+        self._step_seq = 0
+        # when the host last learned that a step had ended; the next
+        # step's completion time starts here (or at its own dispatch,
+        # whichever is later)
+        self._last_fetched = 0.0
         # per-slot occupancy generation: bumped on EVERY occupancy
         # change (admit, finish, preempt, fail), so a lagged token is
         # emitted only if its slot still holds the same admission it
@@ -690,7 +737,9 @@ class Scheduler:
             "Per-request prefill forward seconds", buckets=STEP_BUCKETS)
         self._h_decode_step = R.histogram(
             "ome_engine_decode_step_seconds",
-            "Batched decode step seconds (one token per active slot)",
+            "Batched decode step seconds (one token per active slot), "
+            "from the step's completion: result fetched minus the later "
+            "of its dispatch and the previous step's fetch",
             buckets=STEP_BUCKETS)
         self._h_step_gap = R.histogram(
             "ome_engine_step_gap_seconds",
@@ -755,26 +804,25 @@ class Scheduler:
         self._g_pc_host_bytes = R.gauge(
             "ome_engine_prefix_host_bytes",
             "Host-DRAM bytes resident in the prefix-cache spill tier")
-        # step-phase attribution (ROADMAP open item 2): where a decode
-        # step + its host-side gap actually go, measured ONLY from
-        # timestamps the pipelined loop already crosses — dispatch
-        # (the compiled decode call), mask_apply (grammar mask build),
-        # device_wait (blocking at the lag-queue read), host_sample
-        # (token emit/offload after the read). Their sum tracks
-        # decode_step + step_gap within bookkeeping tolerance.
+        # step-phase attribution: where the scheduler thread's time
+        # goes, each phase observed by `_phase` from the same two
+        # timestamps as its `sched.<phase>` profiler span — plan (the
+        # whole of _plan_step; it contains mask_apply, the grammar
+        # mask build), dispatch (the compiled decode call returning:
+        # an enqueue under pipelining; device_loop for a K-token
+        # chunk), device_wait (blocking at the lag-queue read),
+        # host_sample (token emit/offload after the read), insert (a
+        # prefilled request entering its slot). The device sets the
+        # pace when device_wait dominates; decode_step_seconds is the
+        # step's completion time and is NOT their sum.
         self._h_step_phase = R.histogram(
             "ome_engine_step_phase_seconds",
-            "Decode step time attributed by phase (dispatch / "
-            "mask_apply / device_wait / host_sample)",
+            "Scheduler-thread time by phase (plan / mask_apply / "
+            "dispatch / device_loop / device_wait / host_sample / "
+            "insert)",
             labelnames=("phase",), buckets=STEP_BUCKETS)
-        self._ph_dispatch = self._h_step_phase.labels(phase="dispatch")
-        self._ph_mask = self._h_step_phase.labels(phase="mask_apply")
-        self._ph_wait = self._h_step_phase.labels(phase="device_wait")
-        self._ph_sample = self._h_step_phase.labels(phase="host_sample")
-        # multi-step chunks attribute their whole on-device loop here
-        # (K tokens per observation) instead of `dispatch` (1 token)
-        self._ph_device_loop = self._h_step_phase.labels(
-            phase="device_loop")
+        self._ph = {name: self._h_step_phase.labels(phase=name)
+                    for name in SCHED_PHASES}
         self._g_steps_per_dispatch = R.gauge(
             "ome_engine_steps_per_dispatch",
             "Decode iterations fused per device dispatch (the "
@@ -876,23 +924,6 @@ class Scheduler:
             "ome_engine_class_queue_depth",
             "Pending-queue depth by priority class",
             labelnames=("class",)))
-        # online roofline (docs/perf-attribution.md): the ledger's
-        # bytes-per-dispatch over the measured step time, gauged every
-        # step and distributed for the long view; only meaningful when
-        # the engine carries a ledger (fakes skip the update path)
-        self._g_roofline_eff = R.gauge(
-            "ome_engine_roofline_efficiency",
-            "Expected-over-measured time of the last decode dispatch "
-            "(1.0 = running at the device roofline)")
-        self._g_achieved_gbps = R.gauge(
-            "ome_engine_step_achieved_gbps",
-            "Ledger bytes of the last decode dispatch over its "
-            "measured wall time, in GB/s")
-        self._h_roofline_eff = R.histogram(
-            "ome_engine_roofline_step_efficiency",
-            "Per-dispatch roofline efficiency distribution",
-            buckets=(0.05, 0.1, 0.2, 0.3, 0.4, 0.5, 0.6, 0.7, 0.8,
-                     0.9, 1.0, 1.25, 1.5))
         self._c_slow_steps = R.counter(
             "ome_engine_slow_steps_total",
             "Decode steps exceeding slow_step_factor x the rolling "
@@ -1039,6 +1070,24 @@ class Scheduler:
                 self.span_log.write(q)
 
     # -- flight recorder + span plumbing -------------------------------
+
+    @contextlib.contextmanager
+    def _phase(self, name: str, **attrs):
+        """One scheduler phase: a `sched.<name>` span on the
+        profiler's clock (kept only while POST /debug/profile
+        captures; otherwise one atomic load) and an observation of
+        ome_engine_step_phase_seconds{phase=<name>} from the same two
+        timestamps, so the histogram and the span cannot drift.
+        Yields the timestamps. `attrs` are small scalars (a step
+        number, a plan kind, a request id) — never a prompt or a
+        token list."""
+        with jax.profiler.TraceAnnotation(SCHED_PREFIX + name, **attrs):
+            ph = _Phase()
+            try:
+                yield ph
+            finally:
+                ph.t1 = time.monotonic()
+                self._ph[name].observe(ph.dt)
 
     def _flight_event(self, event: str, **fields):
         self.flight.record(event, **fields)
@@ -1644,10 +1693,9 @@ class Scheduler:
                     continue
                 self._mark_scheduled(req)
                 pspan = self._begin_prefill_span(req)
-                t0 = time.monotonic()
                 try:
-                    tok, kv, true_len, bucket = self._prefill_req(
-                        req, span=pspan)
+                    tok, kv, true_len, bucket = self._timed_prefill(
+                        req, pspan)
                 except Exception as e:  # noqa: BLE001
                     import logging
 
@@ -1678,7 +1726,6 @@ class Scheduler:
                     self._free_slots.release()
                     self._fault_event.set()
                     continue
-                self._h_prefill.observe(time.monotonic() - t0)
                 self._end_prefill_span(req, pspan)
                 self._inc("prefill_total")
                 # under _lock so a prefill that outlives stop()'s join
@@ -1703,47 +1750,59 @@ class Scheduler:
             except queue.Empty:
                 break
             slot = self.slots.index(None)  # semaphore guarantees one
-            ikw = {} if req.adapter is None else {"adapter": req.adapter}
-            try:
-                self.state = self.engine.insert(
-                    self.state, kv, slot, true_len, tok, bucket, **ikw)
-            except Exception as e:  # noqa: BLE001
-                from .core import KVPoolExhausted, UnknownAdapterError
-                if isinstance(e, KVPoolExhausted):
-                    # paged-KV backpressure: requeue until running
-                    # streams free blocks (prefilled KV is dropped —
-                    # the request re-prefills on its next turn)
-                    self._requeue.appendleft(req)
-                    self._free_slots.release()
-                    continue
-                transient = (UnknownAdapterError,) + tuple(
-                    getattr(self.engine, "transient_prefill_errors",
-                            ()))
-                if isinstance(e, transient):
-                    # adapter hot-unloaded between prefill and insert,
-                    # or a PD insert of fetched KV failed: this
-                    # request fails, the node stays up
-                    req.finish("error")
-                    self._free_slots.release()
-                    continue
-                # engine fault: req is out of every queue so _recover
-                # cannot see it — fail it (and return its slot credit)
-                # before propagating to the recovery handler in _run
+            with self._phase("insert", request=req.id, slot=slot):
+                did = self._insert_one(req, tok, kv, true_len, bucket,
+                                       slot) or did
+        return did
+
+    def _insert_one(self, req: Request, tok, kv, true_len, bucket,
+                    slot: int) -> bool:
+        """Move one prefilled request into `slot`; False when it was
+        requeued or failed instead."""
+        ikw = {} if req.adapter is None else {"adapter": req.adapter}
+        try:
+            self.state = self.engine.insert(
+                self.state, kv, slot, true_len, tok, bucket, **ikw)
+        except Exception as e:  # noqa: BLE001
+            from .core import KVPoolExhausted, UnknownAdapterError
+            if isinstance(e, KVPoolExhausted):
+                # paged-KV backpressure: requeue until running
+                # streams free blocks (prefilled KV is dropped —
+                # the request re-prefills on its next turn)
+                self._requeue.appendleft(req)
+                self._free_slots.release()
+                return False
+            transient = (UnknownAdapterError,) + tuple(
+                getattr(self.engine, "transient_prefill_errors", ()))
+            if isinstance(e, transient):
+                # adapter hot-unloaded between prefill and insert,
+                # or a PD insert of fetched KV failed: this
+                # request fails, the node stays up
                 req.finish("error")
                 self._free_slots.release()
-                raise
-            self.slots[slot] = req
-            self._slot_changed(slot)
-            self._note_slot_assign(slot, req)
-            self._temp[slot] = req.temperature
-            self._top_k[slot] = req.top_k
-            self._top_p[slot] = req.top_p
-            self._true_len[slot] = true_len
-            self._base_out[slot] = len(req.output_ids)
-            req.emit(tok)
-            self._maybe_finish(slot, tok)
-            did = True
-        return did
+                return False
+            # engine fault: req is out of every queue so _recover
+            # cannot see it — fail it (and return its slot credit)
+            # before propagating to the recovery handler in _run
+            req.finish("error")
+            self._free_slots.release()
+            raise
+        self._seat(req, slot, tok, true_len)
+        return True
+
+    def _seat(self, req: Request, slot: int, tok, true_len) -> None:
+        """Slot bookkeeping of a request whose KV the engine just
+        inserted, then its first token."""
+        self.slots[slot] = req
+        self._slot_changed(slot)
+        self._note_slot_assign(slot, req)
+        self._temp[slot] = req.temperature
+        self._top_k[slot] = req.top_k
+        self._top_p[slot] = req.top_p
+        self._true_len[slot] = true_len
+        self._base_out[slot] = len(req.output_ids)
+        req.emit(tok)
+        self._maybe_finish(slot, tok)
 
     def _admit(self, limit: Optional[int] = None) -> bool:
         did = False
@@ -1773,17 +1832,17 @@ class Scheduler:
                     break
                 self._mark_scheduled(req)
                 pspan = self._begin_prefill_span(req)
-                t0 = time.monotonic()
                 try:
-                    tok, kv, true_len, bucket = self._prefill_req(
-                        req, span=pspan)
-                    self._h_prefill.observe(time.monotonic() - t0)
+                    tok, kv, true_len, bucket = self._timed_prefill(
+                        req, pspan)
                     self._end_prefill_span(req, pspan)
                     ikw = {} if req.adapter is None \
                         else {"adapter": req.adapter}
-                    self.state = self.engine.insert(
-                        self.state, kv, slot, true_len, tok, bucket,
-                        **ikw)
+                    with self._phase("insert", request=req.id,
+                                     slot=slot):
+                        self.state = self.engine.insert(
+                            self.state, kv, slot, true_len, tok,
+                            bucket, **ikw)
                 except Exception as e:
                     from .core import (KVPoolExhausted,
                                        UnknownAdapterError)
@@ -1806,17 +1865,8 @@ class Scheduler:
                     # here before propagating to _recover in _run
                     req.finish("error")
                     raise
-                self.slots[slot] = req
-                self._slot_changed(slot)
-                self._note_slot_assign(slot, req)
-                self._temp[slot] = req.temperature
-                self._top_k[slot] = req.top_k
-                self._top_p[slot] = req.top_p
-                self._true_len[slot] = true_len
-                self._base_out[slot] = len(req.output_ids)
                 self._inc("prefill_total")
-                req.emit(tok)
-                self._maybe_finish(slot, tok)
+                self._seat(req, slot, tok, true_len)
                 did = True
                 admitted += 1
             finally:
@@ -1892,7 +1942,7 @@ class Scheduler:
 
     def _inflight_rows(self) -> int:
         """Summed per-slot KV rows of every plan still in flight."""
-        return sum(self._flight_rows(p) for p, _, _ in self._inflight)
+        return sum(self._flight_rows(e[0]) for e in self._inflight)
 
     def _note_actual(self, slot: int, toks) -> None:
         """Reconcile one drained slot against the planner's predicted
@@ -1931,36 +1981,35 @@ class Scheduler:
         did = False
         drained = 0
         while len(self._inflight) > keep:
-            toks, snap_slots, snap_gens = self._inflight.popleft()
+            toks, snap_slots, snap_gens, sent = self._inflight.popleft()
             if isinstance(toks, _SpecStep):
-                self._drain_spec(toks, snap_slots, snap_gens)
+                self._drain_spec(toks, snap_slots, snap_gens, sent)
                 did = True
                 drained += 1
                 continue
             if isinstance(toks, _MultiStep):
-                self._drain_multi(toks, snap_slots, snap_gens)
+                self._drain_multi(toks, snap_slots, snap_gens, sent)
                 did = True
                 drained += 1
                 continue
             # phase attribution: the block below is the lag-queue
             # read — the only point the host waits on the device —
             # and the emit loop after it is host-side sampling/offload
-            t_read = time.monotonic()
-            host_toks = np.asarray(toks)
-            t_fetched = time.monotonic()
-            self._ph_wait.observe(t_fetched - t_read)
-            for slot, req in enumerate(snap_slots):
-                if (req is None or self.slots[slot] is not req
-                        or self._slot_gen[slot] != snap_gens[slot]):
-                    continue
-                tok = int(host_toks[slot])
-                self._note_actual(slot, (tok,))
-                req.emit(tok)
-                self._inc("tokens_generated_total")
-                self._c_class_tokens[self._class_of(req)].inc()
-                self._note_decode_progress(req)
-                self._maybe_finish(slot, tok)
-            self._ph_sample.observe(time.monotonic() - t_fetched)
+            with self._phase("device_wait", step=sent.n) as wait:
+                host_toks = np.asarray(toks)
+            self._step_done(sent, wait.t1)
+            with self._phase("host_sample", step=sent.n):
+                for slot, req in enumerate(snap_slots):
+                    if (req is None or self.slots[slot] is not req
+                            or self._slot_gen[slot] != snap_gens[slot]):
+                        continue
+                    tok = int(host_toks[slot])
+                    self._note_actual(slot, (tok,))
+                    req.emit(tok)
+                    self._inc("tokens_generated_total")
+                    self._c_class_tokens[self._class_of(req)].inc()
+                    self._note_decode_progress(req)
+                    self._maybe_finish(slot, tok)
             did = True
             drained += 1
         if drained:
@@ -1968,7 +2017,8 @@ class Scheduler:
                                kept=keep)
         return did
 
-    def _drain_spec(self, step: _SpecStep, snap_slots, snap_gens):
+    def _drain_spec(self, step: _SpecStep, snap_slots, snap_gens,
+                    sent: _Dispatched):
         """Emit one drained verify step: slot b produced
         out[b, :accepted[b]+1] (accepted draft prefix + one sampled
         token). Runs only from _drain_inflight — the host fetch below
@@ -1978,11 +2028,16 @@ class Scheduler:
         never have run without speculation; the usual generation
         check discards whole slots that changed occupant since
         dispatch."""
-        t_read = time.monotonic()
-        host_out = np.asarray(step.out)
-        host_acc = np.asarray(step.accepted)
-        t_fetched = time.monotonic()
-        self._ph_wait.observe(t_fetched - t_read)
+        with self._phase("device_wait", step=sent.n) as wait:
+            host_out = np.asarray(step.out)
+            host_acc = np.asarray(step.accepted)
+        self._step_done(sent, wait.t1)
+        with self._phase("host_sample", step=sent.n):
+            self._emit_spec(step, host_out, host_acc, snap_slots,
+                            snap_gens)
+
+    def _emit_spec(self, step: _SpecStep, host_out, host_acc,
+                   snap_slots, snap_gens):
         dlen = step.draft_len
         proposed = int(dlen.sum())
         accepted = 0
@@ -2031,9 +2086,9 @@ class Scheduler:
                                                - step.t_dispatch))
             s.end().set(proposed=proposed, accepted=accepted)
             self.span_log.write(s)
-        self._ph_sample.observe(time.monotonic() - t_fetched)
 
-    def _drain_multi(self, step: _MultiStep, snap_slots, snap_gens):
+    def _drain_multi(self, step: _MultiStep, snap_slots, snap_gens,
+                     sent: _Dispatched):
         """Emit one drained multi-token chunk: slot b produced
         step.out[b, :advanced[b]] (docs/multi-step-decode.md). Runs
         only from _drain_inflight — the host fetch below completes
@@ -2046,11 +2101,16 @@ class Scheduler:
         whole slots whose occupant changed since dispatch. Paged
         engines reconcile allocator state per slot via commit_spec,
         reserving rows for chunks still in flight."""
-        t_read = time.monotonic()
-        host_out = np.asarray(step.out)       # [B, k]
-        host_adv = np.asarray(step.advanced)  # [B]
-        t_fetched = time.monotonic()
-        self._ph_wait.observe(t_fetched - t_read)
+        with self._phase("device_wait", step=sent.n) as wait:
+            host_out = np.asarray(step.out)       # [B, k]
+            host_adv = np.asarray(step.advanced)  # [B]
+        self._step_done(sent, wait.t1)
+        with self._phase("host_sample", step=sent.n):
+            self._emit_multi(step, host_out, host_adv, snap_slots,
+                             snap_gens)
+
+    def _emit_multi(self, step: _MultiStep, host_out, host_adv,
+                    snap_slots, snap_gens):
         commit = getattr(self.engine, "commit_spec", None)
         # later plans were dispatched against block pre-allocations
         # covering their rows; commit must not trim those
@@ -2091,7 +2151,6 @@ class Scheduler:
                       program_bytes=step.cost["bytes"])
             self.span_log.write(s)
         self._flight_event("multi_chunk", k=step.k, emitted=emitted)
-        self._ph_sample.observe(time.monotonic() - t_fetched)
 
     def _decode(self) -> bool:
         if not any(r is not None for r in self.slots):
@@ -2105,7 +2164,8 @@ class Scheduler:
         # lag queue to _recover, which drops it unread — lagged
         # tokens of a failed batch are never emitted.
         faults.fire("engine_step")
-        plan = self._plan_step()
+        with self._phase("plan"):
+            plan = self._plan_step()
         if plan is None:
             return True  # a precondition drain finished every slot
         return self._execute(plan)
@@ -2142,40 +2202,28 @@ class Scheduler:
                       1)
         if self._gcache is not None and masked_slots:
             self._gcache.begin_plan()
-        tm0 = time.monotonic()
         mask_s = 0.0
         walks: Dict[int, tuple] = {}
-        legacy_masked = False
-        for s in masked_slots:
-            m = self.slots[s].masker
-            if (self._planned_tail[s] is None
-                    or not callable(getattr(m, "copy", None))):
-                legacy_masked = True
-                break
-            try:
-                walks[s] = self._walk_masker(s, horizon)
-            except AttributeError:
-                # the masker copies but its automaton cannot
-                legacy_masked = True
-                break
-        if legacy_masked:
-            # plan precondition re-established by draining: a grammar
-            # that cannot be walked ahead is only consistent with the
-            # committed stream, so nothing may be in flight when its
-            # mask is built — one synchronous masked step, exactly
-            # the pre-plan behavior for copyless maskers, and the one
-            # case that still counts as a masked degradation
-            self._degrade("masked")
-            if self._inflight and self._flush_inflight():
-                return None
-            mask = self._build_mask()
-            mask_s = time.monotonic() - tm0
-            self._ph_mask.observe(mask_s)
-            return StepPlan("decode", sync=True, mask=mask,
-                            mask_s=mask_s)
         if masked_slots:
-            mask_s = time.monotonic() - tm0
-            self._ph_mask.observe(mask_s)
+            with self._phase("mask_apply") as masking:
+                legacy_masked = self._walk_maskers(masked_slots,
+                                                   horizon, walks)
+                if legacy_masked:
+                    # plan precondition re-established by draining: a
+                    # grammar that cannot be walked ahead is only
+                    # consistent with the committed stream, so nothing
+                    # may be in flight when its mask is built — one
+                    # synchronous masked step, exactly the pre-plan
+                    # behavior for copyless maskers, and the one case
+                    # that still counts as a masked degradation
+                    self._degrade("masked")
+                    if self._inflight and self._flush_inflight():
+                        return None
+                    mask = self._build_mask()
+            mask_s = masking.dt
+            if legacy_masked:
+                return StepPlan("decode", sync=True, mask=mask,
+                                mask_s=mask_s)
         # -- speculative drafts over predicted continuations. Masked
         # slots draft THROUGH the grammar when their mask rows are
         # device-resident: forced runs verbatim (the masked target
@@ -2324,6 +2372,23 @@ class Scheduler:
                             mask_idx=mask_idx, mask_s=mask_s)
         self._predict_step(plan, walks, n)
         return plan
+
+    def _walk_maskers(self, masked_slots, horizon: int,
+                      walks: Dict[int, tuple]) -> bool:
+        """Walk every masked slot's automaton ahead into `walks`;
+        True when one of them cannot be walked (its masker does not
+        copy, or its continuation is unknown)."""
+        for s in masked_slots:
+            m = self.slots[s].masker
+            if (self._planned_tail[s] is None
+                    or not callable(getattr(m, "copy", None))):
+                return True
+            try:
+                walks[s] = self._walk_masker(s, horizon)
+            except AttributeError:
+                # the masker copies but its automaton cannot
+                return True
+        return False
 
     def _walk_masker(self, slot: int, horizon: int):
         """Advance a COPY of the slot's grammar ahead of its
@@ -2542,55 +2607,24 @@ class Scheduler:
         never decides composition; it only honors plan.sync by
         running this step's window at depth 0."""
         sampling = self._sampling()
-        t0 = time.monotonic()
-        gap_s = None
-        if self._dispatch_end is not None:
-            gap_s = t0 - self._dispatch_end
-            self._h_step_gap.observe(gap_s)
         n_steps = plan.k if plan.kind == "chunk" else 1
-        if plan.kind == "verify":
-            kw = {}
-            if getattr(self.engine, "kv_block", 0):
-                # paged pre-allocation must cover this plan AND every
-                # plan still in flight (their commits have not
-                # advanced the host length mirror yet)
-                kw["lookahead_rows"] = self._inflight_rows() + plan.rows
-            if plan.mask_idx is not None:
-                kw["mask_idx"] = plan.mask_idx
-            elif plan.mask is not None:
-                kw["mask"] = plan.mask
-            self.state, out, acc = self.engine.verify(
-                self.state, plan.drafts, plan.dlen, *sampling, **kw)
-            toks = _SpecStep(out, acc, plan.dlen, t0)
-        elif plan.kind == "chunk":
-            kw = {}
-            if plan.mask_stack_idx is not None:
-                kw["mask_idx"] = plan.mask_stack_idx
-            elif plan.mask_stack is not None:
-                kw["mask"] = plan.mask_stack
-            self.state, out, adv = self.engine.decode_multi(
-                self.state, *sampling, steps=plan.k,
-                budget=plan.budget, stop_ids=self._stop_table(),
-                lookahead_rows=self._inflight_rows() + plan.rows,
-                **kw)
-            led = getattr(self.engine, "ledger", None)
-            toks = _MultiStep(
-                out, adv, plan.k, t0,
-                cost=led.last_dispatch() if led is not None else None)
-        elif plan.mask_idx is not None:
-            self.state, toks = self.engine.decode(
-                self.state, *sampling, mask_idx=plan.mask_idx)
-        elif plan.mask is not None:
-            self.state, toks = self.engine.decode(
-                self.state, *sampling, mask=plan.mask)
-        else:  # engine wrappers/fakes need no mask kwarg in their API
-            self.state, toks = self.engine.decode(
-                self.state, *sampling)
-        self._dispatch_end = time.monotonic()
-        dt = self._dispatch_end - t0
-        # per-STEP time (the queue-wait estimator and step histogram
-        # stay per-token): a K-chunk dispatch amortizes over K steps
-        dt_step = dt / n_steps
+        self._step_seq += 1
+        # multi-step chunks attribute their whole on-device loop to
+        # `device_loop` (K tokens per observation), not `dispatch`
+        with self._phase("device_loop" if n_steps > 1 else "dispatch",
+                         step=self._step_seq, kind=plan.kind) as sent:
+            t0 = sent.t0
+            gap_s = None
+            if self._dispatch_end is not None:
+                gap_s = t0 - self._dispatch_end
+                self._h_step_gap.observe(gap_s)
+            toks = self._dispatch(plan, sampling, t0)
+        self._dispatch_end = sent.t1
+        # per-STEP time of the DISPATCH — what the jitted call took to
+        # return, an enqueue under pipelining — feeds the queue-wait
+        # estimator as it always has (a K-chunk amortizes over K
+        # steps); the step histogram reads completions (_step_done)
+        dt_step = sent.dt / n_steps
         # a program's FIRST dispatch includes its compilation — tens
         # of seconds on the chip, not a service time. One such sample
         # held the queue-wait estimate over the admission cap and an
@@ -2600,20 +2634,15 @@ class Scheduler:
         if cost is None or cost["dispatches"] > 1:
             self._ewma_step_s = dt_step if self._ewma_step_s is None \
                 else 0.9 * self._ewma_step_s + 0.1 * dt_step
-        self._h_decode_step.observe(dt_step)
-        if n_steps > 1:
-            self._ph_device_loop.observe(dt)
-        else:
-            self._ph_dispatch.observe(dt)
-        self._observe_roofline(toks, dt, dt_step, n_steps,
-                               gap_s, plan.mask_s)
         self._inc("decode_steps_total", n_steps)
         if plan.kind == "verify":
             self._inc("spec_steps_total")
             self._inc("spec_proposed_tokens_total",
                       int(plan.dlen.sum()))
         self._inflight.append(
-            (toks, list(self.slots), list(self._slot_gen)))
+            (toks, list(self.slots), list(self._slot_gen),
+             _Dispatched(self._step_seq, t0, n_steps, gap_s,
+                         plan.mask_s, cost)))
         depth = 0 if plan.sync else self.pipeline_depth
         # emit steps older than the pipeline window — with the next
         # step now dispatched, reading them costs no dispatch overlap
@@ -2655,19 +2684,63 @@ class Scheduler:
             self._drain_inflight()
         return True
 
-    def _observe_roofline(self, toks, dt: float, dt_step: float,
-                          k_steps: int, gap_s, mask_s: float) -> None:
-        """Per-dispatch online roofline + slow-step outlier detection
-        (docs/perf-attribution.md). Both need the ledger entry of the
-        program just dispatched — engines without one (fakes, remote
-        wrappers) only feed the slow-step window."""
-        led = getattr(self.engine, "ledger", None)
-        entry = led.last_dispatch() if led is not None else None
-        if entry is not None and dt > 0:
-            self._g_achieved_gbps.set(entry["bytes"] / dt / 1e9)
-            eff = (entry["expected_ms"] / 1000.0) / dt
-            self._g_roofline_eff.set(eff)
-            self._h_roofline_eff.observe(eff)
+    def _dispatch(self, plan: StepPlan, sampling, t0: float):
+        """The one compiled-program call of a plan, keyed on
+        plan.kind; returns the lag-queue payload."""
+        if plan.kind == "verify":
+            kw = {}
+            if getattr(self.engine, "kv_block", 0):
+                # paged pre-allocation must cover this plan AND every
+                # plan still in flight (their commits have not
+                # advanced the host length mirror yet)
+                kw["lookahead_rows"] = self._inflight_rows() + plan.rows
+            if plan.mask_idx is not None:
+                kw["mask_idx"] = plan.mask_idx
+            elif plan.mask is not None:
+                kw["mask"] = plan.mask
+            self.state, out, acc = self.engine.verify(
+                self.state, plan.drafts, plan.dlen, *sampling, **kw)
+            toks = _SpecStep(out, acc, plan.dlen, t0)
+        elif plan.kind == "chunk":
+            kw = {}
+            if plan.mask_stack_idx is not None:
+                kw["mask_idx"] = plan.mask_stack_idx
+            elif plan.mask_stack is not None:
+                kw["mask"] = plan.mask_stack
+            self.state, out, adv = self.engine.decode_multi(
+                self.state, *sampling, steps=plan.k,
+                budget=plan.budget, stop_ids=self._stop_table(),
+                lookahead_rows=self._inflight_rows() + plan.rows,
+                **kw)
+            led = getattr(self.engine, "ledger", None)
+            toks = _MultiStep(
+                out, adv, plan.k, t0,
+                cost=led.last_dispatch() if led is not None else None)
+        elif plan.mask_idx is not None:
+            self.state, toks = self.engine.decode(
+                self.state, *sampling, mask_idx=plan.mask_idx)
+        elif plan.mask is not None:
+            self.state, toks = self.engine.decode(
+                self.state, *sampling, mask=plan.mask)
+        else:  # engine wrappers/fakes need no mask kwarg in their API
+            self.state, toks = self.engine.decode(
+                self.state, *sampling)
+        return toks
+
+    def _step_done(self, sent: _Dispatched, t_fetched: float) -> None:
+        """The host just learned that step `sent` ended. Its step
+        time is a COMPLETION time: from the later of its own dispatch
+        and the previous step's fetch to this fetch, per decode
+        iteration for a chunk. Under load that is the device's step
+        plus whatever the device ran in between (a prefill); on an
+        idle server it is dispatch to result. Feeds
+        ome_engine_decode_step_seconds and the slow-step outlier
+        detector (docs/perf-attribution.md)."""
+        dt_step = (t_fetched - max(self._last_fetched, sent.t_dispatch)
+                   ) / sent.k_steps
+        self._last_fetched = t_fetched
+        self._h_decode_step.observe(dt_step)
+        entry = sent.entry
         # slow-step detector: compare against the rolling median of
         # recent per-step times, not a fixed threshold — "slow" means
         # slow relative to THIS batch shape on THIS device. Warm-up
@@ -2682,9 +2755,9 @@ class Scheduler:
                     step_ms=round(dt_step * 1e3, 3),
                     median_ms=round(med * 1e3, 3),
                     ratio=round(dt_step / med, 2),
-                    k_steps=k_steps,
-                    mask_ms=round(mask_s * 1e3, 3),
-                    gap_ms=round((gap_s or 0.0) * 1e3, 3))
+                    k_steps=sent.k_steps,
+                    mask_ms=round(sent.mask_s * 1e3, 3),
+                    gap_ms=round((sent.gap_s or 0.0) * 1e3, 3))
                 if entry is not None:
                     fields["program"] = entry["program"]
                     fields["expected_ms"] = round(
@@ -2804,6 +2877,24 @@ class Scheduler:
                                request_id=req.id,
                                prefix_len=true_len)
         return token, (k, v), true_len, bucket
+
+    def _timed_prefill(self, req: Request, span: Optional[Span]):
+        """`_prefill_req` under an `admit.prefill` profiler span
+        (request id, prompt length and bucket: no token list). Its
+        host-observed duration feeds ome_engine_prefill_seconds and,
+        for the request's first prefill, the request log's
+        `prefill_s`."""
+        with jax.profiler.TraceAnnotation(
+                ADMIT_PREFILL, request=req.id,
+                prompt_tokens=len(req.prompt_ids)) as ann:
+            t0 = time.monotonic()
+            out = self._prefill_req(req, span=span)
+            dt = time.monotonic() - t0
+            ann.set_metadata(bucket=out[3])
+        self._h_prefill.observe(dt)
+        if req.prefill_s is None:
+            req.prefill_s = dt
+        return out
 
     def _prefill_req(self, req: Request, span: Optional[Span] = None):
         """Engine prefill for one request; constrained requests pass

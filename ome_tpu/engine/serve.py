@@ -635,6 +635,15 @@ def main(argv=None) -> int:
     args = build_parser().parse_args(argv)
     from .. import device
     cache_dir = device.enable_compile_cache()
+    # the server's programs carry names that a profiler capture is
+    # read by (telemetry/scopes.py). JAX leaves metadata out of the
+    # cache key by default: a program whose operations a release did
+    # not change would then be loaded under the names it was first
+    # compiled with. With metadata in the key a cache entry is found
+    # again only from the same source tree at the same path.
+    import jax
+    jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                      True)
     if args.faults:
         from .. import faults
         faults.install(args.faults)

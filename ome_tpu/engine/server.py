@@ -797,8 +797,9 @@ class EngineServer:
         tpot = None
         if req.first_token_at is not None and n > 1:
             tpot = round((end - req.first_token_at) / (n - 1), 6)
-        # schema v3 (docs/autoscaling.md): v2 plus the priority class,
-        # so per-class SLO replay does not have to re-derive tenancy.
+        # schema v4 (telemetry/reqlog.py): v3 (v2 plus the priority
+        # class, so per-class SLO replay does not have to re-derive
+        # tenancy) plus `prefill_s`.
         # The ADMIT instant is on both clocks — req.created is
         # monotonic, so the wall-clock half is recovered by rebasing
         # against now. Trace replay reconstructs inter-arrival gaps
@@ -817,6 +818,8 @@ class EngineServer:
             "class": req.priority,
             "queue_wait_s": _delta(req.created, req.scheduled_at),
             "ttft_s": _delta(req.created, req.first_token_at),
+            "prefill_s": None if req.prefill_s is None
+            else round(req.prefill_s, 6),
             "tpot_s": tpot,
             "e2e_s": round(end - req.created, 6),
             "prompt_tokens": len(req.prompt_ids),

@@ -13,9 +13,10 @@ statically bounded:
   * a loop or comprehension variable ranging over a literal sequence
     of constants, a module-level tuple/list-of-strings constant, the
     keys of a module-level string-keyed dict (``.items()`` /
-    ``.keys()`` / the dict itself), or the priority-class enum
-    (``PRIORITY_CLASSES`` — the fixed tenant-class vocabulary of
-    ome_tpu/priority.py).
+    ``.keys()`` / the dict itself), or a fixed enum of another
+    module (``PRIORITY_CLASSES`` — the tenant-class vocabulary of
+    ome_tpu/priority.py; ``SCHED_PHASES`` — the scheduler's step
+    phases, ome_tpu/telemetry/scopes.py).
 
 The dict-splat spelling ``labels(**{"class": c})`` — required because
 ``class`` is a Python keyword — is checked key-by-key the same way;
@@ -37,8 +38,9 @@ from ..context import Context
 from ..core import Finding, Project, Rule, SourceFile
 
 # enums defined outside the checked file that are bounded by
-# construction; today only the tenant priority classes
-BOUNDED_ENUM_NAMES = frozenset({"PRIORITY_CLASSES"})
+# construction: the tenant priority classes and the scheduler's step
+# phases (telemetry/scopes.py)
+BOUNDED_ENUM_NAMES = frozenset({"PRIORITY_CLASSES", "SCHED_PHASES"})
 
 
 def _is_const_seq(node: ast.AST) -> bool:

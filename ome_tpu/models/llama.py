@@ -23,6 +23,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..ops.attention import attention
+from ..telemetry.scopes import scoped
 from .config import ModelConfig
 from .quant import QTensor
 
@@ -610,11 +611,13 @@ def _layer(x: jax.Array, lp: Params, cfg: ModelConfig, freqs: jax.Array,
     if window is _WINDOW_FROM_CFG:
         window = cfg.sliding_window
     uo = cfg.unit_offset_norm
-    h = block_norm(x, lp, "attn_norm", cfg)
+    with jax.named_scope("qkv"):
+        h = block_norm(x, lp, "attn_norm", cfg)
     if cfg.mla:
         from .mla import mla_attention
-        a, new_cache = mla_attention(h, lp, cfg, positions, kv_len,
-                                     cache_kv, cache_index)
+        with jax.named_scope("attn"):
+            a, new_cache = mla_attention(h, lp, cfg, positions, kv_len,
+                                         cache_kv, cache_index)
     else:
         a, new_cache = _mha(h, lp, cfg, freqs, positions, kv_len,
                             cache_kv, cache_index, window, uo,
@@ -623,19 +626,22 @@ def _layer(x: jax.Array, lp: Params, cfg: ModelConfig, freqs: jax.Array,
     if cfg.parallel_block:
         # command-r: attention and MLP both read the SAME normed
         # input and add into one residual (CohereDecoderLayer)
-        mlp_out = moe_mlp(h, lp, cfg) if use_moe \
-            else dense_mlp(h, lp, cfg, adapter_ids)
+        with jax.named_scope("mlp"):
+            mlp_out = moe_mlp(h, lp, cfg) if use_moe \
+                else dense_mlp(h, lp, cfg, adapter_ids)
         return x + a + mlp_out, new_cache
     if cfg.post_block_norms:
-        a = rms_norm(a, lp["attn_post_norm"], cfg.rms_norm_eps, uo)
+        with jax.named_scope("o_proj"):
+            a = rms_norm(a, lp["attn_post_norm"], cfg.rms_norm_eps, uo)
     x = x + a
 
-    h = block_norm(x, lp, "mlp_norm", cfg)
-    mlp_out = moe_mlp(h, lp, cfg) if use_moe \
-        else dense_mlp(h, lp, cfg, adapter_ids)
-    if cfg.post_block_norms:
-        mlp_out = rms_norm(mlp_out, lp["mlp_post_norm"],
-                           cfg.rms_norm_eps, uo)
+    with jax.named_scope("mlp"):
+        h = block_norm(x, lp, "mlp_norm", cfg)
+        mlp_out = moe_mlp(h, lp, cfg) if use_moe \
+            else dense_mlp(h, lp, cfg, adapter_ids)
+        if cfg.post_block_norms:
+            mlp_out = rms_norm(mlp_out, lp["mlp_post_norm"],
+                               cfg.rms_norm_eps, uo)
     return x + mlp_out, new_cache
 
 
@@ -674,38 +680,57 @@ def _mha(h: jax.Array, lp: Params, cfg: ModelConfig, freqs: jax.Array,
          uo: bool, adapter_ids: Optional[jax.Array] = None,
          use_rope: bool = True):
     """Standard multi-head (GQA) attention on the pre-normed input."""
-    q, k, v = _qkv(h, lp, cfg, freqs, positions, uo, adapter_ids,
-                   rope=use_rope)
+    with jax.named_scope("qkv"):
+        q, k, v = _qkv(h, lp, cfg, freqs, positions, uo, adapter_ids,
+                       rope=use_rope)
 
     if cache_kv is not None:
         ck, cv = cache_kv
-        if cache_index.ndim == 1:
-            # per-slot write positions (continuous batching): vmap the
-            # update over the batch so each slot writes at its own length
-            upd = jax.vmap(
-                lambda c, u, i: lax.dynamic_update_slice(
-                    c, u.astype(c.dtype), (i, 0, 0)))
-            ck = upd(ck, k, cache_index)
-            cv = upd(cv, v, cache_index)
-        else:
-            ck = lax.dynamic_update_slice(ck, k.astype(ck.dtype),
-                                          (0, cache_index, 0, 0))
-            cv = lax.dynamic_update_slice(cv, v.astype(cv.dtype),
-                                          (0, cache_index, 0, 0))
+        with jax.named_scope("kv_write"):
+            if cache_index.ndim == 1:
+                # per-slot write positions (continuous batching): vmap
+                # the update over the batch so each slot writes at its
+                # own length
+                upd = jax.vmap(
+                    lambda c, u, i: lax.dynamic_update_slice(
+                        c, u.astype(c.dtype), (i, 0, 0)))
+                ck = upd(ck, k, cache_index)
+                cv = upd(cv, v, cache_index)
+            else:
+                ck = lax.dynamic_update_slice(ck, k.astype(ck.dtype),
+                                              (0, cache_index, 0, 0))
+                cv = lax.dynamic_update_slice(cv, v.astype(cv.dtype),
+                                              (0, cache_index, 0, 0))
         k_full, v_full = ck, cv
         new_cache = (ck, cv)
     else:
         k_full, v_full = k, v
         new_cache = None
 
-    attn = attention(q, k_full, v_full, positions=positions, kv_len=kv_len,
-                     sliding_window=window, scale=cfg.query_scale,
-                     logit_softcap=cfg.attn_logit_softcap,
-                     sinks=lp.get("sinks") if cfg.attn_sinks else None)
-    a = _proj_lora(attn, lp, "wo", adapter_ids, cfg.dtype, flatten=2)
-    if "bo" in lp:  # phimoe/gpt_oss: o_proj carries a bias too
-        a = a + lp["bo"]
+    with jax.named_scope("attn"):
+        attn = attention(q, k_full, v_full, positions=positions,
+                         kv_len=kv_len, sliding_window=window,
+                         scale=cfg.query_scale,
+                         logit_softcap=cfg.attn_logit_softcap,
+                         sinks=lp.get("sinks") if cfg.attn_sinks else None)
+    with jax.named_scope("o_proj"):
+        a = _proj_lora(attn, lp, "wo", adapter_ids, cfg.dtype, flatten=2)
+        if "bo" in lp:  # phimoe/gpt_oss: o_proj carries a bias too
+            a = a + lp["bo"]
     return a, new_cache
+
+
+def _embed(params: Params, cfg: ModelConfig,
+           tokens: jax.Array) -> jax.Array:
+    """Token embeddings in the compute dtype — shared by forward and
+    forward_paged."""
+    with jax.named_scope("embed"):
+        emb = params["embed"]
+        x = emb.take(tokens, cfg.dtype) if isinstance(emb, QTensor) \
+            else jnp.take(emb, tokens, axis=0).astype(cfg.dtype)
+        if cfg.embed_scale:  # gemma: normalizer in the compute dtype
+            x = x * jnp.asarray(cfg.hidden_size ** 0.5, cfg.dtype)
+    return x
 
 
 def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
@@ -736,11 +761,7 @@ def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
             idx = cache.index
             base = base + (idx[:, None] if idx.ndim == 1 else idx)
         positions = jnp.broadcast_to(base, (B, S))
-    emb = params["embed"]
-    x = emb.take(tokens, cfg.dtype) if isinstance(emb, QTensor) \
-        else jnp.take(emb, tokens, axis=0).astype(cfg.dtype)
-    if cfg.embed_scale:  # gemma: normalizer in the compute dtype
-        x = x * jnp.asarray(cfg.hidden_size ** 0.5, cfg.dtype)
+    x = _embed(params, cfg, tokens)
     freqs = _rope_frequencies(cfg)
 
     kv_len = jnp.broadcast_to(cache.index + S, (B,)) \
@@ -748,8 +769,10 @@ def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
     index = cache.index if cache is not None else None
 
     if cfg.alt_sliding_window:
-        x, new_cache = _alt_window_scan(params, cfg, x, freqs, positions,
-                                        kv_len, cache, adapter_ids)
+        with jax.named_scope("layers"):
+            x, new_cache = _alt_window_scan(params, cfg, x, freqs,
+                                            positions, kv_len, cache,
+                                            adapter_ids)
     else:
         # DeepSeek first_k_dense: leading dense-MLP layers scan as
         # their own block; the cache's layer dim covers both blocks
@@ -764,7 +787,8 @@ def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
                 return x, nc
 
             carry_cache = (ck, cv) if cache is not None else None
-            x, nc = lax.scan(body, x, (block, carry_cache))
+            with jax.named_scope("layers"):
+                x, nc = lax.scan(body, x, (block, carry_cache))
             return x, nc
 
         if cache is not None:
@@ -815,11 +839,7 @@ def forward_paged(params: Params, cfg: ModelConfig, tokens: jax.Array,
     positions = cache.index[:, None] + jnp.arange(S,
                                                   dtype=jnp.int32)[None, :]
     kv_len = cache.index + 1
-    emb = params["embed"]
-    x = emb.take(tokens, cfg.dtype) if isinstance(emb, QTensor) \
-        else jnp.take(emb, tokens, axis=0).astype(cfg.dtype)
-    if cfg.embed_scale:
-        x = x * jnp.asarray(cfg.hidden_size ** 0.5, cfg.dtype)
+    x = _embed(params, cfg, tokens)
     freqs = _rope_frequencies(cfg)
     uo = cfg.unit_offset_norm
     rows = jnp.arange(B)
@@ -860,49 +880,58 @@ def forward_paged(params: Params, cfg: ModelConfig, tokens: jax.Array,
         else:
             lp, kp, vp = per
             ksp = vsp = None
-        h = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps, uo)
-        q, k, v = _qkv(h, lp, cfg, freqs, positions, uo, adapter_ids)
-        kp, ksp = _append(kp, ksp, k)
-        vp, vsp = _append(vp, vsp, v)
-        if S == 1:
-            attn = paged_attention(q, kp, vp, cache.table, kv_len,
-                                   scale=cfg.query_scale,
-                                   logit_softcap=cfg.attn_logit_softcap,
-                                   k_scale=ksp, v_scale=vsp)
-        else:
-            attn = paged_attention_multi(
-                q, kp, vp, cache.table, positions,
-                scale=cfg.query_scale,
-                logit_softcap=cfg.attn_logit_softcap,
-                k_scale=ksp, v_scale=vsp)
-        a = _proj_lora(attn, lp, "wo", adapter_ids, cfg.dtype,
-                       flatten=2)
-        if cfg.post_block_norms:
-            a = rms_norm(a, lp["attn_post_norm"], cfg.rms_norm_eps, uo)
+        with jax.named_scope("qkv"):
+            h = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps, uo)
+            q, k, v = _qkv(h, lp, cfg, freqs, positions, uo, adapter_ids)
+        with jax.named_scope("kv_write"):
+            kp, ksp = _append(kp, ksp, k)
+            vp, vsp = _append(vp, vsp, v)
+        with jax.named_scope("attn"):
+            if S == 1:
+                attn = paged_attention(
+                    q, kp, vp, cache.table, kv_len,
+                    scale=cfg.query_scale,
+                    logit_softcap=cfg.attn_logit_softcap,
+                    k_scale=ksp, v_scale=vsp)
+            else:
+                attn = paged_attention_multi(
+                    q, kp, vp, cache.table, positions,
+                    scale=cfg.query_scale,
+                    logit_softcap=cfg.attn_logit_softcap,
+                    k_scale=ksp, v_scale=vsp)
+        with jax.named_scope("o_proj"):
+            a = _proj_lora(attn, lp, "wo", adapter_ids, cfg.dtype,
+                           flatten=2)
+            if cfg.post_block_norms:
+                a = rms_norm(a, lp["attn_post_norm"], cfg.rms_norm_eps,
+                             uo)
         x = x + a
-        h = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps, uo)
-        mlp_out = dense_mlp(h, lp, cfg, adapter_ids)
-        if cfg.post_block_norms:
-            mlp_out = rms_norm(mlp_out, lp["mlp_post_norm"],
-                               cfg.rms_norm_eps, uo)
+        with jax.named_scope("mlp"):
+            h = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps, uo)
+            mlp_out = dense_mlp(h, lp, cfg, adapter_ids)
+            if cfg.post_block_norms:
+                mlp_out = rms_norm(mlp_out, lp["mlp_post_norm"],
+                                   cfg.rms_norm_eps, uo)
         out = (x + mlp_out, ((kp, vp, ksp, vsp) if quantized
                              else (kp, vp)))
         return out
 
-    if quantized:
-        x, (nk, nv, nks, nvs) = lax.scan(
-            body, x, (params["layers"], cache.k, cache.v,
-                      cache.k_scale, cache.v_scale))
-    else:
-        x, (nk, nv) = lax.scan(body, x,
-                               (params["layers"], cache.k, cache.v))
-        nks = nvs = None
+    with jax.named_scope("layers"):
+        if quantized:
+            x, (nk, nv, nks, nvs) = lax.scan(
+                body, x, (params["layers"], cache.k, cache.v,
+                          cache.k_scale, cache.v_scale))
+        else:
+            x, (nk, nv) = lax.scan(body, x,
+                                   (params["layers"], cache.k, cache.v))
+            nks = nvs = None
     new_cache = PagedKVCache(k=nk, v=nv, index=cache.index + S,
                              table=cache.table,
                              k_scale=nks, v_scale=nvs)
     return _final_logits(params, cfg, x), new_cache
 
 
+@scoped("lm_head")
 def _final_logits(params: Params, cfg: ModelConfig,
                   x: jax.Array) -> jax.Array:
     """Final norm + LM head — shared by forward and forward_paged."""

@@ -200,6 +200,7 @@ def _flash_decode(q, k, v, lo, hi, scale, softcap, interpret,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, K, G, D), q.dtype),
         interpret=interpret,
+        name="flash_decode",
     )(*args)
     return out.reshape(B, 1, H, D)
 
@@ -380,6 +381,7 @@ def _flash_prefill(q, k, v, base, kv_hi, scale, softcap, window, interpret):
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Sq, K, G, D), q.dtype),
         interpret=interpret,
+        name="flash_prefill",
     )(limits, q5, k5, v5)
     return out.reshape(B, Sq, H, D)
 

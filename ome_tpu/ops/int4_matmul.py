@@ -143,6 +143,7 @@ def _mm4(x2, qp2, s2, gsize: int, bkp: int, bn: int, out_dtype,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="int4_matmul",
     )(x2, x2, qp2, s3, s3)
 
 

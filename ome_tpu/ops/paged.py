@@ -227,6 +227,7 @@ def paged_flash_decode(q: jax.Array, k_pool: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, K, G, D), q.dtype),
         interpret=interpret,
+        name="paged_attention",
     )(*args)
     return out.reshape(B, 1, H, D)
 

@@ -7,10 +7,12 @@ dispatches a program for the first time (one ledger entry per
 the AOT path for it: `fn.lower(...).compile()` then
 `cost_analysis()` (FLOPs, bytes accessed), `memory_analysis()`
 (argument/output/temp bytes), how many Mosaic kernels the compiled
-program holds (`tpu_custom_call`) and which kernels declined while it
-was traced (ops/__init__.py). Off-TPU — where a second CPU compile
-of a production-sized model would be pure waste and the analysis is
-not the one serving runs — the ledger degrades to the analytic
+program holds (`tpu_custom_call`), the scope path of each of its
+instructions (`instruction_paths`: a device trace names operations
+by instruction and carries no metadata) and which kernels declined
+while it was traced (ops/__init__.py). Off-TPU — where a second CPU
+compile of a production-sized model would be pure waste and the
+analysis is not the one serving runs — the ledger degrades to the analytic
 byte model the quantizer already maintains (models/quant.py
 `quantized_bytes` + KV-capacity arithmetic), flagged
 `source: "model"` so a reader never mistakes an estimate for a
@@ -31,6 +33,7 @@ response body.
 from __future__ import annotations
 
 import logging
+import re
 import threading
 import time
 from collections import OrderedDict
@@ -83,6 +86,79 @@ def device_spec(device=None) -> Dict[str, object]:
         f"no published peaks for device_kind {device.device_kind!r} "
         f"(platform {platform!r}); add it to perf/ledger.py with its "
         f"source")
+
+
+_HLO_COMPUTATION = re.compile(
+    r"^(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .*\{\s*$")
+_HLO_INSTRUCTION = re.compile(r"^\s+(?:ROOT )?%?([\w.\-]+) = (.*)$")
+_HLO_OPCODE = re.compile(r"[)}\]] ([a-z][a-z\-]*)\(")
+_HLO_REF = re.compile(r"%([\w.\-]+)")
+_HLO_OP_NAME = re.compile(r'op_name="([^"]*)"')
+# attributes through which a control-flow instruction runs a
+# computation whose instructions are timed on their own
+_HLO_CONTROL = re.compile(
+    r"\b(?:body|condition|true_computation|false_computation)=%?"
+    r"([\w.\-]+)|branch_computations=\{([^}]*)\}")
+_HLO_FREE = frozenset({"parameter", "constant", "get-tuple-element",
+                       "tuple", "bitcast"})
+
+
+def instruction_paths(hlo_text: str) -> Dict[str, str]:
+    """`instruction name -> op_name path` for the instructions of a
+    compiled module that run as operations of their own (the entry
+    computation and the bodies of its loops and branches), from
+    `compiled.as_text()`.
+
+    A device trace names an operation by its instruction
+    (`%fusion.2`) and carries no metadata; the scope path
+    (`jit(_decode_paged)/decode/sample/...`, telemetry/scopes.py) is
+    in the compiled text. Instructions the compiler made itself (a
+    scatter expanded into a sort, the copies around a loop's carried
+    buffers, `cumsum`'s reduce-window chain) have no path, or a bare
+    one without a scope: each takes the path of the nearest producer
+    that has one — its operands in order, and for a loop body's
+    parameter the loop instruction itself. What still resolves to
+    nothing is left out."""
+    rows = []                          # (name, computation, opcode, own, refs)
+    caller: Dict[str, str] = {}        # computation -> its caller's own path
+    entry = comp = None
+    for line in hlo_text.splitlines():
+        m = _HLO_COMPUTATION.match(line)
+        if m:
+            comp = m.group(1)
+            if line.startswith("ENTRY"):
+                entry = comp
+            continue
+        m = _HLO_INSTRUCTION.match(line)
+        if not m or comp is None:
+            continue
+        name, rest = m.groups()
+        meta = rest.find(", metadata={")
+        body = rest if meta < 0 else rest[:meta]
+        found = _HLO_OP_NAME.search(rest)
+        own = found.group(1) if found and "/" in found.group(1) else ""
+        for one, many in _HLO_CONTROL.findall(body):
+            for ref in [one] + _HLO_REF.findall(many):
+                if ref:
+                    caller[ref] = own
+        op = _HLO_OPCODE.search(body)
+        rows.append((name, comp, op.group(1) if op else "", own,
+                     _HLO_REF.findall(body)))
+    # the text lists an instruction after its operands, and a loop's
+    # body before the loop: one pass in order resolves every producer
+    # first, once a body's parameter stands for the loop instruction
+    resolved: Dict[str, str] = {}
+    out: Dict[str, str] = {}
+    control = {entry} | set(caller)
+    for name, comp, opcode, own, refs in rows:
+        if not own and opcode == "parameter":
+            own = caller.get(comp, "")
+        resolved[name] = own or next(
+            (resolved[r] for r in refs if resolved.get(r)), "")
+        if (resolved[name] and comp in control
+                and opcode not in _HLO_FREE):
+            out[name] = resolved[name]
+    return out
 
 
 def roofline_ms(flops: float, bytes_moved: float, hbm_gbps: float,
@@ -210,6 +286,10 @@ class ProgramLedger:
             # kernels that declined while it was traced (full mode)
             "mosaic_calls": None,
             "kernel_declines": None,
+            # instruction -> scope path of the compiled text (full
+            # mode): what joins a device trace's operation names to
+            # the program's scopes (benchmark/phases.py)
+            "op_names": None,
             "device": spec["kind"],
             "platform": spec["platform"],
             "dispatches": 0,
@@ -262,8 +342,9 @@ class ProgramLedger:
         except Exception:
             ma = None
         try:
-            entry["mosaic_calls"] = compiled.as_text().count(
-                "tpu_custom_call")
+            text = compiled.as_text()
+            entry["mosaic_calls"] = text.count("tpu_custom_call")
+            entry["op_names"] = instruction_paths(text)
         except Exception:
             pass
         if ma is not None:

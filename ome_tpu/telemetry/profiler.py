@@ -2,9 +2,12 @@
 
 The SRE move when a TPU slice serves slow: grab an N-second device
 trace from the LIVE replica (no restart, no redeploy) and open it in
-TensorBoard/XProf. The endpoint is guarded twice — it only exists
-when the operator launched with `--profile-dir`, and captures are
-serialized (a second concurrent request gets 409 instead of
+TensorBoard/XProf. The capture holds the program's own names: phase
+scopes and kernel names on the device operations, the scheduler's
+`sched.*` spans on the host plane of the same clock
+(telemetry/scopes.py, docs/tracing-timeline.md). The endpoint is
+guarded twice — it only exists when the operator launched with
+`--profile-dir`, and captures are serialized (a second concurrent request gets 409 instead of
 corrupting the active trace). Off-TPU the capture is a structured
 no-op: the endpoint answers with `captured: false` and the platform
 name rather than burning seconds tracing a CPU fallback nobody asked
@@ -53,7 +56,16 @@ def capture(out_dir: str, seconds: float = 1.0, ledger=None) -> dict:
         raise ProfileInProgress("a profile capture is already running")
     try:
         t0 = time.monotonic()
-        jax.profiler.start_trace(out_dir)
+        # the capture holds the program's own names (telemetry/
+        # scopes.py): scope paths and kernel names on the device
+        # planes, the scheduler's `sched.*` / `admit.*` annotations
+        # on the host plane. Those are TraceMe events (host tracer);
+        # the Python tracer, which records every Python call, is off:
+        # a smaller capture, and a traced run closer to an untraced
+        options = jax.profiler.ProfileOptions()
+        options.host_tracer_level = 2
+        options.python_tracer_level = 0
+        jax.profiler.start_trace(out_dir, profiler_options=options)
         try:
             time.sleep(seconds)
         finally:

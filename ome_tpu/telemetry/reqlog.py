@@ -21,6 +21,14 @@ enum in ome_tpu/priority.py) — so per-class SLO replay and the
 fairness invariants read tenancy straight off the log. v1/v2
 records stay loadable; readers default a missing `class` to
 "standard".
+
+Schema v4: engine records carry `prefill_s`, the host-observed
+seconds of the request's first prefill call (what
+`ome_engine_prefill_seconds` observes), `null` for a request that
+never prefilled (rejected, shed, failed before its turn). With it a
+request's TTFT reads from inside as queue wait + prefill + the wait
+for its insert and first emission. Additive: older records lack the
+key.
 """
 
 from __future__ import annotations

@@ -186,3 +186,57 @@ def test_int4_quantizer_keeps_no_float32_copy(one_chip):
     w = one_chip((36, 2560, 9728), jnp.bfloat16)
     c = quantize_tensor_int4.lower(w, (1,), group=128).compile()
     assert c.memory_analysis().temp_size_in_bytes < 256 << 20
+
+
+def test_decode_step_carries_the_programs_names(one_chip, monkeypatch):
+    """Two layers of `forward_paged` plus `sample` at Qwen3-4B widths,
+    under the `decode` family as engine/core.py scopes its programs:
+    the compiled text names the attention call `paged_attention` and
+    carries an `op_name` for every scope the trace reduction reads
+    (benchmark/phases.py), through the layer scan's `while`. The
+    scopes are metadata: one Mosaic call, as before they existed."""
+    import re
+
+    from ome_tpu.engine import core
+    from ome_tpu.models import llama
+    from ome_tpu.models.config import ModelConfig
+    from ome_tpu.telemetry import scopes
+
+    monkeypatch.setattr(device, "on_tpu", lambda: True)
+    cfg = ModelConfig(vocab_size=151936, hidden_size=2560, num_layers=2,
+                      num_heads=H, num_kv_heads=K, head_dim=D,
+                      intermediate_size=9728, max_seq_len=2048,
+                      qk_norm=True, tie_word_embeddings=True,
+                      dtype=jnp.bfloat16)
+    n_blocks, max_blocks = 198, 16
+    params = jax.tree.map(
+        lambda a: one_chip(a.shape, a.dtype),
+        jax.eval_shape(lambda k: llama.init_params(k, cfg),
+                       jax.random.PRNGKey(0)))
+    pool = one_chip((2, n_blocks, BS, K, D), jnp.bfloat16)
+
+    @scopes.scoped("decode")
+    def _decode_paged(params, k, v, lengths, table, tokens, key,
+                      temperature, top_k, top_p):
+        cache = llama.PagedKVCache(k=k, v=v, index=lengths, table=table,
+                                   k_scale=None, v_scale=None)
+        logits, nc = llama.forward_paged(params, cfg, tokens[:, None],
+                                         cache)
+        toks = core.sample(logits[:, -1], key, temperature, top_k, top_p)
+        return nc.k, nc.v, toks
+
+    ints = one_chip((B,), jnp.int32)
+    floats = one_chip((B,), jnp.float32)
+    text = _compile(_decode_paged, params, pool, pool, ints,
+                    one_chip((B, max_blocks), jnp.int32), ints,
+                    one_chip((2,), jnp.uint32), floats, ints,
+                    floats).as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert re.search(r"%paged_attention(\.\d+)? = ", text)
+    paths = set(re.findall(r'op_name="([^"]*)"', text))
+    assert any(p.startswith("jit(_decode_paged)/decode/") for p in paths)
+    for scope in ("decode", "layers", "qkv", "kv_write", "attn", "o_proj",
+                  "mlp", "lm_head", "sample"):
+        assert any(scope in p.split("/") for p in paths), scope
+    # inside the scan the path runs through the loop's body
+    assert any("/layers/while/body/" in p and "/mlp/" in p for p in paths)

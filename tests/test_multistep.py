@@ -358,7 +358,7 @@ def _run_comp_matrix(engine, masked, specs=(0, 2), ks=(1, 4),
                 outs[(spec, k, depth)] = [list(r.output_ids)
                                           for r in reqs]
                 chunked[(spec, k, depth)] = \
-                    sched._ph_device_loop.count
+                    sched._ph["device_loop"].count
     return outs, chunked
 
 
@@ -673,7 +673,7 @@ class TestSurfaces:
             sched.registry.render()
         # chunk dispatches attribute their device time to the
         # device_loop phase, not the K=1 dispatch phase
-        assert sched._ph_device_loop.count > 0
+        assert sched._ph["device_loop"].count > 0
         # decode_steps_total counts TOKENS-worth of steps, not chunks
         assert sched.stats["decode_steps_total"] >= \
             len(req.output_ids) - 1

@@ -1,6 +1,6 @@
 """Device-performance attribution (ISSUE 12): program cost ledger,
-live HBM accounting, online roofline + slow-step outliers, and the
-bench regression gate.
+live HBM accounting, the step's completion time + slow-step outliers,
+and the bench regression gate.
 
 Covers: ledger capture in forced-full mode (AOT cost/memory
 introspection works on the CPU backend too) and its off-TPU analytic
@@ -53,6 +53,101 @@ def _tiny_engine(ledger=None, **kw):
     params = init_params(jax.random.PRNGKey(0), cfg)
     return InferenceEngine(params, cfg, max_slots=2, max_seq=64,
                            ledger=ledger, **kw)
+
+
+# -- instruction -> scope path, from the compiled text ----------------
+
+
+HLO = """HloModule jit__decode_paged, is_scheduled=true
+
+%fused_computation.5 (param_0.1: f32[8]) -> f32[8] {
+  %param_0.1 = f32[8]{0} parameter(0)
+  ROOT %neg.1 = f32[8]{0} negate(%param_0.1)
+}
+
+%body.2 (arg: (s32[], f32[8])) -> (s32[], f32[8]) {
+  %arg = (s32[], f32[8]{0}) parameter(0)
+  %get-tuple-element.7 = f32[8]{0} get-tuple-element(%arg), index=1
+  %slice-start.2 = f32[8]{0} copy(%get-tuple-element.7)
+  %fusion.9 = f32[8]{0} fusion(%slice-start.2), kind=kLoop, calls=%fused_computation.5, metadata={op_name="jit(_decode_paged)/decode/layers/while/body/closed_call/mlp/dot_general" source_file="x.py" source_line=3}
+  %copy.3 = f32[8]{0} copy(%fusion.9)
+  ROOT %tuple.4 = (s32[], f32[8]{0}) tuple(%get-tuple-element.7, %copy.3)
+}
+
+%cond.3 (arg.1: (s32[], f32[8])) -> pred[] {
+  %arg.1 = (s32[], f32[8]{0}) parameter(0)
+  ROOT %lt.1 = pred[] constant(true)
+}
+
+ENTRY %main.9 (x.1: f32[8], key.1: u32[2]) -> f32[8] {
+  %x.1 = f32[8]{0} parameter(0)
+  %key.1 = u32[2]{0} parameter(1)
+  %copy.1 = f32[8]{0} copy(%x.1)
+  %tuple.1 = (s32[], f32[8]{0}) tuple(%copy.1, %copy.1)
+  %while.4 = (s32[], f32[8]{0}) while(%tuple.1), condition=%cond.3, body=%body.2, metadata={op_name="jit(_decode_paged)/decode/layers/while"}
+  %get-tuple-element.2 = f32[8]{0} get-tuple-element(%while.4), index=1
+  %copy.78 = f32[8]{0} copy(%get-tuple-element.2)
+  %fusion.2 = f32[8]{0} fusion(%copy.78), kind=kLoop, calls=%fused_computation.5, metadata={op_name="jit(_decode_paged)/decode/sample/jit(argsort)/sort"}
+  %sort.2 = f32[8]{0} sort(%fusion.2), dimensions={0}, to_apply=%cond.3
+  %fusion.5 = f32[8]{0} fusion(%sort.2), kind=kLoop, calls=%fused_computation.5, metadata={op_name="reduce_window_sum"}
+  ROOT %paged_attention.3 = f32[8]{0} custom-call(%fusion.5, %key.1), custom_call_target="tpu_custom_call", metadata={op_name="jit(_decode_paged)/decode/layers/attn/pallas_call"}
+}
+"""
+
+
+class TestInstructionPaths:
+    """perf/ledger.instruction_paths: what joins a device trace's
+    operation names to the program's scopes."""
+
+    @pytest.fixture(scope="class")
+    def paths(self):
+        from ome_tpu.perf.ledger import instruction_paths
+        return instruction_paths(HLO)
+
+    @pytest.mark.parametrize("instr,ends", [
+        ("fusion.9", "/mlp/dot_general"),          # its own
+        ("fusion.2", "/sample/jit(argsort)/sort"),
+        ("while.4", "/decode/layers/while"),
+        ("paged_attention.3", "/attn/pallas_call"),
+        ("sort.2", "/sample/jit(argsort)/sort"),   # its producer's
+        ("fusion.5", "/sample/jit(argsort)/sort"),  # a bare op_name
+        ("copy.78", "/decode/layers/while"),       # through the loop's result
+        ("copy.3", "/mlp/dot_general"),
+        ("slice-start.2", "/decode/layers/while"),  # the body's parameter
+    ])
+    def test_own_path_or_the_nearest_producers(self, paths, instr, ends):
+        assert paths[instr].endswith(ends), paths[instr]
+
+    def test_what_resolves_to_nothing_is_left_out(self, paths):
+        assert "copy.1" not in paths              # hangs on an argument
+
+    def test_only_instructions_that_run_on_their_own(self, paths):
+        for free in ("x.1", "arg", "get-tuple-element.7", "tuple.4",
+                     "neg.1", "lt.1"):
+            assert free not in paths
+
+    def test_full_mode_entry_serves_the_paths_of_a_real_program(self):
+        import jax.numpy as jnp
+        from ome_tpu.telemetry.scopes import scoped
+
+        @jax.jit
+        @scoped("decode")
+        def step(x):
+            with jax.named_scope("sample"):
+                return jnp.cumsum(jnp.tanh(x))
+
+        led = ProgramLedger(mode="full")
+        entry = led.capture("step", "", step, (jnp.ones((8, 128)),), {},
+                            {"flops": 1.0, "bytes": 1.0})
+        assert entry["op_names"]
+        assert all("/decode/" in p for p in entry["op_names"].values())
+        assert any("/decode/sample/" in p
+                   for p in entry["op_names"].values())
+        # the model-mode entry has none, and says so with None
+        model = ProgramLedger(mode="model").capture(
+            "step", "", step, (jnp.ones((8, 128)),), {},
+            {"flops": 1.0, "bytes": 1.0})
+        assert model["op_names"] is None
 
 
 # -- ledger unit behavior --------------------------------------------
@@ -389,29 +484,133 @@ class TestSlowStep:
                     if e["event"] == "slow_step"]
 
 
-# -- online roofline through a real engine ---------------------------
+# -- a step time that is a step ----------------------------------------
 
 
-class TestRooflineOnline:
-    def test_scheduler_exports_roofline_gauges(self):
+class _Lagged:
+    """Device tokens whose host fetch blocks: np.asarray() on it is
+    the scheduler's lag-queue read."""
+
+    def __init__(self, toks, fetch_s):
+        self.toks, self.fetch_s = toks, fetch_s
+
+    def __array__(self, dtype=None, copy=None):
+        time.sleep(self.fetch_s)
+        return self.toks
+
+
+class LaggedEngine(FakeEngine):
+    """Dispatch returns at once; the result takes `fetch_s` to reach
+    the host — a pipelined device step seen from the host."""
+
+    def __init__(self, fetch_s=0.02, **kw):
+        super().__init__(**kw)
+        self.fetch_s = fetch_s
+
+    def decode(self, state, t, k, p):
+        return state, _Lagged(np.full(self.max_slots, 3, np.int32),
+                              self.fetch_s)
+
+
+def _run_to_done(sched, req, timeout=60):
+    sched.submit(req)
+    deadline = time.monotonic() + timeout
+    while not req.done.is_set() and time.monotonic() < deadline:
+        sched.step()
+    assert req.done.is_set()
+
+
+class TestStepTime:
+    REMOVED = ("ome_engine_roofline_efficiency",
+               "ome_engine_step_achieved_gbps",
+               "ome_engine_roofline_step_efficiency")
+
+    @pytest.fixture(scope="class")
+    def lagged(self):
+        sched = Scheduler(LaggedEngine(max_slots=1, fetch_s=0.02))
+        _run_to_done(sched, Request(id="r1", prompt_ids=[1, 2],
+                                    max_new_tokens=12))
+        return sched
+
+    def test_step_seconds_reads_the_completion_not_the_enqueue(
+            self, lagged):
+        h = lagged._h_decode_step
+        assert h.count >= 10
+        mean = h.sum / h.count
+        # parent: ~0 (the fake dispatch returns at once)
+        assert 0.015 < mean < 0.06, mean
+
+    def test_queue_wait_estimator_still_fed_the_dispatch(self, lagged):
+        """`_ewma_step_s` keeps its input: what the dispatch took to
+        return, not the completion (a behaviour change for a PR of
+        its own — PERF.md section 7)."""
+        assert lagged._ewma_step_s is not None
+        assert lagged._ewma_step_s < 0.005
+
+    def test_phase_helper_moves_its_histogram_child(self, lagged):
+        child = lagged._ph["host_sample"]
+        before = (child.count, child.sum, lagged._ewma_step_s)
+        with lagged._phase("host_sample", step=99) as ph:
+            time.sleep(0.01)
+        assert child.count == before[0] + 1
+        assert child.sum - before[1] == pytest.approx(ph.dt)
+        assert ph.dt >= 0.01
+        assert lagged._ewma_step_s == before[2]
+
+    @pytest.mark.parametrize("phase,at_least", [
+        ("plan", 10), ("insert", 1), ("dispatch", 10),
+        ("device_wait", 10), ("host_sample", 10)])
+    def test_phases_observed(self, lagged, phase, at_least):
+        assert lagged._ph[phase].count >= at_least
+        text = lagged.registry.render()
+        assert f'ome_engine_step_phase_seconds_count{{phase="{phase}"}}' \
+            in text
+
+    def test_device_wait_holds_the_fetch_not_the_dispatch(self, lagged):
+        wait, sent = lagged._ph["device_wait"], lagged._ph["dispatch"]
+        assert wait.sum / wait.count > 0.015
+        assert sent.sum / sent.count < 0.005
+
+    def test_idle_server_reads_dispatch_to_result(self):
+        """After a pause the step starts at its own dispatch, not at
+        the previous step's fetch."""
+        sched = Scheduler(LaggedEngine(max_slots=1, fetch_s=0.01))
+        _run_to_done(sched, Request(id="a", prompt_ids=[1],
+                                    max_new_tokens=3))
+        sched.step()   # _run would: reads out the step left in flight
+        time.sleep(0.3)
+        h = sched._h_decode_step
+        before = (h.count, h.sum)
+        _run_to_done(sched, Request(id="b", prompt_ids=[1],
+                                    max_new_tokens=3))
+        steps = h.count - before[0]
+        assert steps >= 2
+        assert (h.sum - before[1]) / steps < 0.1
+
+    @pytest.mark.parametrize("name", REMOVED)
+    def test_roofline_series_are_gone_from_metrics(self, lagged, name):
+        assert name not in lagged.registry.render()
+
+    @pytest.mark.parametrize("name", REMOVED)
+    def test_roofline_series_are_gone_from_the_catalog(self, name):
+        for rel in ("docs/observability.md", "docs/perf-attribution.md",
+                    "ome_tpu/engine/scheduler.py"):
+            with open(os.path.join(REPO, rel)) as f:
+                assert name not in f.read(), rel
+
+    def test_real_engine_still_exports_hbm_and_program_gauges(self):
         eng = _tiny_engine(ledger=ProgramLedger(mode="model"))
         sched = Scheduler(eng)
-        req = Request(id="r1", prompt_ids=[1, 2, 3], max_new_tokens=8)
-        sched.submit(req)
-        deadline = time.monotonic() + 120
-        while not req.done.is_set() and time.monotonic() < deadline:
-            sched.step()
-        assert req.done.is_set()
-        assert sched.registry.get("ome_engine_roofline_efficiency") \
-            > 0
-        assert sched.registry.get("ome_engine_step_achieved_gbps") > 0
-        # histograms resolve to their _count through Registry.get
-        assert sched.registry.get(
-            "ome_engine_roofline_step_efficiency") > 0
+        _run_to_done(sched, Request(id="r1", prompt_ids=[1, 2, 3],
+                                    max_new_tokens=8), timeout=120)
+        assert sched._h_decode_step.count >= 7
         # HBM gauges refresh on the scrape path
         sched.update_gauges()
         assert sched.registry.get("ome_engine_hbm_bytes_in_use") > 0
+        assert "ome_engine_program_bytes" in sched.registry.render()
 
+
+class TestRooflineOnline:
     def test_compile_dispatch_stays_out_of_the_queue_wait_estimate(
             self):
         """A program's first dispatch includes its compilation. On the
