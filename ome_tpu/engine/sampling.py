@@ -3,16 +3,76 @@
 Each decode step samples one token per batch slot. Because slots in the
 continuous-batching engine belong to different requests, temperature /
 top-k / top-p are [B] vectors rather than scalars, and everything is
-computed with static shapes (sort + mask, no data-dependent gathers) so
-the whole step stays inside one compiled XLA program.
+computed with static shapes so the whole step stays inside one compiled
+XLA program. The work follows what the rows ask for, decided on the
+device from those vectors: when no row filters (every row greedy, or
+top_k <= 0 with top_p >= 1) nothing is sorted; when one does, one sort
+of (value, token id) and one comparison against the last kept rank make
+the mask — no [B, V] gather, no scatter.
 """
 
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 NEG_INF = -1.0e30
+
+
+def row_filters(temperature, top_k, top_p):
+    """[B] bool: which rows ask for a filter. Greedy rows never do;
+    top_k <= 0 with top_p >= 1 is the documented "disabled". Plain
+    operators, so the scheduler evaluates the same expression on its
+    host (numpy) copy of the vectors to count the tier a step ran."""
+    return (temperature > 0) & ((top_k > 0) | (top_p < 1.0))
+
+
+def _mask_below_cutoff(scaled: jax.Array, filters: jax.Array,
+                       top_k: jax.Array, top_p: jax.Array) -> jax.Array:
+    """The filter tier: `scaled` [B, V] with every token outside a
+    filtering row's top-k / nucleus prefix set to NEG_INF."""
+    B, V = scaled.shape
+    # one descending ordering, values and token ids together: a stable
+    # ascending sort read backwards, so among equal values the HIGHER
+    # token id ranks first (bf16 logits tie by the thousand; the order
+    # of the ties decides who sits inside a prefix)
+    ids = lax.broadcasted_iota(jnp.int32, (B, V), 1)
+    asc_logits, asc_ids = lax.sort((scaled, ids), dimension=1,
+                                   is_stable=True, num_keys=1)
+    sorted_logits = asc_logits[:, ::-1]
+
+    # both filters are rank-based prefix masks — never
+    # probability-threshold comparisons, which are brittle to softmax
+    # rounding across recomputations
+    ranks = jnp.arange(V)[None, :]
+    # top-k: keep the first k ranks (top_k<=0 disables)
+    keep_k = jnp.where(top_k[:, None] > 0, ranks < top_k[:, None], True)
+
+    # top-p (nucleus): smallest prefix of the sorted distribution whose
+    # mass reaches top_p — a rank is kept if the mass before it is < top_p
+    # (top_p>=1 disables: the float32 cumsum reaches 1.0 before the tail
+    # does, and wavers by an ulp there, so comparing against 1.0 would
+    # mask by rounding)
+    probs_sorted = jax.nn.softmax(sorted_logits, axis=-1)
+    cumulative = jnp.cumsum(probs_sorted, axis=-1)
+    keep_p = jnp.where(top_p[:, None] < 1.0,
+                       (cumulative - probs_sorted) < top_p[:, None], True)
+
+    # the kept set is a prefix of ranks (rank 0 always survives both),
+    # so its length says everything: the first dropped rank, V for a
+    # row of a mixed batch that does not filter
+    dropped = ~(keep_k & keep_p) & filters[:, None]
+    n_keep = jnp.min(jnp.where(dropped, ranks, V), axis=-1)  # [B] >= 1
+
+    # the value and token id at the last kept rank, a [B, 1] read;
+    # token j is kept iff it ranks at or before that one: a larger
+    # value, or the same value and (the tie order above) an id >= its
+    cut = (V - n_keep)[:, None]  # position in the ascending order
+    v_cut = jnp.take_along_axis(asc_logits, cut, axis=-1)
+    id_cut = jnp.take_along_axis(asc_ids, cut, axis=-1)
+    keep = (scaled > v_cut) | ((scaled == v_cut) & (ids >= id_cut))
+    return jnp.where(keep, scaled, NEG_INF)
 
 
 def filtered_logits(logits: jax.Array, temperature: jax.Array,
@@ -22,36 +82,27 @@ def filtered_logits(logits: jax.Array, temperature: jax.Array,
     The distribution `sample` (and the speculative verify acceptance
     rule) actually draws from: logits [B, V] float, params [B].
     Filtered-out entries are NEG_INF; greedy rows (temperature<=0)
-    pass through with temperature 1 — callers pick argmax for those.
-    Returns [B, V] float32.
+    pass through with temperature 1 and unmasked — callers pick argmax
+    for those. Returns [B, V] float32.
+
+    A row filters iff temperature > 0 and (top_k > 0 or top_p < 1)
+    (`row_filters`); top_k <= 0 with top_p >= 1 filters nothing, and
+    such a row comes back as logits / temperature. The sort runs only when some row of
+    the call filters (a `lax.cond` on that one scalar). Then ranks
+    descend by value and, among equal values, by token id; the kept
+    set is the prefix of ranks inside both top-k and the nucleus.
     """
     logits = logits.astype(jnp.float32)
-    B, V = logits.shape
 
     # scale by temperature (guard the greedy rows against div-by-zero)
     safe_t = jnp.where(temperature > 0, temperature, 1.0)[:, None]
     scaled = logits / safe_t
 
-    # one descending ordering; both filters are rank-based prefix masks
-    # scattered back by rank — never probability-threshold comparisons,
-    # which are brittle to softmax rounding across recomputations
-    order = jnp.argsort(scaled, axis=-1)[:, ::-1]  # [B, V] desc indices
-    sorted_logits = jnp.take_along_axis(scaled, order, axis=-1)
-
-    ranks = jnp.arange(V)[None, :]
-    # top-k: keep the first k ranks (top_k<=0 disables)
-    keep_k = jnp.where(top_k[:, None] > 0, ranks < top_k[:, None], True)
-
-    # top-p (nucleus): smallest prefix of the sorted distribution whose
-    # mass reaches top_p — a rank is kept if the mass before it is < top_p
-    probs_sorted = jax.nn.softmax(sorted_logits, axis=-1)
-    cumulative = jnp.cumsum(probs_sorted, axis=-1)
-    keep_p = (cumulative - probs_sorted) < top_p[:, None]
-
-    keep_sorted = keep_k & keep_p  # rank 0 always survives both
-    keep = jax.vmap(
-        lambda o, m: jnp.zeros((V,), bool).at[o].set(m))(order, keep_sorted)
-    return jnp.where(keep, scaled, NEG_INF)
+    filters = row_filters(temperature, top_k, top_p)
+    return lax.cond(
+        jnp.any(filters),
+        lambda s: _mask_below_cutoff(s, filters, top_k, top_p),
+        lambda s: s, scaled)
 
 
 def sample(logits: jax.Array, key: jax.Array, temperature: jax.Array,

@@ -66,6 +66,7 @@ from ..telemetry.tracing import Span, SpanContext, coerce_span_log, \
     new_trace
 from . import spec as spec_drafter
 from .core import DecodeState, InferenceEngine
+from .sampling import row_filters
 
 _ids = itertools.count()
 
@@ -684,6 +685,9 @@ class Scheduler:
         # one jnp tuple), rebuilt only when a slot's occupancy or
         # params change — not three np.asarray uploads per step
         self._sampling_dev: Optional[tuple] = None
+        # which tier of sampling.filtered_logits the cached params
+        # select; re-evaluated with the device copy (_sampling)
+        self._sample_tier = "plain"
         # device-resident [B, NS] per-slot stop table for multi-step
         # chunks, cached on the same invalidation rule
         self._stops_dev = None
@@ -828,6 +832,18 @@ class Scheduler:
             "Decode iterations fused per device dispatch (the "
             "--steps-per-dispatch K; 1 = per-token dispatch)")
         self._g_steps_per_dispatch.set(self.steps_per_dispatch)
+        # which tier of sampling.filtered_logits each decode step ran:
+        # `plain` when no slot filters (no sort on the device),
+        # `filtered` when one does. Decided on the host from the same
+        # [B] vectors the device reads; the two sum to decode_steps.
+        _tier = R.counter(
+            "ome_engine_sample_tier_steps_total",
+            "Decode steps by the sampling tier their batch selected "
+            "(plain: no slot sets top_k/top_p, nothing is sorted / "
+            "filtered: at least one sampling slot does)",
+            labelnames=("tier",))
+        self._c_sample_tier = {t: _tier.labels(tier=t)
+                               for t in ("plain", "filtered")}
         self._c_flight_events = R.counter(
             "ome_engine_flight_events_total",
             "Scheduler lifecycle events recorded by the flight ring")
@@ -1893,6 +1909,10 @@ class Scheduler:
             self._sampling_dev = (jnp.asarray(self._temp),
                                   jnp.asarray(self._top_k),
                                   jnp.asarray(self._top_p))
+            # the predicate sampling.filtered_logits evaluates on the
+            # device, here on the host's copy of the same vectors
+            self._sample_tier = "filtered" if row_filters(
+                self._temp, self._top_k, self._top_p).any() else "plain"
         return self._sampling_dev
 
     def _stop_table(self):
@@ -2635,6 +2655,7 @@ class Scheduler:
             self._ewma_step_s = dt_step if self._ewma_step_s is None \
                 else 0.9 * self._ewma_step_s + 0.1 * dt_step
         self._inc("decode_steps_total", n_steps)
+        self._c_sample_tier[self._sample_tier].inc(n_steps)
         if plan.kind == "verify":
             self._inc("spec_steps_total")
             self._inc("spec_proposed_tokens_total",
