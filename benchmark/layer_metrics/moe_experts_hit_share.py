@@ -1,0 +1,15 @@
+"""Programs: held experts that a routed pair of the decode batch
+reached, a layer-step, over the experts held, %. From the program's
+counters (`ome_engine_moe_experts_hit_total` over
+`ome_engine_moe_layer_steps_total`, counted on the device), as they
+moved over the window. What the decode step's expert bytes scale
+with: an expert that no token reached need not be read."""
+
+import subphases
+
+
+def read(ctx):
+    hit = subphases.experts_hit_a_layer(ctx)
+    if hit is None:
+        return None
+    return 100.0 * hit / ctx["config"]["num_experts"]

@@ -63,6 +63,41 @@ class DecodeState:
     # bf16 pools and the dense cache
     k_scale: jax.Array = None
     v_scale: jax.Array = None
+    # hybrid models only (cfg.is_hybrid), the second kind of per-slot
+    # state: what a slot carries through the Gated DeltaNet layers,
+    # {"S": [Ll, B, Hv, dk, dv] float32, "conv": [Ll, B, W-1, C]}
+    # (llama.recurrent_state); k and v then hold the full-attention
+    # layers only. A slot's insert overwrites its rows of both; there
+    # is nothing to free
+    rec: dict = None
+    # models served with a share of their experts only: [3] uint32
+    # counters the decode programs add to (llama.KVCache.stats)
+    moe_stats: jax.Array = None
+
+
+def _cache_of(st: DecodeState) -> llama.KVCache:
+    """The dense slab (and a hybrid model's recurrent state) of a
+    decode state as the model's forward takes it."""
+    return llama.KVCache(k=st.k, v=st.v, index=st.lengths, rec=st.rec,
+                         stats=st.moe_stats)
+
+
+def _state_of(nc: llama.KVCache, toks: jax.Array, st: DecodeState,
+              lengths: Optional[jax.Array] = None) -> DecodeState:
+    """The decode state after a forward over the dense slab left
+    `nc`; `lengths` where they are not the cache's own index."""
+    return DecodeState(k=nc.k, v=nc.v,
+                       lengths=nc.index if lengths is None else lengths,
+                       tokens=toks, adapters=st.adapters, rec=nc.rec,
+                       moe_stats=nc.stats)
+
+
+def _counts_of(st: DecodeState) -> tuple:
+    """What a dense decode program returns after its other results:
+    the expert counters as an output of their own, which no later
+    step donates (InferenceEngine.moe_counters reads it), or nothing
+    for a model that does not count."""
+    return () if st.moe_stats is None else (st.moe_stats + 0,)
 
 
 class UnknownAdapterError(ValueError):
@@ -430,6 +465,43 @@ class PrefixCache:
                 self._ensure_swap_thread()
 
 
+# what cannot be had with a hybrid model, whose DeltaNet layers carry
+# recurrent state per slot and not KV rows, and why: feature -> reason.
+# The engine refuses the first four at construction, serve.py all of
+# them at start-up (docs/recurrent-state.md)
+RECURRENT_STATE_REFUSALS = {
+    "kv_block": "--kv-block: the paged pool holds KV rows by block; "
+                "a slot's recurrent state is not rows and has no "
+                "place in it",
+    "prefix_cache": "--prefix-cache-mb / --prefix-cache-host-mb: a "
+                    "cached prefix holds the KV rows of the full-"
+                    "attention layers without the recurrent state at "
+                    "the prefix's end, so a suffix prefill cannot "
+                    "resume from it; pass --prefix-cache-mb 0",
+    "lora": "--adapter name=dir / --lora-slots: the adapter stacks "
+            "cover the attention and MLP projections of one block "
+            "kind, not the DeltaNet mixer's",
+    "tp": "--tp: the sharded engine has no rules for the DeltaNet "
+          "leaves or the recurrent state",
+    "spec_tokens": "--spec-tokens: rejecting drafted tokens needs the "
+                   "recurrent state rolled back to the last accepted "
+                   "one, and a verify step keeps only the state after "
+                   "all of them",
+    "pd": "--disaggregation-mode: the PD transfer ships KV rows only",
+    "journal": "--journal: resume re-enters requests through the "
+               "prefix-seeded path, which has no recurrent state to "
+               "seed",
+}
+
+
+def recurrent_state_refusals(cfg: ModelConfig, **asked) -> List[str]:
+    """Reasons, for the features in `asked` that are truthy, why a
+    hybrid model cannot be served with them; [] for any other model."""
+    if not cfg.is_hybrid:
+        return []
+    return [RECURRENT_STATE_REFUSALS[k] for k, v in asked.items() if v]
+
+
 class InferenceEngine:
     """Compiled prefill/insert/decode over one model + one mesh."""
 
@@ -460,6 +532,15 @@ class InferenceEngine:
         # more slots with mixed-length sequences (vLLM/SGLang
         # PagedAttention, TPU-static: ops/paged.py; r4 verdict #2)
         self.kv_block = int(kv_block)
+        refused = recurrent_state_refusals(
+            cfg, kv_block=kv_block,
+            prefix_cache=prefix_cache_bytes or prefix_host_bytes,
+            lora=lora_slots)
+        if refused:
+            raise ValueError(
+                "this model's linear-attention layers carry recurrent "
+                "state per slot, not KV rows; refused: "
+                + "; ".join(refused))
         # int8-quantized paged pools (--kv-dtype int8): KV rows are
         # stored as int8 + a per-(row, head) f32 scale plane, halving
         # block-pool HBM per cached token — the same budget holds ~2x
@@ -479,11 +560,13 @@ class InferenceEngine:
             if (cfg.mla or cfg.is_moe or cfg.first_k_dense
                     or cfg.sliding_window or cfg.alt_sliding_window
                     or cfg.norm_type != "rmsnorm" or cfg.parallel_block
-                    or cfg.attn_sinks):
+                    or cfg.attn_sinks or cfg.is_hybrid):
                 raise ValueError(
                     "paged KV supports standard rmsnorm GQA models; "
                     "MLA/MoE/sliding-window/parallel-block/layernorm/"
-                    "sink models use the dense cache")
+                    "sink models and hybrid models with recurrent-"
+                    "state (linear-attention) layers use the dense "
+                    "cache")
             if device.on_tpu() and (
                     self.kv_block % 128 or cfg.head_dim % 128
                     or cfg.num_heads < 8):
@@ -521,6 +604,16 @@ class InferenceEngine:
                 b *= 2
             prefill_buckets.append(self.max_seq)
         self.prefill_buckets = prefill_buckets
+        # an expert layer that holds a share of its experts counts
+        # what it is hit by (llama.ragged_experts), on the device
+        self._counts_experts = bool(
+            cfg.is_hybrid and cfg.is_moe and cfg.moe_impl == "ragged")
+        self._moe_stats_dev: Optional[jax.Array] = None
+        self._moe_seen = [0, 0, 0]
+        self._moe_totals = {"layer_steps": 0, "experts_hit": 0,
+                            "pairs": 0}
+        import threading
+        self._moe_lock = threading.Lock()
         self.prefix_cache = PrefixCache(
             prefix_cache_bytes,
             host_capacity_bytes=prefix_host_bytes)
@@ -557,6 +650,7 @@ class InferenceEngine:
             self.params = dict(params, layers=layers)
 
         cfg_ = cfg
+        hybrid = cfg.is_hybrid
 
         @functools.partial(jax.jit, static_argnames=("bucket",))
         @scoped("prefill")
@@ -565,13 +659,18 @@ class InferenceEngine:
                      bucket: int):
             cache = llama.KVCache.create(cfg_, 1, bucket)
             # last REAL token's logits only (right padding occupies
-            # the tail): the head runs on that one row
+            # the tail): the head runs on that one row. KV rows hide
+            # the padded tail behind the slot's length; a hybrid
+            # model's recurrent state is handed back as it stood at
+            # true_len (valid_len)
             logits, new_cache = llama.forward(params, cfg_, padded,
                                               cache=cache,
                                               adapter_ids=adapter,
-                                              logits_at=true_len - 1)
+                                              logits_at=true_len - 1,
+                                              valid_len=true_len)
             tok = sample(logits[:, 0], key, temperature, top_k, top_p)
-            return tok[0], new_cache.k, new_cache.v
+            return (tok[0], new_cache.k, new_cache.v) + (
+                (new_cache.rec,) if hybrid else ())
 
         @functools.partial(jax.jit,
                            static_argnames=("total_bucket", "keep"))
@@ -608,31 +707,38 @@ class InferenceEngine:
         @scoped("insert")
         def _insert(state: DecodeState, kv_k, kv_v, slot: jax.Array,
                     true_len: jax.Array, token: jax.Array,
-                    adapter: jax.Array, bucket: int):
+                    adapter: jax.Array, rec=None, *, bucket: int):
             keep = min(bucket, self.max_seq)
             k = lax.dynamic_update_slice(
                 state.k, kv_k[:, :, :keep], (0, slot, 0, 0, 0))
             v = lax.dynamic_update_slice(
                 state.v, kv_v[:, :, :keep], (0, slot, 0, 0, 0))
+            new_rec = state.rec
+            if rec is not None:
+                # the slot's recurrent state, whole: [Ll, 1, ...] into
+                # batch row `slot` of [Ll, B, ...]
+                new_rec = jax.tree.map(
+                    lambda whole, one: lax.dynamic_update_slice(
+                        whole, one.astype(whole.dtype),
+                        (0, slot) + (0,) * (whole.ndim - 2)),
+                    state.rec, rec)
             return DecodeState(
                 k=k, v=v,
                 lengths=state.lengths.at[slot].set(true_len),
                 tokens=state.tokens.at[slot].set(token),
-                adapters=state.adapters.at[slot].set(adapter))
+                adapters=state.adapters.at[slot].set(adapter),
+                rec=new_rec, moe_stats=state.moe_stats)
 
         @functools.partial(jax.jit, donate_argnums=(1,))
         @scoped("decode")
         def _decode(params, state: DecodeState, temperature, top_k, top_p,
                     key) -> Tuple[DecodeState, jax.Array]:
-            cache = llama.KVCache(k=state.k, v=state.v, index=state.lengths)
             logits, new_cache = llama.forward(
-                params, cfg_, state.tokens[:, None], cache=cache,
-                adapter_ids=state.adapters)
+                params, cfg_, state.tokens[:, None],
+                cache=_cache_of(state), adapter_ids=state.adapters)
             toks = sample(logits[:, -1], key, temperature, top_k, top_p)
-            return DecodeState(k=new_cache.k, v=new_cache.v,
-                               lengths=new_cache.index,
-                               tokens=toks,
-                               adapters=state.adapters), toks
+            new_state = _state_of(new_cache, toks, state)
+            return (new_state, toks) + _counts_of(new_state)
 
         @functools.partial(jax.jit, donate_argnums=(1,))
         @scoped("decode")
@@ -643,16 +749,13 @@ class InferenceEngine:
             outputs / JSON mode — engine/structured.py). Separate
             program so unconstrained batches never pay the mask
             transfer."""
-            cache = llama.KVCache(k=state.k, v=state.v, index=state.lengths)
             logits, new_cache = llama.forward(
-                params, cfg_, state.tokens[:, None], cache=cache,
-                adapter_ids=state.adapters)
+                params, cfg_, state.tokens[:, None],
+                cache=_cache_of(state), adapter_ids=state.adapters)
             masked = jnp.where(mask, logits[:, -1], -jnp.inf)
             toks = sample(masked, key, temperature, top_k, top_p)
-            return DecodeState(k=new_cache.k, v=new_cache.v,
-                               lengths=new_cache.index,
-                               tokens=toks,
-                               adapters=state.adapters), toks
+            new_state = _state_of(new_cache, toks, state)
+            return (new_state, toks) + _counts_of(new_state)
 
         @functools.partial(jax.jit, static_argnames=("bucket",))
         @scoped("prefill")
@@ -665,10 +768,12 @@ class InferenceEngine:
             logits, new_cache = llama.forward(params, cfg_, padded,
                                               cache=cache,
                                               adapter_ids=adapter,
-                                              logits_at=true_len - 1)
+                                              logits_at=true_len - 1,
+                                              valid_len=true_len)
             last = jnp.where(mask, logits[:, 0], -jnp.inf)
             tok = sample(last, key, temperature, top_k, top_p)
-            return tok[0], new_cache.k, new_cache.v
+            return (tok[0], new_cache.k, new_cache.v) + (
+                (new_cache.rec,) if hybrid else ())
 
         kvb = self.kv_block
         kvq = self.kv_quantized
@@ -784,7 +889,10 @@ class InferenceEngine:
             rows leave a slot unconstrained."""
             st, done, acc, adv = carry
             active = (~done) & (i < budget) & (st.lengths < smax)
-            logits, nc = forward_one(st)
+            # a frozen slot's recurrent state (hybrid models) must not
+            # move either: `active` reaches the DeltaNet layers as the
+            # row's valid length
+            logits, nc = forward_one(st, active)
             last = logits[:, -1]
             if mask is not None:
                 last = jnp.where(mask[:, i], last, -jnp.inf)
@@ -799,7 +907,9 @@ class InferenceEngine:
                 lengths=jnp.where(active, nc.index, st.lengths),
                 tokens=toks, adapters=st.adapters,
                 k_scale=getattr(nc, "k_scale", None),
-                v_scale=getattr(nc, "v_scale", None))
+                v_scale=getattr(nc, "v_scale", None),
+                rec=getattr(nc, "rec", None),
+                moe_stats=getattr(nc, "stats", None))
             return st, done, acc, adv
 
         def _multi_loop(state, key, temperature, top_k, top_p, budget,
@@ -820,7 +930,7 @@ class InferenceEngine:
                     stop_ids=stop_ids, forward_one=forward_one,
                     mask=mask),
                 carry)
-            return state, acc, adv
+            return (state, acc, adv) + _counts_of(state)
 
         @functools.partial(jax.jit, donate_argnums=(1,),
                            static_argnames=("n",))
@@ -839,12 +949,11 @@ class InferenceEngine:
             tokens[b, :advanced[b]], the rest is frozen filler the
             host discards."""
 
-            def forward_one(st):
-                cache = llama.KVCache(k=st.k, v=st.v,
-                                      index=st.lengths)
+            def forward_one(st, active):
                 return llama.forward(params, cfg_, st.tokens[:, None],
-                                     cache=cache,
-                                     adapter_ids=st.adapters)
+                                     cache=_cache_of(st),
+                                     adapter_ids=st.adapters,
+                                     valid_len=active.astype(jnp.int32))
 
             return _multi_loop(state, key, temperature, top_k, top_p,
                                budget, stop_ids, forward_one, n)
@@ -862,7 +971,7 @@ class InferenceEngine:
             commit_spec() reconciles lengths + returns the surplus
             once `advanced` is drained."""
 
-            def forward_one(st):
+            def forward_one(st, active):
                 cache = llama.PagedKVCache(k=st.k, v=st.v,
                                            index=st.lengths,
                                            table=table,
@@ -885,12 +994,11 @@ class InferenceEngine:
             stack (structured outputs inside a fused chunk). Separate
             program so unmasked chunks never pay the mask transfer."""
 
-            def forward_one(st):
-                cache = llama.KVCache(k=st.k, v=st.v,
-                                      index=st.lengths)
+            def forward_one(st, active):
                 return llama.forward(params, cfg_, st.tokens[:, None],
-                                     cache=cache,
-                                     adapter_ids=st.adapters)
+                                     cache=_cache_of(st),
+                                     adapter_ids=st.adapters,
+                                     valid_len=active.astype(jnp.int32))
 
             return _multi_loop(state, key, temperature, top_k, top_p,
                                budget, stop_ids, forward_one, n,
@@ -904,7 +1012,7 @@ class InferenceEngine:
                                        top_p, key, budget, stop_ids,
                                        mask, n: int):
 
-            def forward_one(st):
+            def forward_one(st, active):
                 cache = llama.PagedKVCache(k=st.k, v=st.v,
                                            index=st.lengths,
                                            table=table,
@@ -1049,17 +1157,13 @@ class InferenceEngine:
                                ) -> Tuple[DecodeState, jax.Array]:
             """Decode gathering each slot's allowed-token row from the
             device mask table by state index ([B] int32)."""
-            cache = llama.KVCache(k=state.k, v=state.v,
-                                  index=state.lengths)
             logits, new_cache = llama.forward(
-                params, cfg_, state.tokens[:, None], cache=cache,
-                adapter_ids=state.adapters)
+                params, cfg_, state.tokens[:, None],
+                cache=_cache_of(state), adapter_ids=state.adapters)
             masked = jnp.where(mtab[midx], logits[:, -1], -jnp.inf)
             toks = sample(masked, key, temperature, top_k, top_p)
-            return DecodeState(k=new_cache.k, v=new_cache.v,
-                               lengths=new_cache.index,
-                               tokens=toks,
-                               adapters=state.adapters), toks
+            new_state = _state_of(new_cache, toks, state)
+            return (new_state, toks) + _counts_of(new_state)
 
         @functools.partial(jax.jit, donate_argnums=(1,))
         @scoped("decode")
@@ -1091,12 +1195,11 @@ class InferenceEngine:
             """Multi-token decode whose per-iteration [B, n, V] mask
             stack is gathered from the mask table ([B, n] int32)."""
 
-            def forward_one(st):
-                cache = llama.KVCache(k=st.k, v=st.v,
-                                      index=st.lengths)
+            def forward_one(st, active):
                 return llama.forward(params, cfg_, st.tokens[:, None],
-                                     cache=cache,
-                                     adapter_ids=st.adapters)
+                                     cache=_cache_of(st),
+                                     adapter_ids=st.adapters,
+                                     valid_len=active.astype(jnp.int32))
 
             return _multi_loop(state, key, temperature, top_k, top_p,
                                budget, stop_ids, forward_one, n,
@@ -1111,7 +1214,7 @@ class InferenceEngine:
                                            stop_ids, mtab, midx,
                                            n: int):
 
-            def forward_one(st):
+            def forward_one(st, active):
                 cache = llama.PagedKVCache(k=st.k, v=st.v,
                                            index=st.lengths,
                                            table=table,
@@ -1247,11 +1350,26 @@ class InferenceEngine:
         (layer, head) row."""
         cfg = self.cfg
         if getattr(self, "kv_quantized", False):
-            return cfg.num_layers * cfg.kv_cache_heads * (
+            return cfg.kv_cache_layers * cfg.kv_cache_heads * (
                 cfg.kv_cache_k_dim + cfg.kv_cache_v_dim + 2 * 4)
-        return (cfg.num_layers * cfg.kv_cache_heads
+        return (cfg.kv_cache_layers * cfg.kv_cache_heads
                 * (cfg.kv_cache_k_dim + cfg.kv_cache_v_dim)
                 * jnp.dtype(cfg.dtype).itemsize)
+
+    def state_bytes(self) -> int:
+        """HBM bytes of the recurrent state all slots hold together
+        (a hybrid model's DeltaNet layers: the float32 state matrices
+        and the conv tails); 0 for every other model. The second
+        tenant beside `kv_row_bytes` (perf/hbm.py)."""
+        cfg = self.cfg
+        if not cfg.is_hybrid:
+            return 0
+        per_slot = cfg.linear_layers * (
+            cfg.linear_num_value_heads * cfg.linear_key_head_dim
+            * cfg.linear_value_head_dim * 4
+            + (cfg.linear_conv_kernel - 1) * cfg.linear_conv_dim
+            * jnp.dtype(cfg.dtype).itemsize)
+        return self.max_slots * per_slot
 
     def _cost_model(self, tokens: int, kv_rows: int,
                     weight_passes: int = 1) -> Dict[str, float]:
@@ -1306,7 +1424,7 @@ class InferenceEngine:
 
     def new_state(self) -> DecodeState:
         cfg = self.cfg
-        L, B, S = cfg.num_layers, self.max_slots, self.max_seq
+        L, B, S = cfg.kv_cache_layers, self.max_slots, self.max_seq
         if self.kv_block:
             # pool-shaped k/v; the block table stays host-side and is
             # passed to the decode program each step (tiny int32)
@@ -1340,7 +1458,44 @@ class InferenceEngine:
             v=jnp.zeros(base + (cfg.kv_cache_v_dim,), cfg.dtype),
             lengths=jnp.zeros((B,), jnp.int32),
             tokens=jnp.zeros((B,), jnp.int32),
-            adapters=jnp.zeros((B,), jnp.int32))
+            adapters=jnp.zeros((B,), jnp.int32),
+            rec=llama.recurrent_state(cfg, B),
+            moe_stats=(jnp.zeros((3,), jnp.uint32)
+                       if self._counts_experts else None))
+
+    def _take_counts(self, outs: tuple) -> tuple:
+        """Split off the expert counters that a counting engine's
+        dense decode programs return last (`_counts_of`)."""
+        if self._counts_experts:
+            *outs, self._moe_stats_dev = outs
+        return tuple(outs)
+
+    def moe_counters(self) -> Optional[Dict[str, int]]:
+        """Totals of the expert layers' device counters since start:
+        layer-steps run by decode programs, held experts hit, routed
+        pairs that landed on a held expert. None for a model that
+        does not count. Reads the counters the LAST dispatched decode
+        step returned (a fresh output, never donated), so it waits
+        for that step at most and costs the step path nothing; call
+        it from a scrape, not from the decode loop. The device adds
+        in uint32 and the totals here follow its wrap-around, which
+        holds as long as two reads are under 2**32 pairs apart
+        (hours of decoding)."""
+        if not self._counts_experts:
+            return None
+        dev = self._moe_stats_dev
+        if dev is not None:
+            now = [int(x) for x in np.asarray(dev)]  # may wait a step
+            with self._moe_lock:
+                grown = [(n - seen) % (1 << 32)
+                         for n, seen in zip(now, self._moe_seen)]
+                # a reading older than the last one applied (two
+                # scrapes at once) would look like a wrap: drop it
+                if max(grown) < (1 << 31):
+                    for name, d in zip(self._moe_totals, grown):
+                        self._moe_totals[name] += d
+                    self._moe_seen = now
+        return dict(self._moe_totals)
 
     # -- paged-pool block allocator ------------------------------------
 
@@ -1664,6 +1819,7 @@ class InferenceEngine:
                 self._prefill_suffix_fn, args, kw,
                 tokens=sbucket, kv_rows=bucket)
             tok, k, v = self._prefill_suffix_fn(*args, **kw)
+            rec = []
         else:
             bucket = _bucketize(len(ids), self.prefill_buckets)
             padded = np.asarray(
@@ -1679,8 +1835,8 @@ class InferenceEngine:
                     self._prefill_masked_fn, args,
                     dict(bucket=bucket), tokens=bucket,
                     kv_rows=bucket)
-                tok, k, v = self._prefill_masked_fn(*args,
-                                                    bucket=bucket)
+                tok, k, v, *rec = self._prefill_masked_fn(
+                    *args, bucket=bucket)
             else:
                 args = (self.params, padded,
                         np.asarray([len(ids)], np.int32), *sampling,
@@ -1689,13 +1845,16 @@ class InferenceEngine:
                     "prefill", f"bucket={bucket}", self._prefill_fn,
                     args, dict(bucket=bucket), tokens=bucket,
                     kv_rows=bucket)
-                tok, k, v = self._prefill_fn(*args, bucket=bucket)
+                tok, k, v, *rec = self._prefill_fn(*args,
+                                                   bucket=bucket)
         if aid == 0:
             self.prefix_cache.put(ids, k, v, len(ids), bucket)
         # multi-host: int() on an array spanning non-addressable
         # devices raises; fetch the local replica instead
         from .multihost import host_value
-        return int(host_value(tok)), (k, v), len(ids), bucket
+        # a hybrid model's prefill hands its recurrent state at
+        # true_len as a third element; insert() takes the tuple whole
+        return int(host_value(tok)), (k, v, *rec), len(ids), bucket
 
     def blocks_needed(self, n_tokens: int) -> int:
         """Pool blocks covering `n_tokens` KV rows + the next write —
@@ -1751,10 +1910,14 @@ class InferenceEngine:
                 np.asarray(slot, np.int32),
                 np.asarray(true_len, np.int32),
                 np.asarray(token, np.int32), aid, bucket=bucket)
+        if self.cfg.is_hybrid and len(kv) < 3:
+            raise ValueError(
+                "insert: this model's slots own recurrent state beside "
+                "their KV rows; the prefill result holds none")
         return self._insert_fn(
             state, kv[0], kv[1], np.asarray(slot, np.int32),
             np.asarray(true_len, np.int32),
-            np.asarray(token, np.int32), aid,
+            np.asarray(token, np.int32), aid, *kv[2:],
             bucket=bucket)
 
     def _mask_table(self) -> jax.Array:
@@ -1846,20 +2009,23 @@ class InferenceEngine:
                 "decode_masked_idx", "", self._decode_masked_idx_fn,
                 args, {}, tokens=self.max_slots,
                 kv_rows=self._kv_capacity_rows())
-            state, toks = self._decode_masked_idx_fn(*args)
+            state, toks = self._take_counts(
+                self._decode_masked_idx_fn(*args))
         elif mask is not None:
             args = (self.params, state, *sampling, key,
                     np.asarray(mask, bool))
             self._ledger_capture(
                 "decode_masked", "", self._decode_masked_fn, args, {},
                 tokens=self.max_slots, kv_rows=self._kv_capacity_rows())
-            state, toks = self._decode_masked_fn(*args)
+            state, toks = self._take_counts(
+                self._decode_masked_fn(*args))
         else:
             args = (self.params, state, *sampling, key)
             self._ledger_capture(
                 "decode", "", self._decode_fn, args, {},
                 tokens=self.max_slots, kv_rows=self._kv_capacity_rows())
-            state, toks = self._decode_fn(*args)
+            state, toks = self._take_counts(
+                self._decode_fn(*args))
         copy = getattr(toks, "copy_to_host_async", None)
         if copy is not None:  # sharded/global arrays may not have it
             copy()
@@ -1952,8 +2118,8 @@ class InferenceEngine:
                 self._decode_multi_masked_idx_fn, args, dict(n=n),
                 tokens=self.max_slots * n,
                 kv_rows=n * self._kv_capacity_rows(), weight_passes=n)
-            state, toks, adv = \
-                self._decode_multi_masked_idx_fn(*args, n=n)
+            state, toks, adv = self._take_counts(
+                self._decode_multi_masked_idx_fn(*args, n=n))
         elif mask is not None:
             args = (self.params, state, *sampling, key, budget,
                     stop_ids, np.asarray(mask, bool))
@@ -1962,7 +2128,8 @@ class InferenceEngine:
                 self._decode_multi_masked_fn, args, dict(n=n),
                 tokens=self.max_slots * n,
                 kv_rows=n * self._kv_capacity_rows(), weight_passes=n)
-            state, toks, adv = self._decode_multi_masked_fn(*args, n=n)
+            state, toks, adv = self._take_counts(
+                self._decode_multi_masked_fn(*args, n=n))
         else:
             args = (self.params, state, *sampling, key, budget,
                     stop_ids)
@@ -1970,7 +2137,8 @@ class InferenceEngine:
                 "decode_multi", f"n={n}", self._decode_multi_fn, args,
                 dict(n=n), tokens=self.max_slots * n,
                 kv_rows=n * self._kv_capacity_rows(), weight_passes=n)
-            state, toks, adv = self._decode_multi_fn(*args, n=n)
+            state, toks, adv = self._take_counts(
+                self._decode_multi_fn(*args, n=n))
         for arr in (toks, adv):
             copy = getattr(arr, "copy_to_host_async", None)
             if copy is not None:
@@ -2007,6 +2175,8 @@ class InferenceEngine:
         covers in-flight plans, and reconcile each drained step with
         commit_spec(slot, accepted+1, reserve=...) — the same surplus
         discipline as decode_multi."""
+        if self.cfg.is_hybrid:
+            raise ValueError(RECURRENT_STATE_REFUSALS["spec_tokens"])
         key = self._next_key()
         sampling = (_sampling_array(temperature, np.float32),
                     _sampling_array(top_k, np.int32),

@@ -781,6 +781,30 @@ class Scheduler:
             buckets=(0.0, 1.0, 2.0, 3.0, 4.0, 6.0, 8.0, 12.0, 16.0))
         # prefix-cache observability (engine counters are plain ints;
         # update_gauges mirrors them by delta so /metrics sees them)
+        self._g_state_bytes = R.gauge(
+            "ome_engine_state_bytes",
+            "Device bytes of recurrent state all decode slots hold "
+            "together (a hybrid model's linear-attention layers: a "
+            "float32 state matrix a head and a conv tail a slot); 0 "
+            "for a model whose slots own KV rows only")
+        self._c_moe = {
+            "layer_steps": R.counter(
+                "ome_engine_moe_layer_steps_total",
+                "Expert layers run by decode programs (layers x "
+                "steps) on an engine that holds a share of its "
+                "experts; the denominator of the two below"),
+            "experts_hit": R.counter(
+                "ome_engine_moe_experts_hit_total",
+                "Held experts that at least one routed pair of the "
+                "batch reached, summed over expert layers and decode "
+                "steps (counted on the device, read at scrape)"),
+            "pairs": R.counter(
+                "ome_engine_moe_pairs_total",
+                "Routed token-expert pairs that landed on an expert "
+                "held here, summed over expert layers and decode "
+                "steps; the rest were routed to absent experts and "
+                "cost no grouped-matmul rows"),
+        }
         self._c_pc_hits = R.counter(
             "ome_engine_prefix_cache_hits_total",
             "Prefix-cache hits (prompts that reused cached KV)")
@@ -1301,6 +1325,19 @@ class Scheduler:
         pd = getattr(self.engine, "update_pd_gauges", None)
         if callable(pd):
             pd()
+        # the second kind of per-slot state, and what an expert layer
+        # that holds a share of its experts counted on the device:
+        # both read here, at scrape, never on the step path
+        state_fn = getattr(self.engine, "state_bytes", None)
+        if callable(state_fn):
+            self._g_state_bytes.set(state_fn())
+        counts_fn = getattr(self.engine, "moe_counters", None)
+        counts = counts_fn() if callable(counts_fn) else None
+        if counts:
+            for name, counter in self._c_moe.items():
+                delta = counts[name] - counter.value
+                if delta > 0:
+                    counter.inc(delta)
         # live HBM partition (perf/hbm.py): refreshed per scrape, not
         # per step — memory_stats() is a host call the decode loop
         # should not pay

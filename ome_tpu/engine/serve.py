@@ -325,6 +325,37 @@ def _adapter_args(args):
     return (bare[0] if bare else None), named
 
 
+def _refuse_for_recurrent_state(args) -> None:
+    """Stop, before any weight is loaded and with the reasons, where
+    the model's config.json describes a hybrid model (linear-attention
+    layers that carry recurrent state per slot) and the command line
+    asks for something that takes KV rows to be all a slot owns."""
+    import json
+    import os
+
+    from ..models.config import ModelConfig
+    from .core import recurrent_state_refusals
+    path = os.path.join(args.model_dir, "config.json")
+    if not os.path.exists(path):
+        return
+    with open(path) as f:
+        cfg = ModelConfig.from_hf_config(json.load(f))
+    _, named = _adapter_args(args)
+    refused = recurrent_state_refusals(
+        cfg, kv_block=args.kv_block or args.kv_blocks,
+        prefix_cache=args.prefix_cache_mb
+        or getattr(args, "prefix_cache_host_mb", 0),
+        lora=named or args.lora_slots, tp=args.tp > 1,
+        spec_tokens=getattr(args, "spec_tokens", 0),
+        pd=getattr(args, "disaggregation_mode", "none") != "none",
+        journal=getattr(args, "journal", None))
+    if refused:
+        raise SystemExit(
+            "this model's linear-attention layers carry recurrent "
+            "state per slot, not KV rows (docs/recurrent-state.md); "
+            "refused:\n  " + "\n  ".join(refused))
+
+
 def load_engine(args, dist=None):
     import jax.numpy as jnp
 
@@ -340,6 +371,7 @@ def load_engine(args, dist=None):
         args.tp = jax.device_count()
         log.info("multi-host: tp=%d over %d processes", args.tp,
                  dist.num_processes)
+    _refuse_for_recurrent_state(args)
     mesh = None
     if args.tp > 1:
         from ..parallel.mesh import MeshConfig, build_mesh

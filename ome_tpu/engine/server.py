@@ -57,6 +57,17 @@ def _retry_after_str(seconds) -> str:
     return str(int(min(max(val, 1), 30)))
 
 
+class _BurstHTTPServer(ThreadingHTTPServer):
+    """`socketserver`'s listen backlog is 5: a burst of more
+    connections than that between two `accept` calls (64 closed-loop
+    clients starting at once through the router) resets the rest, the
+    router marks its only backend unhealthy on the first reset and
+    answers 503 until the next health probe. Hold a burst the size of
+    any slot count instead (seen on the chip, PR 27)."""
+
+    request_queue_size = 1024
+
+
 class EngineServer:
     def __init__(self, scheduler: Scheduler, tokenizer=None,
                  model_name: str = "ome-model", host: str = "127.0.0.1",
@@ -737,7 +748,7 @@ class EngineServer:
                 chunk(b"data: [DONE]\n\n")
                 chunk(b"")  # terminal chunk
 
-        self.httpd = ThreadingHTTPServer((host, port), Handler)
+        self.httpd = _BurstHTTPServer((host, port), Handler)
         self.port = self.httpd.server_address[1]
         self._thread: Optional[threading.Thread] = None
 
@@ -837,7 +848,11 @@ class EngineServer:
                 "paged_kv": paged,
                 "kv_blocks": eng.kv_blocks if paged else 0,
                 "kv_quantized": bool(getattr(eng, "kv_quantized",
-                                             False))}
+                                             False)),
+                # the slots' second kind of state (a hybrid model's
+                # linear-attention layers), 0 where rows are all
+                "recurrent_state_bytes": int(
+                    getattr(eng, "state_bytes", lambda: 0)())}
 
     def begin_drain(self):
         """Flip this replica to draining: /ready answers 503 (the

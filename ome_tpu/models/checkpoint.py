@@ -16,8 +16,8 @@ huggingface_hub does.
 
 Name mapping covers the Llama superset the model implements: llama /
 mistral / qwen2 (attention bias) / qwen3 (qk-norm) / gemma2 (softcap)
-dense models, and mixtral / qwen2-moe / deepseek-style MoE with shared
-experts.
+dense models, mixtral / qwen2-moe / deepseek-style MoE with shared
+experts, and qwen3-next (Gated DeltaNet layers beside gated attention).
 """
 
 from __future__ import annotations
@@ -199,8 +199,13 @@ def convert_llama(ckpt: Checkpoint, cfg, dtype=None) -> Dict[str, Any]:
     mla = getattr(cfg, "mla", False)
     n_dense = cfg.first_k_dense if (cfg.is_moe
                                     and cfg.first_k_dense) else 0
-    st_main = _Stacker(L - n_dense, np_dt)
+    hybrid = getattr(cfg, "is_hybrid", False)
+    st_main = _Stacker((cfg.kv_cache_layers if hybrid else L) - n_dense,
+                       np_dt)
     st_dense = _Stacker(n_dense, np_dt) if n_dense else None
+    # hybrid (Qwen3-Next): the Gated DeltaNet layers stack apart
+    st_lin = _Stacker(cfg.linear_layers, np_dt) if hybrid else None
+    f32 = np.dtype(np.float32)
 
     def take(name: str) -> np.ndarray:
         if name not in ckpt and name.startswith("model."):
@@ -214,7 +219,13 @@ def convert_llama(ckpt: Checkpoint, cfg, dtype=None) -> Dict[str, Any]:
 
     for li in range(L):
         p = f"model.layers.{li}."
-        if li < n_dense:
+        linear = False
+        if hybrid:
+            P = cfg.full_attn_interval
+            g, j = divmod(li, P)
+            linear = j != P - 1
+            st, i = (st_lin, g * (P - 1) + j) if linear else (st_main, g)
+        elif li < n_dense:
             st, i = st_dense, li
         else:
             st, i = st_main, li - n_dense
@@ -242,7 +253,45 @@ def convert_llama(ckpt: Checkpoint, cfg, dtype=None) -> Dict[str, Any]:
             if layernorm:
                 st.put("mlp_norm_bias", i,
                        take(p + "post_attention_layernorm.bias"))
-        if mla:
+        if linear:
+            # `in_proj_qkvz` and `in_proj_ba` are laid out per KEY
+            # head: q, k, then that head's r value heads' v and z
+            # (resp. b and a). The mixer here takes q | k | v flat,
+            # the order its conv's channels have
+            Hk, Hv = cfg.linear_num_key_heads, cfg.linear_num_value_heads
+            dk, dv = cfg.linear_key_head_dim, cfg.linear_value_head_dim
+            r = Hv // Hk
+            la = p + "linear_attn."
+            w = take(la + "in_proj_qkvz.weight").T.reshape(
+                D, Hk, 2 * dk + 2 * r * dv)
+            st.put("w_qkv", i, np.concatenate(
+                [w[..., :dk].reshape(D, Hk * dk),
+                 w[..., dk:2 * dk].reshape(D, Hk * dk),
+                 w[..., 2 * dk:2 * dk + r * dv].reshape(D, Hv * dv)],
+                axis=-1))
+            st.put("w_z", i, w[..., 2 * dk + r * dv:].reshape(D, Hv * dv))
+            ba = take(la + "in_proj_ba.weight").T.reshape(D, Hk, 2 * r)
+            st.put("w_b", i, ba[..., :r].reshape(D, Hv))
+            st.put("w_a", i, ba[..., r:].reshape(D, Hv))
+            st.put("conv_w", i, take(la + "conv1d.weight")[:, 0, :])
+            st.put("A_log", i, take(la + "A_log"), dtype=f32)
+            st.put("dt_bias", i, take(la + "dt_bias"), dtype=f32)
+            st.put("gdn_norm", i, take(la + "norm.weight"))
+            st.put("w_lin_out", i, linear_in_out(la + "out_proj.weight"))
+        elif hybrid:
+            # gated attention: q_proj gives, per head, a query and a
+            # gate of head_dim each
+            qg = take(p + "self_attn.q_proj.weight").T.reshape(
+                D, H, 2 * Dh)
+            st.put("wq", i, qg[..., :Dh])
+            st.put("w_ogate", i, qg[..., Dh:])
+            st.put("wk", i,
+                   take(p + "self_attn.k_proj.weight").T.reshape(D, K, Dh))
+            st.put("wv", i,
+                   take(p + "self_attn.v_proj.weight").T.reshape(D, K, Dh))
+            st.put("wo", i,
+                   take(p + "self_attn.o_proj.weight").T.reshape(H, Dh, D))
+        elif mla:
             qk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
             r, vd = cfg.kv_lora_rank, cfg.v_head_dim
             if cfg.q_lora_rank:
@@ -300,7 +349,7 @@ def convert_llama(ckpt: Checkpoint, cfg, dtype=None) -> Dict[str, Any]:
         if getattr(cfg, "attn_sinks", False):
             st.put("sinks", i, take(p + "self_attn.sinks"),
                    dtype=np.dtype(np.float32))
-        if cfg.qk_norm:
+        if cfg.qk_norm and not linear:
             st.put("q_norm", i, take(p + "self_attn.q_norm.weight"))
             st.put("k_norm", i, take(p + "self_attn.k_norm.weight"))
         if layer_is_moe and p + "mlp.experts.gate_up_proj" in ckpt:
@@ -335,7 +384,10 @@ def convert_llama(ckpt: Checkpoint, cfg, dtype=None) -> Dict[str, Any]:
                        take(p + "mlp.gate.e_score_correction_bias"),
                        dtype=np.dtype(np.float32))
             gates, ups, downs = [], [], []
-            for e in range(cfg.num_experts):
+            # a cut config (`ep_num_experts_total`) holds the experts
+            # from `expert_offset` on; the router above stays whole
+            lo = getattr(cfg, "expert_offset", 0)
+            for e in range(lo, lo + cfg.num_experts):
                 if f"{p}block_sparse_moe.experts.{e}.w1.weight" in ckpt:
                     en = f"{p}block_sparse_moe.experts.{e}."
                     g, u, d = en + "w1.weight", en + "w3.weight", \
@@ -360,6 +412,9 @@ def convert_llama(ckpt: Checkpoint, cfg, dtype=None) -> Dict[str, Any]:
                         st.put("ws_down", i,
                                linear_in_out(p + sn + "down_proj.weight"))
                         break
+            if getattr(cfg, "shared_expert_gate", False):
+                st.put("w_sg", i, linear_in_out(
+                    p + "mlp.shared_expert_gate.weight"))
         elif p + "mlp.gate_up_proj.weight" in ckpt:
             # phi3: fused gate|up rows (Phi3MLP chunks in halves)
             guw = take(p + "mlp.gate_up_proj.weight")
@@ -372,11 +427,15 @@ def convert_llama(ckpt: Checkpoint, cfg, dtype=None) -> Dict[str, Any]:
             st.put("w_up", i, linear_in_out(p + "mlp.up_proj.weight"))
             st.put("w_down", i, linear_in_out(p + "mlp.down_proj.weight"))
 
+    # a config whose vocabulary is a slice holds the first rows
+    V = cfg.vocab_size
     params: Dict[str, Any] = {
-        "embed": take("model.embed_tokens.weight").astype(np_dt),
+        "embed": take("model.embed_tokens.weight")[:V].astype(np_dt),
         "final_norm": take("model.norm.weight").astype(np_dt),
         "layers": st_main.out,
     }
+    if st_lin is not None:
+        params["linear_layers"] = st_lin.out
     if getattr(cfg, "norm_type", "rmsnorm") == "layernorm":
         params["final_norm_bias"] = take("model.norm.bias").astype(np_dt)
     if st_dense is not None:
@@ -384,7 +443,7 @@ def convert_llama(ckpt: Checkpoint, cfg, dtype=None) -> Dict[str, Any]:
     if not cfg.tie_word_embeddings:
         if "lm_head.weight" in ckpt:
             params["lm_head"] = linear_in_out(
-                "lm_head.weight").astype(np_dt)
+                "lm_head.weight")[:, :V].astype(np_dt)
         # some checkpoints omit lm_head despite tie=False in config:
         # fall back to tied embeddings (forward() handles the absence)
     if getattr(cfg, "lm_head_bias", False) and "lm_head.bias" in ckpt:
@@ -407,6 +466,11 @@ SUPPORTED_ARCHITECTURES = frozenset({
     # rope, logit scale), gpt-oss (sinks, clamped-GLU biased experts)
     "Phi3ForCausalLM", "PhimoeForCausalLM", "PhiMoEForCausalLM",
     "CohereForCausalLM", "Cohere2ForCausalLM", "GptOssForCausalLM",
+    # hybrid Gated DeltaNet / gated attention / sparse experts
+    # (models/gdn.py); the checkpoint's `mtp.*` tensors (one multi-
+    # token-prediction module) are not part of the forward pass and
+    # are never read
+    "Qwen3NextForCausalLM",
     # decoder embedding models (engine/embed.py): bare AutoModel
     # checkpoints whose tensors lack the "model." prefix
     "MistralModel", "Qwen2Model", "Qwen3Model",

@@ -86,10 +86,55 @@ class ModelConfig:
     router_jitter: float = 0.0     # phimoe sparsemixer threshold eps
     moe_activation: str = "silu"   # "silu" | "gptoss_glu" (clamped)
     moe_bias: bool = False         # gpt_oss expert + router biases
+    # hybrid linear/full attention (Qwen3-Next; models/gdn.py): layer
+    # i is gated full attention iff (i+1) % full_attn_interval == 0,
+    # every other layer a Gated DeltaNet mixer whose per-sequence
+    # state is a [Hv, dk, dv] float32 matrix and a conv tail, not KV
+    # rows. 0 = every layer is full attention
+    full_attn_interval: int = 0
+    linear_num_key_heads: int = 0
+    linear_num_value_heads: int = 0
+    linear_key_head_dim: int = 0
+    linear_value_head_dim: int = 0
+    linear_conv_kernel: int = 0
+    partial_rotary_factor: float = 1.0  # leading share of head_dim roped
+    attn_output_gate: bool = False  # attention out * sigmoid(gate proj)
+    shared_expert_gate: bool = False  # shared expert * sigmoid(h . w)
+    # an expert-parallel share: the router stays `num_experts_total`
+    # wide, the `num_experts` experts from `expert_offset` on are held
+    # here and only pairs routed to them are computed; 0 = all held
+    num_experts_total: int = 0
+    expert_offset: int = 0
 
     @property
     def is_moe(self) -> bool:
         return self.num_experts > 0
+
+    @property
+    def is_hybrid(self) -> bool:
+        return self.full_attn_interval > 1
+
+    @property
+    def router_width(self) -> int:
+        return self.num_experts_total or self.num_experts
+
+    @property
+    def kv_cache_layers(self) -> int:
+        """Layers that own KV rows: a hybrid model's linear layers
+        carry recurrent state instead (engine/core.py DecodeState)."""
+        if self.is_hybrid:
+            return self.num_layers // self.full_attn_interval
+        return self.num_layers
+
+    @property
+    def linear_layers(self) -> int:
+        return self.num_layers - self.kv_cache_layers
+
+    @property
+    def linear_conv_dim(self) -> int:
+        """Channels of the DeltaNet's depthwise conv: q | k | v."""
+        return (2 * self.linear_num_key_heads * self.linear_key_head_dim
+                + self.linear_num_value_heads * self.linear_value_head_dim)
 
     # KV-cache geometry (engine + KVCache.create): MLA caches ONE
     # latent "head" of kv_lora_rank+rope dims and no separate V rows
@@ -222,6 +267,50 @@ class ModelConfig:
                          rope_interleaved=True,
                          qk_norm=bool(cfg.get("use_qk_norm")),
                          rms_norm_eps=cfg.get("layer_norm_eps", 1e-5))
+        elif arch == "Qwen3NextForCausalLM":
+            # Qwen3-Next: 3 Gated DeltaNet layers to 1 gated full-
+            # attention layer, zero-centred RMSNorms, rotary on a
+            # share of the head, an MoE with a gated shared expert in
+            # every layer. `mlp_only_layers` / `decoder_sparse_step`
+            # other than the published ([] / 1) are not implemented
+            if cfg.get("mlp_only_layers") or \
+                    cfg.get("decoder_sparse_step", 1) != 1:
+                raise ValueError(
+                    "Qwen3Next: only decoder_sparse_step 1 with no "
+                    "mlp_only_layers is implemented")
+            shared = cfg.get("shared_expert_intermediate_size", 0) or 0
+            moe_w = cfg.get("moe_intermediate_size", 0) or 0
+            if shared and moe_w and shared % moe_w:
+                raise ValueError(
+                    "Qwen3Next: shared_expert_intermediate_size must "
+                    "be a multiple of moe_intermediate_size")
+            extra = dict(
+                unit_offset_norm=True, attn_output_gate=True,
+                full_attn_interval=cfg.get("full_attention_interval",
+                                           4),
+                linear_num_key_heads=cfg["linear_num_key_heads"],
+                linear_num_value_heads=cfg["linear_num_value_heads"],
+                linear_key_head_dim=cfg["linear_key_head_dim"],
+                linear_value_head_dim=cfg["linear_value_head_dim"],
+                linear_conv_kernel=cfg.get("linear_conv_kernel_dim", 4),
+                partial_rotary_factor=cfg.get("partial_rotary_factor",
+                                              1.0),
+                num_shared_experts=(shared // moe_w) if moe_w else 0,
+                shared_expert_gate=bool(shared),
+                # softmax over all experts, top-k, renormalised: the
+                # "mixtral" flavour (softmax over the selected logits)
+                # is the same numbers when norm_topk_prob is set
+                norm_topk_prob=bool(cfg.get("norm_topk_prob", True)),
+                # a cut config (model-configs guide section 4):
+                # `num_experts` counts the experts held here, these
+                # two keys of this repo give the router's published
+                # width and where the held range starts
+                num_experts_total=cfg.get("ep_num_experts_total", 0)
+                or 0,
+                expert_offset=cfg.get("ep_expert_offset", 0) or 0)
+            if not extra["norm_topk_prob"]:
+                raise ValueError("Qwen3Next: norm_topk_prob false is "
+                                 "not implemented")
         elif arch == "GptOssForCausalLM":
             # gpt-oss: attention sinks, alternating sliding layers,
             # top-4 softmax router with bias, clamped-GLU experts with

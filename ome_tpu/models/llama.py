@@ -24,6 +24,7 @@ from jax import lax
 
 from ..ops.attention import attention
 from ..telemetry.scopes import scoped
+from . import gdn
 from .config import ModelConfig
 from .quant import QTensor
 
@@ -84,6 +85,15 @@ class KVCache:
     k: jax.Array
     v: jax.Array
     index: jax.Array
+    # hybrid models only (cfg.is_hybrid): what a sequence carries
+    # through the Gated DeltaNet layers, which is not KV rows:
+    # {"S": [Ll, B, Hv, dk, dv] float32, "conv": [Ll, B, W-1, C]};
+    # k and v then hold the full-attention layers only
+    rec: Any = None
+    # [3] uint32, expert-layer counters a decode program adds to:
+    # layer-steps run, held experts hit, routed pairs that landed on
+    # a held expert (engine/core.py reads them at scrape)
+    stats: Any = None
 
     @classmethod
     def create(cls, cfg: ModelConfig, batch: int, max_seq: Optional[int] = None,
@@ -94,10 +104,26 @@ class KVCache:
         # zero-width v plane (models/mla.py); dense models cache K/V
         K, Dk, Dv = (cfg.kv_cache_heads, cfg.kv_cache_k_dim,
                      cfg.kv_cache_v_dim)
-        L = cfg.num_layers
+        L = cfg.kv_cache_layers
         return cls(k=jnp.zeros((L, batch, S, K, Dk), dtype),
                    v=jnp.zeros((L, batch, S, K, Dv), dtype),
-                   index=jnp.zeros((), jnp.int32))
+                   index=jnp.zeros((), jnp.int32),
+                   rec=recurrent_state(cfg, batch, dtype))
+
+
+def recurrent_state(cfg: ModelConfig, batch: int, dtype=None):
+    """Zeroed per-sequence state of a hybrid model's DeltaNet layers
+    (None for every other model)."""
+    if not cfg.is_hybrid:
+        return None
+    Ll = cfg.linear_layers
+    return {
+        "S": jnp.zeros((Ll, batch, cfg.linear_num_value_heads,
+                        cfg.linear_key_head_dim,
+                        cfg.linear_value_head_dim), jnp.float32),
+        "conv": jnp.zeros((Ll, batch, cfg.linear_conv_kernel - 1,
+                           cfg.linear_conv_dim), dtype or cfg.dtype),
+    }
 
 
 @jax.tree_util.register_dataclass
@@ -151,8 +177,10 @@ class PagedKVCache:
 
 
 def _init_layer_block(rng: jax.Array, cfg: ModelConfig, L: int,
-                      moe: bool) -> Params:
-    """One stacked block of L structurally-identical layers."""
+                      moe: bool, linear: bool = False) -> Params:
+    """One stacked block of L structurally-identical layers.
+    `linear`: a hybrid model's Gated DeltaNet layers (the mixer's
+    leaves in place of the attention projections)."""
     D, H, K, Dh, F = (cfg.hidden_size, cfg.num_heads, cfg.num_kv_heads,
                       cfg.head_dim, cfg.intermediate_size)
     keys = iter(jax.random.split(rng, 24))
@@ -207,7 +235,7 @@ def _init_layer_block(rng: jax.Array, cfg: ModelConfig, L: int,
     if moe:
         E, Fm = cfg.num_experts, cfg.moe_intermediate_size or F
         layers.update({
-            "router": norm((L, D, E), next(keys)),
+            "router": norm((L, D, cfg.router_width), next(keys)),
             "we_gate": norm((L, E, D, Fm), next(keys)),
             "we_up": norm((L, E, D, Fm), next(keys)),
             "we_down": norm((L, E, Fm, D), next(keys),
@@ -230,6 +258,38 @@ def _init_layer_block(rng: jax.Array, cfg: ModelConfig, L: int,
             "w_down": norm((L, F, D), next(keys),
                            std=0.02 / (2 * depth) ** 0.5),
         })
+    # later additions draw after everything above, so the leaves of
+    # the models that came first keep their values
+    if moe and cfg.shared_expert_gate:
+        layers["w_sg"] = norm((L, D, 1), next(keys))
+    if linear:
+        Hk, Hv = cfg.linear_num_key_heads, cfg.linear_num_value_heads
+        dk, dv = cfg.linear_key_head_dim, cfg.linear_value_head_dim
+        C, W = cfg.linear_conv_dim, cfg.linear_conv_kernel
+        for name in ("wq", "wk", "wv", "wo", "q_norm", "k_norm"):
+            layers.pop(name, None)
+        layers.update({
+            "w_qkv": norm((L, D, C), next(keys)),
+            "w_z": norm((L, D, Hv * dv), next(keys)),
+            "w_b": norm((L, D, Hv), next(keys)),
+            "w_a": norm((L, D, Hv), next(keys)),
+            # fan-in scaled: four taps of std 0.02 would shrink the
+            # signal fifty-fold before the L2 and RMS norms
+            "conv_w": norm((L, C, W), next(keys), std=W ** -0.5),
+            "w_lin_out": norm((L, Hv * dv, D), next(keys),
+                              std=0.02 / (2 * depth) ** 0.5),
+            "gdn_norm": jnp.ones((L, dv), cfg.dtype),
+            # exp(g) = exp(-exp(A_log) * softplus(a + dt_bias)): with
+            # A_log 0 and this dt_bias a head's decay at a = 0 is
+            # uniform over (0.5, 0.999); the checkpoint's own init
+            # (A ~ U(0, 16), dt_bias 1) forgets within a token
+            "A_log": jnp.zeros((L, Hv), jnp.float32),
+            "dt_bias": jnp.log(jnp.expm1(-jnp.log(
+                0.5 + 0.499 * jax.random.uniform(
+                    next(keys), (L, Hv), jnp.float32)))),
+        })
+    elif cfg.attn_output_gate:
+        layers["w_ogate"] = norm((L, D, H, Dh), next(keys))
     return layers
 
 
@@ -249,14 +309,24 @@ def init_params(rng: jax.Array, cfg: ModelConfig) -> Params:
     n_dense = cfg.first_k_dense if cfg.is_moe else 0
     params: Params = {
         "embed": norm((cfg.vocab_size, D), next(keys)),
-        "layers": _init_layer_block(k_moe, cfg, cfg.num_layers - n_dense,
-                                    cfg.is_moe),
+        # a hybrid model: "layers" holds its full-attention layers
+        # (every `full_attn_interval`-th), "linear_layers" the rest
+        "layers": _init_layer_block(
+            k_moe, cfg, cfg.kv_cache_layers - n_dense, cfg.is_moe),
         "final_norm": (jnp.zeros if cfg.unit_offset_norm
                        else jnp.ones)((D,), cfg.dtype),
     }
     if n_dense:
         params["dense_layers"] = _init_layer_block(k_dense, cfg, n_dense,
                                                    moe=False)
+    if cfg.is_hybrid:
+        if n_dense or cfg.num_layers % cfg.full_attn_interval:
+            raise ValueError(
+                "a hybrid model needs whole periods of "
+                f"{cfg.full_attn_interval} layers and no leading "
+                "dense ones")
+        params["linear_layers"] = _init_layer_block(
+            k_dense, cfg, cfg.linear_layers, cfg.is_moe, linear=True)
     if not cfg.tie_word_embeddings:
         params["lm_head"] = norm((D, cfg.vocab_size), next(keys))
     return params
@@ -306,7 +376,9 @@ def block_norm(x: jax.Array, lp: Params, name: str,
 
 
 def _rope_frequencies(cfg: ModelConfig) -> jax.Array:
-    half = cfg.head_dim // 2
+    # partial rotary (Qwen3-Next): frequencies for the leading share
+    # of the head only; apply_rope passes the rest through
+    half = int(cfg.head_dim * cfg.partial_rotary_factor) // 2
     freqs = 1.0 / cfg.rope_theta ** (jnp.arange(half, dtype=jnp.float32) / half)
     sc = cfg.rope_scaling
     rtype = sc.get("rope_type", sc.get("type")) if sc else None
@@ -353,7 +425,13 @@ def apply_rope(x: jax.Array, positions: jax.Array, freqs: jax.Array,
                interleaved: bool = False) -> jax.Array:
     """RoPE. x: [B, S, N, Dh]. Default is rotate-half (HF Llama
     convention); `interleaved` pairs even/odd dims (command-r's
-    repeat_interleave convention)."""
+    repeat_interleave convention). Fewer frequencies than Dh / 2
+    rotate the leading 2 * len(freqs) dims and leave the rest."""
+    rot = 2 * freqs.shape[-1]
+    if rot < x.shape[-1]:
+        return jnp.concatenate(
+            [apply_rope(x[..., :rot], positions, freqs, interleaved),
+             x[..., rot:]], axis=-1)
     angles = positions[..., None].astype(jnp.float32) * freqs  # [B, S, half]
     cos = jnp.cos(angles)[:, :, None, :]
     sin = jnp.sin(angles)[:, :, None, :]
@@ -538,55 +616,127 @@ def moe_mlp_dense(x: jax.Array, p: Params, cfg: ModelConfig) -> jax.Array:
                       mix.astype(expert_out.dtype))
 
 
-def moe_mlp_ragged(x: jax.Array, p: Params, cfg: ModelConfig) -> jax.Array:
-    """Dropless ragged dispatch: sort token-expert pairs by expert and
-    run grouped matmuls (lax.ragged_dot -> TPU grouped GEMM).
+def ragged_experts(xf: jax.Array, weights: jax.Array, idx: jax.Array,
+                   p: Params, cfg: ModelConfig, lo=0):
+    """The routed experts' part of the result that the experts HELD
+    HERE give: `p["we_*"]` hold experts lo .. lo + E_held - 1 of the
+    `cfg.router_width` the router chose among (all of them on one
+    device with every expert; a share under expert parallelism,
+    parallel/moe.py, and in a cut configuration). Dropless ragged
+    dispatch: the token-expert pairs are sorted by expert and run as
+    grouped matmuls (lax.ragged_dot -> TPU grouped GEMM). A pair
+    routed to an expert that is not held sorts behind every group, so
+    it belongs to no group's rows, and contributes nothing.
 
-    O(k/E) of the dense path's expert FLOPs with NO capacity dropping —
-    static [T*k] shapes, so it jits cleanly. The sort/gather/scatter
-    costs bandwidth proportional to activations (tiny next to expert
-    weights), which is the right trade on TPU where the MoE block is
-    weight-bound. Serving-path default (models/config.py moe_impl).
-    """
-    B, S, D = x.shape
-    k, E = cfg.experts_per_token, cfg.num_experts
-    T = B * S
-    weights, idx = _route(x, p, cfg)
-    xf = x.reshape(T, D)
-    expert_ids = idx.reshape(T * k)
-    order = jnp.argsort(expert_ids)                      # stable
+    `p["expert_layer"]` (a traced layer index, set by a layer scan
+    that keeps the experts out of its scanned leaves) says that
+    `p["we_*"]` are the STACKS of every layer's experts, [Ls, E, ..]:
+    they are then read as Ls * E groups of which only this layer's
+    have rows. The grouped matmul reads the experts that have rows
+    and nothing else; a layer's experts sliced out of the stack
+    first would be copied whole, hit or not, every step (1.2 GB a
+    layer at 128 experts of 2048 x 512, chip compiler, PR 27).
+
+    xf: [T, D]; weights, idx: [T, k] from `_route`. Returns (out
+    [T, D], (held experts hit, pairs that landed here))."""
+    T, D = xf.shape
+    k = idx.shape[-1]
+    layer = p.get("expert_layer")
+
+    def experts(name):
+        w = p[name]
+        if layer is None:
+            return _w(p, name, cfg.dtype)
+        if isinstance(w, QTensor):
+            # quantized stacks: dequantize this layer's slice only
+            w = jax.tree.map(
+                lambda a: lax.dynamic_index_in_dim(a, layer, 0, False), w)
+            return w.dequant(cfg.dtype)
+        return w.reshape(-1, *w.shape[2:])
+
+    we_gate = experts("we_gate")
+    held = p["we_gate"].shape[0 if layer is None else 1]
+    ids = idx.reshape(T * k) - lo
+    mine = (ids >= 0) & (ids < held)
+    local_ids = jnp.where(mine, ids, held)               # absent: last
+    order = jnp.argsort(local_ids)                       # stable
     token_of = order // k                                # source token
     xs = jnp.take(xf, token_of, axis=0)                  # [T*k, D]
-    group_sizes = jnp.bincount(expert_ids, length=E).astype(jnp.int32)
-    gate = lax.ragged_dot(xs, _w(p, "we_gate", cfg.dtype), group_sizes)
-    up = lax.ragged_dot(xs, _w(p, "we_up", cfg.dtype), group_sizes)
+    counts = jnp.bincount(local_ids, length=held + 1)[:held] \
+        .astype(jnp.int32)
+    group_sizes = counts
+    if we_gate.shape[0] != held:
+        # the whole stack: this layer's groups among empty ones
+        group_sizes = lax.dynamic_update_slice(
+            jnp.zeros((we_gate.shape[0],), jnp.int32), counts,
+            (layer * held,))
+    sorted_ids = jnp.minimum(jnp.take(local_ids, order), held - 1)
+    gate = lax.ragged_dot(xs, we_gate, group_sizes)
+    up = lax.ragged_dot(xs, experts("we_up"), group_sizes)
     if cfg.moe_bias:
-        gate = gate + jnp.take(p["we_gate_b"], expert_ids[order],
-                               axis=0)
-        up = up + jnp.take(p["we_up_b"], expert_ids[order], axis=0)
+        gate = gate + jnp.take(p["we_gate_b"], sorted_ids, axis=0)
+        up = up + jnp.take(p["we_up_b"], sorted_ids, axis=0)
     h = _moe_act(gate, up, cfg)  # same dtype flow as the dense path
-    out_sorted = lax.ragged_dot(h, _w(p, "we_down", cfg.dtype), group_sizes)  # [T*k, D]
+    out_sorted = lax.ragged_dot(h, experts("we_down"),
+                                group_sizes)             # [T*k, D]
     if cfg.moe_bias:
-        out_sorted = out_sorted + jnp.take(p["we_down_b"],
-                                           expert_ids[order], axis=0)
+        out_sorted = out_sorted + jnp.take(p["we_down_b"], sorted_ids,
+                                           axis=0)
     w_sorted = jnp.take(weights.reshape(T * k), order, axis=0)
-    contrib = out_sorted * w_sorted[:, None].astype(out_sorted.dtype)
+    # rows behind the last group hold whatever the grouped matmul
+    # leaves there: select, do not multiply by zero
+    contrib = jnp.where(
+        jnp.take(mine, order)[:, None],
+        out_sorted * w_sorted[:, None].astype(out_sorted.dtype), 0)
     out = jnp.zeros((T, D), contrib.dtype).at[token_of].add(contrib)
-    return out.reshape(B, S, D).astype(x.dtype)
+    return out, (jnp.sum(counts > 0), jnp.sum(counts))
 
 
-def moe_mlp(x: jax.Array, p: Params, cfg: ModelConfig) -> jax.Array:
-    """Top-k MoE block (Mixtral/Qwen-MoE/DeepSeek-style)."""
+def moe_mlp_ragged(x: jax.Array, p: Params, cfg: ModelConfig,
+                   with_stats: bool = False):
+    """Dropless ragged dispatch over the experts held here
+    (`ragged_experts`): O(k/E) of the dense path's expert FLOPs with
+    NO capacity dropping, static [T*k] shapes, so it jits cleanly. The
+    sort/gather/scatter costs bandwidth proportional to activations
+    (tiny next to expert weights), which is the right trade on TPU
+    where the MoE block is weight-bound. Serving-path default
+    (models/config.py moe_impl)."""
+    B, S, D = x.shape
+    with jax.named_scope("moe_router"):
+        weights, idx = _route(x, p, cfg)
+    with jax.named_scope("moe_experts"):
+        out, stats = ragged_experts(x.reshape(B * S, D), weights, idx,
+                                    p, cfg, lo=cfg.expert_offset)
+    out = out.reshape(B, S, D).astype(x.dtype)
+    return (out, stats) if with_stats else out
+
+
+def moe_mlp(x: jax.Array, p: Params, cfg: ModelConfig,
+            with_stats: bool = False):
+    """Top-k MoE block (Mixtral/Qwen-MoE/DeepSeek-style). With
+    `with_stats` also (held experts hit, pairs landed here), which
+    only the ragged dispatch counts."""
+    stats = None
     if cfg.moe_impl == "ragged":
-        out = moe_mlp_ragged(x, p, cfg)
+        out, stats = moe_mlp_ragged(x, p, cfg, with_stats=True)
+    elif cfg.num_experts_total not in (0, cfg.num_experts):
+        raise ValueError("a share of the experts needs the ragged "
+                         "dispatch (moe_impl='ragged')")
     else:
         out = moe_mlp_dense(x, p, cfg)
     if cfg.num_shared_experts > 0:
         # DeepSeek-MoE shared experts: always-active dense branch
-        shared = {"w_gate": p["ws_gate"], "w_up": p["ws_up"],
-                  "w_down": p["ws_down"]}  # dense_mlp dequantizes via _w
-        out = out + dense_mlp(x, shared)
-    return out
+        with jax.named_scope("moe_shared"):
+            shared = {"w_gate": p["ws_gate"], "w_up": p["ws_up"],
+                      "w_down": p["ws_down"]}  # dense_mlp dequantizes via _w
+            sh = dense_mlp(x, shared)
+            if cfg.shared_expert_gate:
+                # Qwen-MoE / Qwen3-Next: times sigmoid(h . w_sg)
+                sg = jnp.einsum("bsd,do->bso", x, p["w_sg"])
+                sh = sh * jax.nn.sigmoid(sg.astype(jnp.float32)) \
+                    .astype(sh.dtype)
+            out = out + sh
+    return (out, stats) if with_stats else out
 
 
 # -- forward ---------------------------------------------------------------
@@ -601,13 +751,16 @@ def _layer(x: jax.Array, lp: Params, cfg: ModelConfig, freqs: jax.Array,
            cache_index: Optional[jax.Array],
            window=_WINDOW_FROM_CFG, moe: Optional[bool] = None,
            adapter_ids: Optional[jax.Array] = None,
-           use_rope: bool = True):
+           use_rope: bool = True, moe_stats: bool = False):
     """One transformer block. cache_kv: ([B,Smax,K,Dh], [B,Smax,K,Dh]).
     `window` overrides cfg.sliding_window (the gemma2 pair-scan passes
     the per-layer value; None = global attention). `moe` overrides
     cfg.is_moe (DeepSeek's first_k_dense leading dense layers).
     `adapter_ids` ([B]) selects each slot's LoRA delta (multi-adapter
-    serving; None = no adapter stacks present)."""
+    serving; None = no adapter stacks present). `moe_stats` adds
+    the expert layer's counts (`moe_mlp`) as a third result."""
+    if moe_stats:
+        assert not cfg.parallel_block and not cfg.post_block_norms
     if window is _WINDOW_FROM_CFG:
         window = cfg.sliding_window
     uo = cfg.unit_offset_norm
@@ -634,6 +787,9 @@ def _layer(x: jax.Array, lp: Params, cfg: ModelConfig, freqs: jax.Array,
         with jax.named_scope("o_proj"):
             a = rms_norm(a, lp["attn_post_norm"], cfg.rms_norm_eps, uo)
     x = x + a
+    if moe_stats:
+        x, stats = _moe_residual(x, lp, cfg)
+        return x, new_cache, stats
 
     with jax.named_scope("mlp"):
         h = block_norm(x, lp, "mlp_norm", cfg)
@@ -643,6 +799,156 @@ def _layer(x: jax.Array, lp: Params, cfg: ModelConfig, freqs: jax.Array,
             mlp_out = rms_norm(mlp_out, lp["mlp_post_norm"],
                                cfg.rms_norm_eps, uo)
     return x + mlp_out, new_cache
+
+
+def _moe_residual(x: jax.Array, lp: Params, cfg: ModelConfig):
+    """x + MoE(norm(x)) with the expert layer's counts."""
+    with jax.named_scope("mlp"):
+        h = block_norm(x, lp, "mlp_norm", cfg)
+        mlp_out, stats = moe_mlp(h, lp, cfg, with_stats=True)
+    return x + mlp_out, stats
+
+
+def _linear_layer(x: jax.Array, lp: Params, cfg: ModelConfig,
+                  S: jax.Array, tail: jax.Array,
+                  valid_len: Optional[jax.Array]):
+    """One Gated DeltaNet block of a hybrid model (Qwen3-Next): the
+    mixer (models/gdn.py holds the recurrence) and the MoE. S:
+    [B, Hv, dk, dv] float32 and tail: [B, W-1, C] are what the
+    sequence carries; positions from `valid_len` [B] on (None: none)
+    leave both as they were. Returns (x, S, tail, moe counts)."""
+    B, T, _ = x.shape
+    Hk, Hv = cfg.linear_num_key_heads, cfg.linear_num_value_heads
+    dk, dv = cfg.linear_key_head_dim, cfg.linear_value_head_dim
+    valid = None if valid_len is None else \
+        jnp.arange(T, dtype=jnp.int32)[None, :] < valid_len[:, None]
+    with jax.named_scope("gdn_mixer"):
+        with jax.named_scope("qkv"):
+            h = block_norm(x, lp, "attn_norm", cfg)
+            qkv = _proj(h, lp["w_qkv"], cfg.dtype)
+            z = _proj(h, lp["w_z"], cfg.dtype, out_dims=(Hv, dv))
+            b = _proj(h, lp["w_b"], cfg.dtype).astype(jnp.float32)
+            a = _proj(h, lp["w_a"], cfg.dtype).astype(jnp.float32)
+            y, tail = gdn.conv_seq(qkv, tail, lp["conv_w"], valid_len)
+            y = jax.nn.silu(y)
+            q, k, v = jnp.split(y, [Hk * dk, 2 * Hk * dk], axis=-1)
+            q = gdn.l2norm(q.reshape(B, T, Hk, dk)) * dk ** -0.5
+            k = gdn.l2norm(k.reshape(B, T, Hk, dk))
+            # each key head serves Hv / Hk consecutive value heads
+            q = jnp.repeat(q, Hv // Hk, axis=2)
+            k = jnp.repeat(k, Hv // Hk, axis=2)
+            v = v.reshape(B, T, Hv, dv)
+            beta = jax.nn.sigmoid(b)
+            g = -jnp.exp(lp["A_log"].astype(jnp.float32)) \
+                * jax.nn.softplus(a + lp["dt_bias"].astype(jnp.float32))
+        with jax.named_scope("attn"):
+            if T == 1:
+                o, S = gdn.step(q[:, 0], k[:, 0], v[:, 0], g[:, 0],
+                                beta[:, 0], S,
+                                None if valid is None else valid[:, 0])
+                o = o[:, None]
+            else:
+                o, S = gdn.chunked(q, k, v, g, beta, S, valid)
+        with jax.named_scope("o_proj"):
+            # per-head RMSNorm with a plain weight, times SiLU(z)
+            o = rms_norm(o, lp["gdn_norm"], cfg.rms_norm_eps) \
+                * jax.nn.silu(z.astype(jnp.float32))
+            out = _proj(o.astype(cfg.dtype), lp["w_lin_out"], cfg.dtype,
+                        flatten=2)
+    x, stats = _moe_residual(x + out, lp, cfg)
+    return x, S, tail, stats
+
+
+def _hybrid_scan(params: Params, cfg: ModelConfig, x: jax.Array, freqs,
+                 positions, kv_len, cache: Optional[KVCache],
+                 adapter_ids: Optional[jax.Array],
+                 valid_len: Optional[jax.Array]):
+    """Scan over layer PERIODS of `cfg.full_attn_interval` (P): P - 1
+    Gated DeltaNet layers, then one gated full-attention layer
+    (Qwen3-Next). The two kinds have different leaves, so they are two
+    stacked blocks (`linear_layers`, `layers`) and the body unrolls
+    one period. KV rows exist for the full layers only; the DeltaNet
+    layers carry `cache.rec` (zeros when there is no cache: every
+    sequence then starts from an empty state)."""
+    L, P = cfg.num_layers, cfg.full_attn_interval
+    G = L // P
+    B = x.shape[0]
+    EXPERTS = ("we_gate", "we_up", "we_down")
+    ragged = cfg.moe_impl == "ragged"
+
+    def split(block):
+        """(leaves the scan slices a layer at a time, the experts'
+        stacks whole): the grouped matmuls address the stack by layer
+        (`ragged_experts`), so no layer's experts are ever sliced out
+        and copied."""
+        if not ragged:
+            return block, {}
+        return ({k: v for k, v in block.items() if k not in EXPERTS},
+                {k: block[k] for k in EXPERTS})
+
+    def group(a):
+        return a.reshape(G, P - 1, *a.shape[1:])
+
+    lin, lin_experts = split(params["linear_layers"])
+    full, full_experts = split(params["layers"])
+    rec = cache.rec if cache is not None else None
+    if rec is None:
+        rec = recurrent_state(cfg, B, cfg.dtype)
+    index = cache.index if cache is not None else None
+
+    def with_experts(lp, stacks, layer):
+        return dict(lp, **stacks, expert_layer=layer) if stacks else lp
+
+    def body(carry, per):
+        # the recurrent state rides the carry whole and each layer
+        # updates its own rows in place; as scanned input and output
+        # it would be copied in and out every step
+        x, rec = carry
+        g, lin_g, full_g, c = per
+        counts = jnp.zeros((3,), jnp.uint32)
+        for j in range(P - 1):
+            li = g * (P - 1) + j
+            lp = with_experts(jax.tree.map(lambda a: a[j], lin_g),
+                              lin_experts, li)
+            x, S_j, tail_j, st = _linear_layer(
+                x, lp, cfg,
+                lax.dynamic_index_in_dim(rec["S"], li, 0, False),
+                lax.dynamic_index_in_dim(rec["conv"], li, 0, False),
+                valid_len)
+            with jax.named_scope("kv_write"), \
+                    jax.named_scope("gdn_state"):
+                rec = {"S": lax.dynamic_update_index_in_dim(
+                           rec["S"], S_j, li, 0),
+                       "conv": lax.dynamic_update_index_in_dim(
+                           rec["conv"], tail_j.astype(rec["conv"].dtype),
+                           li, 0)}
+            counts = counts + _moe_counts(st)
+        x, nc, st = _layer(x, with_experts(full_g, full_experts, g), cfg,
+                           freqs, positions, kv_len, c, index,
+                           adapter_ids=adapter_ids, moe_stats=True)
+        counts = counts + _moe_counts(st)
+        return (x, rec), (nc, counts)
+
+    xs = (jnp.arange(G, dtype=jnp.int32), jax.tree.map(group, lin), full,
+          (cache.k, cache.v) if cache is not None else None)
+    (x, rec), (nc, counts) = lax.scan(body, (x, rec), xs)
+    if cache is None:
+        return x, None
+    S = positions.shape[1]
+    stats = cache.stats
+    if stats is not None:
+        stats = stats + jnp.sum(counts, axis=0, dtype=jnp.uint32)
+    return x, KVCache(k=nc[0], v=nc[1], index=cache.index + S,
+                      rec=rec, stats=stats)
+
+
+def _moe_counts(stats) -> jax.Array:
+    """[layer-steps, experts hit, pairs landed] of one expert layer."""
+    if stats is None:
+        return jnp.zeros((3,), jnp.uint32)
+    return jnp.stack([jnp.ones((), jnp.uint32),
+                      stats[0].astype(jnp.uint32),
+                      stats[1].astype(jnp.uint32)])
 
 
 def _qkv(h: jax.Array, lp: Params, cfg: ModelConfig, freqs: jax.Array,
@@ -714,6 +1020,13 @@ def _mha(h: jax.Array, lp: Params, cfg: ModelConfig, freqs: jax.Array,
                          logit_softcap=cfg.attn_logit_softcap,
                          sinks=lp.get("sinks") if cfg.attn_sinks else None)
     with jax.named_scope("o_proj"):
+        if cfg.attn_output_gate:
+            # Qwen3-Next: a gate per head and dim, projected from the
+            # same normed input as the query
+            gate = _proj(h, lp["w_ogate"], cfg.dtype,
+                         out_dims=(cfg.num_heads, cfg.head_dim))
+            attn = attn * jax.nn.sigmoid(gate.astype(jnp.float32)) \
+                .astype(attn.dtype)
         a = _proj_lora(attn, lp, "wo", adapter_ids, cfg.dtype, flatten=2)
         if "bo" in lp:  # phimoe/gpt_oss: o_proj carries a bias too
             a = a + lp["bo"]
@@ -738,6 +1051,7 @@ def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
             cache: Optional[KVCache] = None,
             adapter_ids: Optional[jax.Array] = None,
             logits_at: Optional[jax.Array] = None,
+            valid_len: Optional[jax.Array] = None,
             ) -> Tuple[jax.Array, Optional[KVCache]]:
     """Run the decoder.
 
@@ -752,6 +1066,12 @@ def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
     same) and logits come back [B, 1, vocab] — a serving prefill
     samples from one row, and [1, S, vocab] in f32 is gigabytes at a
     150k vocabulary.
+    `valid_len` ([B] int32) says how many leading positions of each
+    row are real (a right-padded prefill bucket; 0 for a frozen slot
+    of the multi-token decode loop). KV rows hide a padded tail
+    behind `kv_len`; a hybrid model's recurrent state cannot, so its
+    DeltaNet layers leave their state untouched from there on. No
+    other model reads it.
     Returns (logits [B, S, vocab], updated cache or None).
     """
     B, S = tokens.shape
@@ -768,7 +1088,12 @@ def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
         if cache is not None else None
     index = cache.index if cache is not None else None
 
-    if cfg.alt_sliding_window:
+    if cfg.is_hybrid:
+        with jax.named_scope("layers"):
+            x, new_cache = _hybrid_scan(params, cfg, x, freqs,
+                                        positions, kv_len, cache,
+                                        adapter_ids, valid_len)
+    elif cfg.alt_sliding_window:
         with jax.named_scope("layers"):
             x, new_cache = _alt_window_scan(params, cfg, x, freqs,
                                             positions, kv_len, cache,
@@ -826,8 +1151,9 @@ def forward_paged(params: Params, cfg: ModelConfig, tokens: jax.Array,
     at offsets `(index[b]+s) % block` (the engine pre-allocates the
     covering blocks), then attends over its block chain with
     per-query causal masking (ops/paged.py). Standard GQA models
-    only — MLA, MoE, and sliding-window variants keep the dense path
-    (the engine guards). cite: vLLM PagedAttention, which the
+    only — MLA, MoE, sliding-window variants and hybrid models (whose
+    DeltaNet layers carry recurrent state, not rows) keep the dense
+    path (the engine guards). cite: vLLM PagedAttention, which the
     reference consumes via its SGLang/vLLM runtimes (SURVEY.md L0,
     /root/reference/config/runtimes/srt/*); here it is in-repo and
     TPU-static.

@@ -211,6 +211,11 @@ _LAYER_CONTRACT = {
     "wkv_a": (1,),                         # [L, D, r+rope]
     "w_uk": (2,),                          # [L, H, nope, r]
     "w_uv": (2,),                          # [L, H, r, v]
+    # hybrid models (Qwen3-Next): the attention's output gate and the
+    # DeltaNet mixer's projections; conv, router, w_b / w_a stay fp
+    "w_ogate": (1,),                       # [L, D, H, Dh]
+    "w_qkv": (1,), "w_z": (1,),            # [L, D, C] / [L, D, Hv*dv]
+    "w_lin_out": (1,),                     # [L, Hv*dv, D]
 }
 _TOP_CONTRACT = {
     "embed": (1,),     # per-ROW scales: rows are both lookup outputs
@@ -243,7 +248,7 @@ def quantize_params(params: Dict[str, Any], mode: str = "int8",
         raise ValueError(f"unknown quantization mode {mode!r}")
     int4 = mode == "int4"
     base_q = quantize_tensor_fp8 if mode == "fp8" else quantize_tensor
-    _INT8_ONLY = {"w_down", "ws_down", "wo"}
+    _INT8_ONLY = {"w_down", "ws_down", "wo", "w_lin_out"}
     log = logging.getLogger("ome.models.quant")
 
     def q_layer(k: str, v):
@@ -260,7 +265,7 @@ def quantize_params(params: Dict[str, Any], mode: str = "int8",
 
     out: Dict[str, Any] = {}
     for name, leaf in params.items():
-        if name in ("layers", "dense_layers"):
+        if name in ("layers", "dense_layers", "linear_layers"):
             out[name] = {k: q_layer(k, v) for k, v in leaf.items()}
         elif name in _TOP_CONTRACT:
             out[name] = base_q(leaf, _TOP_CONTRACT[name])

@@ -340,7 +340,13 @@ def _flash_prefill(q, k, v, base, kv_hi, scale, softcap, window, interpret):
     B, Sq, H, D = q.shape
     S, K = k.shape[1], k.shape[2]
     G = H // K
-    bq = _pick_block(Sq, (256, 128, 64, 32, 16))
+    # the query block holds whole GQA groups: bq * G rows of D. Keep
+    # it at what 8 heads a KV head of 128 dims take (the most the
+    # kernel has been compiled at): a wider head or group halves bq
+    # instead of outgrowing VMEM (head_dim 256 with 8 heads a KV head
+    # needs 24 MB of the 16 at bq 256, chip compiler, PR 27)
+    bq = _pick_block(Sq, tuple(c for c in (256, 128, 64, 32, 16)
+                               if c * G * D <= 256 * 8 * 128))
     bs = _pick_block(S, (512, 256, 128, 64, 32, 16))
     if bq is None or bs is None or bq * G < 8 or D % 128 != 0:
         return None

@@ -10,19 +10,19 @@ memory sharded, one psum over the ep axis to combine contributions
 dispatch, SURVEY.md §2.9 "--moe-a2a-backend deepep").
 
 Routing is computed redundantly on every device (cheap: one [T, E]
-matmul) so there is no dispatch collective at all: non-local pairs are
-weighted to zero and psum sums each pair's contribution exactly once.
+matmul) so there is no dispatch collective at all: a pair routed to
+an expert another device holds sorts behind every local group, takes
+no grouped-matmul rows here, and psum sums each pair's contribution
+exactly once.
 """
 
 from __future__ import annotations
 
-import functools
-
 import jax
-import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
+from ..models import llama
 from ..models.config import ModelConfig
 
 
@@ -35,35 +35,15 @@ def moe_mlp_ragged_ep(x: jax.Array, lp, cfg: ModelConfig, mesh: Mesh,
     assert E % ep == 0, f"experts {E} must divide over {axis}={ep}"
 
     def local(x, router, we_gate, we_up, we_down):
-        local_e = we_gate.shape[0]
-        rank = lax.axis_index(axis)
-        lo = rank * local_e
+        # the layer of models/llama.py that is told which experts it
+        # holds: the same body serves one chip of a cut configuration,
+        # there without the exchange
         B, S, D = x.shape
-        k = cfg.experts_per_token
-        T = B * S
-        logits = jnp.einsum("bsd,de->bse", x, router).astype(jnp.float32)
-        weights, idx = lax.top_k(logits, k)
-        weights = jax.nn.softmax(weights, axis=-1)
-        ids = idx.reshape(T * k)
-        w = weights.reshape(T * k)
-        mine = (ids >= lo) & (ids < lo + local_e)
-        # non-local pairs: route to local expert 0 with weight 0 — they
-        # compute garbage that contributes nothing, and psum over the ep
-        # axis counts every pair exactly once on its owner
-        local_ids = jnp.where(mine, ids - lo, 0)
-        w = jnp.where(mine, w, 0.0)
-        order = jnp.argsort(local_ids)
-        token_of = order // k
-        xs = jnp.take(x.reshape(T, D), token_of, axis=0)
-        group_sizes = jnp.bincount(local_ids, length=local_e) \
-            .astype(jnp.int32)
-        gate = lax.ragged_dot(xs, we_gate, group_sizes)
-        up = lax.ragged_dot(xs, we_up, group_sizes)
-        out_sorted = lax.ragged_dot(jax.nn.silu(gate) * up, we_down,
-                                    group_sizes)
-        w_sorted = jnp.take(w, order, axis=0)
-        contrib = out_sorted * w_sorted[:, None].astype(out_sorted.dtype)
-        out = jnp.zeros((T, D), contrib.dtype).at[token_of].add(contrib)
+        weights, idx = llama._route(x, {"router": router}, cfg)
+        held = {"we_gate": we_gate, "we_up": we_up, "we_down": we_down}
+        out, _ = llama.ragged_experts(
+            x.reshape(B * S, D), weights, idx, held, cfg,
+            lo=lax.axis_index(axis) * we_gate.shape[0])
         out = lax.psum(out, axis)
         return out.reshape(B, S, D).astype(x.dtype)
 

@@ -3,7 +3,9 @@
 `device.memory_stats()` gives the allocator's truth (bytes in use,
 peak, limit); the engine knows its own tenants — weights (the
 quantizer's byte model), KV cache (pool capacity in paged mode, the
-dense slab otherwise), prefix cache (its own byte counter). The
+dense slab otherwise), recurrent state (what a hybrid model's slots
+carry through its linear-attention layers), prefix cache (its own
+byte counter). The
 residual is workspace: XLA temp buffers, collectives scratch,
 fragmentation. Partitioning the allocator number against the tenants
 turns "HBM is 93% full" into "weights 41%, KV 38%, prefix 6%,
@@ -25,7 +27,8 @@ from typing import Dict, Optional
 # fixed tenant enum: gauge children are pre-created for exactly this
 # set, so label cardinality is bounded by construction (the
 # metrics-label-cardinality lint pattern)
-HBM_TENANTS = ("weights", "kv_cache", "prefix_cache", "workspace")
+HBM_TENANTS = ("weights", "kv_cache", "recurrent_state", "prefix_cache",
+               "workspace")
 
 
 def kv_capacity_bytes(engine) -> int:
@@ -80,8 +83,10 @@ class HbmAccountant:
         fam = registry.gauge(
             "ome_engine_hbm_tenant_bytes",
             "Device bytes attributed per tenant: weights (quantizer "
-            "byte model), kv_cache (pool/slab capacity), prefix_cache "
-            "(its byte counter), workspace (the residual)",
+            "byte model), kv_cache (pool/slab capacity), "
+            "recurrent_state (a hybrid model's per-slot DeltaNet "
+            "state), prefix_cache (its byte counter), workspace (the "
+            "residual)",
             labelnames=("tenant",))
         self._tenants = {t: fam.labels(tenant=t) for t in HBM_TENANTS}
 
@@ -125,10 +130,14 @@ class HbmAccountant:
         """Refresh the gauges (one /metrics scrape). Returns the
         partition dict (tests assert the arithmetic on it)."""
         kv = kv_capacity_bytes(engine) if engine is not None else 0
+        # the slots' other state: a hybrid model's DeltaNet layers
+        # hold a float32 matrix a head and a conv tail, not rows
+        state_fn = getattr(engine, "state_bytes", None)
+        rs = int(state_fn()) if callable(state_fn) else 0
         pc = getattr(engine, "prefix_cache", None)
         pcb = int(getattr(pc, "bytes", 0) or 0)
         stats = self._read_stats()
-        tenant_sum = self.weight_bytes + kv + pcb
+        tenant_sum = self.weight_bytes + kv + rs + pcb
         if stats:
             in_use = float(stats.get("bytes_in_use", tenant_sum))
             limit = float(stats.get("bytes_limit", 0) or 0)
@@ -138,8 +147,8 @@ class HbmAccountant:
         workspace = max(in_use - tenant_sum, 0.0)
         part = {"bytes_in_use": in_use, "bytes_limit": limit,
                 "peak_bytes": peak, "weights": float(self.weight_bytes),
-                "kv_cache": float(kv), "prefix_cache": float(pcb),
-                "workspace": workspace}
+                "kv_cache": float(kv), "recurrent_state": float(rs),
+                "prefix_cache": float(pcb), "workspace": workspace}
         self._g_in_use.set(in_use)
         self._g_limit.set(limit)
         self._g_peak.set(peak)
@@ -154,6 +163,7 @@ class HbmAccountant:
                     peak_bytes=int(peak), bytes_in_use=int(in_use),
                     bytes_limit=int(limit),
                     weights=int(self.weight_bytes), kv_cache=int(kv),
-                    prefix_cache=pcb, workspace=int(workspace))
+                    recurrent_state=rs, prefix_cache=pcb,
+                    workspace=int(workspace))
             self._last_peak = peak
         return part

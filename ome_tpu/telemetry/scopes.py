@@ -51,6 +51,26 @@ PHASES = ("embed", "layers", "qkv", "kv_write", "attn", "o_proj",
 KERNELS = ("paged_attention", "flash_decode", "flash_prefill",
            "int4_matmul")
 
+# finer names, each written INSIDE one of the PHASES above, so a reader
+# that knows only PHASES still books the time on the phase around it
+# (the deepest name it knows). They are tuples of their own, not
+# longer old ones: the benchmark holds its copy of PHASES and KERNELS
+# equal to the two above (tests/benchmark/test_phases.py) and reads
+# these from files of its own (benchmark/subphases.py).
+#   gdn_mixer   a Gated DeltaNet layer's mixer whole: projections and
+#               conv (under `qkv`), the recurrence (`attn`), the gated
+#               norm and output projection (`o_proj`)
+#   gdn_state   inside it, under `kv_write`: the recurrent state's
+#               decay and rank-one update (models/gdn.py)
+#   moe_router, moe_experts, moe_shared   under `mlp`: router logits,
+#               top-k and the sort; the routed experts' gather,
+#               grouped matmuls and scatter; the shared expert and its
+#               gate (models/llama.py)
+SUBPHASES = ("gdn_mixer", "gdn_state", "moe_router", "moe_experts",
+             "moe_shared")
+# `name=` of Pallas kernels written after KERNELS was copied: none yet
+SUBKERNELS = ()
+
 # ome_engine_step_phase_seconds{phase=...} label values, each also a
 # `sched.<phase>` span (scheduler._phase)
 SCHED_PHASES = ("plan", "mask_apply", "dispatch", "device_loop",
