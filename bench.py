@@ -621,8 +621,9 @@ def main() -> None:
     # harness — the shape that amortizes the host dispatch — across
     # batch {64, 128, 256} and pool dtype {bf16, int8}. The serving
     # engine's compiled program (llama.forward_paged: scan over
-    # layers, token-exactness in tests/test_paged_kv.py and
-    # tests/test_kv_int8.py) shares the kernels but not the unroll;
+    # layers with the pool in its carry, token-exactness in
+    # tests/test_paged_kv.py and tests/test_kv_int8.py) shares the
+    # kernels and the whole-pool layout but not the unroll;
     # these numbers bound what that program reaches as its dispatch
     # amortization improves. Pool sized to dense-equivalent rows per
     # point, so the int8 column shows the --kv-dtype int8 trade the
@@ -650,7 +651,6 @@ def main() -> None:
             kv_len = index + 1
             blk = table[rows, index // bs]
             off = index % bs
-            nks, nvs, nkss, nvss = [], [], [], []
             for l in range(cfg.num_layers):
                 lp = per[l]
                 h = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
@@ -674,26 +674,21 @@ def main() -> None:
                         return qv, sc
                     kq, ksc = qrow(k)
                     vq, vsc = qrow(v)
-                    kp = ks[l].at[blk, off].set(kq)
-                    vp = vs[l].at[blk, off].set(vq)
-                    ksp = kss[l].at[blk, :, off].set(ksc)
-                    vsp = vss[l].at[blk, :, off].set(vsc)
+                    ks = ks.at[l, blk, off].set(kq)
+                    vs = vs.at[l, blk, off].set(vq)
+                    kss = kss.at[l, blk, :, off].set(ksc)
+                    vss = vss.at[l, blk, :, off].set(vsc)
                 else:
-                    kp = ks[l].at[blk, off].set(k[:, 0])
-                    vp = vs[l].at[blk, off].set(v[:, 0])
-                    ksp = vsp = None
-                nks.append(kp)
-                nvs.append(vp)
-                nkss.append(ksp)
-                nvss.append(vsp)
-                attn = paged_attention(q, kp, vp, table, kv_len,
-                                       k_scale=ksp, v_scale=vsp)
+                    ks = ks.at[l, blk, off].set(k[:, 0])
+                    vs = vs.at[l, blk, off].set(v[:, 0])
+                attn = paged_attention(q, ks, vs, table, kv_len, l,
+                                       k_scale=kss, v_scale=vss)
                 x = x + _proj(attn, lp["wo"], cfg.dtype, flatten=2)
                 h = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps)
                 x = x + dense_mlp(h, lp, cfg)
             tok = jnp.argmax(head_logits(top, x),
                              axis=-1).astype(jnp.int32)
-            return tok, nks, nvs, nkss, nvss, index + 1
+            return tok, ks, vs, kss, vss, index + 1
 
         @jax.jit
         def paged_k(per, top, tok, ks, vs, kss, vss, index):
@@ -706,14 +701,15 @@ def main() -> None:
 
         K, Dh = cfg.num_kv_heads, cfg.head_dim
         pool_dt = jnp.int8 if quantized else cfg.dtype
-        ks = [jnp.zeros((nblk, bs, K, Dh), pool_dt)
-              for _ in range(cfg.num_layers)]
-        vs = [jnp.zeros((nblk, bs, K, Dh), pool_dt)
-              for _ in range(cfg.num_layers)]
-        kss = [jnp.zeros((nblk, K, bs), jnp.float32) if quantized
-               else None for _ in range(cfg.num_layers)]
-        vss = [jnp.zeros((nblk, K, bs), jnp.float32) if quantized
-               else None for _ in range(cfg.num_layers)]
+        # whole pools [L, N, bs, K, D], written and read in place
+        # through (layer, block): ops/paged.py's layout
+        L = cfg.num_layers
+        ks = jnp.zeros((L, nblk, bs, K, Dh), pool_dt)
+        vs = jnp.zeros((L, nblk, bs, K, Dh), pool_dt)
+        kss = jnp.zeros((L, nblk, K, bs), jnp.float32) \
+            if quantized else None
+        vss = jnp.zeros((L, nblk, K, bs), jnp.float32) \
+            if quantized else None
         tok0 = jnp.zeros((PB, 1), jnp.int32)
         index0 = jnp.full((PB,), PREFILL, jnp.int32)
         n_disp = (DECODE_STEPS - 1) // MULTISTEP

@@ -201,14 +201,16 @@ def size_pool(cfg: dict, slots: int, max_seq: int, block: int) -> dict:
     """KV pool blocks for the bf16 phase, by the arithmetic of
     ome_tpu/perf/hbm.py (row = layers x kv_heads x (Dk + Dv) x 2 B).
 
-    What has to fit in HBM at once: the weights; the pool TWICE (the
-    paged decode program scans the pool through its layers as xs/ys,
-    and the chip compiler's memory_analysis shows a pool-sized
-    temporary beside the donated pool); one 2048-bucket prefill's KV,
-    in flight on the admission thread while decode runs, twice (its
-    output and the insert's argument); the default 256 MiB prefix
-    cache; and 0.5 GiB of margin for sampling buffers and allocator
-    fragmentation."""
+    What has to fit in HBM at once: the weights; the pool ONCE (the
+    paged decode program carries the donated pool through its layer
+    scan and writes and reads it in place: the chip compiler's
+    memory_analysis shows 11 MB of temporaries beside it, not a
+    second pool); one 2048-bucket prefill's KV, in flight on the
+    admission thread while decode runs, twice (its output and the
+    insert's argument); the default 256 MiB prefix cache; and 0.5 GiB
+    of margin for sampling buffers and allocator fragmentation. At
+    Qwen3-4B's size that leaves room for more blocks than 16 slots of
+    2048 can fill, so the dense equivalent caps it."""
     hidden, layers = cfg["hidden_size"], cfg["num_hidden_layers"]
     heads, kv_heads = cfg["num_attention_heads"], cfg["num_key_value_heads"]
     dh, mlp, vocab = cfg["head_dim"], cfg["intermediate_size"], cfg["vocab_size"]
@@ -221,7 +223,7 @@ def size_pool(cfg: dict, slots: int, max_seq: int, block: int) -> dict:
     prefill_kv = 2 * max_seq * row
     budget = (V5E_HBM_BYTES - weights - prefill_kv - (256 << 20)
               - (512 << 20))
-    blocks = budget // (2 * block * row)
+    blocks = budget // (block * row)
     dense_equivalent = slots * -(-max_seq // block)
     blocks = int(max(min(blocks, dense_equivalent), 2))
     return {"kv_blocks": blocks + 1,      # +1: block 0 is the trash block

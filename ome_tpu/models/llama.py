@@ -1150,17 +1150,29 @@ def forward_paged(params: Params, cfg: ModelConfig, tokens: jax.Array,
     new K/V rows into pool blocks `table[b, (index[b]+s) // block]`
     at offsets `(index[b]+s) % block` (the engine pre-allocates the
     covering blocks), then attends over its block chain with
-    per-query causal masking (ops/paged.py). Standard GQA models
-    only — MLA, MoE, sliding-window variants and hybrid models (whose
-    DeltaNet layers carry recurrent state, not rows) keep the dense
-    path (the engine guards). cite: vLLM PagedAttention, which the
-    reference consumes via its SGLang/vLLM runtimes (SURVEY.md L0,
-    /root/reference/config/runtimes/srt/*); here it is in-repo and
-    TPU-static.
+    per-query causal masking (ops/paged.py).
+
+    The pool `[L, N, bs, K, D]` (and an int8 pool's scale planes) is
+    the layer scan's CARRY, never its xs/ys: the scan runs over
+    (stacked layers, arange(L)), layer `l` scatters its B x S rows in
+    place at `(l, blk, off)` and the attention entries read layer `l`
+    of the whole pool through the index. As xs/ys the scan would
+    slice a layer's pool out, write it back into the stacked ys and
+    copy the pool after the loop: four passes over it a step to write
+    B x S rows a layer, and a temporary of its size beside the donated
+    pool. One body for every caller (S == 1 and S > 1, bf16 and int8
+    pools, with and without adapters).
+
+    Standard GQA models only — MLA, MoE, sliding-window variants and
+    hybrid models (whose DeltaNet layers carry recurrent state, not
+    rows) keep the dense path (the engine guards). cite: vLLM
+    PagedAttention, which the reference consumes via its SGLang/vLLM
+    runtimes (SURVEY.md L0, /root/reference/config/runtimes/srt/*);
+    here it is in-repo and TPU-static.
     """
     from ..ops.paged import paged_attention, paged_attention_multi
     B, S = tokens.shape
-    bs = cache.k.shape[2]
+    L, _, bs = cache.k.shape[:3]
     M = cache.table.shape[1]
     positions = cache.index[:, None] + jnp.arange(S,
                                                   dtype=jnp.int32)[None, :]
@@ -1176,14 +1188,15 @@ def forward_paged(params: Params, cfg: ModelConfig, tokens: jax.Array,
     off = positions % bs
     quantized = cache.k_scale is not None
 
-    def _append(pool, scale_pool, rows_new):
-        """Write S fresh [B, K, D] rows into the pool; int8 pools
-        quantize per (row, head) on the way in (amax/127 symmetric,
-        the ops/flash.py quantize_kv_block discipline) and store the
-        f32 scale at the same (block, offset). The S writes per slot
-        land on consecutive rows (distinct (block, offset) pairs), so
-        the unrolled scatter order doesn't matter; trash-block
-        collisions between inactive slots are never read back."""
+    def _append(pool, scale_pool, rows_new, layer):
+        """Scatter the B x S fresh [K, D] rows into layer `layer` of
+        the pool, in place; int8 pools quantize per (row, head) on the
+        way in (amax/127 symmetric, the ops/flash.py quantize_kv_block
+        discipline) and store the f32 scale at the same (layer, block,
+        offset). A slot's S writes land on consecutive rows (distinct
+        (block, offset) pairs), so the scatter's order doesn't matter;
+        trash-block collisions between inactive slots are never read
+        back."""
         if quantized:
             amax = jnp.max(jnp.abs(rows_new.astype(jnp.float32)),
                            axis=-1)                        # [B, S, K]
@@ -1191,37 +1204,30 @@ def forward_paged(params: Params, cfg: ModelConfig, tokens: jax.Array,
             rows_new = jnp.clip(
                 jnp.round(rows_new.astype(jnp.float32)
                           / sc[..., None]),
-                -127, 127).astype(jnp.int8)
-        for s in range(S):
-            pool = pool.at[blk[:, s], off[:, s]].set(
-                rows_new[:, s].astype(pool.dtype))
-            if quantized:
-                scale_pool = scale_pool.at[blk[:, s], :,
-                                           off[:, s]].set(sc[:, s])
+                -127, 127)
+            scale_pool = scale_pool.at[layer, blk, :, off].set(sc)
+        pool = pool.at[layer, blk, off].set(rows_new.astype(pool.dtype))
         return pool, scale_pool
 
-    def body(x, per):
-        if quantized:
-            lp, kp, vp, ksp, vsp = per
-        else:
-            lp, kp, vp = per
-            ksp = vsp = None
+    def body(carry, per):
+        x, kp, vp, ksp, vsp = carry
+        lp, layer = per
         with jax.named_scope("qkv"):
             h = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps, uo)
             q, k, v = _qkv(h, lp, cfg, freqs, positions, uo, adapter_ids)
         with jax.named_scope("kv_write"):
-            kp, ksp = _append(kp, ksp, k)
-            vp, vsp = _append(vp, vsp, v)
+            kp, ksp = _append(kp, ksp, k, layer)
+            vp, vsp = _append(vp, vsp, v, layer)
         with jax.named_scope("attn"):
             if S == 1:
                 attn = paged_attention(
-                    q, kp, vp, cache.table, kv_len,
+                    q, kp, vp, cache.table, kv_len, layer,
                     scale=cfg.query_scale,
                     logit_softcap=cfg.attn_logit_softcap,
                     k_scale=ksp, v_scale=vsp)
             else:
                 attn = paged_attention_multi(
-                    q, kp, vp, cache.table, positions,
+                    q, kp, vp, cache.table, positions, layer,
                     scale=cfg.query_scale,
                     logit_softcap=cfg.attn_logit_softcap,
                     k_scale=ksp, v_scale=vsp)
@@ -1238,19 +1244,13 @@ def forward_paged(params: Params, cfg: ModelConfig, tokens: jax.Array,
             if cfg.post_block_norms:
                 mlp_out = rms_norm(mlp_out, lp["mlp_post_norm"],
                                    cfg.rms_norm_eps, uo)
-        out = (x + mlp_out, ((kp, vp, ksp, vsp) if quantized
-                             else (kp, vp)))
-        return out
+        return (x + mlp_out, kp, vp, ksp, vsp), None
 
     with jax.named_scope("layers"):
-        if quantized:
-            x, (nk, nv, nks, nvs) = lax.scan(
-                body, x, (params["layers"], cache.k, cache.v,
-                          cache.k_scale, cache.v_scale))
-        else:
-            x, (nk, nv) = lax.scan(body, x,
-                                   (params["layers"], cache.k, cache.v))
-            nks = nvs = None
+        # a None scale plane (bf16 pool) is no leaf of the carry
+        (x, nk, nv, nks, nvs), _ = lax.scan(
+            body, (x, cache.k, cache.v, cache.k_scale, cache.v_scale),
+            (params["layers"], jnp.arange(L, dtype=jnp.int32)))
     new_cache = PagedKVCache(k=nk, v=nv, index=cache.index + S,
                              table=cache.table,
                              k_scale=nks, v_scale=nvs)

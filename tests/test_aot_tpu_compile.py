@@ -10,7 +10,10 @@ that passes is not a chip run; it says the kernel is accepted, nothing
 about its results or its speed.
 """
 
+import functools
+import math
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
@@ -67,22 +70,26 @@ def _compile(fn, *args):
 
 @pytest.mark.parametrize("pool_dtype", ["bf16", "int8"])
 def test_paged_flash_decode(one_chip, pool_dtype):
-    n_blocks, max_blocks = 257, 16
+    """The kernel handed the whole pool and a traced layer index."""
+    layers, n_blocks, max_blocks = 4, 257, 16
     dt = jnp.int8 if pool_dtype == "int8" else jnp.bfloat16
-    pool = one_chip((n_blocks, BS, K, D), dt)
-    scale = (one_chip((n_blocks, K, BS), jnp.float32)
+    pool = one_chip((layers, n_blocks, BS, K, D), dt)
+    scale = (one_chip((layers, n_blocks, K, BS), jnp.float32)
              if pool_dtype == "int8" else None)
 
-    def f(q, kp, vp, table, kv_len, ks, vs):
-        out = paged.paged_flash_decode(q, kp, vp, table, kv_len,
+    def f(q, kp, vp, table, kv_len, layer, ks, vs):
+        out = paged.paged_flash_decode(q, kp, vp, table, kv_len, layer,
                                        k_scale=ks, v_scale=vs)
         assert out is not None, "kernel declined the serve-path shape"
         return out
 
     c = _compile(f, one_chip((B, 1, H, D), jnp.bfloat16), pool, pool,
                  one_chip((B, max_blocks), jnp.int32),
-                 one_chip((B,), jnp.int32), scale, scale)
+                 one_chip((B,), jnp.int32), one_chip((), jnp.int32),
+                 scale, scale)
     assert "tpu_custom_call" in c.as_text()
+    # read in place: no layer's pool is sliced out for the kernel
+    assert c.memory_analysis().temp_size_in_bytes < 1 << 20
 
 
 def test_flash_decode_dense_slab(one_chip):
@@ -188,49 +195,79 @@ def test_int4_quantizer_keeps_no_float32_copy(one_chip):
     assert c.memory_analysis().temp_size_in_bytes < 256 << 20
 
 
-def test_decode_step_carries_the_programs_names(one_chip, monkeypatch):
-    """Two layers of `forward_paged` plus `sample` at Qwen3-4B widths,
-    under the `decode` family as engine/core.py scopes its programs:
-    the compiled text names the attention call `paged_attention` and
-    carries an `op_name` for every scope the trace reduction reads
-    (benchmark/phases.py), through the layer scan's `while`. The
-    scopes are metadata: one Mosaic call, as before they existed."""
-    import re
+LAYERS, N_BLOCKS = 36, 198             # the qwen3-4b cells' depth, pool
 
+
+@pytest.fixture(scope="module")
+def decode_paged(topo):
+    """`_decode_paged` as engine/core.py builds it (`forward_paged`
+    plus `sample` under the `decode` family, the pool donated) at
+    Qwen3-4B's widths and depth over the cells' pool, compiled for the
+    described chip once per pool dtype: (compiled, pool shape,
+    scale-plane shape or None). The whole depth, because it costs
+    nothing (the 20 s are the vocabulary-wide sort of `sample`) and
+    because under 12 layers the compiler hoists relayout copies of
+    the stacked attention weights out of the loop (ROADMAP A4), which
+    would be read here as the pool's."""
     from ome_tpu.engine import core
     from ome_tpu.models import llama
     from ome_tpu.models.config import ModelConfig
     from ome_tpu.telemetry import scopes
 
-    monkeypatch.setattr(device, "on_tpu", lambda: True)
-    cfg = ModelConfig(vocab_size=151936, hidden_size=2560, num_layers=2,
-                      num_heads=H, num_kv_heads=K, head_dim=D,
-                      intermediate_size=9728, max_seq_len=2048,
-                      qk_norm=True, tie_word_embeddings=True,
-                      dtype=jnp.bfloat16)
-    n_blocks, max_blocks = 198, 16
-    params = jax.tree.map(
-        lambda a: one_chip(a.shape, a.dtype),
-        jax.eval_shape(lambda k: llama.init_params(k, cfg),
-                       jax.random.PRNGKey(0)))
-    pool = one_chip((2, n_blocks, BS, K, D), jnp.bfloat16)
+    sharding = SingleDeviceSharding(topo.devices[0])
+
+    def struct(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    cfg = ModelConfig(vocab_size=151936, hidden_size=2560,
+                      num_layers=LAYERS, num_heads=H, num_kv_heads=K,
+                      head_dim=D, intermediate_size=9728,
+                      max_seq_len=2048, qk_norm=True,
+                      tie_word_embeddings=True, dtype=jnp.bfloat16)
 
     @scopes.scoped("decode")
-    def _decode_paged(params, k, v, lengths, table, tokens, key,
+    def _decode_paged(params, k, v, ks, vs, lengths, table, tokens, key,
                       temperature, top_k, top_p):
         cache = llama.PagedKVCache(k=k, v=v, index=lengths, table=table,
-                                   k_scale=None, v_scale=None)
+                                   k_scale=ks, v_scale=vs)
         logits, nc = llama.forward_paged(params, cfg, tokens[:, None],
                                          cache)
         toks = core.sample(logits[:, -1], key, temperature, top_k, top_p)
-        return nc.k, nc.v, toks
+        return nc.k, nc.v, nc.k_scale, nc.v_scale, toks
 
-    ints = one_chip((B,), jnp.int32)
-    floats = one_chip((B,), jnp.float32)
-    text = _compile(_decode_paged, params, pool, pool, ints,
-                    one_chip((B, max_blocks), jnp.int32), ints,
-                    one_chip((2,), jnp.uint32), floats, ints,
-                    floats).as_text()
+    @functools.lru_cache(maxsize=None)
+    def compiled(pool_dtype):
+        params = jax.tree.map(
+            lambda a: struct(a.shape, a.dtype),
+            jax.eval_shape(lambda k: llama.init_params(k, cfg),
+                           jax.random.PRNGKey(0)))
+        quantized = pool_dtype == "int8"
+        pool = struct((LAYERS, N_BLOCKS, BS, K, D),
+                      jnp.int8 if quantized else jnp.bfloat16)
+        scale = (struct((LAYERS, N_BLOCKS, K, BS), jnp.float32)
+                 if quantized else None)
+        ints, floats = struct((B,), jnp.int32), struct((B,), jnp.float32)
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(device, "on_tpu", lambda: True)
+            c = jax.jit(_decode_paged,
+                        donate_argnums=(1, 2, 3, 4)).lower(
+                params, pool, pool, scale, scale, ints,
+                struct((B, 16), jnp.int32), ints,
+                struct((2,), jnp.uint32), floats, ints,
+                floats).compile()
+        return c, pool.shape, scale.shape if quantized else None
+
+    return compiled
+
+
+def test_decode_step_carries_the_programs_names(decode_paged):
+    """`forward_paged` plus `sample` at Qwen3-4B widths, under the
+    `decode` family as engine/core.py scopes its programs: the
+    compiled text names the attention call `paged_attention` and
+    carries an `op_name` for every scope the trace reduction reads
+    (benchmark/phases.py), through the layer scan's `while`. The
+    scopes are metadata: one Mosaic call, as before they existed."""
+    text = decode_paged("bf16")[0].as_text()
     assert text.count("tpu_custom_call") == 1
     assert re.search(r"%paged_attention(\.\d+)? = ", text)
     paths = set(re.findall(r'op_name="([^"]*)"', text))
@@ -240,3 +277,28 @@ def test_decode_step_carries_the_programs_names(one_chip, monkeypatch):
         assert any(scope in p.split("/") for p in paths), scope
     # inside the scan the path runs through the loop's body
     assert any("/layers/while/body/" in p and "/mlp/" in p for p in paths)
+
+
+@pytest.mark.parametrize("pool_dtype", ["bf16", "int8"])
+def test_decode_paged_leaves_the_pool_where_it_is(decode_paged,
+                                                  pool_dtype):
+    """The paged pool is the layer scan's carry, written in place and
+    read by the kernel through a layer index: the compiled decode
+    step holds one Mosaic call, temporaries under ONE layer's K pool
+    (as xs/ys of the scan it kept a second pool: 3.74 GB), and no
+    `copy`, `dynamic-slice` or `dynamic-update-slice` whose result is
+    the pool or a layer of it, an int8 pool's scale planes
+    included."""
+    compiled, pool, scale = decode_paged(pool_dtype)
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert re.search(r"%paged_attention(\.\d+)? = ", text)
+    one_layer = math.prod(pool[1:]) * (1 if scale else 2)
+    assert compiled.memory_analysis().temp_size_in_bytes < one_layer
+
+    held = [pool, pool[1:]] + ([scale, scale[1:]] if scale else [])
+    shapes = "|".join(",".join(str(d) for d in h) for h in held)
+    moved = [line.strip()[:160] for line in text.splitlines()
+             if re.search(rf"= \w+\[({shapes})\]\S* (copy|dynamic-slice|"
+                          r"dynamic-update-slice)\(", line)]
+    assert not moved, moved

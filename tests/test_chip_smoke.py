@@ -63,3 +63,21 @@ def test_alone_in_a_directory_it_fails(tmp_path):
     assert rc != 0
     assert lines[-1] == {"ok": False, "device": None}, (lines, err)
     assert "No module named" in lines[-2]["error"]
+
+
+def test_size_pool_counts_the_pool_once():
+    """The decode program carries the donated pool and keeps no second
+    one, so the sizing counts it once: at Qwen3-4B's size the dense
+    equivalent of 16 slots of 2048 fits, what it adds up stays inside
+    the compiler's HBM, and a second pool would not."""
+    import chip_smoke
+    cfg = chip_smoke.QWEN3_4B
+    sizes = chip_smoke.size_pool(cfg, 16, 2048, 128)
+    assert sizes["kv_blocks"] == sizes["dense_equivalent_blocks"] + 1 \
+        == 257
+    row = (cfg["num_hidden_layers"] * cfg["num_key_value_heads"]
+           * 2 * cfg["head_dim"] * 2)
+    pool = sizes["kv_blocks"] * 128 * row
+    held = (2 * sizes["expected_params"] + pool + 2 * 2048 * row
+            + (256 << 20) + (512 << 20))
+    assert held <= chip_smoke.V5E_HBM_BYTES < held + pool

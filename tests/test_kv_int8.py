@@ -68,25 +68,30 @@ PROMPTS = ["hello world", "a", "the quick brown fox jumps over",
 
 def _quantize_pool(pool):
     """amax/127 per (row, head) over the feature axis; scales in the
-    S-minor [N, K, bs] layout the kernel's BlockSpec streams."""
-    x = np.asarray(pool, np.float32)                  # [N, bs, K, D]
-    amax = np.abs(x).max(axis=-1)                     # [N, bs, K]
+    S-minor [L, N, K, bs] layout the kernel's BlockSpec streams."""
+    x = np.asarray(pool, np.float32)                  # [L, N, bs, K, D]
+    amax = np.abs(x).max(axis=-1)                     # [L, N, bs, K]
     sc = np.maximum(amax, 1e-8) / 127.0
     q = np.clip(np.rint(x / sc[..., None]), -127, 127).astype(np.int8)
-    return jnp.asarray(q), jnp.asarray(np.swapaxes(sc, 1, 2))
+    return jnp.asarray(q), jnp.asarray(np.swapaxes(sc, -1, -2))
+
+
+LAYERS = 3
 
 
 class TestQuantizedPagedNumerics:
     def _pool(self, rng, B, H, K, D, bs, M, N):
         q = jnp.asarray(rng.standard_normal((B, 1, H, D)), jnp.float32)
-        kp = jnp.asarray(rng.standard_normal((N, bs, K, D)),
+        kp = jnp.asarray(rng.standard_normal((LAYERS, N, bs, K, D)),
                          jnp.float32)
-        vp = jnp.asarray(rng.standard_normal((N, bs, K, D)),
+        vp = jnp.asarray(rng.standard_normal((LAYERS, N, bs, K, D)),
                          jnp.float32)
         ids = rng.permutation(N)[:B * M].reshape(B, M)
         return q, kp, vp, jnp.asarray(ids, jnp.int32)
 
-    def test_xla_quantized_is_exact_dequant_and_close_to_fp32(self):
+    @pytest.mark.parametrize("layer", range(LAYERS))
+    def test_xla_quantized_is_exact_dequant_and_close_to_fp32(self,
+                                                              layer):
         from ome_tpu.ops.attention import attention
         from ome_tpu.ops.paged import paged_attention_xla
         rng = np.random.default_rng(0)
@@ -95,13 +100,14 @@ class TestQuantizedPagedNumerics:
         kv_len = jnp.asarray([5, 128, 200, 512], jnp.int32)
         kq, ksc = _quantize_pool(kp)
         vq, vsc = _quantize_pool(vp)
-        out = paged_attention_xla(q, kq, vq, table, kv_len,
+        out = paged_attention_xla(q, kq, vq, table, kv_len, layer,
                                   k_scale=ksc, v_scale=vsc)
-        # exact: dense attention over the explicitly dequantized pool
-        deq_k = (np.asarray(kq, np.float32)
-                 * np.swapaxes(np.asarray(ksc), 1, 2)[..., None])
-        deq_v = (np.asarray(vq, np.float32)
-                 * np.swapaxes(np.asarray(vsc), 1, 2)[..., None])
+        # exact: dense attention over the layer's explicitly
+        # dequantized pool
+        deq_k = (np.asarray(kq[layer], np.float32)
+                 * np.swapaxes(np.asarray(ksc[layer]), 1, 2)[..., None])
+        deq_v = (np.asarray(vq[layer], np.float32)
+                 * np.swapaxes(np.asarray(vsc[layer]), 1, 2)[..., None])
         kg = jnp.take(jnp.asarray(deq_k), table,
                       axis=0).reshape(B, M * bs, K, D)
         vg = jnp.take(jnp.asarray(deq_v), table,
@@ -111,11 +117,12 @@ class TestQuantizedPagedNumerics:
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=1e-6)
         # and within int8 quantization error of the fp32 pool
-        full = paged_attention_xla(q, kp, vp, table, kv_len)
+        full = paged_attention_xla(q, kp, vp, table, kv_len, layer)
         np.testing.assert_allclose(np.asarray(out), np.asarray(full),
                                    atol=5e-2)
 
-    def test_pallas_kernel_matches_quantized_xla(self):
+    @pytest.mark.parametrize("layer", range(LAYERS))
+    def test_pallas_kernel_matches_quantized_xla(self, layer):
         from ome_tpu.ops.paged import (paged_attention_xla,
                                        paged_flash_decode)
         rng = np.random.default_rng(1)
@@ -124,10 +131,10 @@ class TestQuantizedPagedNumerics:
         kv_len = jnp.asarray([1, 100, 256, 512], jnp.int32)
         kq, ksc = _quantize_pool(kp)
         vq, vsc = _quantize_pool(vp)
-        out = paged_flash_decode(q, kq, vq, table, kv_len,
-                                 k_scale=ksc, v_scale=vsc,
-                                 interpret=True)
-        ref = paged_attention_xla(q, kq, vq, table, kv_len,
+        out = jax.jit(lambda l: paged_flash_decode(
+            q, kq, vq, table, kv_len, l, k_scale=ksc, v_scale=vsc,
+            interpret=True))(jnp.int32(layer))
+        ref = paged_attention_xla(q, kq, vq, table, kv_len, layer,
                                   k_scale=ksc, v_scale=vsc)
         # same tolerance as the unquantized kernel-vs-XLA test: the
         # CPU build's default f32 matmul is reduced-precision

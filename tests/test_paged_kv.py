@@ -5,8 +5,11 @@ allocates worst-case [L, B, Smax, K, D] HBM per slot; the paged pool
 allocates by tokens in flight. These tests pin:
 
   * numerics: the XLA paged path is exactly the dense computation on
-    gathered blocks; the Pallas kernel (interpret mode) agrees within
-    the platform's reduced-precision matmul noise;
+    gathered blocks of the layer it is pointed at; the Pallas kernel
+    (interpret mode, handed the whole pool and a layer index) agrees
+    layer by layer within the platform's reduced-precision matmul
+    noise; `forward_paged` (the pool in the layer scan's carry) equals
+    a plain loop over the layers, each on its own pool;
   * the engine serves TOKEN-IDENTICAL outputs dense vs paged across
     mixed lengths, slot reuse, and block-boundary growth;
   * 2x the slot count fits the SAME cache HBM budget with mixed-length
@@ -33,48 +36,129 @@ from ome_tpu.ops.paged import paged_attention_xla, paged_flash_decode
 CFG = tiny_test().replace(dtype=jnp.float32, max_seq_len=128)
 
 
-def _pool(rng, B, H, K, D, bs, M, N):
+LAYERS = 3
+
+
+def _pool(rng, B, H, K, D, bs, M, N, L=LAYERS):
+    """A whole pool [L, N, bs, K, D], every layer its own rows, and a
+    table of distinct blocks (the same chain in every layer)."""
     q = jnp.asarray(rng.standard_normal((B, 1, H, D)), jnp.float32)
-    kp = jnp.asarray(rng.standard_normal((N, bs, K, D)), jnp.float32)
-    vp = jnp.asarray(rng.standard_normal((N, bs, K, D)), jnp.float32)
+    kp = jnp.asarray(rng.standard_normal((L, N, bs, K, D)), jnp.float32)
+    vp = jnp.asarray(rng.standard_normal((L, N, bs, K, D)), jnp.float32)
     ids = rng.permutation(N)[:B * M].reshape(B, M)
     return q, kp, vp, jnp.asarray(ids, jnp.int32)
 
 
 class TestPagedAttentionNumerics:
-    def test_xla_matches_dense_gather(self):
+    @pytest.mark.parametrize("layer", range(LAYERS))
+    def test_xla_matches_dense_gather(self, layer):
         rng = np.random.default_rng(0)
         B, H, K, D, bs, M, N = 4, 16, 8, 128, 128, 4, 32
         q, kp, vp, table = _pool(rng, B, H, K, D, bs, M, N)
         kv_len = jnp.asarray([5, 128, 200, 512], jnp.int32)
-        out = paged_attention_xla(q, kp, vp, table, kv_len)
-        kg = jnp.take(kp, table, axis=0).reshape(B, M * bs, K, D)
-        vg = jnp.take(vp, table, axis=0).reshape(B, M * bs, K, D)
+        out = paged_attention_xla(q, kp, vp, table, kv_len, layer)
+        kg = jnp.take(kp[layer], table, axis=0).reshape(B, M * bs, K, D)
+        vg = jnp.take(vp[layer], table, axis=0).reshape(B, M * bs, K, D)
         ref = attention(q, kg, vg, positions=(kv_len - 1)[:, None],
                         kv_len=kv_len, backend="xla")
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=1e-6)
 
-    def test_pallas_kernel_matches_xla(self):
+    @pytest.mark.parametrize("layer", range(LAYERS))
+    def test_pallas_kernel_matches_xla(self, layer):
+        """The kernel reads layer `layer` of the whole pool through
+        its scalar-prefetched index; the layer is traced, as under
+        the layer scan."""
         rng = np.random.default_rng(1)
         B, H, K, D, bs, M, N = 4, 16, 8, 128, 128, 4, 32
         q, kp, vp, table = _pool(rng, B, H, K, D, bs, M, N)
         kv_len = jnp.asarray([1, 100, 256, 512], jnp.int32)
-        out = paged_flash_decode(q, kp, vp, table, kv_len,
-                                 interpret=True)
-        ref = paged_attention_xla(q, kp, vp, table, kv_len)
+        out = jax.jit(lambda l: paged_flash_decode(
+            q, kp, vp, table, kv_len, l, interpret=True))(
+                jnp.int32(layer))
+        ref = paged_attention_xla(q, kp, vp, table, kv_len, layer)
         # platform note: this CPU build's default f32 matmul is
         # reduced-precision, so block partitioning differences show up
         # at ~1e-2 — the same kernels on TPU agree with XLA at bf16
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=2e-2)
+        # and it is that layer's rows, not a neighbour's
+        other = paged_attention_xla(q, kp, vp, table, kv_len,
+                                    (layer + 1) % LAYERS)
+        assert np.abs(np.asarray(out) - np.asarray(other)).max() > 0.1
 
     def test_kernel_uncovered_shapes_return_none(self):
         rng = np.random.default_rng(2)
         q, kp, vp, table = _pool(rng, 2, 4, 2, 64, 16, 2, 8)
         assert paged_flash_decode(
-            q, kp, vp, table, jnp.asarray([3, 9], jnp.int32),
+            q, kp, vp, table, jnp.asarray([3, 9], jnp.int32), 0,
             interpret=True) is None
+
+
+def _plain_paged_forward(params, cfg, tokens, cache):
+    """`forward_paged` the plain way: a Python loop over the layers,
+    each on a pool of its own (numpy writes, one row at a time), dense
+    attention over the slot's gathered chain. Independent of the scan,
+    of the scatter and of ops/paged.py."""
+    B, S = tokens.shape
+    bs = cache.k.shape[2]
+    index, table = np.asarray(cache.index), np.asarray(cache.table)
+    positions = jnp.asarray(index[:, None] + np.arange(S)[None, :],
+                            jnp.int32)
+    kpool, vpool = np.array(cache.k), np.array(cache.v)
+    x = llama._embed(params, cfg, tokens)
+    freqs = llama._rope_frequencies(cfg)
+    for l in range(cfg.num_layers):
+        lp = jax.tree.map(lambda a: a[l], params["layers"])
+        h = llama.rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
+        q, k, v = llama._qkv(h, lp, cfg, freqs, positions, False, None)
+        for b in range(B):
+            for s in range(S):
+                pos = index[b] + s
+                kpool[l, table[b, pos // bs], pos % bs] = k[b, s]
+                vpool[l, table[b, pos // bs], pos % bs] = v[b, s]
+        kg = jnp.asarray(kpool[l][table]).reshape(
+            B, -1, *kpool.shape[3:])
+        vg = jnp.asarray(vpool[l][table]).reshape(
+            B, -1, *vpool.shape[3:])
+        attn = attention(q, kg, vg, positions=positions,
+                         kv_len=jnp.asarray(index + S), backend="xla")
+        x = x + llama._proj(attn, lp["wo"], cfg.dtype, flatten=2)
+        h = llama.rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps)
+        x = x + llama.dense_mlp(h, lp, cfg)
+    return llama._final_logits(params, cfg, x), kpool, vpool
+
+
+@pytest.mark.parametrize("S", [1, 3])
+def test_forward_paged_equals_plain_layer_loop(S):
+    """The pool carried through the layer scan and written in place
+    at (layer, block, offset) gives the logits and the pool of a plain
+    per-layer loop, for decode (S = 1) and verify (S = 3) shapes,
+    with rows that cross a block boundary."""
+    cfg = CFG
+    B, bs, M, N = 3, 16, 4, 16
+    rng = np.random.default_rng(7)
+    params = llama.init_params(jax.random.PRNGKey(1), cfg)
+    cache = llama.PagedKVCache.create(cfg, B, N, bs, M)
+    shape = cache.k.shape
+    table = 1 + rng.permutation(N - 1)[:B * M].reshape(B, M)
+    cache = llama.PagedKVCache(
+        k=jnp.asarray(rng.standard_normal(shape), jnp.float32),
+        v=jnp.asarray(rng.standard_normal(shape), jnp.float32),
+        index=jnp.asarray([0, 15, 37], jnp.int32),
+        table=jnp.asarray(table, jnp.int32))
+    tokens = jnp.asarray(rng.integers(0, cfg.vocab_size, (B, S)),
+                         jnp.int32)
+    ref, kref, vref = _plain_paged_forward(params, cfg, tokens, cache)
+    logits, nc = jax.jit(
+        lambda p, t, c: llama.forward_paged(p, cfg, t, c))(
+            params, tokens, cache)
+    np.testing.assert_allclose(np.asarray(logits), np.asarray(ref),
+                               atol=2e-4, rtol=2e-4)
+    np.testing.assert_allclose(np.asarray(nc.k), kref, atol=1e-5)
+    np.testing.assert_allclose(np.asarray(nc.v), vref, atol=1e-5)
+    np.testing.assert_array_equal(np.asarray(nc.index),
+                                  np.asarray(cache.index) + S)
 
 
 def _run(engine, prompts, max_new=24, temperature=0.0, maskers=None):
