@@ -75,21 +75,58 @@ class DecodeState:
     moe_stats: jax.Array = None
 
 
-def _cache_of(st: DecodeState) -> llama.KVCache:
-    """The dense slab (and a hybrid model's recurrent state) of a
-    decode state as the model's forward takes it."""
-    return llama.KVCache(k=st.k, v=st.v, index=st.lengths, rec=st.rec,
-                         stats=st.moe_stats)
+def _cache_of(st: DecodeState, table: Optional[jax.Array] = None):
+    """A decode state as the model's forward takes it: the dense slab
+    (and a hybrid model's recurrent state), or, with a block `table`,
+    the paged pool."""
+    if table is None:
+        return llama.KVCache(k=st.k, v=st.v, index=st.lengths,
+                             rec=st.rec, stats=st.moe_stats)
+    return llama.PagedKVCache(k=st.k, v=st.v, index=st.lengths,
+                              table=table, k_scale=st.k_scale,
+                              v_scale=st.v_scale)
 
 
-def _state_of(nc: llama.KVCache, toks: jax.Array, st: DecodeState,
+def _state_of(nc, toks: jax.Array, st: DecodeState,
               lengths: Optional[jax.Array] = None) -> DecodeState:
-    """The decode state after a forward over the dense slab left
-    `nc`; `lengths` where they are not the cache's own index."""
+    """The decode state after a forward left the cache `nc`, of
+    either kind; `lengths` where they are not the cache's own index.
+    The one place a program body builds its next state: a field the
+    model's caches grow is carried on here."""
     return DecodeState(k=nc.k, v=nc.v,
                        lengths=nc.index if lengths is None else lengths,
-                       tokens=toks, adapters=st.adapters, rec=nc.rec,
-                       moe_stats=nc.stats)
+                       tokens=toks, adapters=st.adapters,
+                       k_scale=getattr(nc, "k_scale", None),
+                       v_scale=getattr(nc, "v_scale", None),
+                       rec=getattr(nc, "rec", None),
+                       moe_stats=getattr(nc, "stats", None))
+
+
+# how a structured-output mask reaches a program, as the suffix of the
+# program's name and what it takes after its other arguments
+# (docs/structured-outputs.md): nothing, so that an unconstrained
+# batch never pays a mask transfer; a dense bool array the host
+# ships; the device-resident mask table and int32 row indices into
+# it, a few ints per slot on the wire instead of a vocabulary of bools
+MASK_KINDS = ("", "_masked", "_masked_idx")
+
+
+def _mask_bits(mask: tuple) -> Optional[jax.Array]:
+    """The allowed-token bits of a program's trailing mask arguments:
+    () none, (bits,) as given, (table, idx) the table's rows gathered
+    here, inside the program. Row 0 of the table is all-True, so an
+    unmasked slot's index 0 masks nothing."""
+    if not mask:
+        return None
+    if len(mask) == 1:
+        return mask[0]
+    table, idx = mask
+    return table[idx]
+
+
+def _masked(logits: jax.Array, bits: Optional[jax.Array]) -> jax.Array:
+    """`logits` with every token outside `bits` made unsampleable."""
+    return logits if bits is None else jnp.where(bits, logits, -jnp.inf)
 
 
 def _counts_of(st: DecodeState) -> tuple:
@@ -652,11 +689,15 @@ class InferenceEngine:
         cfg_ = cfg
         hybrid = cfg.is_hybrid
 
-        @functools.partial(jax.jit, static_argnames=("bucket",))
         @scoped("prefill")
         def _prefill(params, padded: jax.Array, true_len: jax.Array,
-                     temperature, top_k, top_p, key, adapter,
+                     temperature, top_k, top_p, key, *mask_adapter,
                      bucket: int):
+            """Bucketed prefill of one prompt. `mask_adapter` is the
+            adapter id, after a [1, V] mask of the dense kind where
+            the FIRST sampled token honors a structured-output
+            grammar."""
+            *mask, adapter = mask_adapter
             cache = llama.KVCache.create(cfg_, 1, bucket)
             # last REAL token's logits only (right padding occupies
             # the tail): the head runs on that one row. KV rows hide
@@ -668,7 +709,8 @@ class InferenceEngine:
                                               adapter_ids=adapter,
                                               logits_at=true_len - 1,
                                               valid_len=true_len)
-            tok = sample(logits[:, 0], key, temperature, top_k, top_p)
+            tok = sample(_masked(logits[:, 0], _mask_bits(mask)), key,
+                         temperature, top_k, top_p)
             return (tok[0], new_cache.k, new_cache.v) + (
                 (new_cache.rec,) if hybrid else ())
 
@@ -722,58 +764,12 @@ class InferenceEngine:
                         whole, one.astype(whole.dtype),
                         (0, slot) + (0,) * (whole.ndim - 2)),
                     state.rec, rec)
-            return DecodeState(
-                k=k, v=v,
+            return dataclasses.replace(
+                state, k=k, v=v,
                 lengths=state.lengths.at[slot].set(true_len),
                 tokens=state.tokens.at[slot].set(token),
                 adapters=state.adapters.at[slot].set(adapter),
-                rec=new_rec, moe_stats=state.moe_stats)
-
-        @functools.partial(jax.jit, donate_argnums=(1,))
-        @scoped("decode")
-        def _decode(params, state: DecodeState, temperature, top_k, top_p,
-                    key) -> Tuple[DecodeState, jax.Array]:
-            logits, new_cache = llama.forward(
-                params, cfg_, state.tokens[:, None],
-                cache=_cache_of(state), adapter_ids=state.adapters)
-            toks = sample(logits[:, -1], key, temperature, top_k, top_p)
-            new_state = _state_of(new_cache, toks, state)
-            return (new_state, toks) + _counts_of(new_state)
-
-        @functools.partial(jax.jit, donate_argnums=(1,))
-        @scoped("decode")
-        def _decode_masked(params, state: DecodeState, temperature,
-                           top_k, top_p, key, mask,
-                           ) -> Tuple[DecodeState, jax.Array]:
-            """Decode with a [B, V] allowed-token mask (structured
-            outputs / JSON mode — engine/structured.py). Separate
-            program so unconstrained batches never pay the mask
-            transfer."""
-            logits, new_cache = llama.forward(
-                params, cfg_, state.tokens[:, None],
-                cache=_cache_of(state), adapter_ids=state.adapters)
-            masked = jnp.where(mask, logits[:, -1], -jnp.inf)
-            toks = sample(masked, key, temperature, top_k, top_p)
-            new_state = _state_of(new_cache, toks, state)
-            return (new_state, toks) + _counts_of(new_state)
-
-        @functools.partial(jax.jit, static_argnames=("bucket",))
-        @scoped("prefill")
-        def _prefill_masked(params, padded, true_len, temperature,
-                            top_k, top_p, key, mask, adapter,
-                            bucket: int):
-            """Bucketed prefill whose FIRST sampled token honors the
-            structured-output mask."""
-            cache = llama.KVCache.create(cfg_, 1, bucket)
-            logits, new_cache = llama.forward(params, cfg_, padded,
-                                              cache=cache,
-                                              adapter_ids=adapter,
-                                              logits_at=true_len - 1,
-                                              valid_len=true_len)
-            last = jnp.where(mask, logits[:, 0], -jnp.inf)
-            tok = sample(last, key, temperature, top_k, top_p)
-            return (tok[0], new_cache.k, new_cache.v) + (
-                (new_cache.rec,) if hybrid else ())
+                rec=new_rec)
 
         kvb = self.kv_block
         kvq = self.kv_quantized
@@ -821,49 +817,41 @@ class InferenceEngine:
                         ksc, csk, (0, block_ids[i], 0, 0))
                     vsc = lax.dynamic_update_slice(
                         vsc, csv, (0, block_ids[i], 0, 0))
-            return DecodeState(
-                k=k, v=v,
+            return dataclasses.replace(
+                state, k=k, v=v,
                 lengths=state.lengths.at[slot].set(true_len),
                 tokens=state.tokens.at[slot].set(token),
                 adapters=state.adapters.at[slot].set(adapter),
                 k_scale=ksc, v_scale=vsc)
 
-        @functools.partial(jax.jit, donate_argnums=(1,))
-        @scoped("decode")
-        def _decode_paged(params, state: DecodeState, table,
-                          temperature, top_k, top_p, key):
-            cache = llama.PagedKVCache(k=state.k, v=state.v,
-                                       index=state.lengths, table=table,
-                                       k_scale=state.k_scale,
-                                       v_scale=state.v_scale)
-            logits, nc = llama.forward_paged(
-                params, cfg_, state.tokens[:, None], cache,
-                adapter_ids=state.adapters)
-            toks = sample(logits[:, -1], key, temperature, top_k, top_p)
-            return DecodeState(k=nc.k, v=nc.v, lengths=nc.index,
-                               tokens=toks,
-                               adapters=state.adapters,
-                               k_scale=nc.k_scale,
-                               v_scale=nc.v_scale), toks
+        def _forward(params, st: DecodeState, toks, table,
+                     active=None):
+            """The model over `toks` ([B, T]) for a decode state: the
+            slab when there is no block `table`, else the paged pool
+            read through it. `active` ([B] bool, the multi-step
+            loop's) reaches a hybrid model's DeltaNet layers as the
+            row's valid length; KV rows have no use for it."""
+            if table is None:
+                return llama.forward(
+                    params, cfg_, toks, cache=_cache_of(st),
+                    adapter_ids=st.adapters,
+                    valid_len=(None if active is None
+                               else active.astype(jnp.int32)))
+            return llama.forward_paged(params, cfg_, toks,
+                                       _cache_of(st, table),
+                                       adapter_ids=st.adapters)
 
-        @functools.partial(jax.jit, donate_argnums=(1,))
-        @scoped("decode")
-        def _decode_masked_paged(params, state: DecodeState, table,
-                                 temperature, top_k, top_p, key, mask):
-            cache = llama.PagedKVCache(k=state.k, v=state.v,
-                                       index=state.lengths, table=table,
-                                       k_scale=state.k_scale,
-                                       v_scale=state.v_scale)
-            logits, nc = llama.forward_paged(
-                params, cfg_, state.tokens[:, None], cache,
-                adapter_ids=state.adapters)
-            masked = jnp.where(mask, logits[:, -1], -jnp.inf)
-            toks = sample(masked, key, temperature, top_k, top_p)
-            return DecodeState(k=nc.k, v=nc.v, lengths=nc.index,
-                               tokens=toks,
-                               adapters=state.adapters,
-                               k_scale=nc.k_scale,
-                               v_scale=nc.v_scale), toks
+        def _decode_step(params, state: DecodeState, table,
+                         temperature, top_k, top_p, key, *mask):
+            """One token for every slot; `mask` is [B, V] bits or
+            [B] rows of the mask table."""
+            logits, nc = _forward(params, state,
+                                  state.tokens[:, None], table)
+            bits = _mask_bits(mask)
+            toks = sample(_masked(logits[:, -1], bits), key,
+                          temperature, top_k, top_p)
+            new_state = _state_of(nc, toks, state)
+            return (new_state, toks) + _counts_of(new_state)
 
         smax = self.max_seq
 
@@ -893,23 +881,17 @@ class InferenceEngine:
             # move either: `active` reaches the DeltaNet layers as the
             # row's valid length
             logits, nc = forward_one(st, active)
-            last = logits[:, -1]
-            if mask is not None:
-                last = jnp.where(mask[:, i], last, -jnp.inf)
+            last = _masked(logits[:, -1],
+                           None if mask is None else mask[:, i])
             toks = sample(last, jax.random.fold_in(key, i),
                           temperature, top_k, top_p)
             toks = jnp.where(active, toks, st.tokens)
             done = done | jnp.any(toks[:, None] == stop_ids, axis=1)
             acc = acc.at[:, i].set(toks)
             adv = adv + active.astype(jnp.int32)
-            st = DecodeState(
-                k=nc.k, v=nc.v,
-                lengths=jnp.where(active, nc.index, st.lengths),
-                tokens=toks, adapters=st.adapters,
-                k_scale=getattr(nc, "k_scale", None),
-                v_scale=getattr(nc, "v_scale", None),
-                rec=getattr(nc, "rec", None),
-                moe_stats=getattr(nc, "stats", None))
+            st = _state_of(
+                nc, toks, st,
+                lengths=jnp.where(active, nc.index, st.lengths))
             return st, done, acc, adv
 
         def _multi_loop(state, key, temperature, top_k, top_p, budget,
@@ -932,383 +914,134 @@ class InferenceEngine:
                 carry)
             return (state, acc, adv) + _counts_of(state)
 
-        @functools.partial(jax.jit, donate_argnums=(1,),
-                           static_argnames=("n",))
-        @scoped("decode")
-        def _decode_multi(params, state: DecodeState, temperature,
-                          top_k, top_p, key, budget, stop_ids,
-                          n: int):
+        def _decode_chunk(params, state: DecodeState, table,
+                          temperature, top_k, top_p, key, budget,
+                          stop_ids, *mask, n: int):
             """n decode iterations inside ONE device program (ROADMAP
             item 2): a fori_loop over {forward → sample → KV append →
             next-token embed} with sampling fused as the loop epilogue
             (per-iteration keys folded from the chunk key), so the
             host syncs once per n tokens instead of once per token.
             budget: [B] int32 remaining-token cap per slot; stop_ids:
-            [B, NS] int32 stop table (-1 padding). Returns (state,
-            tokens [B, n], advanced [B]) — slot b's real output is
+            [B, NS] int32 stop table (-1 padding); `mask`: [B, n, V]
+            bits, one mask per iteration (structured outputs inside a
+            fused chunk), or [B, n] rows of the mask table, gathered
+            once before the loop. Returns (state, tokens [B, n],
+            advanced [B]) — slot b's real output is
             tokens[b, :advanced[b]], the rest is frozen filler the
-            host discards."""
+            host discards.
+
+            Over the paged pool the block table is STATIC for the
+            whole chunk: the host pre-allocates blocks covering every
+            row the n iterations can write (_grow_blocks_spec, the
+            spec-decode discipline) and commit_spec() reconciles
+            lengths + returns the surplus once `advanced` is
+            drained."""
 
             def forward_one(st, active):
-                return llama.forward(params, cfg_, st.tokens[:, None],
-                                     cache=_cache_of(st),
-                                     adapter_ids=st.adapters,
-                                     valid_len=active.astype(jnp.int32))
-
-            return _multi_loop(state, key, temperature, top_k, top_p,
-                               budget, stop_ids, forward_one, n)
-
-        @functools.partial(jax.jit, donate_argnums=(1,),
-                           static_argnames=("n",))
-        @scoped("decode")
-        def _decode_multi_paged(params, state: DecodeState, table,
-                                temperature, top_k, top_p, key,
-                                budget, stop_ids, n: int):
-            """Paged-pool multi-token decode. The block table is
-            STATIC for the whole chunk: the host pre-allocates blocks
-            covering every row the n iterations can write
-            (_grow_blocks_spec, the spec-decode discipline) and
-            commit_spec() reconciles lengths + returns the surplus
-            once `advanced` is drained."""
-
-            def forward_one(st, active):
-                cache = llama.PagedKVCache(k=st.k, v=st.v,
-                                           index=st.lengths,
-                                           table=table,
-                                           k_scale=st.k_scale,
-                                           v_scale=st.v_scale)
-                return llama.forward_paged(params, cfg_,
-                                           st.tokens[:, None], cache,
-                                           adapter_ids=st.adapters)
-
-            return _multi_loop(state, key, temperature, top_k, top_p,
-                               budget, stop_ids, forward_one, n)
-
-        @functools.partial(jax.jit, donate_argnums=(1,),
-                           static_argnames=("n",))
-        @scoped("decode")
-        def _decode_multi_masked(params, state: DecodeState,
-                                 temperature, top_k, top_p, key,
-                                 budget, stop_ids, mask, n: int):
-            """Multi-token decode with a [B, n, V] per-iteration mask
-            stack (structured outputs inside a fused chunk). Separate
-            program so unmasked chunks never pay the mask transfer."""
-
-            def forward_one(st, active):
-                return llama.forward(params, cfg_, st.tokens[:, None],
-                                     cache=_cache_of(st),
-                                     adapter_ids=st.adapters,
-                                     valid_len=active.astype(jnp.int32))
+                return _forward(params, st, st.tokens[:, None], table,
+                                active)
 
             return _multi_loop(state, key, temperature, top_k, top_p,
                                budget, stop_ids, forward_one, n,
-                               mask=mask)
+                               mask=_mask_bits(mask))
 
-        @functools.partial(jax.jit, donate_argnums=(1,),
-                           static_argnames=("n",))
-        @scoped("decode")
-        def _decode_multi_masked_paged(params, state: DecodeState,
-                                       table, temperature, top_k,
-                                       top_p, key, budget, stop_ids,
-                                       mask, n: int):
-
-            def forward_one(st, active):
-                cache = llama.PagedKVCache(k=st.k, v=st.v,
-                                           index=st.lengths,
-                                           table=table,
-                                           k_scale=st.k_scale,
-                                           v_scale=st.v_scale)
-                return llama.forward_paged(params, cfg_,
-                                           st.tokens[:, None], cache,
-                                           adapter_ids=st.adapters)
-
-            return _multi_loop(state, key, temperature, top_k, top_p,
-                               budget, stop_ids, forward_one, n,
-                               mask=mask)
-
-        @functools.partial(jax.jit, donate_argnums=(1,),
-                           static_argnames=("k",))
-        @scoped("verify")
-        def _verify(params, state: DecodeState, drafts, draft_len,
-                    temperature, top_k, top_p, key, k: int):
+        def _verify_step(params, state: DecodeState, table, drafts,
+                         draft_len, temperature, top_k, top_p, key,
+                         *mask, k: int):
             """Speculative verify: one forward over [last_token,
             draft_0..draft_{k-1}] per slot scores all k+1 positions in
             a single weight pass. Draft K/V is written at the slot's
             cache index like any decode write; the ROLLBACK of
             rejected rows is just the per-slot index update below —
             rows past `lengths + accepted + 1` are unreachable
-            (kv_len masking) and the next step overwrites them."""
-            toks = jnp.concatenate([state.tokens[:, None], drafts],
-                                   axis=1)  # [B, k+1]
-            cache = llama.KVCache(k=state.k, v=state.v,
-                                  index=state.lengths)
-            logits, nc = llama.forward(params, cfg_, toks, cache=cache,
-                                       adapter_ids=state.adapters)
-            out, accepted = spec_verify(logits, drafts, draft_len, key,
-                                        temperature, top_k, top_p)
-            new_tok = jnp.take_along_axis(out, accepted[:, None],
-                                          axis=1)[:, 0]
-            return DecodeState(k=nc.k, v=nc.v,
-                               lengths=state.lengths + accepted + 1,
-                               tokens=new_tok,
-                               adapters=state.adapters), out, accepted
-
-        @functools.partial(jax.jit, donate_argnums=(1,),
-                           static_argnames=("k",))
-        @scoped("verify")
-        def _verify_paged(params, state: DecodeState, table, drafts,
-                          draft_len, temperature, top_k, top_p, key,
-                          k: int):
-            """Paged-pool verify: the engine pre-allocates blocks
-            covering all k+1 speculative rows before dispatch
+            (kv_len masking) and the next step overwrites them. Over
+            the paged pool the engine pre-allocates blocks covering
+            all k+1 speculative rows before dispatch
             (_grow_blocks_spec); commit_spec() returns the surplus to
             the pool after the accepted count is known."""
             toks = jnp.concatenate([state.tokens[:, None], drafts],
-                                   axis=1)
-            cache = llama.PagedKVCache(k=state.k, v=state.v,
-                                       index=state.lengths, table=table,
-                                       k_scale=state.k_scale,
-                                       v_scale=state.v_scale)
-            logits, nc = llama.forward_paged(
-                params, cfg_, toks, cache, adapter_ids=state.adapters)
+                                   axis=1)  # [B, k+1]
+            logits, nc = _forward(params, state, toks, table)
+            bits = _mask_bits(mask)
+            if len(mask) == 1:
+                # the dense kind is a [B, V] position-0 mask: masked
+                # (structured-output) slots ride a verify plan at
+                # draft_len 0 — their single sampled token honors the
+                # grammar mask while drafting slots verify normally
+                # (masked rows never draft, so positions past 0 are
+                # only reached by unmasked slots). All-True rows are
+                # a no-op.
+                logits = logits.at[:, 0].set(
+                    _masked(logits[:, 0], bits))
+            else:
+                # the index kind ([B, k+1] rows) masks ALL k+1
+                # positions, because grammar-constrained slots then
+                # DRAFT (spec-through-grammar): the token emitted at a
+                # rejection position comes from that position's target
+                # logits, which must honor that position's mask.
+                # Unmasked slots point every position at row 0.
+                logits = _masked(logits, bits)
             out, accepted = spec_verify(logits, drafts, draft_len, key,
                                         temperature, top_k, top_p)
             new_tok = jnp.take_along_axis(out, accepted[:, None],
                                           axis=1)[:, 0]
-            return DecodeState(k=nc.k, v=nc.v,
-                               lengths=state.lengths + accepted + 1,
-                               tokens=new_tok,
-                               adapters=state.adapters,
-                               k_scale=nc.k_scale,
-                               v_scale=nc.v_scale), out, accepted
-
-        @functools.partial(jax.jit, donate_argnums=(1,),
-                           static_argnames=("k",))
-        @scoped("verify")
-        def _verify_masked(params, state: DecodeState, drafts,
-                           draft_len, temperature, top_k, top_p, key,
-                           mask, k: int):
-            """Verify with a [B, V] position-0 mask: masked
-            (structured-output) slots ride a verify plan at
-            draft_len 0 — their single sampled token honors the
-            grammar mask while drafting slots verify normally
-            (masked rows never draft, so positions past 0 are only
-            reached by unmasked slots). All-True rows are a no-op."""
-            toks = jnp.concatenate([state.tokens[:, None], drafts],
-                                   axis=1)
-            cache = llama.KVCache(k=state.k, v=state.v,
-                                  index=state.lengths)
-            logits, nc = llama.forward(params, cfg_, toks, cache=cache,
-                                       adapter_ids=state.adapters)
-            logits = logits.at[:, 0].set(
-                jnp.where(mask, logits[:, 0], -jnp.inf))
-            out, accepted = spec_verify(logits, drafts, draft_len, key,
-                                        temperature, top_k, top_p)
-            new_tok = jnp.take_along_axis(out, accepted[:, None],
-                                          axis=1)[:, 0]
-            return DecodeState(k=nc.k, v=nc.v,
-                               lengths=state.lengths + accepted + 1,
-                               tokens=new_tok,
-                               adapters=state.adapters), out, accepted
-
-        @functools.partial(jax.jit, donate_argnums=(1,),
-                           static_argnames=("k",))
-        @scoped("verify")
-        def _verify_masked_paged(params, state: DecodeState, table,
-                                 drafts, draft_len, temperature,
-                                 top_k, top_p, key, mask, k: int):
-            toks = jnp.concatenate([state.tokens[:, None], drafts],
-                                   axis=1)
-            cache = llama.PagedKVCache(k=state.k, v=state.v,
-                                       index=state.lengths, table=table,
-                                       k_scale=state.k_scale,
-                                       v_scale=state.v_scale)
-            logits, nc = llama.forward_paged(
-                params, cfg_, toks, cache, adapter_ids=state.adapters)
-            logits = logits.at[:, 0].set(
-                jnp.where(mask, logits[:, 0], -jnp.inf))
-            out, accepted = spec_verify(logits, drafts, draft_len, key,
-                                        temperature, top_k, top_p)
-            new_tok = jnp.take_along_axis(out, accepted[:, None],
-                                          axis=1)[:, 0]
-            return DecodeState(k=nc.k, v=nc.v,
-                               lengths=state.lengths + accepted + 1,
-                               tokens=new_tok,
-                               adapters=state.adapters,
-                               k_scale=nc.k_scale,
-                               v_scale=nc.v_scale), out, accepted
+            return _state_of(
+                nc, new_tok, state,
+                lengths=state.lengths + accepted + 1), out, accepted
 
         # -- device-resident grammar mask table (docs/structured-
         # outputs.md): cached automaton-state masks live as rows of a
-        # [S, V] device buffer; the *_idx program variants gather each
-        # slot's row in-program from int32 state indices, so a masked
-        # step ships K ints per slot instead of K*V mask bools. Row 0
-        # is reserved all-True (the unmasked sentinel every idx array
+        # [S, V] device buffer; the *_idx programs gather each slot's
+        # row in-program from int32 state indices, so a masked step
+        # ships K ints per slot instead of K*V mask bools. Row 0 is
+        # reserved all-True (the unmasked sentinel every idx array
         # defaults to); set_mask_row() refuses to write it.
 
         @functools.partial(jax.jit, donate_argnums=(0,))
         def _mask_row_set(tab, row, bits):
             return tab.at[row].set(bits)
 
-        @functools.partial(jax.jit, donate_argnums=(1,))
-        @scoped("decode")
-        def _decode_masked_idx(params, state: DecodeState, temperature,
-                               top_k, top_p, key, mtab, midx,
-                               ) -> Tuple[DecodeState, jax.Array]:
-            """Decode gathering each slot's allowed-token row from the
-            device mask table by state index ([B] int32)."""
-            logits, new_cache = llama.forward(
-                params, cfg_, state.tokens[:, None],
-                cache=_cache_of(state), adapter_ids=state.adapters)
-            masked = jnp.where(mtab[midx], logits[:, -1], -jnp.inf)
-            toks = sample(masked, key, temperature, top_k, top_p)
-            new_state = _state_of(new_cache, toks, state)
-            return (new_state, toks) + _counts_of(new_state)
+        def _jit_as(name: str, fn, **jit_kw):
+            """`fn` jitted as `jit__<name>`, the name a trace, the
+            benchmark and the ledger know the program by."""
+            @functools.wraps(fn)
+            def program(*args, **kw):
+                return fn(*args, **kw)
+            program.__name__ = program.__qualname__ = "_" + name
+            return jax.jit(program, **jit_kw)
 
-        @functools.partial(jax.jit, donate_argnums=(1,))
-        @scoped("decode")
-        def _decode_masked_idx_paged(params, state: DecodeState, table,
-                                     temperature, top_k, top_p, key,
-                                     mtab, midx):
-            cache = llama.PagedKVCache(k=state.k, v=state.v,
-                                       index=state.lengths, table=table,
-                                       k_scale=state.k_scale,
-                                       v_scale=state.v_scale)
-            logits, nc = llama.forward_paged(
-                params, cfg_, state.tokens[:, None], cache,
-                adapter_ids=state.adapters)
-            masked = jnp.where(mtab[midx], logits[:, -1], -jnp.inf)
-            toks = sample(masked, key, temperature, top_k, top_p)
-            return DecodeState(k=nc.k, v=nc.v, lengths=nc.index,
-                               tokens=toks,
-                               adapters=state.adapters,
-                               k_scale=nc.k_scale,
-                               v_scale=nc.v_scale), toks
+        def _over_slab(body):
+            def slab(params, state, *rest, **kw):
+                return body(params, state, None, *rest, **kw)
+            return slab
 
-        @functools.partial(jax.jit, donate_argnums=(1,),
-                           static_argnames=("n",))
-        @scoped("decode")
-        def _decode_multi_masked_idx(params, state: DecodeState,
-                                     temperature, top_k, top_p, key,
-                                     budget, stop_ids, mtab, midx,
-                                     n: int):
-            """Multi-token decode whose per-iteration [B, n, V] mask
-            stack is gathered from the mask table ([B, n] int32)."""
-
-            def forward_one(st, active):
-                return llama.forward(params, cfg_, st.tokens[:, None],
-                                     cache=_cache_of(st),
-                                     adapter_ids=st.adapters,
-                                     valid_len=active.astype(jnp.int32))
-
-            return _multi_loop(state, key, temperature, top_k, top_p,
-                               budget, stop_ids, forward_one, n,
-                               mask=mtab[midx])
-
-        @functools.partial(jax.jit, donate_argnums=(1,),
-                           static_argnames=("n",))
-        @scoped("decode")
-        def _decode_multi_masked_idx_paged(params, state: DecodeState,
-                                           table, temperature, top_k,
-                                           top_p, key, budget,
-                                           stop_ids, mtab, midx,
-                                           n: int):
-
-            def forward_one(st, active):
-                cache = llama.PagedKVCache(k=st.k, v=st.v,
-                                           index=st.lengths,
-                                           table=table,
-                                           k_scale=st.k_scale,
-                                           v_scale=st.v_scale)
-                return llama.forward_paged(params, cfg_,
-                                           st.tokens[:, None], cache,
-                                           adapter_ids=st.adapters)
-
-            return _multi_loop(state, key, temperature, top_k, top_p,
-                               budget, stop_ids, forward_one, n,
-                               mask=mtab[midx])
-
-        @functools.partial(jax.jit, donate_argnums=(1,),
-                           static_argnames=("k",))
-        @scoped("verify")
-        def _verify_masked_idx(params, state: DecodeState, drafts,
-                               draft_len, temperature, top_k, top_p,
-                               key, mtab, midx, k: int):
-            """Verify masking ALL k+1 positions from gathered table
-            rows ([B, k+1] int32) — unlike the dense variant's
-            position-0 mask, because grammar-constrained slots now
-            DRAFT (spec-through-grammar): the token emitted at a
-            rejection position comes from that position's target
-            logits, which must honor that position's mask. Unmasked
-            slots point every position at reserved row 0 (all-True)."""
-            toks = jnp.concatenate([state.tokens[:, None], drafts],
-                                   axis=1)
-            cache = llama.KVCache(k=state.k, v=state.v,
-                                  index=state.lengths)
-            logits, nc = llama.forward(params, cfg_, toks, cache=cache,
-                                       adapter_ids=state.adapters)
-            logits = jnp.where(mtab[midx], logits, -jnp.inf)
-            out, accepted = spec_verify(logits, drafts, draft_len, key,
-                                        temperature, top_k, top_p)
-            new_tok = jnp.take_along_axis(out, accepted[:, None],
-                                          axis=1)[:, 0]
-            return DecodeState(k=nc.k, v=nc.v,
-                               lengths=state.lengths + accepted + 1,
-                               tokens=new_tok,
-                               adapters=state.adapters), out, accepted
-
-        @functools.partial(jax.jit, donate_argnums=(1,),
-                           static_argnames=("k",))
-        @scoped("verify")
-        def _verify_masked_idx_paged(params, state: DecodeState, table,
-                                     drafts, draft_len, temperature,
-                                     top_k, top_p, key, mtab, midx,
-                                     k: int):
-            toks = jnp.concatenate([state.tokens[:, None], drafts],
-                                   axis=1)
-            cache = llama.PagedKVCache(k=state.k, v=state.v,
-                                       index=state.lengths, table=table,
-                                       k_scale=state.k_scale,
-                                       v_scale=state.v_scale)
-            logits, nc = llama.forward_paged(
-                params, cfg_, toks, cache, adapter_ids=state.adapters)
-            logits = jnp.where(mtab[midx], logits, -jnp.inf)
-            out, accepted = spec_verify(logits, drafts, draft_len, key,
-                                        temperature, top_k, top_p)
-            new_tok = jnp.take_along_axis(out, accepted[:, None],
-                                          axis=1)[:, 0]
-            return DecodeState(k=nc.k, v=nc.v,
-                               lengths=state.lengths + accepted + 1,
-                               tokens=new_tok,
-                               adapters=state.adapters,
-                               k_scale=nc.k_scale,
-                               v_scale=nc.v_scale), out, accepted
-
-        self._prefill_fn = _prefill
-        self._prefill_masked_fn = _prefill_masked
+        self._prefill_fn = _jit_as("prefill", _prefill,
+                                   static_argnames=("bucket",))
+        self._prefill_masked_fn = _jit_as("prefill_masked", _prefill,
+                                          static_argnames=("bucket",))
         self._prefill_suffix_fn = _prefill_suffix
         self._insert_fn = _insert
-        self._decode_fn = _decode
-        self._decode_masked_fn = _decode_masked
         self._insert_paged_fn = _insert_paged
-        self._decode_paged_fn = _decode_paged
-        self._decode_masked_paged_fn = _decode_masked_paged
-        self._decode_multi_fn = _decode_multi
-        self._decode_multi_paged_fn = _decode_multi_paged
-        self._decode_multi_masked_fn = _decode_multi_masked
-        self._decode_multi_masked_paged_fn = _decode_multi_masked_paged
-        self._verify_fn = _verify
-        self._verify_paged_fn = _verify_paged
-        self._verify_masked_fn = _verify_masked
-        self._verify_masked_paged_fn = _verify_masked_paged
         self._mask_row_fn = _mask_row_set
-        self._decode_masked_idx_fn = _decode_masked_idx
-        self._decode_masked_idx_paged_fn = _decode_masked_idx_paged
-        self._decode_multi_masked_idx_fn = _decode_multi_masked_idx
-        self._decode_multi_masked_idx_paged_fn = \
-            _decode_multi_masked_idx_paged
-        self._verify_masked_idx_fn = _verify_masked_idx
-        self._verify_masked_idx_paged_fn = _verify_masked_idx_paged
+        # the decode and verify programs by the name the ledger and
+        # /debug/programs give them: family + mask kind, + "_paged"
+        # over the pool. One body per family, under the family's
+        # scope; a paged program takes the block table after the
+        # state, a slab program takes none; the state is donated
+        self.programs = {}
+        for family, body, scope, static in (
+                ("decode", _decode_step, "decode", ()),
+                ("decode_multi", _decode_chunk, "decode", ("n",)),
+                ("verify", _verify_step, "verify", ("k",))):
+            body = scoped(scope)(body)
+            slab = _over_slab(body)
+            for kind in MASK_KINDS:
+                for name, fn in ((family + kind, slab),
+                                 (family + kind + "_paged", body)):
+                    self.programs[name] = _jit_as(
+                        name, fn, donate_argnums=(1,),
+                        static_argnames=static)
         self.mask_table_rows = int(mask_table_rows)
         self._mask_table_dev = None  # lazy: [rows, V] bool, row 0 True
         self._step = 0
@@ -1771,6 +1504,12 @@ class InferenceEngine:
         # leave room for one generated token; cap at the largest bucket
         max_prompt = min(self.max_seq - 1, self.prefill_buckets[-1])
         ids = prompt_ids[-max_prompt:]
+        if np.ndim(temperature) or np.ndim(top_k) or np.ndim(top_p):
+            # a [1] array would reach the program as [1, 1] and fail
+            # inside the sampling trace, far from its cause
+            raise ValueError(
+                "prefill serves one prompt: temperature, top_k and "
+                "top_p are scalars (decode takes the per-slot arrays)")
         key = self._next_key()
         sampling = (np.asarray([temperature], np.float32),
                     np.asarray([top_k], np.int32),
@@ -1824,29 +1563,17 @@ class InferenceEngine:
             bucket = _bucketize(len(ids), self.prefill_buckets)
             padded = np.asarray(
                 [ids + [0] * (bucket - len(ids))], np.int32)
-            aid_arr = np.asarray([aid], np.int32)
+            name, fn, mask = "prefill", self._prefill_fn, ()
             if first_mask is not None:
-                args = (self.params, padded,
-                        np.asarray([len(ids)], np.int32), *sampling,
-                        key, np.asarray(first_mask, bool)[None, :],
-                        aid_arr)
-                self._ledger_capture(
-                    "prefill_masked", f"bucket={bucket}",
-                    self._prefill_masked_fn, args,
-                    dict(bucket=bucket), tokens=bucket,
-                    kv_rows=bucket)
-                tok, k, v, *rec = self._prefill_masked_fn(
-                    *args, bucket=bucket)
-            else:
-                args = (self.params, padded,
-                        np.asarray([len(ids)], np.int32), *sampling,
-                        key, aid_arr)
-                self._ledger_capture(
-                    "prefill", f"bucket={bucket}", self._prefill_fn,
-                    args, dict(bucket=bucket), tokens=bucket,
-                    kv_rows=bucket)
-                tok, k, v, *rec = self._prefill_fn(*args,
-                                                   bucket=bucket)
+                name, fn = "prefill_masked", self._prefill_masked_fn
+                mask = (np.asarray(first_mask, bool)[None, :],)
+            args = (self.params, padded,
+                    np.asarray([len(ids)], np.int32), *sampling, key,
+                    *mask, np.asarray([aid], np.int32))
+            self._ledger_capture(
+                name, f"bucket={bucket}", fn, args,
+                dict(bucket=bucket), tokens=bucket, kv_rows=bucket)
+            tok, k, v, *rec = fn(*args, bucket=bucket)
         if aid == 0:
             self.prefix_cache.put(ids, k, v, len(ids), bucket)
         # multi-host: int() on an array spanning non-addressable
@@ -1947,6 +1674,49 @@ class InferenceEngine:
         self._mask_table_dev = self._mask_row_fn(
             tab, np.asarray(row, np.int32), np.asarray(bits, bool))
 
+    def _sampling(self, temperature, top_k, top_p) -> tuple:
+        """A step's per-slot sampling arguments and its fresh key, in
+        the order every program takes them."""
+        return (_sampling_array(temperature, np.float32),
+                _sampling_array(top_k, np.int32),
+                _sampling_array(top_p, np.float32), self._next_key())
+
+    def _dispatch(self, family: str, state: DecodeState, args: tuple,
+                  mask, mask_idx, static: dict, *, tokens: int,
+                  kv_rows: int, weight_passes: int = 1) -> tuple:
+        """Run the program of `family` that the given mask kind and
+        this engine's cache kind name (`self.programs`): `args` go
+        between the state (and the block table, over the pool) and
+        the mask. Returns the program's results, the state first,
+        with host copies of the others already in flight."""
+        name = family
+        if mask_idx is not None:
+            name += "_masked_idx"
+            args += (self._mask_table(), np.asarray(mask_idx, np.int32))
+        elif mask is not None:
+            name += "_masked"
+            args += (np.asarray(mask, bool),)
+        if self.kv_block:
+            name += "_paged"
+            if self._table_dirty or self._table_dev is None:
+                # upload once per table CHANGE, not once per step; the
+                # copy keeps the device table stable while steps run
+                self._table_dev = jnp.asarray(self._table.copy())
+                self._table_dirty = False
+            args = (self._table_dev, *args)
+        args = (self.params, state, *args)
+        fn = self.programs[name]
+        self._ledger_capture(
+            name, ",".join(f"{k}={v}" for k, v in static.items()), fn,
+            args, static, tokens=tokens, kv_rows=kv_rows,
+            weight_passes=weight_passes)
+        outs = self._take_counts(fn(*args, **static))
+        for arr in outs[1:]:
+            copy = getattr(arr, "copy_to_host_async", None)
+            if copy is not None:  # sharded/global arrays may not have it
+                copy()
+        return outs
+
     def decode(self, state: DecodeState, temperature, top_k, top_p,
                mask: Optional[np.ndarray] = None,
                mask_idx: Optional[np.ndarray] = None,
@@ -1966,70 +1736,12 @@ class InferenceEngine:
         caller can dispatch the next step before reading them; the
         eventual `np.asarray(toks)` then completes an overlapped copy
         instead of starting a blocking one."""
-        key = self._next_key()
-        sampling = (_sampling_array(temperature, np.float32),
-                    _sampling_array(top_k, np.int32),
-                    _sampling_array(top_p, np.float32))
         if self.kv_block:
             self._grow_blocks()
-            if self._table_dirty or self._table_dev is None:
-                # upload once per table CHANGE, not once per step; the
-                # copy keeps the device table stable while steps run
-                self._table_dev = jnp.asarray(self._table.copy())
-                self._table_dirty = False
-            table = self._table_dev
-            cap = self._kv_capacity_rows()
-            if mask_idx is not None:
-                args = (self.params, state, table, *sampling, key,
-                        self._mask_table(),
-                        np.asarray(mask_idx, np.int32))
-                self._ledger_capture(
-                    "decode_masked_idx_paged", "",
-                    self._decode_masked_idx_paged_fn, args, {},
-                    tokens=self.max_slots, kv_rows=cap)
-                state, toks = self._decode_masked_idx_paged_fn(*args)
-            elif mask is not None:
-                args = (self.params, state, table, *sampling, key,
-                        np.asarray(mask, bool))
-                self._ledger_capture(
-                    "decode_masked_paged", "",
-                    self._decode_masked_paged_fn, args, {},
-                    tokens=self.max_slots, kv_rows=cap)
-                state, toks = self._decode_masked_paged_fn(*args)
-            else:
-                args = (self.params, state, table, *sampling, key)
-                self._ledger_capture(
-                    "decode_paged", "", self._decode_paged_fn, args,
-                    {}, tokens=self.max_slots, kv_rows=cap)
-                state, toks = self._decode_paged_fn(*args)
-        elif mask_idx is not None:
-            args = (self.params, state, *sampling, key,
-                    self._mask_table(), np.asarray(mask_idx, np.int32))
-            self._ledger_capture(
-                "decode_masked_idx", "", self._decode_masked_idx_fn,
-                args, {}, tokens=self.max_slots,
-                kv_rows=self._kv_capacity_rows())
-            state, toks = self._take_counts(
-                self._decode_masked_idx_fn(*args))
-        elif mask is not None:
-            args = (self.params, state, *sampling, key,
-                    np.asarray(mask, bool))
-            self._ledger_capture(
-                "decode_masked", "", self._decode_masked_fn, args, {},
-                tokens=self.max_slots, kv_rows=self._kv_capacity_rows())
-            state, toks = self._take_counts(
-                self._decode_masked_fn(*args))
-        else:
-            args = (self.params, state, *sampling, key)
-            self._ledger_capture(
-                "decode", "", self._decode_fn, args, {},
-                tokens=self.max_slots, kv_rows=self._kv_capacity_rows())
-            state, toks = self._take_counts(
-                self._decode_fn(*args))
-        copy = getattr(toks, "copy_to_host_async", None)
-        if copy is not None:  # sharded/global arrays may not have it
-            copy()
-        return state, toks
+        return self._dispatch(
+            "decode", state, self._sampling(temperature, top_k, top_p),
+            mask, mask_idx, {}, tokens=self.max_slots,
+            kv_rows=self._kv_capacity_rows())
 
     def decode_multi(self, state: DecodeState, temperature, top_k,
                      top_p, steps: int, budget, stop_ids,
@@ -2062,88 +1774,17 @@ class InferenceEngine:
         that are frozen filler the caller must discard. Paged callers
         reconcile each drained chunk with commit_spec(slot, advanced,
         reserve=...)."""
-        key = self._next_key()
-        sampling = (_sampling_array(temperature, np.float32),
-                    _sampling_array(top_k, np.int32),
-                    _sampling_array(top_p, np.float32))
-        budget = _sampling_array(budget, np.int32)
-        stop_ids = _sampling_array(stop_ids, np.int32)
         n = int(steps)
         if self.kv_block:
-            rows = n if lookahead_rows is None else int(lookahead_rows)
-            self._grow_blocks_spec(rows)
-            if self._table_dirty or self._table_dev is None:
-                self._table_dev = jnp.asarray(self._table.copy())
-                self._table_dirty = False
-            if mask_idx is not None:
-                args = (self.params, state, self._table_dev, *sampling,
-                        key, budget, stop_ids, self._mask_table(),
-                        np.asarray(mask_idx, np.int32))
-                self._ledger_capture(
-                    "decode_multi_masked_idx_paged", f"n={n}",
-                    self._decode_multi_masked_idx_paged_fn, args,
-                    dict(n=n), tokens=self.max_slots * n,
-                    kv_rows=n * self._kv_capacity_rows(),
-                    weight_passes=n)
-                state, toks, adv = \
-                    self._decode_multi_masked_idx_paged_fn(*args, n=n)
-            elif mask is not None:
-                args = (self.params, state, self._table_dev, *sampling,
-                        key, budget, stop_ids, np.asarray(mask, bool))
-                self._ledger_capture(
-                    "decode_multi_masked_paged", f"n={n}",
-                    self._decode_multi_masked_paged_fn, args,
-                    dict(n=n), tokens=self.max_slots * n,
-                    kv_rows=n * self._kv_capacity_rows(),
-                    weight_passes=n)
-                state, toks, adv = \
-                    self._decode_multi_masked_paged_fn(*args, n=n)
-            else:
-                args = (self.params, state, self._table_dev, *sampling,
-                        key, budget, stop_ids)
-                self._ledger_capture(
-                    "decode_multi_paged", f"n={n}",
-                    self._decode_multi_paged_fn, args, dict(n=n),
-                    tokens=self.max_slots * n,
-                    kv_rows=n * self._kv_capacity_rows(),
-                    weight_passes=n)
-                state, toks, adv = \
-                    self._decode_multi_paged_fn(*args, n=n)
-        elif mask_idx is not None:
-            args = (self.params, state, *sampling, key, budget,
-                    stop_ids, self._mask_table(),
-                    np.asarray(mask_idx, np.int32))
-            self._ledger_capture(
-                "decode_multi_masked_idx", f"n={n}",
-                self._decode_multi_masked_idx_fn, args, dict(n=n),
-                tokens=self.max_slots * n,
-                kv_rows=n * self._kv_capacity_rows(), weight_passes=n)
-            state, toks, adv = self._take_counts(
-                self._decode_multi_masked_idx_fn(*args, n=n))
-        elif mask is not None:
-            args = (self.params, state, *sampling, key, budget,
-                    stop_ids, np.asarray(mask, bool))
-            self._ledger_capture(
-                "decode_multi_masked", f"n={n}",
-                self._decode_multi_masked_fn, args, dict(n=n),
-                tokens=self.max_slots * n,
-                kv_rows=n * self._kv_capacity_rows(), weight_passes=n)
-            state, toks, adv = self._take_counts(
-                self._decode_multi_masked_fn(*args, n=n))
-        else:
-            args = (self.params, state, *sampling, key, budget,
-                    stop_ids)
-            self._ledger_capture(
-                "decode_multi", f"n={n}", self._decode_multi_fn, args,
-                dict(n=n), tokens=self.max_slots * n,
-                kv_rows=n * self._kv_capacity_rows(), weight_passes=n)
-            state, toks, adv = self._take_counts(
-                self._decode_multi_fn(*args, n=n))
-        for arr in (toks, adv):
-            copy = getattr(arr, "copy_to_host_async", None)
-            if copy is not None:
-                copy()
-        return state, toks, adv
+            self._grow_blocks_spec(
+                n if lookahead_rows is None else int(lookahead_rows))
+        return self._dispatch(
+            "decode_multi", state,
+            (*self._sampling(temperature, top_k, top_p),
+             _sampling_array(budget, np.int32),
+             _sampling_array(stop_ids, np.int32)),
+            mask, mask_idx, dict(n=n), tokens=self.max_slots * n,
+            kv_rows=n * self._kv_capacity_rows(), weight_passes=n)
 
     def verify(self, state: DecodeState, drafts: np.ndarray,
                draft_len: np.ndarray, temperature, top_k, top_p,
@@ -2177,87 +1818,17 @@ class InferenceEngine:
         discipline as decode_multi."""
         if self.cfg.is_hybrid:
             raise ValueError(RECURRENT_STATE_REFUSALS["spec_tokens"])
-        key = self._next_key()
-        sampling = (_sampling_array(temperature, np.float32),
-                    _sampling_array(top_k, np.int32),
-                    _sampling_array(top_p, np.float32))
         drafts = np.asarray(drafts, np.int32)
-        draft_len = np.asarray(draft_len, np.int32)
         k = int(drafts.shape[1])
         if self.kv_block:
-            rows = (k + 1 if lookahead_rows is None
-                    else int(lookahead_rows))
-            self._grow_blocks_spec(rows)
-            if self._table_dirty or self._table_dev is None:
-                self._table_dev = jnp.asarray(self._table.copy())
-                self._table_dirty = False
-            if mask_idx is not None:
-                args = (self.params, state, self._table_dev, drafts,
-                        draft_len, *sampling, key, self._mask_table(),
-                        np.asarray(mask_idx, np.int32))
-                self._ledger_capture(
-                    "verify_masked_idx_paged", f"k={k}",
-                    self._verify_masked_idx_paged_fn, args, dict(k=k),
-                    tokens=self.max_slots * (k + 1),
-                    kv_rows=self._kv_capacity_rows()
-                    + self.max_slots * (k + 1))
-                state, out, accepted = \
-                    self._verify_masked_idx_paged_fn(*args, k=k)
-            elif mask is not None:
-                args = (self.params, state, self._table_dev, drafts,
-                        draft_len, *sampling, key,
-                        np.asarray(mask, bool))
-                self._ledger_capture(
-                    "verify_masked_paged", f"k={k}",
-                    self._verify_masked_paged_fn, args, dict(k=k),
-                    tokens=self.max_slots * (k + 1),
-                    kv_rows=self._kv_capacity_rows()
-                    + self.max_slots * (k + 1))
-                state, out, accepted = \
-                    self._verify_masked_paged_fn(*args, k=k)
-            else:
-                args = (self.params, state, self._table_dev, drafts,
-                        draft_len, *sampling, key)
-                self._ledger_capture(
-                    "verify_paged", f"k={k}", self._verify_paged_fn,
-                    args, dict(k=k),
-                    tokens=self.max_slots * (k + 1),
-                    kv_rows=self._kv_capacity_rows()
-                    + self.max_slots * (k + 1))
-                state, out, accepted = self._verify_paged_fn(*args,
-                                                             k=k)
-        elif mask_idx is not None:
-            args = (self.params, state, drafts, draft_len, *sampling,
-                    key, self._mask_table(),
-                    np.asarray(mask_idx, np.int32))
-            self._ledger_capture(
-                "verify_masked_idx", f"k={k}",
-                self._verify_masked_idx_fn, args, dict(k=k),
-                tokens=self.max_slots * (k + 1),
-                kv_rows=self._kv_capacity_rows()
-                + self.max_slots * (k + 1))
-            state, out, accepted = \
-                self._verify_masked_idx_fn(*args, k=k)
-        elif mask is not None:
-            args = (self.params, state, drafts, draft_len, *sampling,
-                    key, np.asarray(mask, bool))
-            self._ledger_capture(
-                "verify_masked", f"k={k}", self._verify_masked_fn,
-                args, dict(k=k), tokens=self.max_slots * (k + 1),
-                kv_rows=self._kv_capacity_rows()
-                + self.max_slots * (k + 1))
-            state, out, accepted = self._verify_masked_fn(*args, k=k)
-        else:
-            args = (self.params, state, drafts, draft_len, *sampling,
-                    key)
-            self._ledger_capture(
-                "verify", f"k={k}", self._verify_fn, args, dict(k=k),
-                tokens=self.max_slots * (k + 1),
-                kv_rows=self._kv_capacity_rows()
-                + self.max_slots * (k + 1))
-            state, out, accepted = self._verify_fn(*args, k=k)
-        for arr in (out, accepted):
-            copy = getattr(arr, "copy_to_host_async", None)
-            if copy is not None:
-                copy()
-        return state, out, accepted
+            self._grow_blocks_spec(
+                k + 1 if lookahead_rows is None
+                else int(lookahead_rows))
+        return self._dispatch(
+            "verify", state,
+            (drafts, np.asarray(draft_len, np.int32),
+             *self._sampling(temperature, top_k, top_p)),
+            mask, mask_idx, dict(k=k),
+            tokens=self.max_slots * (k + 1),
+            kv_rows=self._kv_capacity_rows()
+            + self.max_slots * (k + 1))

@@ -162,14 +162,14 @@ def main() -> int:
                     bucket=b))
         if paged:
             table = S((B, eng.max_blocks), i32)
-            report("decode_paged", eng._decode_paged_fn.lower(
+            report("decode_paged", eng.programs["decode_paged"].lower(
                 params, state, table, *sampling(B), key))
             report("decode_multi_paged[n=4]",
-                   eng._decode_multi_paged_fn.lower(
+                   eng.programs["decode_multi_paged"].lower(
                        params, state, table, *sampling(B), key,
                        S((B,), i32), S((B, 4), i32), n=4))
         else:
-            report("decode", eng._decode_fn.lower(
+            report("decode", eng.programs["decode"].lower(
                 params, state, *sampling(B), key))
     return 0
 

@@ -49,7 +49,7 @@ def decode_hlo():
                                  max_seq=64)
     state = eng.new_state()
     import jax.numpy as jnp
-    lowered = eng._decode_fn.lower(
+    lowered = eng.programs["decode"].lower(
         eng.params, state, np.zeros(4, np.float32),
         np.zeros(4, np.int32), np.ones(4, np.float32),
         jax.random.PRNGKey(0))

@@ -88,6 +88,13 @@ def paged_world():
 # -- engine layer: decode_multi against single-step decode ------------
 
 
+def _seeded(engine, prompt):
+    """A fresh state with `prompt` prefilled into slot 0 (scalars, as
+    Scheduler._prefill_req passes them), and its first token."""
+    tok, kv, tl, bucket = engine.prefill(prompt, 0.0, 0, 1.0)
+    return engine.insert(engine.new_state(), kv, 0, tl, tok, bucket), tok
+
+
 class TestEngineDecodeMulti:
     @pytest.mark.parametrize("paged", [False, True],
                              ids=["dense", "paged"])
@@ -107,10 +114,7 @@ class TestEngineDecodeMulti:
         tp = np.ones(B, np.float32)
 
         def seeded():
-            st = engine.new_state()
-            tok, kv, tl, bucket = engine.prefill(
-                prompt, temp[:1], tk[:1], tp[:1])
-            return engine.insert(st, kv, 0, tl, tok, bucket), tok
+            return _seeded(engine, prompt)
 
         # reference: 8 single-step dispatches; only slot 0 occupied
         st, tok0 = seeded()
@@ -137,17 +141,110 @@ class TestEngineDecodeMulti:
             ok, _ = engine.kv_conservation()
             assert ok
 
-        # mid-chunk stop: stop id == 3rd generated token -> the loop
-        # samples it, then freezes the slot for the rest of the chunk
+        # mid-chunk stop: the stop id is a generated token that is new
+        # to the stream at its place j (this model's greedy stream
+        # repeats itself, so "the 3rd token" may already be the 1st)
+        # -> the loop samples it, then freezes the slot for the rest
+        # of the chunk
+        j = next(i for i in range(2, 8) if ref[i] not in ref[1:i])
         st3, _ = seeded()
         stops3 = np.full((B, 4), -1, np.int32)
-        stops3[0, 0] = ref[3]
+        stops3[0, 0] = ref[j]
         st3, out3, adv3 = engine.decode_multi(st3, temp, tk, tp,
                                               steps=8, budget=budget,
                                               stop_ids=stops3)
         out3, adv3 = np.asarray(out3), np.asarray(adv3)
-        assert int(adv3[0]) == 3
-        assert [int(x) for x in out3[0, :3]] == ref[1:4]
+        assert int(adv3[0]) == j
+        assert [int(x) for x in out3[0, :j]] == ref[1:j + 1]
+        # frozen: the held token fills the tail, the length stands
+        assert set(out3[0, j:].tolist()) == {ref[j]}
+        assert int(np.asarray(st3.lengths)[0]) == len(prompt) + j
+
+    @pytest.mark.parametrize("family",
+                             ["decode", "decode_multi", "verify"])
+    @pytest.mark.parametrize("paged", [False, True],
+                             ids=["dense", "paged"])
+    def test_mask_kinds_agree_and_are_named(
+            self, family, paged, world, paged_world, monkeypatch):
+        """The program table (InferenceEngine.programs): a family's
+        three mask kinds are one body, so no mask, an all-True dense
+        mask and every index at row 0 of the mask table give the same
+        greedy tokens; each is dispatched under the name the ledger
+        knows, jitted as `jit__<name>`, with the family as the root
+        scope of what it traces."""
+        cfg, params, engine = paged_world if paged else world
+        B, V, steps = engine.max_slots, cfg.vocab_size, 4
+        greedy = (np.zeros(B, np.float32), np.zeros(B, np.int32),
+                  np.ones(B, np.float32))
+        captured = []
+
+        def capture(name, static_desc, fn, args, static, **_):
+            shapes = jax.tree.map(
+                lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), args)
+            captured.append((name, static_desc, fn, shapes, static))
+
+        def run(mask_shape=None, idx_shape=None):
+            st, _ = _seeded(engine, [1, 7, 3, 9])
+            kw = {}
+            if mask_shape is not None:
+                kw["mask"] = np.ones(mask_shape, bool)
+            if idx_shape is not None:
+                kw["mask_idx"] = np.zeros(idx_shape, np.int32)
+            if family == "decode":
+                outs = engine.decode(st, *greedy, **kw)
+            elif family == "decode_multi":
+                budget = np.zeros(B, np.int32)
+                budget[0] = steps
+                outs = engine.decode_multi(
+                    st, *greedy, steps=steps, budget=budget,
+                    stop_ids=np.full((B, 4), -1, np.int32), **kw)
+            else:
+                drafts = np.zeros((B, steps - 1), np.int32)
+                drafts[0] = [420, 77, 5]  # what is accepted must agree
+                dlen = np.zeros(B, np.int32)
+                dlen[0] = steps - 1
+                outs = engine.verify(st, drafts, dlen, *greedy, **kw)
+            return [np.asarray(o)[0].tolist() for o in outs[1:]]
+
+        dense, idx = {"decode": ((B, V), (B,)),
+                      "decode_multi": ((B, steps, V), (B, steps)),
+                      "verify": ((B, V), (B, steps))}[family]
+        monkeypatch.setattr(engine, "_ledger_capture", capture)
+        plain = run()
+        assert run(mask_shape=dense) == plain
+        assert run(idx_shape=idx) == plain
+        tail = "_paged" if paged else ""
+        static = {"decode": "", "decode_multi": f"n={steps}",
+                  "verify": f"k={steps - 1}"}[family]
+        scope = "verify" if family == "verify" else "decode"
+        dispatched = [c for c in captured if c[0].startswith(family)]
+        assert [(c[0], c[1]) for c in dispatched] == [
+            (family + kind + tail, static)
+            for kind in ("", "_masked", "_masked_idx")]
+        for name, _, fn, shapes, kw in dispatched:
+            assert fn is engine.programs[name]
+            text = fn.lower(*shapes, **kw).as_text(debug_info=True)
+            paths = [ln for ln in text.splitlines()
+                     if ln.startswith("#loc")
+                     and f'"jit(_{name})/' in ln]
+            assert paths and all(
+                f'"jit(_{name})/{scope}/' in ln for ln in paths), name
+
+    def test_prefill_refuses_per_slot_sampling_arrays(self, world):
+        """One prompt, one value a parameter: a [1] array used to
+        reach the program as [1, 1] and die in the sampling trace."""
+        with pytest.raises(ValueError, match="scalars"):
+            world[2].prefill([1, 7, 3, 9], np.zeros(1, np.float32),
+                             0, 1.0)
+
+    def test_program_table_holds_the_eighteen(self, world):
+        """family x mask kind x cache kind, nothing else: the next
+        program to go is the deletion of one of these names."""
+        assert sorted(world[2].programs) == sorted(
+            family + kind + tail
+            for family in ("decode", "decode_multi", "verify")
+            for kind in ("", "_masked", "_masked_idx")
+            for tail in ("", "_paged"))
 
 
 # -- scheduler layer: the K x depth x backend equivalence matrix ------
