@@ -33,6 +33,7 @@ from jax import lax
 from .. import device
 from ..models import llama
 from ..models.config import ModelConfig
+from ..ops.paged import TRASH_BLOCK
 from ..telemetry.scopes import scoped
 from . import sampling
 
@@ -617,15 +618,18 @@ class InferenceEngine:
                     f"Pallas kernel (got kv_block={self.kv_block}, "
                     f"head_dim={cfg.head_dim}, heads={cfg.num_heads})")
             self.max_blocks = -(-self.max_seq // self.kv_block)
-            # default pool = dense-equivalent capacity (+1: block 0 is
-            # the reserved trash block, never allocated, never read)
+            # default pool = dense-equivalent capacity (+1: block
+            # TRASH_BLOCK is reserved, never allocated, never read —
+            # the attention kernel ends a slot's walk at a row that
+            # starts there, ops/paged.py)
             self.kv_blocks = kv_blocks or (
                 max_slots * self.max_blocks + 1)
-            self._table = np.zeros((max_slots, self.max_blocks),
-                                   np.int32)
+            self._table = np.full((max_slots, self.max_blocks),
+                                  TRASH_BLOCK, np.int32)
             self._owned: List[List[int]] = [[] for _ in
                                             range(max_slots)]
-            self._free_blocks = list(range(self.kv_blocks - 1, 0, -1))
+            self._free_blocks = list(range(self.kv_blocks - 1,
+                                           TRASH_BLOCK, -1))
             self._host_len = np.zeros(max_slots, np.int64)
             self._preempted: List[int] = []
             # device-resident copy of the block table, re-uploaded
@@ -1161,9 +1165,10 @@ class InferenceEngine:
         if self.kv_block:
             # pool-shaped k/v; the block table stays host-side and is
             # passed to the decode program each step (tiny int32)
-            self._table[:] = 0
+            self._table[:] = TRASH_BLOCK
             self._owned = [[] for _ in range(B)]
-            self._free_blocks = list(range(self.kv_blocks - 1, 0, -1))
+            self._free_blocks = list(range(self.kv_blocks - 1,
+                                           TRASH_BLOCK, -1))
             self._host_len[:] = 0
             self._preempted = []
             self._table_dirty = True
@@ -1241,7 +1246,9 @@ class InferenceEngine:
             return
         self._free_blocks.extend(reversed(self._owned[slot]))
         self._owned[slot] = []
-        self._table[slot] = 0
+        # the whole row: the kernel reads entry 0 to know the chain is
+        # empty, whatever the device's length still says
+        self._table[slot] = TRASH_BLOCK
         self._table_dirty = True
         self._host_len[slot] = 0
 
@@ -1377,7 +1384,7 @@ class InferenceEngine:
             self.max_seq))
         while len(self._owned[slot]) > need:
             nid = self._owned[slot].pop()
-            self._table[slot, len(self._owned[slot])] = 0
+            self._table[slot, len(self._owned[slot])] = TRASH_BLOCK
             self._free_blocks.append(nid)
             self._table_dirty = True
 
@@ -1411,7 +1418,7 @@ class InferenceEngine:
         blocks = [int(b) for b in free] + owned_all
         ok = (len(blocks) == self.kv_blocks - 1
               and len(set(blocks)) == len(blocks)
-              and 0 not in blocks)
+              and TRASH_BLOCK not in blocks)
         # hierarchical-KV extension: the prefix cache's two tiers
         # must also account exactly (device trie + host LRU sum, no
         # double residency) — one gauge covers the whole KV hierarchy
@@ -1628,8 +1635,8 @@ class InferenceEngine:
         aid = np.asarray(aid_i, np.int32)
         if self.kv_block:
             nb_write = -(-bucket // bs)
-            # blocks past the valid length land in the trash block (0)
-            block_ids = np.zeros(nb_write, np.int32)
+            # blocks past the valid length land in the trash block
+            block_ids = np.full(nb_write, TRASH_BLOCK, np.int32)
             nw = min(need, nb_write)
             block_ids[:nw] = ids[:nw]
             return self._insert_paged_fn(

@@ -1315,6 +1315,15 @@ class Scheduler:
                 self.registry.gauge(
                     "ome_engine_kv_blocks_owned",
                     "Paged-KV blocks held by live slots").set(owned)
+                # the share of the block table that holds a block: the
+                # attention kernel walks these cells and no others, so
+                # it is the share of a (slots x table width) grid that
+                # would have been work
+                cells = self.engine.max_slots * self.engine.max_blocks
+                self.registry.gauge(
+                    "ome_engine_kv_table_fill_ratio",
+                    "Block-table cells that hold a block, over slots "
+                    "x table width").set(owned / cells)
                 # authoritative at quiescence; a concurrent
                 # insert/free can briefly read as 0 mid-scrape
                 self.registry.gauge(

@@ -302,3 +302,29 @@ def test_decode_paged_leaves_the_pool_where_it_is(decode_paged,
              if re.search(rf"= \w+\[({shapes})\]\S* (copy|dynamic-slice|"
                           r"dynamic-update-slice)\(", line)]
     assert not moved, moved
+
+
+@pytest.mark.parametrize("pool_dtype", ["bf16", "int8"])
+def test_decode_paged_kernel_takes_the_pool_whole(decode_paged,
+                                                  pool_dtype):
+    """The kernel walks a slot's chain itself: the step still holds
+    ONE Mosaic call, its operands are the lengths, the table, the
+    layer index, the queries and the WHOLE pools (an int8 pool's scale
+    planes too), left in HBM for the kernel's own copies, and no
+    temporary of a layer's pool's size stands beside them."""
+    compiled, pool, scale = decode_paged(pool_dtype)
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines()
+             if "tpu_custom_call" in line
+             and re.search(r"%paged_attention(\.\d+)? = ", line)]
+    assert len(calls) == text.count("tpu_custom_call") == 1
+    operands = re.search(r"operand_layout_constraints=\{(.*?)\}\}, ",
+                         calls[0]).group(1)
+    dims = re.findall(r"\w+\[([\d,]*)\]", operands)
+    whole = [",".join(str(d) for d in pool)] * 2
+    if scale:
+        whole += [",".join(str(d) for d in scale)] * 2
+    assert dims == [str(B), f"{B},16", "1", f"{B},{K},{H // K},{D}"] \
+        + whole, dims
+    one_layer = math.prod(pool[1:]) * (1 if scale else 2)
+    assert compiled.memory_analysis().temp_size_in_bytes < one_layer

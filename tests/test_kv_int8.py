@@ -86,7 +86,8 @@ class TestQuantizedPagedNumerics:
                          jnp.float32)
         vp = jnp.asarray(rng.standard_normal((LAYERS, N, bs, K, D)),
                          jnp.float32)
-        ids = rng.permutation(N)[:B * M].reshape(B, M)
+        # never block 0: the kernel ends a slot's walk at the trash block
+        ids = (rng.permutation(N - 1)[:B * M] + 1).reshape(B, M)
         return q, kp, vp, jnp.asarray(ids, jnp.int32)
 
     @pytest.mark.parametrize("layer", range(LAYERS))

@@ -31,7 +31,8 @@ from ome_tpu.engine.tokenizer import ByteTokenizer
 from ome_tpu.models import llama
 from ome_tpu.models.config import tiny_test
 from ome_tpu.ops.attention import attention
-from ome_tpu.ops.paged import paged_attention_xla, paged_flash_decode
+from ome_tpu.ops.paged import (TRASH_BLOCK, paged_attention_xla,
+                               paged_flash_decode)
 
 CFG = tiny_test().replace(dtype=jnp.float32, max_seq_len=128)
 
@@ -45,8 +46,19 @@ def _pool(rng, B, H, K, D, bs, M, N, L=LAYERS):
     q = jnp.asarray(rng.standard_normal((B, 1, H, D)), jnp.float32)
     kp = jnp.asarray(rng.standard_normal((L, N, bs, K, D)), jnp.float32)
     vp = jnp.asarray(rng.standard_normal((L, N, bs, K, D)), jnp.float32)
-    ids = rng.permutation(N)[:B * M].reshape(B, M)
+    # block TRASH_BLOCK is no chain's: the kernel ends a walk there
+    ids = (rng.permutation(N - 1)[:B * M] + 1).reshape(B, M)
     return q, kp, vp, jnp.asarray(ids, jnp.int32)
+
+
+def _quantize_pool(pool):
+    """Per-(row, head) symmetric int8 as the engine's pool holds it:
+    int8 [L, N, bs, K, D] + f32 scales [L, N, K, bs]."""
+    from ome_tpu.ops.flash import quantize_kv_block
+    L, N = pool.shape[:2]
+    qv, sc = quantize_kv_block(pool.reshape((L * N,) + pool.shape[2:]))
+    return (qv.reshape(pool.shape),
+            sc.reshape((L, N) + sc.shape[1:]))
 
 
 class TestPagedAttentionNumerics:
@@ -86,6 +98,54 @@ class TestPagedAttentionNumerics:
         other = paged_attention_xla(q, kp, vp, table, kv_len,
                                     (layer + 1) % LAYERS)
         assert np.abs(np.asarray(out) - np.asarray(other)).max() > 0.1
+
+    # lengths 1, bs - 1, bs, bs + 1, mid-chain and a full table in one
+    # batch; `freed` is the slot whose row is all TRASH_BLOCK while its
+    # device length counts on far past the row (core.free_slot)
+    RAGGED = (1, 127, 128, 129, 300, 512)
+
+    @pytest.mark.parametrize("freed", [None, 0, 3, 5])
+    @pytest.mark.parametrize("layer", range(LAYERS))
+    @pytest.mark.parametrize("pool_dtype", ["bf16", "int8"])
+    def test_kernel_walks_the_chain(self, pool_dtype, layer, freed):
+        """One grid step a slot, a loop over the blocks its chain
+        holds: every live row agrees with the XLA gather, whatever
+        the lengths; a freed slot reads nothing, returns zeros, and
+        moves no live row by a bit."""
+        rng = np.random.default_rng(11)
+        H, K, D, bs, M, N = 16, 8, 128, 128, 4, 32
+        B = len(self.RAGGED)
+        q, kp, vp, table = _pool(rng, B, H, K, D, bs, M, N)
+        kv_len = np.asarray(self.RAGGED, np.int32)
+        if pool_dtype == "int8":
+            (kp, ks), (vp, vs) = _quantize_pool(kp), _quantize_pool(vp)
+        else:
+            q = q.astype(jnp.bfloat16)
+            kp, vp = kp.astype(jnp.bfloat16), vp.astype(jnp.bfloat16)
+            ks = vs = None
+
+        def kernel(q, table, kv_len):
+            return np.asarray(jax.jit(lambda l: paged_flash_decode(
+                q, kp, vp, table, jnp.asarray(kv_len), l, k_scale=ks,
+                v_scale=vs, interpret=True))(jnp.int32(layer)),
+                np.float32)
+
+        live = np.ones(B, bool)
+        if freed is not None:
+            live[freed] = False
+            table = table.at[freed].set(TRASH_BLOCK)
+            kv_len[freed] = 7 * M * bs + 5
+        out = kernel(q, table, kv_len)
+        ref = np.asarray(paged_attention_xla(
+            q, kp, vp, table, jnp.asarray(kv_len), layer, k_scale=ks,
+            v_scale=vs), np.float32)
+        # bf16 probabilities into the second dot: the tolerance of
+        # the kernel-against-XLA tests beside this one
+        np.testing.assert_allclose(out[live], ref[live], atol=2e-2)
+        if freed is not None:
+            assert np.all(out[freed] == 0.0)
+            without = kernel(q[live], table[live], kv_len[live])
+            np.testing.assert_array_equal(out[live], without)
 
     def test_kernel_uncovered_shapes_return_none(self):
         rng = np.random.default_rng(2)
@@ -197,6 +257,30 @@ def test_paged_tokens_identical_to_dense():
     # every block returned to the pool after the last request
     assert paged.kv_pool_stats["kv_blocks_free"] == \
         paged.kv_blocks - 1
+
+
+def test_table_fill_gauge_reads_the_owned_lists():
+    """`ome_engine_kv_table_fill_ratio`: blocks owned over slots x
+    table width, read at scrape from the allocator's lists; a freed
+    slot's row is TRASH_BLOCK in every entry, which is what ends the
+    kernel's walk there."""
+    params = llama.init_params(jax.random.PRNGKey(0), CFG)
+    paged = InferenceEngine(params, CFG, max_slots=4,
+                            prefill_buckets=[16, 32], kv_block=16)
+    sched = Scheduler(paged)
+
+    def fill():
+        sched.update_gauges()
+        return sched.registry.gauge(
+            "ome_engine_kv_table_fill_ratio").value
+
+    assert fill() == 0.0
+    paged._owned[1] = [paged._free_blocks.pop() for _ in range(3)]
+    paged._table[1, :3] = paged._owned[1]
+    assert fill() == 3 / (4 * paged.max_blocks)
+    paged.free_slot(1)
+    assert fill() == 0.0
+    assert (paged._table[1] == TRASH_BLOCK).all()
 
 
 def test_double_slots_same_hbm_budget():
