@@ -33,6 +33,7 @@ from jax import lax
 from .. import device
 from ..models import llama
 from ..models.config import ModelConfig
+from ..ops.attention import prefill_block_kinds
 from ..ops.paged import TRASH_BLOCK
 from ..telemetry.scopes import scoped
 from . import sampling
@@ -602,6 +603,27 @@ def slot_state_refusals(cfg: ModelConfig, **asked) -> List[str]:
             if v and kind in SLOT_STATE_REFUSALS[k]]
 
 
+def prefill_attn_block_kinds(cfg: ModelConfig, rows: int, kv_rows: int,
+                             base: int = 0) -> Dict[str, int]:
+    """Grid steps by kind (ops/flash.py: none / whole / edge) of the
+    attention kernel calls in ONE prefill of `rows` prompt rows at
+    positions `base` on over `kv_rows` cache rows, summed over the
+    model's layers by their window; all zero where the prompt takes
+    XLA's attention (off the chip, or float32 logits under
+    `_XLA_PREFILL_CAP`) or the kernel declines. Host arithmetic on
+    shapes: nothing is read from the device."""
+    total = {"none": 0, "whole": 0, "edge": 0}
+    if cfg.attn_sinks:           # sinks take XLA's attention
+        return total
+    for window, layers in cfg.attn_layer_windows:
+        kinds = prefill_block_kinds(
+            rows, kv_rows, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim,
+            base, window)
+        for kind, n in (kinds or {}).items():
+            total[kind] += layers * n
+    return total
+
+
 class InferenceEngine:
     """Compiled prefill/insert/decode over one model + one mesh."""
 
@@ -728,6 +750,11 @@ class InferenceEngine:
                             "pairs": 0}
         import threading
         self._moe_lock = threading.Lock()
+        # grid steps of the prefill attention kernel by kind, summed
+        # over the prefills run (`_count_attn_blocks`); plain ints the
+        # scheduler mirrors at scrape, as it does the prefix cache's
+        self.prefill_attn_blocks = {"none": 0, "whole": 0, "edge": 0}
+        self._attn_block_kinds: Dict[tuple, Dict[str, int]] = {}
         self.prefix_cache = PrefixCache(
             prefix_cache_bytes,
             host_capacity_bytes=prefix_host_bytes)
@@ -1674,6 +1701,7 @@ class InferenceEngine:
                 tokens=sbucket, kv_rows=bucket)
             tok, k, v = self._prefill_suffix_fn(*args, **kw)
             rec = []
+            self._count_attn_blocks(sbucket, bucket, plen)
         else:
             bucket = _bucketize(len(ids), self.prefill_buckets)
             padded = np.asarray(
@@ -1689,6 +1717,7 @@ class InferenceEngine:
                 name, f"bucket={bucket}", fn, args,
                 dict(bucket=bucket), tokens=bucket, kv_rows=bucket)
             tok, k, v, *rec = fn(*args, bucket=bucket)
+            self._count_attn_blocks(bucket, bucket, 0)
         if aid == 0:
             self.prefix_cache.put(ids, k, v, len(ids), bucket)
         # multi-host: int() on an array spanning non-addressable
@@ -1697,6 +1726,15 @@ class InferenceEngine:
         # a hybrid model's prefill hands its recurrent state at
         # true_len as a third element; insert() takes the tuple whole
         return int(host_value(tok)), (k, v, *rec), len(ids), bucket
+
+    def _count_attn_blocks(self, rows: int, kv_rows: int, base: int):
+        shape = (rows, kv_rows, base)
+        kinds = self._attn_block_kinds.get(shape)
+        if kinds is None:
+            kinds = self._attn_block_kinds[shape] = \
+                prefill_attn_block_kinds(self.cfg, *shape)
+        for kind, n in kinds.items():
+            self.prefill_attn_blocks[kind] += n
 
     def blocks_needed(self, n_tokens: int) -> int:
         """Pool blocks covering `n_tokens` KV rows + the next write —

@@ -484,6 +484,14 @@ class Request:
         return self.output_ids
 
 
+def _mirror(counter, value) -> None:
+    """Bring a registry counter up to a tally kept as a plain int
+    (bumped off the registry's locks, read at scrape)."""
+    delta = value - counter.value
+    if delta > 0:
+        counter.inc(delta)
+
+
 class Scheduler:
     """Drives one InferenceEngine; thread-safe submit()."""
 
@@ -818,6 +826,17 @@ class Scheduler:
                 "steps; the rest were routed to absent experts and "
                 "cost no grouped-matmul rows"),
         }
+        attn_blocks = R.counter(
+            "ome_engine_prefill_attn_blocks_total",
+            "Grid steps of the prefill attention kernel "
+            "(flash_prefill) by kind, summed over the prefills run "
+            "and the model's layers: whole = every pair of the block "
+            "is seen, no mask arithmetic; edge = a mask edge crosses "
+            "it, the mask is built; none = nothing to do. Reckoned on "
+            "the host from each prefill's shape; all zero where "
+            "prompts take XLA's attention", labelnames=("kind",))
+        self._c_attn_blocks = {k: attn_blocks.labels(kind=k)
+                               for k in ("none", "whole", "edge")}
         self._c_pc_hits = R.counter(
             "ome_engine_prefix_cache_hits_total",
             "Prefix-cache hits (prompts that reused cached KV)")
@@ -1306,9 +1325,7 @@ class Scheduler:
                                     getattr(pc, "host_swapins", 0)),
                                    (self._c_pc_host_recomputes,
                                     getattr(pc, "host_recomputes", 0))):
-                delta = value - counter.value
-                if delta > 0:
-                    counter.inc(delta)
+                _mirror(counter, value)
             self._g_pc_bytes.set(pc.bytes)
             self._g_pc_host_bytes.set(getattr(pc, "host_bytes", 0))
         pool = getattr(self.engine, "kv_pool_stats", None)
@@ -1344,6 +1361,11 @@ class Scheduler:
                     "1 when free + owned blocks account for the whole "
                     "pool (checked per scrape; authoritative when "
                     "idle)").set(1 if ok else 0)
+        # what the prefill kernel's grid held, by kind: plain ints the
+        # engine adds to at each prefill it runs; mirror by delta
+        blocks = getattr(self.engine, "prefill_attn_blocks", None) or {}
+        for kind, n in blocks.items():
+            _mirror(self._c_attn_blocks[kind], n)
         pd = getattr(self.engine, "update_pd_gauges", None)
         if callable(pd):
             pd()
@@ -1363,9 +1385,7 @@ class Scheduler:
         counts = counts_fn() if callable(counts_fn) else None
         if counts:
             for name, counter in self._c_moe.items():
-                delta = counts[name] - counter.value
-                if delta > 0:
-                    counter.inc(delta)
+                _mirror(counter, counts[name])
         # live HBM partition (perf/hbm.py): refreshed per scrape, not
         # per step — memory_stats() is a host call the decode loop
         # should not pay

@@ -152,6 +152,22 @@ class ModelConfig:
         return self.num_layers - self.kv_cache_layers
 
     @property
+    def attn_layer_windows(self) -> tuple:
+        """((window, layers), ...): the layers whose attention goes
+        through ops.attention (not MLA's own, not a hybrid model's
+        linear layers) by the sliding window they mask with, None for
+        a global layer (models/llama.py `forward`'s three scans)."""
+        if self.mla:
+            return ()
+        if self.is_hybrid:
+            return ((self.sliding_window, self.kv_cache_layers),)
+        if self.alt_sliding_window:
+            n_global = self.num_layers // self.sliding_pattern
+            return ((self.sliding_window, self.num_layers - n_global),
+                    (None, n_global))
+        return ((self.sliding_window, self.num_layers),)
+
+    @property
     def linear_conv_dim(self) -> int:
         """Channels of the DeltaNet's depthwise conv: q | k | v."""
         return (2 * self.linear_num_key_heads * self.linear_key_head_dim

@@ -89,9 +89,37 @@ def _layer_of(slab: jax.Array, layer) -> jax.Array:
     return jax.lax.dynamic_index_in_dim(slab, layer, 0, keepdims=False)
 
 
-def _logits_bytes(q, k) -> int:
-    B, Sq, H, _ = q.shape
-    return B * H * Sq * k.shape[1] * 4
+def _auto_backend(B: int, Sq: int, H: int, Skv: int) -> str:
+    """The backend `attention` takes when none is asked for."""
+    backend = os.environ.get("OME_ATTN_BACKEND")
+    if backend is not None:
+        return backend
+    if not device.on_tpu():
+        return "xla"
+    if Sq > 1 and B * H * Sq * Skv * 4 <= _XLA_PREFILL_CAP:
+        # SHORT-sequence prefill: XLA's materialized-mask attention
+        # beats the flash kernel (measured 249 vs 320 ms on the
+        # bench shape — at small S the [Sq, Skv] float32 logits are
+        # cheap and XLA's fusion wins; flash earns its keep when the
+        # materialization would blow HBM, i.e. long context)
+        return "xla"
+    return "pallas"
+
+
+def prefill_block_kinds(Sq: int, Skv: int, H: int, K: int, D: int,
+                        base: int, window: Optional[int]):
+    """Grid steps by kind ({"none", "whole", "edge"}:
+    flash.prefill_block_kinds) of the kernel call that `attention`
+    makes for one sequence's `Sq` prompt rows at positions `base`
+    on, over `Skv` cache rows of which `base + Sq` are valid (what
+    llama.forward passes); None where that call takes XLA's
+    attention or the kernel declines the shape. Host arithmetic."""
+    if Sq == 1 or H % K or \
+            not _auto_backend(1, Sq, H, Skv).startswith("pallas"):
+        return None
+    from . import flash
+    return flash.prefill_block_kinds(Sq, Skv, K, H // K, D, base,
+                                     base + Sq, window)
 
 
 def make_causal_mask(q_pos: jax.Array, kv_pos: jax.Array,
@@ -174,22 +202,11 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array,
         # head-sharded trace whose per-device kernel takes one
         # layer's slab): take the layer out here, once
         k, v, layer = _layer_of(k, layer), _layer_of(v, layer), None
-    if backend is None:
-        backend = os.environ.get("OME_ATTN_BACKEND")
     if sinks is not None:
         backend = "xla"
-    if backend is None:
-        if not device.on_tpu():
-            backend = "xla"
-        elif q.shape[1] > 1 and _logits_bytes(q, k) <= _XLA_PREFILL_CAP:
-            # SHORT-sequence prefill: XLA's materialized-mask attention
-            # beats the flash kernel (measured 249 vs 320 ms on the
-            # bench shape — at small S the [Sq, Skv] logits are cheap
-            # and XLA's fusion wins; flash earns its keep when the
-            # materialization would blow HBM, i.e. long context)
-            backend = "xla"
-        else:
-            backend = "pallas"
+    elif backend is None:
+        backend = _auto_backend(q.shape[0], q.shape[1], q.shape[2],
+                                k.shape[1])
     if backend in ("pallas", "pallas_interpret"):
         out = _flash(
             q, k, v, positions, kv_len,

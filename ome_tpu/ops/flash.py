@@ -10,18 +10,38 @@ Pallas kernel pair designed around the TPU memory system:
     skips the DMA when the block index repeats, so a sequence at
     length 300 in a 2048-slot cache streams ~300 rows of KV through
     VMEM, not 2048 (decode is HBM-bandwidth-bound; this is the win).
-  * **prefill**: grid (B, K, q_blocks, kv_blocks) with the same
+  * **prefill**: grid (B, K, q_blocks, kv_steps) with the same
     clamping on the causal frontier, so upper-triangle KV blocks are
-    neither fetched nor computed. GQA is handled by folding the G
-    query heads of each KV head into the row dimension of one MXU
-    matmul — no K/V duplication in VMEM.
+    neither fetched nor computed. GQA: the G query heads of a KV
+    head lie side by side in the lanes of one query block
+    [bq, G * D] and meet the one key block [bs, D] a head at a time
+    — no K/V duplication in VMEM, and every block is a dense tile of
+    the array as it lies in HBM (a [.., 1, D] key block is a
+    (1, 128) tile a row, loaded a sublane at a time and shuffled
+    dense every step: most of this kernel's time before PR 33, by
+    its compiled bundles). Each (query block, key block) step is
+    sorted from the scalars the kernel already has
+    (`_prefill_block_kind`) into one of three kinds:
+    **none**: no (row, column) pair is seen: nothing runs. Under a
+    sliding window the grid's key dimension holds only as many steps
+    as a query block's windows can reach (`_prefill_key_steps`), not
+    one a key block of the sequence;
+    **whole**: every pair is seen (the causal edge, the cache's
+    length and the window's lower edge all pass outside the block):
+    the dots and the softmax update, with no iota, compare or select;
+    a mask would change nothing there, and nearly all blocks of a
+    long prompt are such blocks;
+    **edge**: an edge crosses the block: the same update under the
+    mask. `prefill_block_kinds` counts a call's steps by kind on the
+    host, for the engine's `ome_engine_prefill_attn_blocks_total`.
 
 Both kernels keep fp32 online-softmax state (m, l, acc) in VMEM
 scratch across the innermost grid dimension and never materialize a
-mask: causality, per-sequence KV length, and sliding windows are iota
-comparisons against scalar limits. Supports GQA (H % K == 0), logit
-softcap (Gemma-2), and chunked prefill (nonzero per-batch position
-base writing into a pre-filled cache).
+mask in HBM: causality, per-sequence KV length, and sliding windows
+are iota comparisons against scalar limits, made only in the blocks
+they cross (prefill). Supports GQA (H % K == 0), logit softcap
+(Gemma-2), and chunked prefill (nonzero per-batch position base
+writing into a pre-filled cache).
 
 Returns None for shapes the kernels don't cover (tiny heads, ragged
 sizes) — callers fall back to the XLA path (ops/attention.py), which
@@ -272,24 +292,91 @@ def flash_decode_quantized(q: jax.Array, kq: jax.Array, vq: jax.Array,
 
 
 # -- prefill kernel --------------------------------------------------------
+#
+# The scalar arithmetic that sorts a (query block, key block) pair is
+# written once and runs twice: traced, on the int32 scalars the kernel
+# and its index maps read from SMEM, and on Python integers, for the
+# host's count of grid steps by kind (`prefill_block_kinds`).
+
+
+def _traced(*xs) -> bool:
+    return not all(isinstance(x, int) for x in xs)
+
+
+def _div(a, b):
+    """a // b for a >= 0. Below 0 the traced quotient rounds to 0 and
+    the integer one down: every caller clamps it to 0 next."""
+    return lax.div(a, b) if _traced(a, b) else a // b
+
+
+def _min(a, b):
+    return jnp.minimum(a, b) if _traced(a, b) else min(a, b)
+
+
+def _max(a, b):
+    return jnp.maximum(a, b) if _traced(a, b) else max(a, b)
 
 
 def _prefill_block_range(base, kv_hi, qi, bq, bs, window):
     """[first, last] KV block indices a q block can attend — the same
     mapping the prefill BlockSpec index maps use."""
-    causal_last = lax.div(base + (qi + 1) * bq - 1, bs)
-    len_last = jnp.maximum(lax.div(kv_hi - 1, bs), 0)
-    last = jnp.minimum(causal_last, len_last)
+    causal_last = _div(base + (qi + 1) * bq - 1, bs)
+    len_last = _max(_div(kv_hi - 1, bs), 0)
+    last = _min(causal_last, len_last)
+    first = 0 if window is None else \
+        _max(_div(base + qi * bq - window + 1, bs), 0)
+    return _min(first, last), _max(last, 0)
+
+
+def _prefill_key_steps(S: int, bq: int, bs: int, window) -> int:
+    """The grid's key dimension: every key block without a window;
+    with one, the most blocks `last - first + 1` can be. A query
+    block's rows see the `window + bq - 1` columns from its first
+    row's window to its last row, and n columns that start on a
+    block's last column touch (n + bs - 2) // bs + 1 blocks."""
     if window is None:
-        first = jnp.zeros_like(last)
-    else:
-        first = jnp.maximum(lax.div(base + qi * bq - window + 1, bs), 0)
-    return jnp.minimum(first, last), jnp.maximum(last, 0)
+        return S // bs
+    return min((window + bq + bs - 3) // bs + 1, S // bs)
+
+
+def _prefill_block_kind(base, kv_hi, qi, ki, bq, bs, window):
+    """Where the mask's edges lie against grid step (qi, ki):
+    (start, some, whole). `start` is the first column of the key block
+    the step was given (the index map's clamp, undone); `some`: a
+    (row, column) pair of it is seen; `whole`: every pair is: the
+    causal edge, the cache's length and the window's lower edge all
+    pass outside the block, so the mask would change nothing."""
+    first, last = _prefill_block_range(base, kv_hi, qi, bq, bs, window)
+    start = _min(first + ki, last) * bs
+    q_lo = base + qi * bq            # absolute position of first q row
+    q_hi = q_lo + bq - 1
+    # `first + ki <= last` keeps clamped (repeated, DMA-skipped) steps
+    # from double-counting the boundary block
+    given = first + ki <= last
+    some = given & (start <= q_hi) & (start < kv_hi)
+    whole = given & (start + bs - 1 <= q_lo) & (start + bs <= kv_hi)
+    if window is not None:
+        some = some & (start + bs > q_lo - window + 1) \
+            & (kv_hi > q_lo - window + 1)
+        whole = whole & (start > q_hi - window)
+    return start, some, whole
+
+
+def _lanes(x, n: int):
+    """[rows, 128] whose lanes are alike, as [rows, n]."""
+    return jnp.tile(x, (1, n // 128)) if n % 128 == 0 else x[:, :n]
 
 
 def _prefill_kernel(lim_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
-                    acc_ref, *, bq: int, bs: int, g: int, scale: float,
-                    softcap: Optional[float], window: Optional[int]):
+                    acc_ref, *, bq: int, bs: int, g: int, d: int,
+                    scale: float, softcap: Optional[float],
+                    window: Optional[int]):
+    """q_ref, o_ref: [1, bq, G * D], the G query heads of this KV head
+    side by side in the lanes; k_ref, v_ref: [1, bs, D]. The running
+    softmax state is a row a (head, query row), head-major: m_ref and
+    l_ref [G * bq, 128] with a row's value in every lane (so it meets
+    a [bq, 128] tile with no broadcast across lanes), acc_ref
+    [G * bq, D]."""
     b, qi, ki = pl.program_id(0), pl.program_id(2), pl.program_id(3)
     nk = pl.num_programs(3)
 
@@ -301,77 +388,134 @@ def _prefill_kernel(lim_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
 
     base = lim_ref[b, 0]             # absolute position of q row 0
     kv_hi = lim_ref[b, 1]            # valid KV rows
-    first, last = _prefill_block_range(base, kv_hi, qi, bq, bs, window)
-    start = jnp.minimum(first + ki, last) * bs  # matches kv_index below
-    q_lo = base + qi * bq            # absolute position of first q row
-    q_hi = q_lo + bq - 1
-    # block participates iff some (row, col) pair passes causal+len+window;
-    # `first + ki <= last` keeps clamped (repeated, DMA-skipped) steps
-    # from double-counting the boundary block
-    process = (first + ki <= last) & (start <= q_hi) & (start < kv_hi)
-    if window is not None:
-        process = process & (start + bs > q_lo - window + 1)
+    start, some, whole = _prefill_block_kind(base, kv_hi, qi, ki, bq, bs,
+                                             window)
 
-    @pl.when(process)
+    def update(valid=None):
+        """This key block into the running softmax of every head;
+        `valid` [bq, bs] masks it (the same for every head), None is
+        a block of which every pair is seen."""
+        kb = k_ref[0]                # [bs, D]
+        vb = v_ref[0]
+        for h in range(g):           # a head: [bq, D] x [D, bs]
+            rows = pl.ds(h * bq, bq)
+            x = lax.dot_general(
+                q_ref[0, :, h * d:(h + 1) * d], kb,
+                (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale   # [bq, bs]
+            if softcap:
+                x = jnp.tanh(x / softcap) * softcap
+            if valid is not None:
+                x = jnp.where(valid, x, M_INIT)
+            m_prev = m_ref[rows, :]
+            m_new = jnp.maximum(m_prev, jnp.max(x, axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(x - _lanes(m_new, bs))
+            if valid is not None:
+                p = jnp.where(valid, p, 0.0)
+            l_new = alpha * l_ref[rows, :] \
+                + jnp.sum(p, axis=1, keepdims=True)
+            pv = lax.dot_general(
+                p.astype(vb.dtype), vb, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)            # [bq, D]
+            acc_ref[rows, :] = acc_ref[rows, :] * _lanes(alpha, d) + pv
+            m_ref[rows, :] = m_new
+            l_ref[rows, :] = l_new
+
+    @pl.when(whole)
     def _():
-        q = q_ref[0, :, 0]           # [bq, G, D]
-        D = q.shape[-1]
-        rows = bq * g
-        qf = q.reshape(rows, D)
-        kb = k_ref[0, :, 0, 0]       # [bs, D]
-        logits = lax.dot_general(
-            qf, kb, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale   # [rows, bs]
-        if softcap:
-            logits = jnp.tanh(logits / softcap) * softcap
-        col = start + lax.broadcasted_iota(jnp.int32, (rows, bs), 1)
-        qpos = q_lo + lax.broadcasted_iota(jnp.int32, (rows, bs), 0) // g
+        update()
+
+    @pl.when(some & jnp.logical_not(whole))
+    def _():
+        q_lo = base + qi * bq        # absolute position of first q row
+        col = start + lax.broadcasted_iota(jnp.int32, (bq, bs), 1)
+        qpos = q_lo + lax.broadcasted_iota(jnp.int32, (bq, bs), 0)
         valid = (col <= qpos) & (col < kv_hi)
         if window is not None:
             valid = valid & (col > qpos - window)
-        logits = jnp.where(valid, logits, M_INIT)
-
-        m_prev = m_ref[:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(logits, axis=1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(logits - m_new)
-        p = jnp.where(valid, p, 0.0)
-        l_new = alpha * l_ref[:, :1] + jnp.sum(p, axis=1, keepdims=True)
-        pv = lax.dot_general(
-            p.astype(v_ref.dtype), v_ref[0, :, 0, 0],
-            (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)            # [rows, D]
-        acc_ref[:] = acc_ref[:] * alpha + pv
-        m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
+        update(valid)
 
     @pl.when(ki == nk - 1)
     def _():
-        bq_, _, g_, D = o_ref.shape[1:]
-        l = jnp.maximum(l_ref[:, :1], 1e-30)
-        o_ref[0, :, 0] = (acc_ref[:] / l).reshape(bq_, g_, D) \
-            .astype(o_ref.dtype)
+        for h in range(g):
+            rows = pl.ds(h * bq, bq)
+            l = jnp.maximum(l_ref[rows, :], 1e-30)
+            o_ref[0, :, h * d:(h + 1) * d] = \
+                (acc_ref[rows, :] / _lanes(l, d)).astype(o_ref.dtype)
 
 
-def _flash_prefill(q, k, v, base, kv_hi, scale, softcap, window, interpret):
-    B, Sq, H, D = q.shape
-    S, K = k.shape[1], k.shape[2]
-    G = H // K
-    # the query block holds whole GQA groups: bq * G rows of D. Keep
-    # it at what 8 heads a KV head of 128 dims take (the most the
-    # kernel has been compiled at): a wider head or group halves bq
-    # instead of outgrowing VMEM (head_dim 256 with 8 heads a KV head
-    # needs 24 MB of the 16 at bq 256, chip compiler, PR 27)
+def _prefill_blocks(Sq: int, S: int, G: int, D: int):
+    """(bq, bs), or None for shapes the kernel does not cover."""
+    # the query block holds whole GQA groups: bq rows of G * D. It is
+    # kept at what 8 heads a KV head of 128 dims take: a wider head
+    # or group halves bq. The rule dates from the kernel that took all
+    # G heads' [bq * G, bs] logits at once (head_dim 256 with 8 heads
+    # a KV head needed 24 MB of VMEM's 16 at bq 256, chip compiler,
+    # PR 27); a head at a time needs a sixteenth of that, and the
+    # blocks were not retuned with it (ROADMAP A8 (e))
     bq = _pick_block(Sq, tuple(c for c in (256, 128, 64, 32, 16)
                                if c * G * D <= 256 * 8 * 128))
     bs = _pick_block(S, (512, 256, 128, 64, 32, 16))
     if bq is None or bs is None or bq * G < 8 or D % 128 != 0:
         return None
+    return bq, bs
+
+
+def prefill_block_kinds(Sq: int, S: int, K: int, G: int, D: int,
+                        base: int, kv_hi: int, window: Optional[int]):
+    """Grid steps of one sequence's `flash_prefill` call by kind,
+    {"none", "whole", "edge"}, on the host and in Python integers from
+    the kernel's own tests; None where the kernel declines the shape.
+    `whole` steps run the softmax with no mask arithmetic, `edge`
+    steps build the mask, `none` steps do nothing (their key block's
+    DMA is skipped too): whole / (whole + edge) is how often the cheap
+    body engages, none what is left of the grid."""
+    blocks = _prefill_blocks(Sq, S, G, D)
+    if blocks is None:
+        return None
+    bq, bs = blocks
+    kinds = {"none": 0, "whole": 0, "edge": 0}
+    for qi in range(Sq // bq):
+        for ki in range(_prefill_key_steps(S, bq, bs, window)):
+            _, some, whole = _prefill_block_kind(base, kv_hi, qi, ki, bq,
+                                                 bs, window)
+            kinds["whole" if whole else "edge" if some else "none"] += K
+    return kinds
+
+
+def _flash_prefill(q, k, v, base, kv_hi, scale, softcap, window, interpret):
+    G = q.shape[2] // k.shape[2]
+    blocks = _prefill_blocks(q.shape[1], k.shape[1], G, q.shape[3])
+    if blocks is None:
+        return None
+    return _prefill_call(q, k, v, base, kv_hi, blocks=blocks, scale=scale,
+                         softcap=softcap, window=window, interpret=interpret)
+
+
+# A jit of its own: a model's layers call the kernel at one shape from
+# several places (the periods of a window / global scan, unrolled and
+# scanned) and a program is traced twice at its first dispatch (the
+# ledger's `lower`, then the call). The kernel's body is a few hundred
+# equations a trace, seconds of a warm start over a model's buckets;
+# under the jit's cache it is traced once a shape and lowered once a
+# module. XLA inlines the call.
+@functools.partial(jax.jit, static_argnames=(
+    "blocks", "scale", "softcap", "window", "interpret"))
+def _prefill_call(q, k, v, base, kv_hi, *, blocks, scale, softcap, window,
+                  interpret):
+    B, Sq, H, D = q.shape
+    S, K = k.shape[1], k.shape[2]
+    G = H // K
+    bq, bs = blocks
     limits = jnp.stack(
         [base.astype(jnp.int32), kv_hi.astype(jnp.int32)], axis=1)
-    q5 = q.reshape(B, Sq, K, G, D)
-    k5 = k.reshape(B, S, K, 1, D)
-    v5 = v.reshape(B, S, K, 1, D)
+    # heads side by side in the lanes: a query block is [bq, G * D]
+    # and a key block [bs, D], both dense tiles of the arrays as they
+    # lie in HBM (no [.., 1, D] or [.., G, D] minor tiles to repack)
+    q3 = q.reshape(B, Sq, H * D)
+    k3 = k.reshape(B, S, K * D)
+    v3 = v.reshape(B, S, K * D)
 
     def kv_index(b, kh, qi, ki, lim):
         # clamp to [first, last]: the upper causal triangle, the cache
@@ -379,19 +523,19 @@ def _flash_prefill(q, k, v, base, kv_hi, scale, softcap, window, interpret):
         # mapped to repeated indices -> Pallas skips their DMA
         first, last = _prefill_block_range(lim[b, 0], lim[b, 1], qi, bq,
                                            bs, window)
-        return (b, jnp.minimum(first + ki, last), kh, 0, 0)
+        return (b, jnp.minimum(first + ki, last), kh)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(B, K, Sq // bq, S // bs),
+        grid=(B, K, Sq // bq, _prefill_key_steps(S, bq, bs, window)),
         in_specs=[
-            pl.BlockSpec((1, bq, 1, G, D),
-                         lambda b, kh, qi, ki, lim: (b, qi, kh, 0, 0)),
-            pl.BlockSpec((1, bs, 1, 1, D), kv_index),
-            pl.BlockSpec((1, bs, 1, 1, D), kv_index),
+            pl.BlockSpec((1, bq, G * D),
+                         lambda b, kh, qi, ki, lim: (b, qi, kh)),
+            pl.BlockSpec((1, bs, D), kv_index),
+            pl.BlockSpec((1, bs, D), kv_index),
         ],
         out_specs=pl.BlockSpec(
-            (1, bq, 1, G, D), lambda b, kh, qi, ki, lim: (b, qi, kh, 0, 0)),
+            (1, bq, G * D), lambda b, kh, qi, ki, lim: (b, qi, kh)),
         scratch_shapes=[
             pltpu.VMEM((bq * G, 128), jnp.float32),
             pltpu.VMEM((bq * G, 128), jnp.float32),
@@ -399,13 +543,13 @@ def _flash_prefill(q, k, v, base, kv_hi, scale, softcap, window, interpret):
         ],
     )
     out = pl.pallas_call(
-        functools.partial(_prefill_kernel, bq=bq, bs=bs, g=G, scale=scale,
-                          softcap=softcap, window=window),
+        functools.partial(_prefill_kernel, bq=bq, bs=bs, g=G, d=D,
+                          scale=scale, softcap=softcap, window=window),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Sq, K, G, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, Sq, H * D), q.dtype),
         interpret=interpret,
         name="flash_prefill",
-    )(limits, q5, k5, v5)
+    )(limits, q3, k3, v3)
     return out.reshape(B, Sq, H, D)
 
 

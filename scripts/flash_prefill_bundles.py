@@ -1,0 +1,136 @@
+#!/usr/bin/env python
+"""What `flash_prefill` compiles to, without the chip: the kernel's
+final instruction bundles for a described v5e, counted.
+
+The installed libtpu compiles for a chip that is described, not
+attached (on-chip-measurement guide, section 2), and with
+`--xla_jf_dump_to=DIR --xla_jf_dump_llo_text=true` in
+`LIBTPU_INIT_ARGS` it writes every pass of its low-level compiler
+beside the kernel: `*flash_prefill*final_bundles.txt` is the VLIW
+schedule the chip runs, `*final_hlo-static-per-bundle-utilization.txt`
+how many of each slot a bundle fills (capacities on a v5e: MXU 4,
+XLU 3, VALU 4, EUP 1, vector load 3, vector store 1, scalar 2). A
+grid step's body is straight-line code, so its bundle count is its
+time to a factor: 12.5 k bundles ran in 14.5 us and 4.9 k in 3.6 us
+(my chip runs, PR 33). It costs ten seconds and no chip time, and it
+is how PR 33 found that the kernel's time was single-sublane loads,
+repacking and 3300 spills a step on the one store slot, not the
+mask's arithmetic.
+
+    python scripts/flash_prefill_bundles.py                # 16 384 bucket's tile
+    python scripts/flash_prefill_bundles.py --body whole   # one body alone
+    python scripts/flash_prefill_bundles.py --heads 16 --kv-heads 2 --dim 256
+
+`--body whole|edge|none` compiles the kernel with every block sorted
+as that kind (none: init, finish and the pipeline's own code; a
+body's size is the difference). The dump ends in a crash of the
+compiler's report writer (a template file it does not ship): the
+bundles are written before it, so the child's exit code is ignored.
+A compile that passes is not a chip run: a bundle count is not a time.
+"""
+
+import argparse
+import collections
+import glob
+import os
+import re
+import shutil
+import subprocess
+import sys
+import tempfile
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _compile(args):
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    sys.path.insert(0, ROOT)
+    from ome_tpu.ops import flash
+    if args.body != "both":
+        kind = flash._prefill_block_kind
+
+        def only(*a):
+            start, some, _ = kind(*a)
+            return (start, args.body != "none" and some,
+                    args.body == "whole")
+
+        flash._prefill_block_kind = only
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def struct(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    S, D = args.rows, args.dim
+
+    def f(q, k, v, base, kv_hi):
+        return flash._flash_prefill(q, k, v, base, kv_hi, D ** -0.5, None,
+                                    args.window, False)
+
+    kv = struct((1, S, args.kv_heads, D), jnp.bfloat16)
+    jax.jit(f).lower(struct((1, S, args.heads, D), jnp.bfloat16), kv, kv,
+                     struct((1,), jnp.int32),
+                     struct((1,), jnp.int32)).compile()
+
+
+def main():
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--rows", type=int, default=2048,
+                    help="Sq = S; the tile does not depend on it")
+    ap.add_argument("--heads", type=int, default=32)
+    ap.add_argument("--kv-heads", type=int, default=4)
+    ap.add_argument("--dim", type=int, default=128)
+    ap.add_argument("--window", type=int, default=None)
+    ap.add_argument("--body", default="both",
+                    choices=("both", "whole", "edge", "none"))
+    ap.add_argument("--keep", default=None,
+                    help="directory to keep the final bundles in")
+    args = ap.parse_args()
+    if os.environ.get("_FLASH_BUNDLES_CHILD"):
+        return _compile(args)
+    out = tempfile.mkdtemp(prefix="flash_bundles_")
+    env = dict(os.environ, _FLASH_BUNDLES_CHILD="1", JAX_PLATFORMS="cpu",
+               LIBTPU_INIT_ARGS=f"--xla_jf_dump_to={out} "
+                                "--xla_jf_dump_llo_text=true")
+    child = subprocess.run([sys.executable, __file__] + sys.argv[1:],
+                           env=env, capture_output=True, text=True)
+    try:
+        found = [f for f in glob.glob(
+            f"{out}/*flash_prefill*final_bundles.txt")
+            if "schedule-analysis" not in f]
+        if not found:
+            sys.exit(child.stderr[-3000:] or "no bundles were written")
+        bundles = [line for line in open(found[0])
+                   if re.match(r"\s*(0x[0-9a-f]+|\d+)\s", line)]
+        ops = collections.Counter()
+        for line in bundles:
+            for ins in line.partition("{")[2].split(";;"):
+                m = re.search(r"=\s*([a-z_.0-9]+)", ins)
+                if m:
+                    ops[re.sub(r"\.(xlu|mxu)\d", "", m.group(1))] += 1
+        util = glob.glob(f"{out}/*flash_prefill*final_hlo-static-per-"
+                         "bundle-utilization.txt")[0]
+        text = open(util).read().split("\n")
+        names = text[1].replace(" ", "").split(",")
+        caps = text[2].split()
+        rows = [[int(x) for x in line.split()] for line in text[4:]
+                if line.strip()]
+        print(f"body={args.body}: {len(bundles)} bundles")
+        for i, (name, cap) in enumerate(zip(names, caps)):
+            print(f"  {name:13s} {sum(r[i] for r in rows):6d} slot uses "
+                  f"({cap} a bundle)")
+        print("  " + ", ".join(f"{k} {v}" for k, v in ops.most_common(24)))
+        if args.keep:
+            os.makedirs(args.keep, exist_ok=True)
+            shutil.copy(found[0], args.keep)
+    finally:
+        shutil.rmtree(out, ignore_errors=True)
+
+
+if __name__ == "__main__":
+    main()

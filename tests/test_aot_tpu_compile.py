@@ -122,6 +122,47 @@ def test_flash_prefill(one_chip, S):
     assert "tpu_custom_call" in c.as_text()
 
 
+@pytest.mark.parametrize("S,heads,kv_heads,dim,window,key_steps", [
+    # trinity-mini-ep4.long-doc's 16 384 bucket, a global and a window
+    # layer; qwen3-next-80b-a3b-ep4.long-batch's 4096 bucket (bq 128)
+    (16384, 32, 4, 128, None, 32),
+    (16384, 32, 4, 128, 2048, 6),
+    (4096, 16, 2, 256, None, 8),
+])
+def test_flash_prefill_at_the_cells_shapes(one_chip, monkeypatch, S,
+                                           heads, kv_heads, dim, window,
+                                           key_steps):
+    """Both bodies (a whole block's, with no mask arithmetic, and an
+    edge block's) inside the VMEM limit at the slab cells' widths, and
+    a window layer's grid trimmed to the key blocks a query block's
+    windows can reach."""
+    grids = []
+    call = flash.pl.pallas_call
+
+    def spy(kernel, *, grid_spec, **kw):
+        grids.append(grid_spec.grid)
+        return call(kernel, grid_spec=grid_spec, **kw)
+
+    monkeypatch.setattr(flash.pl, "pallas_call", spy)
+    flash._prefill_call.clear_cache()    # trace the call anew, spied on
+    kv = one_chip((1, S, kv_heads, dim), jnp.bfloat16)
+
+    def f(q, k, v, base, kv_hi):
+        out = flash._flash_prefill(q, k, v, base, kv_hi, dim ** -0.5,
+                                   None, window, False)
+        assert out is not None
+        return out
+
+    c = _compile(f, one_chip((1, S, heads, dim), jnp.bfloat16), kv, kv,
+                 one_chip((1,), jnp.int32), one_chip((1,), jnp.int32))
+    assert "tpu_custom_call" in c.as_text()
+    bq = 128 if dim == 256 else 256
+    assert grids == [(1, kv_heads, S // bq, key_steps)]
+    kinds = flash.prefill_block_kinds(S, S, kv_heads, heads // kv_heads,
+                                      dim, 0, S, window)
+    assert kinds["whole"] and kinds["edge"]
+
+
 @pytest.mark.parametrize("k,n", [(2560, 9728), (9728, 2560),
                                  (4096, 14336)])
 def test_int4_matmul_compiles_or_declines(one_chip, monkeypatch, k, n):

@@ -133,3 +133,227 @@ def test_flash_decode_quantized_tracks_full_precision():
                     backend="xla")
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32), atol=3e-2)
+
+
+# -- flash_prefill's three kinds of block ------------------------------
+#
+# The kernel sorts each (query block, key block) into none / whole /
+# edge from its scalars and masks only the edge blocks. The sorting is
+# held to the full boolean mask; the numerics run where whole blocks
+# exist (several key blocks a query block), which the single-block
+# cases above never reach.
+
+
+def _seen(base, kv_hi, window, Sq, S):
+    """The [Sq, S] mask the XLA path builds (ops/attention.py)."""
+    from ome_tpu.ops.attention import make_causal_mask
+    q_pos = (base + np.arange(Sq))[None, :]
+    kv_pos = np.arange(S)
+    m = np.asarray(make_causal_mask(q_pos, kv_pos, np.asarray([kv_hi])))[0]
+    if window is not None:
+        m = m & (kv_pos[None, :] > q_pos[0][:, None] - window)
+    return m
+
+
+@pytest.mark.parametrize("seed", range(12))
+def test_prefill_block_kinds_match_the_mask(seed):
+    """30 draws a seed: every grid step's kind equals the kind read
+    off the mask, each key block with a seen pair is given to exactly
+    one step, and a query block's range fits the trimmed grid."""
+    from ome_tpu.ops import flash
+    rng = np.random.default_rng(seed)
+    for _ in range(30):
+        bq = int(rng.choice([16, 32, 64]))
+        bs = int(rng.choice([16, 32, 64, 128]))
+        Sq = bq * int(rng.integers(1, 6))
+        S = bs * int(rng.integers(1, 9))
+        base = int(rng.integers(0, S + 1))
+        kv_hi = int(rng.choice([base + Sq, rng.integers(0, S + 1), S]))
+        kv_hi = min(kv_hi, S)
+        window = None if rng.random() < 0.4 \
+            else int(rng.integers(1, S + bq))
+        seen = _seen(base, kv_hi, window, Sq, S)
+        nk = flash._prefill_key_steps(S, bq, bs, window)
+        draw = dict(base=base, kv_hi=kv_hi, window=window, bq=bq, bs=bs,
+                    Sq=Sq, S=S)
+        for qi in range(Sq // bq):
+            first, last = flash._prefill_block_range(base, kv_hi, qi, bq,
+                                                     bs, window)
+            assert last - first + 1 <= nk, draw
+            rows = seen[qi * bq:(qi + 1) * bq]
+            given = []
+            for ki in range(nk):
+                start, some, whole = flash._prefill_block_kind(
+                    base, kv_hi, qi, ki, bq, bs, window)
+                block = rows[:, start:start + bs]
+                assert whole == (some and block.all()), (draw, qi, ki)
+                if not some:
+                    continue
+                assert block.any(), (draw, qi, ki)
+                given.append(start // bs)
+            want = [j for j in range(S // bs)
+                    if rows[:, j * bs:(j + 1) * bs].any()]
+            assert given == want, (draw, qi)
+
+
+@pytest.mark.parametrize("window,work,edge,none", [
+    # a KV head a layer at the 16 384 bucket of trinity-mini's cell:
+    # the causal triangle of 64 query blocks over 32 key blocks, and
+    # a window of 2048 (five key blocks a query block past the ramp,
+    # two of them crossed by an edge: the diagonal one, and from the
+    # ninth query block on the one the window's lower edge crosses) in
+    # a grid of six
+    (None, 1056, 64, 992),
+    (2048, 300, 120, 84),
+])
+def test_prefill_block_kinds_at_the_long_doc_bucket(window, work, edge,
+                                                    none):
+    from ome_tpu.ops import flash
+    K = 4
+    kinds = flash.prefill_block_kinds(16384, 16384, K, 8, 128, 0, 16384,
+                                      window)
+    nk = flash._prefill_key_steps(16384, 256, 512, window)
+    assert nk == (32 if window is None else 6)
+    assert sum(kinds.values()) == K * 64 * nk
+    assert kinds == {"none": K * none, "whole": K * (work - edge),
+                     "edge": K * edge}
+
+
+def test_prefill_block_kinds_declines_with_the_kernel():
+    from ome_tpu.ops import flash
+    assert flash.prefill_block_kinds(64, 64, 4, 2, 64, 0, 64, None) is None
+    # a short prompt's single block is an edge block: today's body
+    assert flash.prefill_block_kinds(64, 64, 4, 2, 128, 0, 64, None) == \
+        {"none": 0, "whole": 0, "edge": 4}
+
+
+@pytest.mark.parametrize("case", [
+    dict(S=1024),
+    dict(S=2048),
+    # a window under bq + bs - 1 = 767 covers no block whole: every
+    # block that holds work is an edge block, in a grid of 3 for 4
+    dict(S=2048, sliding_window=600, whole=False),
+    dict(S=2048, sliding_window=1024),
+    dict(S=2048, Sq=512, base=512, kv_len=1100),
+    dict(S=2048, Sq=512, base=1536, sliding_window=900),
+    dict(S=1024, H=8, D=256),                 # bq 128
+    dict(S=1024, logit_softcap=30.0),
+    dict(S=2048, sliding_window=1024, logit_softcap=50.0),
+], ids=lambda c: "-".join(f"{k}{v}" for k, v in c.items()))
+def test_flash_prefill_whole_blocks_match_xla(case):
+    """Several key blocks a query block, so whole blocks (no mask
+    arithmetic) and edge blocks (the mask) both run, and with a window
+    the grid's key dimension is trimmed."""
+    from ome_tpu.ops import flash
+    case = dict(case)
+    S, H, D = case.pop("S"), case.pop("H", 2), case.pop("D", 128)
+    Sq, base = case.pop("Sq", S), case.pop("base", 0)
+    kv_len, whole = case.pop("kv_len", None), case.pop("whole", True)
+    kinds = flash.prefill_block_kinds(
+        Sq, S, 1, H, D, base, S if kv_len is None else kv_len,
+        case.get("sliding_window"))
+    assert kinds["edge"] and bool(kinds["whole"]) == whole, kinds
+    q, k, v = _mk(jax.random.PRNGKey(6), 1, Sq, S, H, 1, D, jnp.bfloat16)
+    positions = base + jnp.arange(Sq, dtype=jnp.int32)[None, :]
+    if kv_len is not None:
+        kv_len = jnp.asarray([kv_len], jnp.int32)
+    _check(q, k, v, positions, kv_len, ATOL[jnp.bfloat16], **case)
+
+
+@pytest.mark.parametrize("window", [None, 1024])
+def test_flash_prefill_whole_blocks_are_the_masked_body(monkeypatch,
+                                                        window):
+    """A mask changes nothing in a block every pair of which is seen:
+    with every block sent through the masked body (the kernel as it
+    was before it sorted them) the output is the same, to float32's
+    last places (the CPU's compiler fuses the two bodies apart, so
+    not to the bit here)."""
+    from ome_tpu.ops import flash
+    S = 2048
+    q, k, v = _mk(jax.random.PRNGKey(7), 1, S, S, 2, 1, 128, jnp.float32)
+    positions = jnp.arange(S, dtype=jnp.int32)[None, :]
+    kw = dict(positions=positions, sliding_window=window, interpret=True)
+    sorted_ = flash.flash_attention(q, k, v, **kw)
+    kind = flash._prefill_block_kind
+
+    def all_edge(*a):
+        start, some, whole = kind(*a)
+        return start, some, jnp.zeros_like(whole)
+
+    monkeypatch.setattr(flash, "_prefill_block_kind", all_edge)
+    flash._prefill_call.clear_cache()    # the kernel's jit holds the trace
+    try:
+        masked = flash.flash_attention(q, k, v, **kw)
+    finally:
+        flash._prefill_call.clear_cache()
+    np.testing.assert_allclose(np.asarray(sorted_), np.asarray(masked),
+                               atol=2e-6, rtol=0)
+
+
+# -- the counter of those kinds, from a prefill's shape ----------------
+
+
+@pytest.mark.parametrize("name,bucket,want", [
+    # long-doc's larger bucket: 4 global layers (whole 0.94 of the
+    # work) and 12 window layers (0.60), 4 KV heads: 0.78 over both
+    ("trinity-mini-ep4", 16384,
+     dict(none=16 * 992 + 48 * 84, whole=16 * 992 + 48 * 180,
+          edge=16 * 64 + 48 * 120)),
+    ("trinity-mini-ep4", 8192, dict(none=6336, whole=7872, edge=3200)),
+    # 3 full layers of head_dim 256 (bq 128): 0.78
+    ("qwen3-next-80b-a3b-ep4", 4096, dict(none=672, whole=672, edge=192)),
+    # its 2048 bucket's float32 logits are the cap to the byte: XLA
+    ("qwen3-next-80b-a3b-ep4", 2048, dict(none=0, whole=0, edge=0)),
+    # the one bucket of chat-steady over the cap: 36 layers, 0.60
+    ("qwen3-4b", 2048, dict(none=3456, whole=3456, edge=2304)),
+    ("qwen3-4b", 1024, dict(none=0, whole=0, edge=0)),
+])
+def test_prefill_attn_block_kinds_of_the_cells(monkeypatch, name, bucket,
+                                               want):
+    """One prefill's grid steps by kind, summed over the layers by
+    their window, at the benchmark's configurations; nothing where
+    the prompt takes XLA's attention, as every prompt does off the
+    chip."""
+    import json
+    import os
+    from ome_tpu import device
+    from ome_tpu.engine.core import prefill_attn_block_kinds
+    from ome_tpu.models.config import ModelConfig
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           f"{name}.json")) as f:
+        cfg = ModelConfig.from_hf_config(json.load(f))
+    zero = dict(none=0, whole=0, edge=0)
+    assert prefill_attn_block_kinds(cfg, bucket, bucket) == zero
+    monkeypatch.setattr(device, "on_tpu", lambda: True)
+    assert prefill_attn_block_kinds(cfg, bucket, bucket) == want
+
+
+def test_prefill_attn_blocks_counter_follows_the_prefills(monkeypatch):
+    """`ome_engine_prefill_attn_blocks_total{kind=}`: the engine adds
+    a prefill's grid steps where it runs one, the scheduler mirrors
+    the tallies at scrape. A 32-row prompt of a 2-layer model with one
+    KV head is one edge block a layer."""
+    from ome_tpu.engine import InferenceEngine, Scheduler
+    from ome_tpu.models import config as cfgs
+    from ome_tpu.models import llama
+    monkeypatch.setenv("OME_ATTN_BACKEND", "pallas_interpret")
+    cfg = cfgs.tiny_test().replace(
+        num_layers=2, num_heads=2, num_kv_heads=1, head_dim=128,
+        max_seq_len=64, dtype=jnp.float32)
+    engine = InferenceEngine(llama.init_params(jax.random.PRNGKey(0), cfg),
+                             cfg, max_slots=2, prefill_buckets=[32])
+    sched = Scheduler(engine)
+
+    def scraped():
+        sched.update_gauges()
+        text = sched.registry.render()
+        return {k: int(float(text.split(
+            'ome_engine_prefill_attn_blocks_total{kind="%s"} ' % k)[1]
+            .split()[0])) for k in ("none", "whole", "edge")}
+
+    assert scraped() == dict(none=0, whole=0, edge=0)
+    engine.prefill(list(range(1, 20)))
+    assert scraped() == dict(none=0, whole=0, edge=2)
+    engine.prefill(list(range(1, 9)))
+    assert scraped() == dict(none=0, whole=0, edge=4)
