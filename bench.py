@@ -437,10 +437,13 @@ def main() -> None:
                       out_dims=(cfg.num_kv_heads, cfg.head_dim))
             q = apply_rope(q, positions, freqs)
             k = apply_rope(k, positions, freqs)
+            # the slab's rows lie merged, [B, S, K * Dh] (llama.KVCache)
             nks.append(lax.dynamic_update_slice(
-                ks[l], k.astype(ks[l].dtype), (0, index, 0, 0)))
+                ks[l], k.astype(ks[l].dtype).reshape(B, 1, -1),
+                (0, index, 0)))
             nvs.append(lax.dynamic_update_slice(
-                vs[l], v.astype(vs[l].dtype), (0, index, 0, 0)))
+                vs[l], v.astype(vs[l].dtype).reshape(B, 1, -1),
+                (0, index, 0)))
             # single-key softmax: no cache read; XLA backend — the
             # flash-decode kernel's grid assumes a real cache length
             attn = attention(q, k, v, backend="xla")
@@ -469,8 +472,8 @@ def main() -> None:
         nonlocal disp_ms, warm_ms
         per, top = split_layers(p)
         t0 = time.perf_counter()
-        tok, cache = prefill(p, prompt,
-                             llama.KVCache.create(cfg, BATCH, CACHE_LEN))
+        tok, cache = prefill(p, prompt, llama.KVCache.create(
+            cfg, BATCH, CACHE_LEN, merged=True))
         ks = [cache.k[l] for l in range(cfg.num_layers)]
         vs = [cache.v[l] for l in range(cfg.num_layers)]
         index = cache.index
@@ -488,8 +491,8 @@ def main() -> None:
         steps = n_disp * MULTISTEP
         best = float("inf")
         for _ in range(TRIALS):
-            tok, cache = prefill(
-                p, prompt, llama.KVCache.create(cfg, BATCH, CACHE_LEN))
+            tok, cache = prefill(p, prompt, llama.KVCache.create(
+                cfg, BATCH, CACHE_LEN, merged=True))
             ks = [cache.k[l] for l in range(cfg.num_layers)]
             vs = [cache.v[l] for l in range(cfg.num_layers)]
             st = (tok, ks, vs, cache.index)
@@ -509,8 +512,8 @@ def main() -> None:
         # dispatch shape, so step - floor isolates attention/KV
         fbest = float("inf")
         for _ in range(TRIALS):
-            tok2, cache2 = prefill(
-                p, prompt, llama.KVCache.create(cfg, BATCH, CACHE_LEN))
+            tok2, cache2 = prefill(p, prompt, llama.KVCache.create(
+                cfg, BATCH, CACHE_LEN, merged=True))
             ks2 = [cache2.k[l] for l in range(cfg.num_layers)]
             vs2 = [cache2.v[l] for l in range(cfg.num_layers)]
             st2 = (tok2, ks2, vs2, cache2.index)
@@ -543,8 +546,8 @@ def main() -> None:
     # overlaps device execution instead of serializing with it.
     def step_gap_ms(pipelined: bool) -> float:
         per, top = split_layers(params)
-        tok, cache = prefill(params, prompt,
-                             llama.KVCache.create(cfg, BATCH, CACHE_LEN))
+        tok, cache = prefill(params, prompt, llama.KVCache.create(
+            cfg, BATCH, CACHE_LEN, merged=True))
         ks = [cache.k[l] for l in range(cfg.num_layers)]
         vs = [cache.v[l] for l in range(cfg.num_layers)]
         st = (tok, ks, vs, cache.index)
@@ -579,7 +582,7 @@ def main() -> None:
         f"pipelined (async token offload, one-dispatch lag)")
 
     # -- steady-state prefill (TTFT proxy) + MFU ------------------------
-    cache2 = llama.KVCache.create(cfg, BATCH, CACHE_LEN)
+    cache2 = llama.KVCache.create(cfg, BATCH, CACHE_LEN, merged=True)
     prompt2 = jax.random.randint(jax.random.PRNGKey(2), (BATCH, PREFILL),
                                  0, cfg.vocab_size, dtype=jnp.int32)
     sync(prefill(params, prompt2, cache2)[0])

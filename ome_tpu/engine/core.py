@@ -53,8 +53,11 @@ log = logging.getLogger("ome.engine.core")
 class DecodeState:
     """Device-resident state of the decode batch."""
 
-    k: jax.Array        # [L, B, Smax, K, Dh]
-    v: jax.Array        # [L, B, Smax, K, Dh]
+    # the dense slab: merged rows [L, B, Smax, K * Dh], a row's K
+    # heads side by side in the lanes, as `flash_decode` reads them
+    # (llama.KVCache); the paged pool: [L, N, block, K, Dh]
+    k: jax.Array
+    v: jax.Array
     lengths: jax.Array  # [B] int32 — valid kv rows / next write index
     tokens: jax.Array   # [B] int32 — last sampled token per slot
     # [B] int32 — LoRA adapter slot per sequence (0 = base model);
@@ -76,7 +79,7 @@ class DecodeState:
     # counters the decode programs add to (llama.KVCache.stats)
     moe_stats: jax.Array = None
     # periodic window / global models only (cfg.window_layers), the
-    # third kind: the window layers' ring, [Lw, B, W, K, Dh], position
+    # third kind: the window layers' ring, [Lw, B, W, K * Dh], position
     # p in row p % W (llama.KVCache.wk, docs/window-cache.md); k and v
     # then hold the global layers only. A slot's insert overwrites its
     # rows; there is nothing to free
@@ -185,7 +188,8 @@ class PrefixCache:
     byte budget.
 
     Prompts are split into fixed token BLOCKS; each trie node owns one
-    block's KV slice ([L, 1, block, K, Dh] device buffers). Sibling
+    block's KV slice ([L, 1, block, K, Dh] device buffers; with
+    `merged_rows`, a slab engine's, [L, 1, block, K * Dh]). Sibling
     prompts therefore share every common leading block — a prompt that
     diverges halfway through a cached entry still reuses the shared
     half (the sharing the sglang-router's cache-aware steering relies
@@ -199,8 +203,10 @@ class PrefixCache:
     """
 
     def __init__(self, capacity_bytes: int = 0, block: int = 32,
-                 min_prefix: int = 16, host_capacity_bytes: int = 0):
+                 min_prefix: int = 16, host_capacity_bytes: int = 0,
+                 merged_rows: bool = False):
         self.capacity_bytes = capacity_bytes
+        self.merged_rows = merged_rows
         self.block = block
         self.min_prefix = min_prefix
         self._root: Dict[tuple, dict] = {}
@@ -240,6 +246,10 @@ class PrefixCache:
         are padding and never stored)."""
         if self.capacity_bytes <= 0 or true_len < self.min_prefix:
             return
+        if self.merged_rows and k.ndim == 5:
+            # KV a peer sent: the wire's rows lie heads apart
+            # (engine/pd.py), this engine's merged; one trie, one rank
+            k, v = (x.reshape(x.shape[:3] + (-1,)) for x in (k, v))
         with self._tier_lock:
             node_map = self._root
             self._tick += 1
@@ -657,6 +667,12 @@ class InferenceEngine:
         # more slots with mixed-length sequences (vLLM/SGLang
         # PagedAttention, TPU-static: ops/paged.py; r4 verdict #2)
         self.kv_block = int(kv_block)
+        # how KV rows lie, decided here once from what reads them
+        # (llama.KVCache): the slab, which `flash_decode` reads, and
+        # the prefills that fill it have a row's heads merged in the
+        # lanes; a paged engine's prefill keeps [L, 1, bucket, K, D],
+        # which is what `_insert_paged` and the pool's blocks take
+        self.kv_rows_merged = not self.kv_block
         refused = slot_state_refusals(
             cfg, kv_block=kv_block,
             prefix_cache=prefix_cache_bytes or prefix_host_bytes,
@@ -757,7 +773,8 @@ class InferenceEngine:
         self._attn_block_kinds: Dict[tuple, Dict[str, int]] = {}
         self.prefix_cache = PrefixCache(
             prefix_cache_bytes,
-            host_capacity_bytes=prefix_host_bytes)
+            host_capacity_bytes=prefix_host_bytes,
+            merged_rows=self.kv_rows_merged)
 
         # multi-LoRA serving: preallocate `lora_slots` zeroed factor
         # stacks as extra scanned layer leaves ([L, slots+1, r, K]).
@@ -791,6 +808,7 @@ class InferenceEngine:
             self.params = dict(params, layers=layers)
 
         cfg_ = cfg
+        merged = self.kv_rows_merged
 
         @scoped("prefill")
         def _prefill(params, padded: jax.Array, true_len: jax.Array,
@@ -801,7 +819,7 @@ class InferenceEngine:
             the FIRST sampled token honors a structured-output
             grammar."""
             *mask, adapter = mask_adapter
-            cache = llama.KVCache.create(cfg_, 1, bucket)
+            cache = llama.KVCache.create(cfg_, 1, bucket, merged=merged)
             # last REAL token's logits only (right padding occupies
             # the tail): the head runs on that one row. KV rows hide
             # the padded tail behind the slot's length; a hybrid
@@ -833,14 +851,13 @@ class InferenceEngine:
             (positions continue at prefix_len). Rows past the valid
             lengths hold stale data — kv_len masking makes them
             unreachable."""
-            base = (cfg_.num_layers, 1, total_bucket,
-                    cfg_.kv_cache_heads)
-            k0 = lax.dynamic_update_slice(
-                jnp.zeros(base + (cfg_.kv_cache_k_dim,), cfg_.dtype),
-                prefix_k[:, :, :keep], (0, 0, 0, 0, 0))
-            v0 = lax.dynamic_update_slice(
-                jnp.zeros(base + (cfg_.kv_cache_v_dim,), cfg_.dtype),
-                prefix_v[:, :, :keep], (0, 0, 0, 0, 0))
+            shapes = llama.kv_rows_shapes(
+                cfg_, (cfg_.num_layers, 1, total_bucket), merged)
+            k0, v0 = (
+                lax.dynamic_update_slice(
+                    jnp.zeros(shape, cfg_.dtype), prefix[:, :, :keep],
+                    (0,) * len(shape))
+                for shape, prefix in zip(shapes, (prefix_k, prefix_v)))
             cache = llama.KVCache(k=k0, v=v0, index=prefix_len)
             logits, new_cache = llama.forward(params, cfg_, padded,
                                               cache=cache,
@@ -857,21 +874,18 @@ class InferenceEngine:
         def _insert(state: DecodeState, kv_k, kv_v, slot: jax.Array,
                     true_len: jax.Array, token: jax.Array,
                     adapter: jax.Array, other=None, *, bucket: int):
-            keep = min(bucket, self.max_seq)
-            k = lax.dynamic_update_slice(
-                state.k, kv_k[:, :, :keep], (0, slot, 0, 0, 0))
-            v = lax.dynamic_update_slice(
-                state.v, kv_v[:, :, :keep], (0, slot, 0, 0, 0))
-
             def into(whole, one):
                 # the slot's share, [Ll, 1, ...], into batch row
-                # `slot` of [Ll, B, ...]; a prefilled ring of a bucket
-                # wider than the state's is cut to it (no position
-                # past max_seq is ever reached)
+                # `slot` of [Ll, B, ...], laid as the state's rows lie
+                # (KV off the wire arrives heads apart, engine/pd.py);
+                # rows of a bucket wider than the state's are cut to
+                # it (no position past max_seq is ever reached)
                 one = one[:, :, :whole.shape[2]].astype(whole.dtype)
                 return lax.dynamic_update_slice(
-                    whole, one, (0, slot) + (0,) * (whole.ndim - 2))
+                    whole, one.reshape(one.shape[:3] + whole.shape[3:]),
+                    (0, slot) + (0,) * (whole.ndim - 2))
 
+            k, v = into(state.k, kv_k), into(state.v, kv_v)
             grown = {}
             if cfg_.is_hybrid:
                 # the recurrent state at true_len, whole
@@ -1314,10 +1328,10 @@ class InferenceEngine:
                          if self.kv_quantized else None),
                 v_scale=(jnp.zeros(scale_shape, jnp.float32)
                          if self.kv_quantized else None))
-        base = (L, B, S, cfg.kv_cache_heads)
+        ks, vs = llama.kv_rows_shapes(cfg, (L, B, S),
+                                      self.kv_rows_merged)
         return DecodeState(
-            k=jnp.zeros(base + (cfg.kv_cache_k_dim,), cfg.dtype),
-            v=jnp.zeros(base + (cfg.kv_cache_v_dim,), cfg.dtype),
+            k=jnp.zeros(ks, cfg.dtype), v=jnp.zeros(vs, cfg.dtype),
             lengths=jnp.zeros((B,), jnp.int32),
             tokens=jnp.zeros((B,), jnp.int32),
             adapters=jnp.zeros((B,), jnp.int32),
@@ -1331,10 +1345,11 @@ class InferenceEngine:
         cfg = self.cfg
         if not cfg.window_layers:
             return {}
-        shape = (cfg.window_layers, self.max_slots, self.ring_rows,
-                 cfg.kv_cache_heads)
-        return {"wk": jnp.zeros(shape + (cfg.kv_cache_k_dim,), cfg.dtype),
-                "wv": jnp.zeros(shape + (cfg.kv_cache_v_dim,), cfg.dtype)}
+        ks, vs = llama.kv_rows_shapes(
+            cfg, (cfg.window_layers, self.max_slots, self.ring_rows),
+            self.kv_rows_merged)
+        return {"wk": jnp.zeros(ks, cfg.dtype),
+                "wv": jnp.zeros(vs, cfg.dtype)}
 
     def _take_counts(self, outs: tuple) -> tuple:
         """Split off the expert counters that a counting engine's

@@ -79,6 +79,25 @@ def gather_kv(x) -> np.ndarray:
     return np.asarray(multihost_utils.process_allgather(x, tiled=True))
 
 
+def wire_rows(x, heads: int) -> np.ndarray:
+    """A prefill's KV plane as the wire carries it: on the host
+    (`gather_kv`) and with a row's heads apart, [L, 1, S, K, D]. A
+    slab engine's prefill hands rows merged, [L, 1, S, K * D]
+    (llama.KVCache); the wire keeps the one layout whoever sends, so
+    its int8 scales stay per (row, head) and a paged engine's insert
+    takes it as it comes. The receiving slab's insert merges again."""
+    x = gather_kv(x)
+    if x.ndim == 4:
+        x = x.reshape(x.shape[:3] + (heads, x.shape[3] // heads))
+    return x
+
+
+def wire_kv(engine, k, v) -> Tuple[np.ndarray, np.ndarray]:
+    """`engine`'s prefill planes (k, v) as the wire carries them."""
+    heads = engine.cfg.kv_cache_heads
+    return wire_rows(k, heads), wire_rows(v, heads)
+
+
 def quantize_kv_plane(x) -> Tuple[np.ndarray, np.ndarray]:
     """Symmetric per-(row, head) int8 over the feature axis — the
     same scale discipline as the int8 paged pool (ops/flash.py), but
@@ -566,7 +585,7 @@ class RemotePrefillEngine:
             token, (k, v), true_len, bucket = self._engine.prefill(
                 prompt_ids, temperature, top_k, top_p, **kw)
             self._last_peer = "local"
-            blob = serialize_kv(token, gather_kv(k), gather_kv(v),
+            blob = serialize_kv(token, *wire_kv(self._engine, k, v),
                                 true_len, bucket)
             if span is not None:
                 self.span_log.write(span)
@@ -646,7 +665,7 @@ def make_pd_prefill_handler(engine):
             # replay prefill->gather(k)->gather(v) strictly serially,
             # so a second thread's allgather must not interleave
             # omelint: disable=lock-discipline -- the gather/serialize round-trip IS the guarded op (see comment above)
-            return serialize_kv(token, gather_kv(k), gather_kv(v),
-                                true_len, bucket, quantize=quantize)
+            return serialize_kv(token, *wire_kv(engine, k, v), true_len,
+                                bucket, quantize=quantize)
 
     return handler

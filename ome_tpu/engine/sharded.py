@@ -31,6 +31,7 @@ from typing import List, Optional
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..models import llama
 from ..models.config import ModelConfig
 from ..parallel.mesh import MeshConfig, build_mesh
 from ..parallel.sharding import shard_params
@@ -106,31 +107,33 @@ class ShardedInferenceEngine(InferenceEngine):
             return super().decode_multi(*a, **kw)
 
     def _kv_sharding(self) -> NamedSharding:
-        # [L, B, S, K, Dh]: KV heads on tp. MLA caches ONE latent head
+        # [L, B, S, K * Dh], a row's heads merged in the lanes
+        # (llama.KVCache): KV heads on tp, the heads of a chip
+        # contiguous lanes of the merged axis, so it shards as the
+        # head axis did. MLA caches ONE latent head
         # (kv_cache_heads == 1) — replicated; the latent cache is tiny
         # (kv_lora_rank+rope per token) so replication is the right
         # trade vs collectives in the absorbed decode path
         if self.cfg.mla:
             return self._replicated()
-        return NamedSharding(self.mesh, P(None, None, None, "tp", None))
+        return NamedSharding(self.mesh, P(None, None, None, "tp"))
 
     def _replicated(self) -> NamedSharding:
         return NamedSharding(self.mesh, P())
 
     def new_state(self) -> DecodeState:
         # zeros are born sharded (`device=`): building the full
-        # [L, B, S, K, Dh] slab on one device and moving it would need
-        # the whole cache to fit on that device first
+        # [L, B, S, K * Dh] slab on one device and moving it would
+        # need the whole cache to fit on that device first
         cfg = self.cfg
         L, B, S = cfg.num_layers, self.max_slots, self.max_seq
-        base = (L, B, S, cfg.kv_cache_heads)
+        ks, vs = llama.kv_rows_shapes(cfg, (L, B, S),
+                                      self.kv_rows_merged)
         kv = self._kv_sharding()
         rep = self._replicated()
         return DecodeState(
-            k=jnp.zeros(base + (cfg.kv_cache_k_dim,), cfg.dtype,
-                        device=kv),
-            v=jnp.zeros(base + (cfg.kv_cache_v_dim,), cfg.dtype,
-                        device=kv),
+            k=jnp.zeros(ks, cfg.dtype, device=kv),
+            v=jnp.zeros(vs, cfg.dtype, device=kv),
             lengths=jnp.zeros((B,), jnp.int32, device=rep),
             tokens=jnp.zeros((B,), jnp.int32, device=rep),
             adapters=jnp.zeros((B,), jnp.int32, device=rep))

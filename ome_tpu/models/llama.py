@@ -76,11 +76,19 @@ def _proj(x: jax.Array, w, dtype, out_dims=None, flatten: int = 1):
 class KVCache:
     """Fixed-capacity per-layer KV cache.
 
-    k, v: [L, B, S_max, K, Dh]; index: next-write position — scalar
-    int32 (shared by the whole batch: training-style chunked prefill)
-    or [B] int32 (per-slot write positions: the serving engine's
-    continuous-batching decode, where every slot is at a different
-    sequence length).
+    k, v: [L, B, S_max, K, Dh], or MERGED rows [L, B, S_max, K * Dh]:
+    a row's K heads side by side in the lanes, which is how a slab
+    engine's decode state lies, because `flash_decode` reads dense
+    [rows, K * Dh] tiles of it (ops/flash.py). Which of the two is
+    decided once, by who creates the cache (`create(merged=)`), from
+    what will read it: merged for the slab and the prefills that fill
+    it; heads apart for a paged engine's prefill, whose rows go into
+    the pool [L, N, block, K, Dh]. Every reader and writer below
+    tells the two apart by the array's rank.
+    index: next-write position — scalar int32 (shared by the whole
+    batch: training-style chunked prefill) or [B] int32 (per-slot
+    write positions: the serving engine's continuous-batching decode,
+    where every slot is at a different sequence length).
     """
 
     k: jax.Array
@@ -96,32 +104,40 @@ class KVCache:
     # a held expert (engine/core.py reads them at scrape)
     stats: Any = None
     # periodic window / global models only (cfg.window_layers): the
-    # window layers' RING, [Lw, B, W, K, Dh] with W = sliding_window
-    # (or the cache's length where that is shorter): position p lives
-    # in row p % W, so a slot holds its last W rows and no others
+    # window layers' RING, [Lw, B, W, K, Dh] (merged as k and v are:
+    # [Lw, B, W, K * Dh]) with W = sliding_window (or the cache's
+    # length where that is shorter): position p lives in row p % W, so
+    # a slot holds its last W rows and no others
     # (docs/window-cache.md); k and v then hold the global layers only
     wk: Any = None
     wv: Any = None
 
     @classmethod
     def create(cls, cfg: ModelConfig, batch: int, max_seq: Optional[int] = None,
-               dtype=None) -> "KVCache":
+               dtype=None, merged: bool = False) -> "KVCache":
         S = max_seq or cfg.max_seq_len
         dtype = dtype or cfg.dtype
-        # MLA caches one latent "head" of kv_lora_rank+rope dims and a
-        # zero-width v plane (models/mla.py); dense models cache K/V
-        K, Dk, Dv = (cfg.kv_cache_heads, cfg.kv_cache_k_dim,
-                     cfg.kv_cache_v_dim)
         L = cfg.kv_cache_layers
-        ring = None
+        ks, vs = kv_rows_shapes(cfg, (L, batch, S), merged)
+        ring = (None, None)
         if cfg.window_layers:
-            ring = (cfg.window_layers, batch, ring_rows(cfg, S), K)
-        return cls(k=jnp.zeros((L, batch, S, K, Dk), dtype),
-                   v=jnp.zeros((L, batch, S, K, Dv), dtype),
+            ring = kv_rows_shapes(
+                cfg, (cfg.window_layers, batch, ring_rows(cfg, S)), merged)
+        return cls(k=jnp.zeros(ks, dtype), v=jnp.zeros(vs, dtype),
                    index=jnp.zeros((), jnp.int32),
                    rec=recurrent_state(cfg, batch, dtype),
-                   wk=ring and jnp.zeros(ring + (Dk,), dtype),
-                   wv=ring and jnp.zeros(ring + (Dv,), dtype))
+                   wk=ring[0] and jnp.zeros(ring[0], dtype),
+                   wv=ring[1] and jnp.zeros(ring[1], dtype))
+
+
+def kv_rows_shapes(cfg: ModelConfig, lead: Tuple[int, ...], merged: bool):
+    """(k shape, v shape) of KV rows behind the dimensions `lead`:
+    heads apart, lead + [K, D], or merged, lead + [K * D] (`KVCache`).
+    MLA caches one latent "head" of kv_lora_rank+rope dims and a
+    zero-width v plane (models/mla.py); dense models cache K/V."""
+    K = cfg.kv_cache_heads
+    return tuple(lead + ((K * d,) if merged else (K, d))
+                 for d in (cfg.kv_cache_k_dim, cfg.kv_cache_v_dim))
 
 
 def ring_rows(cfg: ModelConfig, max_seq: int) -> int:
@@ -857,10 +873,10 @@ def _layer(x: jax.Array, lp: Params, cfg: ModelConfig, freqs: jax.Array,
            adapter_ids: Optional[jax.Array] = None,
            use_rope: bool = True, moe_stats: bool = False,
            attn_scope: Optional[str] = None):
-    """One transformer block. cache_kv: ([B,Smax,K,Dh], [B,Smax,K,Dh]),
-    or a `SlabLayer`: the whole stacked slabs a layer scan carries and
-    the layer of them that is this block's (`_mha`, whose `attn_scope`
-    is too).
+    """One transformer block. cache_kv: ([B,Smax,K,Dh], [B,Smax,K,Dh])
+    (or merged rows, `KVCache`), or a `SlabLayer`: the whole stacked
+    slabs a layer scan carries and the layer of them that is this
+    block's (`_mha`, whose `attn_scope` is too).
     `window` overrides cfg.sliding_window (the gemma2 pair-scan passes
     the per-layer value; None = global attention). `moe` overrides
     cfg.is_moe (DeepSeek's first_k_dense leading dense layers).
@@ -1104,8 +1120,8 @@ class SlabLayer(NamedTuple):
     take one layer's (k, v): the layer's rows are written in place
     and read through the index, and nothing slices a layer out (a
     slice of a carried slab is a copy of it, ops/paged.py)."""
-    k: jax.Array                # [L, B, Smax, K, Dh], all layers
-    v: jax.Array
+    k: jax.Array                # [L, B, Smax, K * Dh] (or [.., K, Dh],
+    v: jax.Array                # `KVCache`), all layers
     layer: Any                  # this block's: an int or a traced index
     # the slabs are the window layers' RINGS (`KVCache.wk / wv`,
     # `_ring_write`), not full-length rows
@@ -1115,15 +1131,34 @@ class SlabLayer(NamedTuple):
     valid_len: Optional[jax.Array] = None
 
 
+def _rows_as(rows: jax.Array, dtype, tail: Tuple[int, ...]) -> jax.Array:
+    """Fresh rows [B, S, K, Dh] in a cache's dtype and row layout:
+    `tail` is what follows the cache's row dimension, [K, Dh] or
+    merged [K * Dh] (`KVCache`). A step's few rows are merged behind
+    a barrier: without one the compiler folds the merge into the
+    projection that made them, wants that layer's `wk` / `wv` as
+    [hidden, K * Dh] and re-lays the weights out inside the layer
+    scan, every layer of every step (chip compiler, PR 38), where a
+    handful of rows cost nothing to re-lay. A prompt's rows are more
+    than the weights' and the compiler is left to choose."""
+    rows = rows.astype(dtype)
+    if rows.shape[2:] == tuple(tail):
+        return rows
+    if rows.shape[0] * rows.shape[1] < 2048:
+        rows = lax.optimization_barrier(rows)
+    return rows.reshape(rows.shape[:2] + tuple(tail))
+
+
 def _write_rows(slab: jax.Array, rows: jax.Array, layer,
                 index: jax.Array) -> jax.Array:
-    """`rows` [B, S, K, Dh] into layer `layer` of the stacked slab
-    [L, B, Smax, K, Dh], in place: from row `index` on (a scalar, the
-    whole batch alike) or from `index[b]` on for batch row b."""
-    rows = rows.astype(slab.dtype)
+    """`rows` [B, S, K, Dh] (or as the slab's lie) into layer `layer`
+    of the stacked slab [L, B, Smax, K, Dh] or [L, B, Smax, K * Dh],
+    in place: from row `index` on (a scalar, the whole batch alike) or
+    from `index[b]` on for batch row b."""
+    rows = _rows_as(rows, slab.dtype, slab.shape[3:])
     if index.ndim == 0:
-        return lax.dynamic_update_slice(slab, rows[None],
-                                        (layer, 0, index, 0, 0))
+        return lax.dynamic_update_slice(
+            slab, rows[None], (layer, 0, index) + (0,) * (slab.ndim - 3))
     B, S = rows.shape[:2]
     at = index[:, None] + jnp.arange(S, dtype=index.dtype)[None, :]
     return slab.at[layer, jnp.arange(B)[:, None], at].set(rows)
@@ -1132,40 +1167,45 @@ def _write_rows(slab: jax.Array, rows: jax.Array, layer,
 def _ring_rows_of(k: jax.Array, n: jax.Array, W: int, rows: int
                   ) -> jax.Array:
     """What a window layer's ring holds after a fresh prompt: of `k`
-    [B, S, K, Dh], whose first `n[b]` positions are real, position p
-    in row p % W for the last min(n, W) positions. Rows no position
-    has reached hold position 0's: the slot's length hides them."""
+    [B, S, K, Dh] or [B, S, K * Dh], whose first `n[b]` positions are
+    real, position p in row p % W for the last min(n, W) positions.
+    Rows no position has reached hold position 0's: the slot's length
+    hides them."""
     r = jnp.arange(rows, dtype=jnp.int32)[None, :]
     p = r + W * jnp.floor_divide(n[:, None] - 1 - r, W)
-    return jnp.take_along_axis(k, jnp.maximum(p, 0)[:, :, None, None],
+    p = jnp.maximum(p, 0)
+    return jnp.take_along_axis(k, p.reshape(p.shape + (1,) * (k.ndim - 2)),
                                axis=1)
 
 
 def _slab_write(k, v, cache_kv, index):
     """The fresh rows k, v [B, S, K, Dh] written into the cache from
     row `index` on (a scalar, or [B] per-slot positions): into one
-    layer's (ck, cv), each [B, Smax, K, Dh], or into a `SlabLayer`'s
-    layer of the whole stacked slabs, in place."""
+    layer's (ck, cv), each [B, Smax, K, Dh] or merged [B, Smax,
+    K * Dh], or into a `SlabLayer`'s layer of the whole stacked
+    slabs, in place."""
     if isinstance(cache_kv, SlabLayer):
         return (_write_rows(cache_kv.k, k, cache_kv.layer, index),
                 _write_rows(cache_kv.v, v, cache_kv.layer, index))
     ck, cv = cache_kv
+    k = _rows_as(k, ck.dtype, ck.shape[2:])
+    v = _rows_as(v, cv.dtype, cv.shape[2:])
+    rest = (0,) * (ck.ndim - 2)
     if index.ndim == 1:
         # per-slot write positions (continuous batching): vmap the
         # update over the batch so each slot writes at its own length
         upd = jax.vmap(lambda c, u, i: lax.dynamic_update_slice(
-            c, u.astype(c.dtype), (i, 0, 0)))
+            c, u, (i,) + rest))
         return upd(ck, k, index), upd(cv, v, index)
-    return (lax.dynamic_update_slice(ck, k.astype(ck.dtype),
-                                     (0, index, 0, 0)),
-            lax.dynamic_update_slice(cv, v.astype(cv.dtype),
-                                     (0, index, 0, 0)))
+    return (lax.dynamic_update_slice(ck, k, (0, index) + rest),
+            lax.dynamic_update_slice(cv, v, (0, index) + rest))
 
 
 def _ring_write(k, v, slab: SlabLayer, index, window: int):
     """The fresh rows k, v [B, S, K, Dh] into `slab`'s layer of the
-    window layers' rings [Lw, B, W, K, Dh], in place. S == 1: position
-    `index[b]` to row index % W, unless the slot is frozen
+    window layers' rings [Lw, B, W, K, Dh] or [Lw, B, W, K * Dh], in
+    place. S == 1: position `index[b]` to row index % W, unless the
+    slot is frozen
     (`slab.valid_len` 0), whose ring stays as it was. S > 1 is a
     FRESH PROMPT only (nothing of the slot's ring is kept, whatever
     `index` says: a chunk on top of rows the ring holds cannot be
@@ -1179,15 +1219,18 @@ def _ring_write(k, v, slab: SlabLayer, index, window: int):
         n = jnp.broadcast_to(S if valid_len is None else valid_len, (B,))
         zero = jnp.zeros((), jnp.int32)
         return tuple(
-            _write_rows(ring, _ring_rows_of(x, n, window, W), slab.layer,
-                        zero)
+            _write_rows(ring, _ring_rows_of(
+                _rows_as(x, ring.dtype, ring.shape[3:]), n, window, W),
+                slab.layer, zero)
             for ring, x in zip(slab[:2], (k, v)))
     row = jnp.broadcast_to(index, (B,)) % W
     out = []
     for ring, x in zip(slab[:2], (k, v)):
+        x = _rows_as(x, ring.dtype, ring.shape[3:])
         if valid_len is not None:
             held = ring[slab.layer, jnp.arange(B), row][:, None]
-            x = jnp.where((valid_len > 0)[:, None, None, None], x, held)
+            live = (valid_len > 0).reshape((B,) + (1,) * (x.ndim - 1))
+            x = jnp.where(live, x, held)
         out.append(_write_rows(ring, x, slab.layer, row))
     return tuple(out)
 
@@ -1198,10 +1241,10 @@ def _mha(h: jax.Array, lp: Params, cfg: ModelConfig, freqs: jax.Array,
          use_rope: bool = True, attn_scope: Optional[str] = None):
     """Standard multi-head (GQA) attention on the pre-normed input.
 
-    `cache_kv` is one layer's (k, v), each [B, Smax, K, Dh], handed
-    back updated; or a `SlabLayer`, the WHOLE stacked slabs of a layer
-    scan that carries them and this layer's index, whose updated
-    slabs are handed back.
+    `cache_kv` is one layer's (k, v), each [B, Smax, K, Dh] or merged
+    [B, Smax, K * Dh] (`KVCache`), handed back updated; or a
+    `SlabLayer`, the WHOLE stacked slabs of a layer scan that carries
+    them and this layer's index, whose updated slabs are handed back.
 
     A `SlabLayer` of rings (`ring`): a decode step (S == 1) writes
     position p at row p % W and attends over the min(p + 1, W) rows
@@ -1527,12 +1570,13 @@ def _alt_window_scan(params: Params, cfg: ModelConfig, x: jax.Array,
     slabs, a window layer's among the rings.
 
     Both caches are the scan's CARRY, written in place (`_mha`): the
-    global layers' full-length slabs `cache.k / v` [Lg, B, S, K, Dh]
-    and the window layers' rings `cache.wk / wv` [Lw, B, W, K, Dh].
-    A model whose configuration keeps no ring (`cfg.window_layers`
-    0: gemma2, cohere2, gpt-oss) has every layer's full-length rows
-    in `cache.k / v` [L, B, S, K, Dh], and a window layer reads its
-    own under the window mask.
+    global layers' full-length slabs `cache.k / v` [Lg, B, S, K * Dh]
+    and the window layers' rings `cache.wk / wv` [Lw, B, W, K * Dh]
+    (a slab engine's merged rows; [.., K, Dh] from a creator that
+    asked for none, `KVCache`). A model whose configuration keeps no
+    ring (`cfg.window_layers` 0: gemma2, cohere2, gpt-oss) has every
+    layer's full-length rows in `cache.k / v` [L, B, S, ..], and a
+    window layer reads its own under the window mask.
     As scanned input and output every step would slice each layer's
     slab out, stack it back and copy the whole after the loop. The
     weights are closed over whole and read by layer index for the

@@ -129,7 +129,8 @@ def mla_attention(h: jax.Array, lp: Params, cfg: ModelConfig,
     """One MLA attention block (pre-normed input h [B, S, D]).
 
     Returns (attn_out [B, S, D], new_cache_kv or None). The cache's k
-    plane holds latents [B, Smax, 1, kv_lora_rank + rope]; the v plane
+    plane holds latents [B, Smax, 1, kv_lora_rank + rope] (or merged
+    rows [B, Smax, kv_lora_rank + rope], llama.KVCache); the v plane
     is zero-width (cfg.kv_cache_v_dim == 0).
     """
     B, S, _ = h.shape
@@ -137,7 +138,7 @@ def mla_attention(h: jax.Array, lp: Params, cfg: ModelConfig,
     nope, rope = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
     r = cfg.kv_lora_rank
 
-    from .llama import _w, rms_norm  # shared weight accessor / norm
+    from .llama import _rows_as, _w, rms_norm  # shared with llama
 
     # -- queries -------------------------------------------------------
     if cfg.q_lora_rank:
@@ -159,17 +160,20 @@ def mla_attention(h: jax.Array, lp: Params, cfg: ModelConfig,
 
     if cache_kv is not None:
         ck_cache, cv_cache = cache_kv
+        # the one latent "head" a row: [B, Smax, 1, r + rope], or a
+        # slab engine's merged rows [B, Smax, r + rope], the same bytes
+        rows = _rows_as(latent, ck_cache.dtype, ck_cache.shape[2:])
+        rest = (0,) * (ck_cache.ndim - 2)
         if cache_index.ndim == 1:
             upd = jax.vmap(
                 lambda cc, u, i: lax.dynamic_update_slice(
-                    cc, u.astype(cc.dtype), (i, 0, 0)))
-            ck_cache = upd(ck_cache, latent, cache_index)
+                    cc, u, (i,) + rest))
+            ck_cache = upd(ck_cache, rows, cache_index)
         else:
             ck_cache = lax.dynamic_update_slice(
-                ck_cache, latent.astype(ck_cache.dtype),
-                (0, cache_index, 0, 0))
+                ck_cache, rows, (0, cache_index) + rest)
         new_cache = (ck_cache, cv_cache)
-        full = ck_cache[:, :, 0]                     # [B, T, r+rope]
+        full = ck_cache.reshape(ck_cache.shape[:2] + (-1,))  # [B, T, r+rope]
         k_pos = jnp.arange(full.shape[1], dtype=jnp.int32)
     else:
         new_cache = None

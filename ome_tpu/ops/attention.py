@@ -63,7 +63,7 @@ def _flash(q, k, v, positions, kv_len, **kw) -> Optional[jax.Array]:
     if mesh is None or positions is None:
         return local(q, k, v, positions, kv_len)
     tp = mesh.shape["tp"]
-    H, K = q.shape[2], k.shape[2]
+    H, K = q.shape[2], flash._kv_heads(k, q.shape[3])
     if H % tp or K % tp:
         return None
     if kv_len is None:
@@ -78,14 +78,20 @@ def _flash(q, k, v, positions, kv_len, **kw) -> Optional[jax.Array]:
     if jax.eval_shape(local, per_device(q), per_device(k),
                       per_device(v), positions, kv_len) is None:
         return None
-    heads = P(None, None, "tp", None)
+
+    def heads(x):
+        # [B, S, heads, D], or a cache's merged rows [B, S, K * D]:
+        # a chip's KV heads are contiguous lanes of those
+        return P(None, None, "tp", *(None,) * (x.ndim - 3))
+
     return jax.shard_map(
-        local, mesh=mesh, in_specs=(heads, heads, heads, P(), P()),
-        out_specs=heads, check_vma=False)(q, k, v, positions, kv_len)
+        local, mesh=mesh,
+        in_specs=(heads(q), heads(k), heads(v), P(), P()),
+        out_specs=heads(q), check_vma=False)(q, k, v, positions, kv_len)
 
 
 def _layer_of(slab: jax.Array, layer) -> jax.Array:
-    """Layer `layer` of a stacked [L, B, S, K, D]: a copy of it."""
+    """Layer `layer` of a stacked [L, B, S, ..]: a copy of it."""
     return jax.lax.dynamic_index_in_dim(slab, layer, 0, keepdims=False)
 
 
@@ -183,10 +189,13 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array,
               layer=None) -> jax.Array:
     """Dispatching attention entry point used by all models.
 
-    layer: k and v are the stacked slabs [L, B, Skv, K, D] that a
-    layer scan carries, and attention is over layer `layer` of them
-    (an int or a traced index). The decode kernel reads it where it
-    lies (ops/flash.py); every other path takes the layer out first.
+    k, v: [B, Skv, K, D], or a slab engine's merged cache rows
+    [B, Skv, K * D] (`llama.KVCache`), told apart by rank.
+    layer: k and v are the stacked slabs [L, B, Skv, K * D] (or
+    [.., K, D]) that a layer scan carries, and attention is over layer
+    `layer` of them (an int or a traced index). The decode kernel
+    reads it where it lies (ops/flash.py); every other path takes the
+    layer out first.
     positions: [B, Sq] absolute query positions (contiguous per row);
     None disables causal masking entirely (bidirectional attention).
     kv_len: [B] valid KV rows for fixed-capacity caches.
@@ -220,6 +229,10 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array,
                      f"the kernels' coverage")
     if layer is not None:       # the XLA path, or a kernel that declined
         k, v = _layer_of(k, layer), _layer_of(v, layer)
+    if k.ndim == 3:             # merged rows: the heads apart again
+        heads = k.shape[2] // q.shape[3]
+        k = k.reshape(k.shape[:2] + (heads, -1))
+        v = v.reshape(v.shape[:2] + (heads, -1))
     mask = None
     if positions is not None:
         kv_pos = jnp.arange(k.shape[1], dtype=jnp.int32)

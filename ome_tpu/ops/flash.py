@@ -10,6 +10,11 @@ Pallas kernel pair designed around the TPU memory system:
     skips the DMA when the block index repeats, so a sequence at
     length 300 in a 2048-slot cache streams ~300 rows of KV through
     VMEM, not 2048 (decode is HBM-bandwidth-bound; this is the win).
+    A cache row's K heads lie side by side in the lanes, [S, K * D],
+    so a key block [bs, K * D] is a dense tile of the slab as it
+    lies in HBM and head `kh` the lane slice [:, kh * D:(kh + 1) * D]
+    (a [bs, K, D] block with K = 4 in the second-minor place is half
+    padding: 3.1 us a 512-row block for 1.3 us of DMA, PR 32).
   * **prefill**: grid (B, K, q_blocks, kv_steps) with the same
     clamping on the causal frontier, so upper-triangle KV blocks are
     neither fetched nor computed. GQA: the G query heads of a KV
@@ -115,14 +120,20 @@ def _decode_kernel(lim_ref, q_ref, k_ref, v_ref, *refs, bs: int,
     @pl.when((first + s <= last) & (start < hi) & (start + bs > lo))
     def _():
         q = q_ref[0]            # [K, G, D]
-        k = k_ref[0]            # [bs, K, D]
-        if quantized:
-            k = k.astype(q.dtype)   # raw int8 values; scale on logits
         K, G, D = q.shape
+
+        def head(ref, kh):
+            """Head kh of a [1, bs, K * D] block, [bs, D]: a slice on
+            a lane tile's edge (D % 128 == 0)."""
+            x = ref[0, :, kh * D:(kh + 1) * D]
+            # int8 KV: raw values; the scales go on logits and probs
+            return x.astype(q.dtype) if quantized else x
+
         # per-KV-head 2D dots (Mosaic's matmul wants batch dims aligned;
         # K is small and static, so unroll): [G,D] x [bs,D]^T -> [G,bs]
         logits = jnp.concatenate(
-            [lax.dot_general(q[kh], k[:, kh, :], (((1,), (1,)), ((), ())),
+            [lax.dot_general(q[kh], head(k_ref, kh),
+                             (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)
              for kh in range(K)], axis=0)                   # [K*G, bs]
         if quantized:
@@ -142,15 +153,13 @@ def _decode_kernel(lim_ref, q_ref, k_ref, v_ref, *refs, bs: int,
         p = jnp.exp(logits - m_new)
         p = jnp.where(valid, p, 0.0)
         l_new = alpha * l_ref[:, :1] + jnp.sum(p, axis=1, keepdims=True)
-        v_blk = v_ref[0]                                    # [bs, K, D]
         if quantized:
-            v_blk = v_blk.astype(q.dtype)  # raw; fold scales into p
             sv = vs_ref[0]                                  # [K, bs]
             p = (p.reshape(K, G, bs) * sv[:, None, :]).reshape(
                 K * G, bs)
-        pb = p.astype(v_blk.dtype)
+        pb = p.astype(q.dtype if quantized else v_ref.dtype)
         pv = jnp.concatenate(
-            [lax.dot_general(pb[kh * G:(kh + 1) * G], v_blk[:, kh, :],
+            [lax.dot_general(pb[kh * G:(kh + 1) * G], head(v_ref, kh),
                              (((1,), (0,)), ((), ())),
                              preferred_element_type=jnp.float32)
              for kh in range(K)], axis=0)                   # [K*G, D]
@@ -167,18 +176,22 @@ def _decode_kernel(lim_ref, q_ref, k_ref, v_ref, *refs, bs: int,
 
 def _flash_decode(q, k, v, lo, hi, scale, softcap, interpret,
                   k_scale=None, v_scale=None, layer=None):
-    """`layer` (an int32 scalar, traced or not): k and v are the
-    STACKED slabs [L, B, S, K, D] of a layer scan that carries them,
-    and the kernel reads layer `layer` of them where they lie: the
-    index rides scalar prefetch into the block index maps, so no
+    """k, v: [B, S, K * D], a row's K heads side by side in the lanes
+    (`llama.KVCache.create(..., merged=True)`: what a slab engine's
+    state is). `layer` (an int32 scalar, traced or not): k and v are
+    the STACKED slabs [L, B, S, K * D] of a layer scan that carries
+    them, and the kernel reads layer `layer` of them where they lie:
+    the index rides scalar prefetch into the block index maps, so no
     layer is sliced out first (a slice of a carried slab is a copy of
     it; ops/paged.py does the same for the pool)."""
     B, _, H, D = q.shape
-    S, K = k.shape[-3], k.shape[-2]
-    G = H // K
+    S, KD = k.shape[-2], k.shape[-1]
+    K = KD // D
     bs = _pick_block(S, (512, 256, 128))
-    if bs is None or H < 8 or D % 128 != 0:
+    if bs is None or H < 8 or D % 128 != 0 or K * D != KD or H % K \
+            or v.shape != k.shape:
         return None
+    G = H // K
     ns = S // bs
     quantized = k_scale is not None
     stacked = layer is not None
@@ -197,12 +210,12 @@ def _flash_decode(q, k, v, lo, hi, scale, softcap, interpret,
     # sliding window) and the cache tail (short sequences).
     def kv_index(b, s, lim):
         first, last = _decode_block_range(lim[b, 0], lim[b, 1], bs)
-        at = (b, jnp.minimum(first + s, last), 0, 0)
+        at = (b, jnp.minimum(first + s, last), 0)
         return (lim[b, 2],) + at if stacked else at
 
     # the layer's dimension is squeezed out of the block: the kernel
-    # sees [1, bs, K, D] either way
-    kv_block = ((None,) if stacked else ()) + (1, bs, K, D)
+    # sees [1, bs, K * D] either way, bs whole rows as they lie in HBM
+    kv_block = ((None,) if stacked else ()) + (1, bs, KD)
 
     def sc_index(b, s, lim):
         first, last = _decode_block_range(lim[b, 0], lim[b, 1], bs)
@@ -242,6 +255,14 @@ def _flash_decode(q, k, v, lo, hi, scale, softcap, interpret,
     return out.reshape(B, 1, H, D)
 
 
+def _merged(x: jax.Array) -> jax.Array:
+    """[.., S, K, D] as [.., S, K * D]. Free for a handful of fresh
+    rows; of a whole slab it is a copy on the chip (the minor tiles
+    differ), so state that lives across decode steps is created
+    merged and never passes here."""
+    return x.reshape(x.shape[:-2] + (x.shape[-2] * x.shape[-1],))
+
+
 def quantize_kv_block(x: jax.Array):
     """Per-(row, head) symmetric int8 for a KV slab [B, S, K, D] ->
     (int8 values [B, S, K, D], f32 scales [B, K, S]). One scale per
@@ -265,7 +286,9 @@ def flash_decode_quantized(q: jax.Array, kq: jax.Array, vq: jax.Array,
                            logit_softcap: Optional[float] = None,
                            interpret: bool = False):
     """Decode attention over an int8 KV cache (quantize_kv_block
-    layout). q: [B, 1, H, D] bf16; kq/vq: [B, S, K, D] int8; scales
+    layout). q: [B, 1, H, D] bf16; kq/vq: [B, S, K, D] int8 (taken
+    to the kernel's [B, S, K * D] here: this slab keeps the layout
+    its quantizer and the paged pool share) or [B, S, K * D]; scales
     [B, K, S] f32. Returns [B, 1, H, D] or None if shapes uncovered.
 
     Experimental building block, NOT wired into the engine: the KV
@@ -287,6 +310,8 @@ def flash_decode_quantized(q: jax.Array, kq: jax.Array, vq: jax.Array,
     hi = jnp.minimum(pos + 1, kv_hi)
     lo = jnp.maximum(pos - sliding_window + 1, 0) if sliding_window \
         else jnp.zeros_like(pos)
+    if kq.ndim == 4:
+        kq, vq = _merged(kq), _merged(vq)
     return _flash_decode(q, kq, vq, lo, hi, scale, logit_softcap,
                          interpret, k_scale=k_scale, v_scale=v_scale)
 
@@ -484,8 +509,15 @@ def prefill_block_kinds(Sq: int, S: int, K: int, G: int, D: int,
     return kinds
 
 
+def _kv_heads(k, D: int, stacked: bool = False) -> int:
+    """KV heads of [B, S, K, D] rows, or of merged [B, S, K * D]
+    ones (`stacked`: a layer dimension leads either), told apart by
+    the array's rank."""
+    return k.shape[-1] // D if k.ndim - stacked == 3 else k.shape[-2]
+
+
 def _flash_prefill(q, k, v, base, kv_hi, scale, softcap, window, interpret):
-    G = q.shape[2] // k.shape[2]
+    G = q.shape[2] // _kv_heads(k, q.shape[3])
     blocks = _prefill_blocks(q.shape[1], k.shape[1], G, q.shape[3])
     if blocks is None:
         return None
@@ -505,14 +537,15 @@ def _flash_prefill(q, k, v, base, kv_hi, scale, softcap, window, interpret):
 def _prefill_call(q, k, v, base, kv_hi, *, blocks, scale, softcap, window,
                   interpret):
     B, Sq, H, D = q.shape
-    S, K = k.shape[1], k.shape[2]
+    S, K = k.shape[1], _kv_heads(k, D)
     G = H // K
     bq, bs = blocks
     limits = jnp.stack(
         [base.astype(jnp.int32), kv_hi.astype(jnp.int32)], axis=1)
     # heads side by side in the lanes: a query block is [bq, G * D]
     # and a key block [bs, D], both dense tiles of the arrays as they
-    # lie in HBM (no [.., 1, D] or [.., G, D] minor tiles to repack)
+    # lie in HBM (no [.., 1, D] or [.., G, D] minor tiles to repack);
+    # a slab engine's cache rows come merged already
     q3 = q.reshape(B, Sq, H * D)
     k3 = k.reshape(B, S, K * D)
     v3 = v.reshape(B, S, K * D)
@@ -566,9 +599,10 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     layer=None) -> Optional[jax.Array]:
     """Flash attention or None when the kernels don't cover the shapes.
 
-    q: [B, Sq, H, D]; k, v: [B, Skv, K, D], H % K == 0; with `layer`
-    (decode only, Sq == 1) the stacked [L, B, Skv, K, D] of which the
-    kernel reads that layer in place (`_flash_decode`).
+    q: [B, Sq, H, D]; k, v: [B, Skv, K, D], H % K == 0, or a slab
+    engine's merged rows [B, Skv, K * D] (told apart by rank); with
+    `layer` (decode only, Sq == 1) the stacked [L, B, Skv, K * D] of
+    which the kernel reads that layer in place (`_flash_decode`).
     positions: [B, Sq] absolute query positions, assumed contiguous per
     row (base + arange — what the model forward produces); None means
     non-causal full attention (not covered here -> None).
@@ -577,13 +611,17 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     if positions is None:
         return None  # non-causal: XLA path
     B, Sq, H, D = q.shape
-    K = k.shape[-2]
-    if H % K != 0 or (layer is not None and Sq != 1):
+    stacked = layer is not None
+    if k.ndim - stacked == 4 and Sq == 1:
+        # rows apart (a caller with no state of its own: tests, bench)
+        k, v = _merged(k), _merged(v)
+    K = _kv_heads(k, D, stacked)
+    if K == 0 or H % K != 0 or (stacked and Sq != 1):
         return None
     scale = scale if scale is not None else D ** -0.5
     base = positions[:, 0]
     if kv_len is None:
-        kv_hi = jnp.full((B,), k.shape[-3], jnp.int32)
+        kv_hi = jnp.full((B,), k.shape[2 if stacked else 1], jnp.int32)
     else:
         kv_hi = jnp.broadcast_to(kv_len, (B,)).astype(jnp.int32)
     if Sq == 1:

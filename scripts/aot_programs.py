@@ -133,7 +133,9 @@ def main() -> int:
         mesh = Mesh(np.array(topo.devices).reshape(1, 1, 4),
                     ("dp", "pp", "tp"))
         rep = NamedSharding(mesh, P())
-        kv_sh = NamedSharding(mesh, P(None, None, None, "tp", None))
+        # the slab's merged rows [L, B, S, K * D] (llama.KVCache): a
+        # chip's KV heads are contiguous lanes of the last axis
+        kv_sh = NamedSharding(mesh, P(None, None, None, "tp"))
         shardings = param_shardings(shapes, mesh)
         scope = heads_sharded_over(mesh)
 
@@ -168,7 +170,7 @@ def main() -> int:
         state = jax.tree.map(lambda a: S(a.shape, a.dtype),
                              jax.eval_shape(eng.new_state))
     else:
-        slab = S((L, B, args.max_seq, Kh, Dh), cfg.dtype, kv_sh)
+        slab = S((L, B, args.max_seq, Kh * Dh), cfg.dtype, kv_sh)
         state = DecodeState(k=slab, v=slab, lengths=S((B,), i32),
                             tokens=S((B,), i32),
                             adapters=S((B,), i32))
@@ -183,7 +185,8 @@ def main() -> int:
             report(f"prefill[bucket={b}]", eng._prefill_fn.lower(
                 params, S((1, b), i32), S((1,), i32), *sampling(1),
                 key, S((1,), i32), bucket=b))
-            kv = S((L, 1, b, Kh, Dh), cfg.dtype, kv_sh)
+            kv = S((L, 1, b, Kh, Dh) if paged else (L, 1, b, Kh * Dh),
+                   cfg.dtype, kv_sh)
             if args.config:
                 # insert takes what this model's prefill hands back
                 _, *handed = jax.eval_shape(

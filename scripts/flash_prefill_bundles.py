@@ -1,6 +1,7 @@
 #!/usr/bin/env python
-"""What `flash_prefill` compiles to, without the chip: the kernel's
-final instruction bundles for a described v5e, counted.
+"""What `flash_prefill` (or, with `--kernel decode`, `flash_decode`)
+compiles to, without the chip: the kernel's final instruction bundles
+for a described v5e, counted.
 
 The installed libtpu compiles for a chip that is described, not
 attached (on-chip-measurement guide, section 2), and with
@@ -20,6 +21,18 @@ mask's arithmetic.
     python scripts/flash_prefill_bundles.py                # 16 384 bucket's tile
     python scripts/flash_prefill_bundles.py --body whole   # one body alone
     python scripts/flash_prefill_bundles.py --heads 16 --kv-heads 2 --dim 256
+    python scripts/flash_prefill_bundles.py --kernel decode --heads 28
+    python scripts/flash_prefill_bundles.py --kernel decode --heads 28 \
+        --tree /root/scratch/parent --apart       # the kernel before PR 38
+
+`--kernel decode` compiles the decode kernel over a stacked slab of
+`--rows` rows for 8 slots (`--apart`: the slab as `[L, B, S, K, D]`,
+which the kernel took before PR 38; since then `[L, B, S, K * D]`),
+`--tree DIR` takes `ome_tpu` from another checkout. Besides the bundle
+and slot counts the vector loads are counted by how many sublanes
+each moves (`sublane_mask`): a block whose second-minor dimension is
+K = 4 loads half-empty tiles, and one whose minor dimensions are
+`[1, D]` a sublane at a time, and no source line shows either.
 
 `--body whole|edge|none` compiles the kernel with every block sorted
 as that kind (none: init, finish and the pipeline's own code; a
@@ -48,9 +61,9 @@ def _compile(args):
     import jax.numpy as jnp
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
-    sys.path.insert(0, ROOT)
+    sys.path.insert(0, args.tree or ROOT)
     from ome_tpu.ops import flash
-    if args.body != "both":
+    if args.body != "both" and args.kernel == "prefill":
         kind = flash._prefill_block_kind
 
         def only(*a):
@@ -67,6 +80,22 @@ def _compile(args):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
 
     S, D = args.rows, args.dim
+    if args.kernel == "decode":
+        slots, layers = 8, 2
+        rows = (args.kv_heads, D) if args.apart else (args.kv_heads * D,)
+        kv = struct((layers, slots, S) + rows, jnp.bfloat16)
+
+        def step(q, k, v, lo, hi, layer):
+            out = flash._flash_decode(q, k, v, lo, hi, D ** -0.5, None,
+                                      False, layer=layer)
+            assert out is not None, "the kernel declined the shape"
+            return out
+
+        ints = struct((slots,), jnp.int32)
+        jax.jit(step).lower(struct((slots, 1, args.heads, D), jnp.bfloat16),
+                            kv, kv, ints, ints,
+                            struct((), jnp.int32)).compile()
+        return
 
     def f(q, k, v, base, kv_hi):
         return flash._flash_prefill(q, k, v, base, kv_hi, D ** -0.5, None,
@@ -80,6 +109,12 @@ def _compile(args):
 
 def main():
     ap = argparse.ArgumentParser()
+    ap.add_argument("--kernel", default="prefill",
+                    choices=("prefill", "decode"))
+    ap.add_argument("--tree", default=None,
+                    help="take ome_tpu from this checkout, not this one")
+    ap.add_argument("--apart", action="store_true",
+                    help="decode: hand the slab as [L, B, S, K, D]")
     ap.add_argument("--rows", type=int, default=2048,
                     help="Sq = S; the tile does not depend on it")
     ap.add_argument("--heads", type=int, default=32)
@@ -100,20 +135,26 @@ def main():
     child = subprocess.run([sys.executable, __file__] + sys.argv[1:],
                            env=env, capture_output=True, text=True)
     try:
+        name = "flash_" + args.kernel
         found = [f for f in glob.glob(
-            f"{out}/*flash_prefill*final_bundles.txt")
+            f"{out}/*{name}*final_bundles.txt")
             if "schedule-analysis" not in f]
         if not found:
             sys.exit(child.stderr[-3000:] or "no bundles were written")
         bundles = [line for line in open(found[0])
                    if re.match(r"\s*(0x[0-9a-f]+|\d+)\s", line)]
-        ops = collections.Counter()
+        ops, sublanes = collections.Counter(), collections.Counter()
         for line in bundles:
             for ins in line.partition("{")[2].split(";;"):
                 m = re.search(r"=\s*([a-z_.0-9]+)", ins)
                 if m:
                     ops[re.sub(r"\.(xlu|mxu)\d", "", m.group(1))] += 1
-        util = glob.glob(f"{out}/*flash_prefill*final_hlo-static-per-"
+                    mask = re.search(r"sm:\$0x([0-9a-f]+)", ins)
+                    if m.group(1).startswith("vld"):
+                        # no mask: all 8 sublanes of the tile
+                        sublanes[bin(int(mask.group(1), 16)).count("1")
+                                 if mask else 8] += 1
+        util = glob.glob(f"{out}/*{name}*final_hlo-static-per-"
                          "bundle-utilization.txt")[0]
         text = open(util).read().split("\n")
         names = text[1].replace(" ", "").split(",")
@@ -124,6 +165,8 @@ def main():
         for i, (name, cap) in enumerate(zip(names, caps)):
             print(f"  {name:13s} {sum(r[i] for r in rows):6d} slot uses "
                   f"({cap} a bundle)")
+        print("  vector loads by sublanes moved: " + ", ".join(
+            f"{n} sublanes x {c}" for n, c in sorted(sublanes.items())))
         print("  " + ", ".join(f"{k} {v}" for k, v in ops.most_common(24)))
         if args.keep:
             os.makedirs(args.keep, exist_ok=True)

@@ -94,7 +94,7 @@ def test_paged_flash_decode(one_chip, pool_dtype):
 
 def test_flash_decode_dense_slab(one_chip):
     S = 4096
-    kv = one_chip((B, S, K, D), jnp.bfloat16)
+    kv = one_chip((B, S, K * D), jnp.bfloat16)  # a row's heads merged
 
     def f(q, k, v, lo, hi):
         out = flash._flash_decode(q, k, v, lo, hi, D ** -0.5, None,
@@ -194,14 +194,19 @@ def test_int4_matmul_compiles_or_declines(one_chip, monkeypatch, k, n):
     assert declined or "tpu_custom_call" in c.as_text()
 
 
+@pytest.mark.parametrize("merged", [False, True],
+                         ids=["heads_apart", "merged_rows"])
 @pytest.mark.parametrize("sq,skv", [(1, 2048), (2048, 2048)])
 def test_tp4_attention_under_engine_shardings(topo, monkeypatch, sq,
-                                              skv):
-    """The sharded engine's layout: q on heads, the cache on KV heads,
-    over the "tp" axis of a (dp, pp, tp) mesh. GSPMD refuses to
-    partition a Mosaic kernel, so attention() runs it per device under
-    shard_map — and because heads mix nothing, the program holds the
-    kernel and NO collective."""
+                                              skv, merged):
+    """The sharded engine's layout: q on heads, the cache on KV heads
+    (its rows merged [B, S, K * D] since PR 38: a chip's heads are
+    contiguous lanes, so the merged axis shards as the head axis
+    did; heads apart for a caller that holds such rows), over the
+    "tp" axis of a (dp, pp, tp) mesh. GSPMD refuses to partition a
+    Mosaic kernel, so attention() runs it per device under shard_map —
+    and because heads mix nothing, the program holds the kernel and
+    NO collective."""
     monkeypatch.setattr(device, "on_tpu", lambda: True)
     import numpy as np
     mesh = Mesh(np.array(topo.devices).reshape(1, 1, 4),
@@ -215,11 +220,15 @@ def test_tp4_attention_under_engine_shardings(topo, monkeypatch, sq,
                                       kv_len=kv_len, backend="pallas")
 
     b = B if sq == 1 else 1       # decode batch, or one prefill
+    rows = jax.ShapeDtypeStruct(
+        (b, skv, K * D), jnp.bfloat16,
+        sharding=NamedSharding(mesh, P(None, None, "tp"))) if merged \
+        else jax.ShapeDtypeStruct((b, skv, K, D), jnp.bfloat16,
+                                  sharding=heads)
     c = _compile(
         f, jax.ShapeDtypeStruct((b, sq, H, D), jnp.bfloat16,
                                 sharding=heads),
-        *[jax.ShapeDtypeStruct((b, skv, K, D), jnp.bfloat16,
-                               sharding=heads)] * 2,
+        rows, rows,
         jax.ShapeDtypeStruct((b, sq), jnp.int32, sharding=rep),
         jax.ShapeDtypeStruct((b,), jnp.int32, sharding=rep))
     text = c.as_text()
@@ -379,13 +388,21 @@ def test_decode_paged_kernel_takes_the_pool_whole(decode_paged,
 # -- the slab path's decode step over two kinds of KV row ----------------
 
 
-@pytest.fixture(scope="module")
-def decode_window(topo):
-    """The engine's own `decode` program for the benchmark's periodic
-    window / global configuration (benchmark/configs/
-    trinity-mini-ep4.json) at the cell's slots and length, compiled
-    for the described chip: (compiled, global slab shape, ring
-    shape)."""
+# the two periodic window / global cells: configuration -> (heads, what
+# a decode step's temporaries may be: the parent of PR 38 read 0.541 GB
+# and 0.626 GB, chip compiler, and merged rows read the same to the MB)
+WINDOW_CELLS = {"trinity-mini-ep4": (32, 545_000_000),
+                "smallthinker-21b-a3b-ep4": (28, 630_000_000)}
+
+
+@pytest.fixture(scope="module", params=sorted(WINDOW_CELLS))
+def decode_window(topo, request):
+    """The engine's own `decode` program for a benchmark configuration
+    of the periodic window / global family (benchmark/configs/
+    trinity-mini-ep4.json: `long-doc`; smallthinker-21b-a3b-ep4.json:
+    `long-decode`) at the cell's slots and length, compiled for the
+    described chip: (compiled, global slab shape, ring shape, the
+    configuration's name, its ModelConfig)."""
     import json
 
     from ome_tpu.engine.core import InferenceEngine
@@ -395,7 +412,7 @@ def decode_window(topo):
 
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(root, "benchmark", "configs",
-                           "trinity-mini-ep4.json")) as f:
+                           request.param + ".json")) as f:
         file = json.load(f)
     cfg = ModelConfig.from_hf_config(
         {k: v for k, v in file.items()
@@ -422,43 +439,78 @@ def decode_window(topo):
         key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=sharding)
         compiled = eng.programs["decode"].lower(
             params, state, floats, ints, floats, key).compile()
-    return compiled, state.k.shape, state.wk.shape
+    return compiled, state.k.shape, state.wk.shape, request.param, cfg
 
 
 def test_decode_leaves_both_caches_where_they_are(decode_window):
-    """The global layers' slab [4, 24, 16384, 4, 128] and the window
-    layers' ring [12, 24, 2048, 4, 128] are the layer scan's carry,
-    written in place and read by `flash_decode` through a layer index
-    in its scalar prefetch: every attention call's operands are the
-    WHOLE stacked arrays, the program's temporaries are under one
-    ring's size (as xs/ys of the scan it kept a second slab: 3.2 GB),
-    and no `copy`, `dynamic-slice` or `dynamic-update-slice` gives a
-    slab, a ring or a layer of either."""
-    compiled, slab, ring = decode_window
+    """The global layers' slab (long-doc: [4, 24, 16384, 4 * 128],
+    long-decode: [6, 8, 16384, 4 * 128]) and the window layers' ring
+    ([12, 24, 2048, 512], [18, 8, 4096, 512]) are the layer scan's
+    carry, written in place and read by `flash_decode` through a layer
+    index in its scalar prefetch: every attention call's operands are
+    the WHOLE stacked arrays with a row's K heads merged in the lanes
+    (the kernel's key block is a dense [rows, K * D] tile of them),
+    the program's temporaries are no more than the parent's (about
+    one ring's size: as xs/ys of the scan it kept a second slab,
+    3.2 GB), and no `copy`, `dynamic-slice` or
+    `dynamic-update-slice` gives a slab, a ring or a layer of
+    either."""
+    compiled, slab, ring, name, cfg = decode_window
+    heads, parent_temp = WINDOW_CELLS[name]
     text = compiled.as_text()
     calls = [line for line in text.splitlines()
              if "tpu_custom_call" in line
              and re.search(r"%flash_decode(\.\d+)? = ", line)]
-    # 4 unrolled layers of the head and the period's 4 in the scan
-    assert len(calls) == 8
+    # the unrolled layers of the head and the period's 4 in the scan
+    P = cfg.sliding_pattern
+    head = -(-cfg.first_k_dense // P) * P
+    assert len(calls) == head + P
     kinds = {"attn_window": ring, "attn_global": slab}
+    B, K, D = slab[1], cfg.num_kv_heads, cfg.head_dim
+    assert slab[3:] == ring[3:] == (K * D,)
     for line in calls:
         kind = next(k for k in kinds if f"/{k}/attn/" in line)
         operands = re.search(r"operand_layout_constraints=\{(.*?)\}\}, ",
                              line).group(1)
         dims = re.findall(r"\w+\[([\d,]*)\]", operands)
         whole = ",".join(str(d) for d in kinds[kind])
-        B, H, K, D = slab[1], 32, slab[3], slab[4]
-        assert dims == [f"{B},3", f"{B},{K},{H // K},{D}", whole, whole], dims
-    assert sum("/attn_window/" in c for c in calls) == 6
-    one_ring = math.prod(ring) * 2
-    assert compiled.memory_analysis().temp_size_in_bytes < one_ring
+        assert whole.endswith(f",{K * D}")
+        assert dims == [f"{B},3", f"{B},{K},{heads // K},{D}", whole,
+                        whole], dims
+    assert sum("/attn_window/" in c for c in calls) \
+        == (head + P) * (P - 1) // P
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp <= parent_temp < 1.1 * math.prod(ring) * 2, temp
     held = [slab, slab[1:], ring, ring[1:]]
     shapes = "|".join(",".join(str(d) for d in h) for h in held)
     moved = [line.strip()[:160] for line in text.splitlines()
              if re.search(rf"= \w+\[({shapes})\]\S* (copy|dynamic-slice|"
                           r"dynamic-update-slice)\(", line)]
     assert not moved, moved
+
+
+def test_decode_writes_a_steps_rows_in_place(decode_window):
+    """A step's fresh rows go into the merged caches by scatters that
+    update them in place, two a layer of the program's body (K and V),
+    and the weights that made the rows are not re-laid inside the
+    layer scan (`llama._rows_as`: without its barrier each layer's
+    `wk` / `wv` slice was copied to [hidden, K * D] every step)."""
+    compiled, slab, ring, name, cfg = decode_window
+    text = compiled.as_text()
+    scatters = [line for line in text.splitlines()
+                if re.search(r"ROOT %scatter\S* = bf16\[", line)
+                and "/kv_write/" in line]
+    P = cfg.sliding_pattern
+    head = -(-cfg.first_k_dense // P) * P
+    assert len(scatters) == 2 * (head + P)
+    shapes = {",".join(str(d) for d in s) for s in (slab, ring)}
+    for line in scatters:
+        assert re.search(r"= bf16\[([\d,]*)\]", line).group(1) in shapes
+    K, D, hidden = cfg.num_kv_heads, cfg.head_dim, cfg.hidden_size
+    relaid = [line.strip()[:160] for line in text.splitlines()
+              if re.search(rf"= bf16\[{hidden},{K * D}\]\S* "
+                           r"(copy|fusion)\(", line)]
+    assert not relaid, relaid
 
 
 def test_decode_names_both_attention_kinds(decode_window):
@@ -471,7 +523,9 @@ def test_decode_names_both_attention_kinds(decode_window):
         for phase in ("kv_write", "attn"):
             assert any(f"/layers/" in p and f"/{kind}/{phase}/" in p
                        for p in paths), (kind, phase)
-    for scope in ("moe_router", "moe_experts", "moe_shared"):
+    shared = decode_window[4].num_shared_experts > 0
+    for scope in ("moe_router", "moe_experts") + (("moe_shared",)
+                                                  if shared else ()):
         assert any(f"/mlp/{scope}/" in p for p in paths), scope
 
 
@@ -479,7 +533,7 @@ def test_flash_decode_reads_a_layer_of_a_stacked_slab(one_chip):
     """The kernel handed stacked slabs and a traced layer index: one
     Mosaic call, no layer sliced out beside it."""
     L, S = 4, 4096
-    kv = one_chip((L, B, S, K, D), jnp.bfloat16)
+    kv = one_chip((L, B, S, K * D), jnp.bfloat16)
 
     def f(q, k, v, lo, hi, layer):
         out = flash._flash_decode(q, k, v, lo, hi, D ** -0.5, None,
@@ -501,7 +555,7 @@ def test_flash_decode_at_a_group_of_seven(one_chip, layers, rows):
     of 4096 rows and the 6 global slabs of 16 384, 8 slots: accepted
     by Mosaic as it is, one call, nothing sliced out."""
     slots, heads, kv_heads = 8, 28, 4
-    kv = one_chip((layers, slots, rows, kv_heads, D), jnp.bfloat16)
+    kv = one_chip((layers, slots, rows, kv_heads * D), jnp.bfloat16)
 
     def f(q, k, v, lo, hi, layer):
         out = flash._flash_decode(q, k, v, lo, hi, D ** -0.5, None,

@@ -67,7 +67,10 @@ def test_prefill_handler_exports_engine_result(world):
     want_tok, (wk, wv), wtl, wb = eng.prefill([5, 6, 7])
     assert (tl, b) == (wtl, wb)
     assert tok == want_tok  # greedy: same logits both calls
-    np.testing.assert_array_equal(np.asarray(wk), k)
+    # the wire carries a row's heads apart, [L, 1, S, K, D], whoever
+    # sends; the slab engine's own prefill hands them merged
+    assert k.ndim == 5 and np.asarray(wk).ndim == 4
+    np.testing.assert_array_equal(np.asarray(wk).reshape(k.shape), k)
     with pytest.raises(PDError):
         handler({"ids": []})
 
@@ -158,7 +161,8 @@ def test_pool_failover_order(world):
         assert eng._last_peer == b_url
         want_tok, (wk, wv), wtl, wb = eng._engine.prefill([5, 6, 7])
         assert (tok, tl, bucket) == (want_tok, wtl, wb)
-        np.testing.assert_array_equal(np.asarray(wk), np.asarray(k))
+        np.testing.assert_array_equal(
+            np.asarray(wk).reshape(np.shape(k)), np.asarray(k))
         # peer A took the breaker charge, B did not
         assert eng.pool.peers[0].fails == 1
         assert eng.pool.peers[1].fails == 0

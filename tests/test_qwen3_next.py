@@ -284,8 +284,9 @@ def test_padded_prompt_equals_unpadded(engine, n):
         np.testing.assert_allclose(
             np.asarray(kv[2][name]), np.asarray(exact.rec[name]),
             atol=1e-5, err_msg=name)
-    np.testing.assert_allclose(np.asarray(kv[0][:, :, :n]),
-                               np.asarray(exact.k), atol=1e-5)
+    held = np.asarray(kv[0][:, :, :n])    # the engine's merged rows
+    np.testing.assert_allclose(
+        held, np.asarray(exact.k).reshape(held.shape), atol=1e-5)
 
 
 def test_two_slots_of_different_length_decode_as_each_alone(cut, engine):

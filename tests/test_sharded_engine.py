@@ -80,7 +80,9 @@ def test_tp4_kv_head_sharding_layout():
     params = llama.init_params(jax.random.PRNGKey(0), cfg)
     eng = ShardedInferenceEngine(params, cfg, tp=4, max_slots=2, max_seq=32)
     state = eng.new_state()
-    # KV cache must actually be laid out split over tp on the head dim
+    # KV cache must actually be laid out split over tp on the head dim:
+    # the merged [.., K * Dh] axis, a chip's heads contiguous lanes of it
     shard_shapes = {s.data.shape for s in state.k.addressable_shards}
     K = cfg.num_kv_heads
-    assert all(sh[3] == K // 4 for sh in shard_shapes)
+    assert state.k.shape[3:] == (K * cfg.head_dim,)
+    assert all(sh[3:] == (K // 4 * cfg.head_dim,) for sh in shard_shapes)

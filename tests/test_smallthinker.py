@@ -530,7 +530,7 @@ def test_two_slots_decode_as_each_alone_and_the_counters_read_right(
     from ome_tpu.engine.scheduler import Scheduler
     _, _, w = cut
     st = engine.new_state()
-    assert st.wk.shape == (6, 3, W, 1, 16) and st.k.shape[:3] == (2, 3, 128)
+    assert st.wk.shape == (6, 3, W, 1 * 16) and st.k.shape[:3] == (2, 3, 128)
     seqs, first = {}, {0: 21, 2: 5}
     for slot, n in first.items():
         ids = [int(t) for t in _tokens(n, seed=slot)]
