@@ -16,12 +16,31 @@ EmbeddingEngine (engine/embed.py) instead of the generation stack.
 from __future__ import annotations
 
 import argparse
+import contextlib
 import logging
 import sys
 import threading
 import time
+from typing import Optional
+
+from ..telemetry.startup import StartupTimeline
 
 log = logging.getLogger("ome.engine.serve")
+
+# this process's start-up phases; `main` makes it, first thing
+_startup: Optional[StartupTimeline] = None
+
+
+def _phase(name: str):
+    """One start-up phase (telemetry/scopes.py STARTUP_PHASES), the
+    start-up's counterpart of `Scheduler._phase`: it runs from the end
+    of the phase before it to the end of the `with` block, so `weights`,
+    which `load_engine` closes, takes its part out of the `engine` block
+    around it. Outside `main` (a test that calls `load_engine`) it is
+    nothing."""
+    if _startup is None:
+        return contextlib.nullcontext()
+    return _startup.phase(name)
 
 
 def build_parser() -> argparse.ArgumentParser:
@@ -381,13 +400,14 @@ def _refuse_for_slot_state(args) -> None:
             + "\n  ".join(refused))
 
 
-def load_engine(args, dist=None):
+def load_engine(args, dist=None, ledger=None):
     import jax.numpy as jnp
 
     from ..perf.ledger import ProgramLedger
     from .core import InferenceEngine
 
-    ledger = ProgramLedger(mode=getattr(args, "ledger_mode", "auto"))
+    if ledger is None:
+        ledger = ProgramLedger(mode=getattr(args, "ledger_mode", "auto"))
     dtype = jnp.bfloat16 if args.dtype == "bfloat16" else jnp.float32
     if dist is not None and args.tp <= 1:
         # multi-host slice: tp spans every chip of every host by
@@ -401,16 +421,21 @@ def load_engine(args, dist=None):
     if args.tp > 1:
         from ..parallel.mesh import MeshConfig, build_mesh
         mesh = build_mesh(MeshConfig(tp=args.tp))
-    params, cfg = _load_params_cfg(args, dtype, mesh)
-    if cfg.is_moe and args.tp == 1:
-        # single-device serving uses the ragged grouped-GEMM dispatch;
-        # tp>1 keeps the dense path (shardable through plain GSPMD)
-        cfg = cfg.replace(moe_impl="ragged")
-    if args.quantization in ("int8", "int4", "fp8"):
-        from ..models.quant import quantize_params
-        params = quantize_params(params, mode=args.quantization)
-        log.info("quantized weights to %s (weight-only)",
-                 args.quantization)
+    with _phase("weights"):
+        params, cfg = _load_params_cfg(args, dtype, mesh)
+        if cfg.is_moe and args.tp == 1:
+            # single-device serving uses the ragged grouped-GEMM
+            # dispatch; tp>1 keeps the dense path (shardable through
+            # plain GSPMD)
+            cfg = cfg.replace(moe_impl="ragged")
+        if args.quantization in ("int8", "int4", "fp8"):
+            from ..models.quant import quantize_params
+            params = quantize_params(params, mode=args.quantization)
+            log.info("quantized weights to %s (weight-only)",
+                     args.quantization)
+        if args.tp <= 1:
+            import jax
+            params = jax.tree.map(jnp.asarray, params)  # one transfer
     max_seq = args.max_seq or min(cfg.max_seq_len, 8192)
     _, named_adapters = _adapter_args(args)
     lora_slots = args.lora_slots if args.lora_slots is not None else \
@@ -444,9 +469,6 @@ def load_engine(args, dist=None):
                                       max_seq=max_seq,
                                       prefix_cache_bytes=args.prefix_cache_mb << 20,
                                       ledger=ledger)
-    import jax
-    params = jax.tree.map(jnp.asarray, params)  # one transfer
-
     kv_dtype = getattr(args, "kv_dtype", "bf16")
 
     def build(kv_block, kv_blocks):
@@ -681,234 +703,266 @@ def load_embedder(args):
 
     from .embed import EmbeddingEngine
     dtype = jnp.bfloat16 if args.dtype == "bfloat16" else jnp.float32
-    params, cfg = _load_params_cfg(args, dtype)
-    params = jax.tree.map(jnp.asarray, params)
+    with _phase("weights"):
+        params, cfg = _load_params_cfg(args, dtype)
+        params = jax.tree.map(jnp.asarray, params)
     return EmbeddingEngine(params, cfg, max_seq=args.max_seq)
 
 
 def main(argv=None) -> int:
-    logging.basicConfig(level=logging.INFO,
-                        format="%(asctime)s %(name)s %(message)s")
-    args = build_parser().parse_args(argv)
-    from .. import device
-    cache_dir = device.enable_compile_cache()
-    # the server's programs carry names that a profiler capture is
-    # read by (telemetry/scopes.py). JAX leaves metadata out of the
-    # cache key by default: a program whose operations a release did
-    # not change would then be loaded under the names it was first
-    # compiled with. With metadata in the key a cache entry is found
-    # again only from the same source tree at the same path.
-    import jax
-    jax.config.update("jax_compilation_cache_include_metadata_in_key",
-                      True)
-    if args.faults:
-        from .. import faults
-        faults.install(args.faults)
-        log.warning("fault injection ACTIVE: %s", args.faults)
-    if _adapter_args(args)[0] and args.random_weights:
-        log.error("--adapter merge requires a real checkpoint "
-                  "(incompatible with --random-weights); name=dir "
-                  "multi-LoRA slots work with either")
-        return 2
-    # parse the multi-tenancy flags up front so a bad spec fails fast
-    # instead of after a multi-minute checkpoint load
-    from ..priority import coerce_priority, parse_weight_spec
-    class_weights = None
-    class_wait_caps = None
-    try:
-        if args.class_weights:
-            class_weights = parse_weight_spec(args.class_weights)
-        if args.class_wait_cap:
-            class_wait_caps = {}
-            for spec in args.class_wait_cap:
-                cls, sep, secs = spec.partition("=")
-                if not sep:
-                    raise ValueError(
-                        f"bad --class-wait-cap {spec!r} "
-                        "(expected class=seconds)")
-                class_wait_caps[coerce_priority(cls)] = float(secs)
-    except ValueError as e:
-        log.error("%s", e)
-        return 2
-
-    # join the cross-host rendezvous FIRST (before any jax call) when
-    # the operator injected the LWS contract env (multinode.py:53-58)
-    from . import multihost
-    dist = multihost.init_from_env()
-    control_port = args.control_port or multihost.CONTROL_PORT
-    # named before any weight is loaded, so a launcher that expected
-    # another platform can stop here
-    dev = device.identity()
-    log.info("device: platform=%s kind=%s count=%d (compile cache %s)",
-             dev["platform"], dev["kind"], dev["count"], cache_dir)
-
-    from .scheduler import Scheduler
-    from .server import EngineServer
-    from .tokenizer import load_tokenizer
-
-    if dist is not None and args.task == "embed":
-        # embeddings are stateless single-host programs; a multi-host
-        # embed group would leave followers waiting on a control
-        # channel the embed leader never opens
-        log.error("--task embed does not support multi-host serving "
-                  "(unset JAX_COORDINATOR_ADDRESS or use one process)")
-        return 2
-    prefill_urls = ([args.prefill_peer] if args.prefill_peer else []) \
-        + list(args.prefill_url or [])
-    if args.disaggregation_mode == "decode" and not prefill_urls:
-        log.error("--disaggregation-mode decode requires at least one "
-                  "--prefill-url (or --prefill-peer)")
-        return 2
-
-    if dist is not None and not dist.is_leader:
-        # followers never serve HTTP: they join the mesh, then replay
-        # the leader's op stream (SPMD requires identical programs in
-        # identical order on every process)
-        engine = load_engine(args, dist)
-        sub = multihost.OpSubscriber(dist.coordinator_host,
-                                     control_port)
-        log.info("follower %d/%d replaying leader ops",
-                 dist.process_id, dist.num_processes)
-        try:
-            return multihost.follower_loop(
-                engine, sub,
-                pd_export=(args.disaggregation_mode == "prefill"))
-        finally:
-            sub.close()
-
-    embedder = None
-    pd_prefill = None
-    journal = None
-    reqlog = None
-    span_log = None
-    if args.span_log:
-        from ..telemetry.tracing import SpanLog
-        span_log = SpanLog(args.span_log, component="engine")
-        log.info("span timeline at %s", args.span_log)
-    if args.journal and (args.task == "embed"
-                         or args.disaggregation_mode == "prefill"):
-        log.warning("--journal only applies to generation/decode "
-                    "scheduling; ignoring it for this role")
-    if args.task == "embed":
-        embedder = load_embedder(args)
-        scheduler = _NullScheduler()
-    elif args.disaggregation_mode == "prefill":
-        from .pd import make_pd_prefill_handler
-        engine = load_engine(args, dist)
-        if dist is not None:
-            # multi-host prefill pool: every /pd/prefill compute runs
-            # SPMD across the group via the same op replication the
-            # generation leader uses
-            pub = multihost.OpPublisher(dist.num_processes - 1,
-                                        port=control_port)
-            engine = multihost.ReplicatedEngine(engine, pub)
-        pd_prefill = make_pd_prefill_handler(engine)
-        scheduler = _PrefillNodeScheduler(engine)
-    else:
-        engine = load_engine(args, dist)
-        if args.disaggregation_mode == "decode":
-            from ..telemetry.reqlog import coerce
-            from .pd import RemotePrefillEngine
-            # one shared JSONL reqlog: the server's request records
-            # and the PD client's peer-failure records interleave in
-            # the same file, joinable by trace id
-            reqlog = coerce(args.request_log)
-            engine = RemotePrefillEngine(
-                engine, peer_urls=prefill_urls,
-                timeout=args.pd_attempt_timeout,
-                local_fallback=args.pd_local_fallback,
-                request_log=reqlog,
-                span_log=span_log)
-            log.info("PD decode node: prefill pool %s%s",
-                     prefill_urls,
-                     " (local fallback)" if args.pd_local_fallback
-                     else "")
-        if dist is not None:
-            pub = multihost.OpPublisher(dist.num_processes - 1,
-                                        port=control_port)
-            engine = multihost.ReplicatedEngine(engine, pub)
-        if (dist is None and args.disaggregation_mode == "none"
-                and args.prefix_cache_mb > 0):
-            # cross-replica prefix reuse: a replica with a live prefix
-            # cache is also a prefix DONOR — peers the router's fleet
-            # directory points at this replica fetch hot prefix KV
-            # over the same hardened /pd/prefill path PD uses
-            # (docs/kv-hierarchy.md). int8-pool engines ship blobs
-            # quantized at half the bytes.
-            from .pd import make_pd_prefill_handler
-            pd_prefill = make_pd_prefill_handler(engine)
-        # prefill/decode overlap is single-host only: multi-host
-        # leaders publish ops from ONE thread in execution order
-        # (followers replay strictly sequentially); on PD decode nodes
-        # it moves the remote KV fetch off the decode thread
-        err = check_plan_preconditions(engine, args)
-        if err is not None:
-            log.error("%s", err)
+    # the first statement: `interpreter` ends here
+    global _startup
+    _startup = StartupTimeline()
+    with _phase("device"):
+        logging.basicConfig(level=logging.INFO,
+                            format="%(asctime)s %(name)s %(message)s")
+        args = build_parser().parse_args(argv)
+        from .. import device
+        cache_dir = device.enable_compile_cache()
+        # the server's programs carry names that a profiler capture is
+        # read by (telemetry/scopes.py). JAX leaves metadata out of the
+        # cache key by default: a program whose operations a release did
+        # not change would then be loaded under the names it was first
+        # compiled with. With metadata in the key a cache entry is found
+        # again only from the same source tree at the same path.
+        import jax
+        jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                          True)
+        # compile and cache-load seconds, by stage and by program, from
+        # here on: the ledger hooks JAX's monitoring events, which fire
+        # where something compiles and nowhere else
+        from ..perf.ledger import ProgramLedger
+        ledger = ProgramLedger(mode=args.ledger_mode)
+        ledger.listen()
+        if args.faults:
+            from .. import faults
+            faults.install(args.faults)
+            log.warning("fault injection ACTIVE: %s", args.faults)
+        if _adapter_args(args)[0] and args.random_weights:
+            log.error("--adapter merge requires a real checkpoint "
+                      "(incompatible with --random-weights); name=dir "
+                      "multi-LoRA slots work with either")
             return 2
-        if args.journal:
-            from .journal import RequestJournal
-            provenance = None
+        # parse the multi-tenancy flags up front so a bad spec fails fast
+        # instead of after a multi-minute checkpoint load
+        from ..priority import coerce_priority, parse_weight_spec
+        class_weights = None
+        class_wait_caps = None
+        try:
+            if args.class_weights:
+                class_weights = parse_weight_spec(args.class_weights)
+            if args.class_wait_cap:
+                class_wait_caps = {}
+                for spec in args.class_wait_cap:
+                    cls, sep, secs = spec.partition("=")
+                    if not sep:
+                        raise ValueError(
+                            f"bad --class-wait-cap {spec!r} "
+                            "(expected class=seconds)")
+                    class_wait_caps[coerce_priority(cls)] = float(secs)
+        except ValueError as e:
+            log.error("%s", e)
+            return 2
+
+        # join the cross-host rendezvous FIRST (before any jax call) when
+        # the operator injected the LWS contract env (multinode.py:53-58)
+        from . import multihost
+        dist = multihost.init_from_env()
+        control_port = args.control_port or multihost.CONTROL_PORT
+        # named before any weight is loaded, so a launcher that expected
+        # another platform can stop here
+        dev = device.identity()
+        log.info("device: platform=%s kind=%s count=%d (compile cache %s)",
+                 dev["platform"], dev["kind"], dev["count"], cache_dir)
+
+    with _phase("engine"):
+        from .scheduler import Scheduler
+        from .server import EngineServer
+        from .tokenizer import load_tokenizer
+
+        if dist is not None and args.task == "embed":
+            # embeddings are stateless single-host programs; a multi-host
+            # embed group would leave followers waiting on a control
+            # channel the embed leader never opens
+            log.error("--task embed does not support multi-host serving "
+                      "(unset JAX_COORDINATOR_ADDRESS or use one process)")
+            return 2
+        prefill_urls = ([args.prefill_peer] if args.prefill_peer else []) \
+            + list(args.prefill_url or [])
+        if args.disaggregation_mode == "decode" and not prefill_urls:
+            log.error("--disaggregation-mode decode requires at least one "
+                      "--prefill-url (or --prefill-peer)")
+            return 2
+
+        if dist is not None and not dist.is_leader:
+            # followers never serve HTTP: they join the mesh, then replay
+            # the leader's op stream (SPMD requires identical programs in
+            # identical order on every process)
+            engine = load_engine(args, dist, ledger)
+            sub = multihost.OpSubscriber(dist.coordinator_host,
+                                         control_port)
+            log.info("follower %d/%d replaying leader ops",
+                     dist.process_id, dist.num_processes)
+            try:
+                return multihost.follower_loop(
+                    engine, sub,
+                    pd_export=(args.disaggregation_mode == "prefill"))
+            finally:
+                sub.close()
+
+        embedder = None
+        pd_prefill = None
+        journal = None
+        reqlog = None
+        span_log = None
+        if args.span_log:
+            from ..telemetry.tracing import SpanLog
+            span_log = SpanLog(args.span_log, component="engine")
+            log.info("span timeline at %s", args.span_log)
+        if args.journal and (args.task == "embed"
+                             or args.disaggregation_mode == "prefill"):
+            log.warning("--journal only applies to generation/decode "
+                        "scheduling; ignoring it for this role")
+        if args.task == "embed":
+            embedder = load_embedder(args)
+            scheduler = _NullScheduler()
+        elif args.disaggregation_mode == "prefill":
+            from .pd import make_pd_prefill_handler
+            engine = load_engine(args, dist, ledger)
+            if dist is not None:
+                # multi-host prefill pool: every /pd/prefill compute runs
+                # SPMD across the group via the same op replication the
+                # generation leader uses
+                pub = multihost.OpPublisher(dist.num_processes - 1,
+                                            port=control_port)
+                engine = multihost.ReplicatedEngine(engine, pub)
+            pd_prefill = make_pd_prefill_handler(engine)
+            scheduler = _PrefillNodeScheduler(engine)
+        else:
+            engine = load_engine(args, dist, ledger)
             if args.disaggregation_mode == "decode":
-                # admit records carry the PD topology, so a resumed
-                # process (and the chaos harness) can tell these
-                # requests re-prefill over the pool on replay
-                provenance = {"mode": "pd-decode",
-                              "peers": prefill_urls}
-            journal = RequestJournal(
-                args.journal, fsync=args.journal_fsync,
-                compact_bytes=args.journal_compact_mb << 20,
-                provenance=provenance)
-            log.info("request journal at %s (fsync=%s)",
-                     journal.path, args.journal_fsync)
-        from ..telemetry.flight import FlightRecorder
-        flight = FlightRecorder(capacity=max(args.flight_events, 16))
-        scheduler = Scheduler(engine, overlap=dist is None,
-                              max_restarts=args.max_restarts,
-                              max_queue_wait=args.max_queue_wait,
-                              pipeline_depth=args.pipeline_depth,
-                              spec_tokens=args.spec_tokens,
-                              steps_per_dispatch=args.steps_per_dispatch,
-                              journal=journal,
-                              span_log=span_log,
-                              flight=flight,
-                              flight_dump_dir=args.flight_dump_dir,
-                              class_weights=class_weights,
-                              class_wait_caps=class_wait_caps,
-                              priority_scheduling=not
-                              args.no_priority_scheduling)
-    log.info("device memory after load, GB per device: %s",
-             device.memory_gb())
-    tok = load_tokenizer(args.model_dir)
-    name = args.model_name or args.model_dir.rstrip("/").rsplit("/", 1)[-1]
-    # measured weight-fetch throughput from the published fetch
-    # manifest, advertised on /ready for the router's cold-start
-    # Retry-After math (docs/model-fleet.md); None when the tree was
-    # staged by something other than the weight plane
-    from ..modelagent import weightplane
-    fetch_bps = weightplane.published_fetch_bps(args.model_dir)
-    server = EngineServer(scheduler, tokenizer=tok, model_name=name,
-                          fetch_bps=fetch_bps, device=dev,
-                          host=args.host, port=args.port,
-                          embedder=embedder, pd_prefill=pd_prefill,
-                          request_log=(reqlog if reqlog is not None
-                                       else args.request_log),
-                          profile_dir=args.profile_dir,
-                          debug_endpoints=args.debug_endpoints,
-                          # structured outputs work in every generation
-                          # mode: masks ship inside the replicated op
-                          # stream (multi-host) and the first token's
-                          # mask rides the /pd/prefill request (PD)
-                          structured=embedder is None)
-    log.info("serving %s on %s:%d (%s)", name, args.host, server.port,
-             "embeddings" if embedder else
-             f"slots={scheduler.engine.max_slots}")
-    # restart resume BEFORE serving: unfinished requests from the
-    # previous process re-enter the queue ahead of new traffic
-    if journal is not None:
-        resume = getattr(scheduler, "resume_from_journal", None)
-        if resume is not None:
-            resume()
-    server.start()
+                from ..telemetry.reqlog import coerce
+                from .pd import RemotePrefillEngine
+                # one shared JSONL reqlog: the server's request records
+                # and the PD client's peer-failure records interleave in
+                # the same file, joinable by trace id
+                reqlog = coerce(args.request_log)
+                engine = RemotePrefillEngine(
+                    engine, peer_urls=prefill_urls,
+                    timeout=args.pd_attempt_timeout,
+                    local_fallback=args.pd_local_fallback,
+                    request_log=reqlog,
+                    span_log=span_log)
+                log.info("PD decode node: prefill pool %s%s",
+                         prefill_urls,
+                         " (local fallback)" if args.pd_local_fallback
+                         else "")
+            if dist is not None:
+                pub = multihost.OpPublisher(dist.num_processes - 1,
+                                            port=control_port)
+                engine = multihost.ReplicatedEngine(engine, pub)
+            if (dist is None and args.disaggregation_mode == "none"
+                    and args.prefix_cache_mb > 0):
+                # cross-replica prefix reuse: a replica with a live prefix
+                # cache is also a prefix DONOR — peers the router's fleet
+                # directory points at this replica fetch hot prefix KV
+                # over the same hardened /pd/prefill path PD uses
+                # (docs/kv-hierarchy.md). int8-pool engines ship blobs
+                # quantized at half the bytes.
+                from .pd import make_pd_prefill_handler
+                pd_prefill = make_pd_prefill_handler(engine)
+            # prefill/decode overlap is single-host only: multi-host
+            # leaders publish ops from ONE thread in execution order
+            # (followers replay strictly sequentially); on PD decode nodes
+            # it moves the remote KV fetch off the decode thread
+            err = check_plan_preconditions(engine, args)
+            if err is not None:
+                log.error("%s", err)
+                return 2
+            if args.journal:
+                from .journal import RequestJournal
+                provenance = None
+                if args.disaggregation_mode == "decode":
+                    # admit records carry the PD topology, so a resumed
+                    # process (and the chaos harness) can tell these
+                    # requests re-prefill over the pool on replay
+                    provenance = {"mode": "pd-decode",
+                                  "peers": prefill_urls}
+                journal = RequestJournal(
+                    args.journal, fsync=args.journal_fsync,
+                    compact_bytes=args.journal_compact_mb << 20,
+                    provenance=provenance)
+                log.info("request journal at %s (fsync=%s)",
+                         journal.path, args.journal_fsync)
+            from ..telemetry.flight import FlightRecorder
+            flight = FlightRecorder(capacity=max(args.flight_events, 16))
+            scheduler = Scheduler(engine, overlap=dist is None,
+                                  max_restarts=args.max_restarts,
+                                  max_queue_wait=args.max_queue_wait,
+                                  pipeline_depth=args.pipeline_depth,
+                                  spec_tokens=args.spec_tokens,
+                                  steps_per_dispatch=args.steps_per_dispatch,
+                                  journal=journal,
+                                  span_log=span_log,
+                                  flight=flight,
+                                  flight_dump_dir=args.flight_dump_dir,
+                                  class_weights=class_weights,
+                                  class_wait_caps=class_wait_caps,
+                                  priority_scheduling=not
+                                  args.no_priority_scheduling)
+    with _phase("tokenizer"):
+        log.info("device memory after load, GB per device: %s",
+                 device.memory_gb())
+        tok = load_tokenizer(args.model_dir)
+    with _phase("listen"):
+        name = args.model_name or args.model_dir.rstrip("/").rsplit("/", 1)[-1]
+        # measured weight-fetch throughput from the published fetch
+        # manifest, advertised on /ready for the router's cold-start
+        # Retry-After math (docs/model-fleet.md); None when the tree was
+        # staged by something other than the weight plane
+        from ..modelagent import weightplane
+        fetch_bps = weightplane.published_fetch_bps(args.model_dir)
+        server = EngineServer(scheduler, tokenizer=tok, model_name=name,
+                              fetch_bps=fetch_bps, device=dev,
+                              host=args.host, port=args.port,
+                              embedder=embedder, pd_prefill=pd_prefill,
+                              startup=_startup,
+                              request_log=(reqlog if reqlog is not None
+                                           else args.request_log),
+                              profile_dir=args.profile_dir,
+                              debug_endpoints=args.debug_endpoints,
+                              # structured outputs work in every generation
+                              # mode: masks ship inside the replicated op
+                              # stream (multi-host) and the first token's
+                              # mask rides the /pd/prefill request (PD)
+                              structured=embedder is None)
+        log.info("serving %s on %s:%d (%s)", name, args.host, server.port,
+                 "embeddings" if embedder else
+                 f"slots={scheduler.engine.max_slots}")
+        # restart resume BEFORE serving: unfinished requests from the
+        # previous process re-enter the queue ahead of new traffic
+        if journal is not None:
+            resume = getattr(scheduler, "resume_from_journal", None)
+            if resume is not None:
+                resume()
+        server.start()
+    # ready: compile seconds count as `serving` from here, and the one
+    # list of phases goes to /metrics, /health and the span log
+    ledger.mark_serving()
+    took = _startup.ready()
+    if not ledger.bound:
+        ledger.bind(server.registry)  # a role with no scheduler
+    _startup.publish(
+        server.registry.gauge(
+            "ome_engine_startup_phase_seconds",
+            "Seconds of each start-up phase; the phases tile process "
+            "creation to ready", labelnames=("phase",)),
+        server.registry.gauge(
+            "ome_engine_startup_seconds",
+            "Seconds from process creation to the listener up"))
+    _startup.write_spans(span_log, getattr(scheduler, "_span_ctx", None))
+    log.info("ready %.2fs after process creation: %s", took, ", ".join(
+        f"{name} {secs:.2f}" for name, secs in _startup.seconds().items()))
     ctl = DrainController(server, scheduler, grace=args.drain_grace,
                           journal=journal)
     try:

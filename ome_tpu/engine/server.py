@@ -78,8 +78,13 @@ class EngineServer:
                  request_log=None, profile_dir: Optional[str] = None,
                  debug_endpoints: bool = False,
                  fetch_bps: Optional[float] = None,
-                 device: Optional[dict] = None):
+                 device: Optional[dict] = None,
+                 startup=None):
         self.scheduler = scheduler
+        # the process's start-up phases (telemetry/startup.py
+        # StartupTimeline; engine/serve.py owns it): /health serves
+        # them, the first admitted request is marked on it
+        self.startup = startup
         # {platform, kind, count} of the accelerator THIS process
         # serves on, as JAX reports it (ome_tpu/device.identity): a
         # caller reading /health learns the device from the process
@@ -203,6 +208,10 @@ class EngineServer:
                             sched, "degradations", {}),
                         "device": outer.device,
                         "engine": outer._engine_facts(),
+                        # phases from process creation to ready, and
+                        # when the first request was admitted
+                        "startup": outer.startup.health()
+                        if outer.startup is not None else None,
                         "uptime_s": round(
                             time.time() - outer.started_at, 1)})
                 elif self.path == "/ready":
@@ -317,6 +326,9 @@ class EngineServer:
                     "device": led.device_spec(),
                     "mode": led.mode,
                     "count": len(led),
+                    # compile seconds by stage and cache events of the
+                    # process; each entry carries its own share
+                    "compile": led.compile_totals(),
                     "programs": led.snapshot()})
 
             def _debug_state(self):
@@ -620,6 +632,8 @@ class EngineServer:
                     return self._json(503, {"error": str(e)},
                                       headers={"Retry-After":
                                           outer._retry_after()})
+                if outer.startup is not None:
+                    outer.startup.mark_first_request()
                 # admitted: this replica is about to hold the prompt's
                 # prefix KV — advertise its digest to the fleet
                 outer._note_prefix(payload)

@@ -8,8 +8,11 @@ carries a real HF tokenizer, `load_tokenizer` upgrades to it via
 
 from __future__ import annotations
 
+import logging
 import os
 from typing import List, Optional, Sequence
+
+log = logging.getLogger("ome.engine.tokenizer")
 
 PAD_ID, BOS_ID, EOS_ID = 0, 1, 2
 _BYTE_OFFSET = 3
@@ -79,6 +82,8 @@ def load_tokenizer(model_dir: Optional[str] = None):
         try:
             from transformers import AutoTokenizer
             return HFTokenizer(AutoTokenizer.from_pretrained(model_dir))
-        except Exception:
-            pass
+        except Exception as e:
+            log.warning("tokenizer.json in %s did not load (%s: %s); "
+                        "serving the byte-level tokenizer", model_dir,
+                        type(e).__name__, e)
     return ByteTokenizer()

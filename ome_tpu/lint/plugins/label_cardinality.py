@@ -15,8 +15,9 @@ statically bounded:
     keys of a module-level string-keyed dict (``.items()`` /
     ``.keys()`` / the dict itself), or a fixed enum of another
     module (``PRIORITY_CLASSES`` — the tenant-class vocabulary of
-    ome_tpu/priority.py; ``SCHED_PHASES`` — the scheduler's step
-    phases, ome_tpu/telemetry/scopes.py).
+    ome_tpu/priority.py; ``SCHED_PHASES``, ``STARTUP_PHASES``,
+    ``COMPILE_STAGES``, ``COMPILE_WHEN``, ``COMPILE_OUTCOMES`` — the
+    fixed tuples of ome_tpu/telemetry/scopes.py).
 
 The dict-splat spelling ``labels(**{"class": c})`` — required because
 ``class`` is a Python keyword — is checked key-by-key the same way;
@@ -38,9 +39,12 @@ from ..context import Context
 from ..core import Finding, Project, Rule, SourceFile
 
 # enums defined outside the checked file that are bounded by
-# construction: the tenant priority classes and the scheduler's step
-# phases (telemetry/scopes.py)
-BOUNDED_ENUM_NAMES = frozenset({"PRIORITY_CLASSES", "SCHED_PHASES"})
+# construction: the tenant priority classes, and the fixed tuples of
+# telemetry/scopes.py: the scheduler's step phases, the start-up
+# phases, and the stages, times and cache outcomes of a compile
+BOUNDED_ENUM_NAMES = frozenset({
+    "PRIORITY_CLASSES", "SCHED_PHASES", "STARTUP_PHASES",
+    "COMPILE_STAGES", "COMPILE_WHEN", "COMPILE_OUTCOMES"})
 
 
 def _is_const_seq(node: ast.AST) -> bool:

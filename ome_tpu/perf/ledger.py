@@ -24,10 +24,28 @@ set is bounded by construction: programs are compiled, and
 compilation is expensive — a serving process accumulates a handful
 of entries, not a stream.
 
+The ledger also keeps what compiling cost (PR 39). `listen()` hooks
+JAX's monitoring events, which fire on compile paths only and never
+on the dispatch of a compiled program: seconds by stage
+(telemetry/scopes.py `COMPILE_STAGES`) and persistent-cache hits and
+misses, summed for the process and booked on the entry whose
+`capture` ran last on the compiling thread (what arrives before the
+thread's next `capture` is that program's; what arrives before its
+first goes to `other`). A program is compiled ONCE: the dispatch
+that follows `capture` finds the jaxpr, the lowering and the
+executable that `_introspect`'s `fn.lower(...).compile()` left in
+JAX's own caches, so what JAX reports from inside `_build_entry` is
+that program's trace, lowering and compile (or cache load), booked
+under those stages, and the stage `introspect` is what is left of
+`_build_entry`'s wall time: the cost analyses, the compiled text and
+the pass over its instructions.
+
 Surfaces: GET /debug/programs (guarded by --debug-endpoints),
 `ome_engine_program_flops` / `ome_engine_program_bytes` gauges,
-attrs on `engine.decode_chunk` spans, and the POST /debug/profile
-response body.
+`ome_engine_compile_seconds_total{stage,when}` /
+`ome_engine_compile_events_total{outcome}` counters, the
+`program_compiled` flight event, attrs on `engine.decode_chunk`
+spans, and the POST /debug/profile response body.
 """
 
 from __future__ import annotations
@@ -41,6 +59,8 @@ from typing import Dict, List, Optional
 
 from .. import device
 from ..ops import collect_declines
+from ..telemetry.scopes import (COMPILE_OUTCOMES, COMPILE_STAGES,
+                                COMPILE_WHEN, PROGRAM_COMPILED)
 
 # Published per-chip peaks, keyed by a substring of `device_kind`:
 # HBM bandwidth (GB/s) and bf16 peak (TFLOP/s). Source: Google Cloud
@@ -61,7 +81,68 @@ _CPU_SPEC = {"hbm_gbps": 50.0, "peak_tflops": 0.2}
 
 LEDGER_MODES = ("auto", "full", "model", "off")
 
+# JAX's monitoring events (jax/_src/dispatch.py, compiler.py,
+# compilation_cache.py) -> the stage or outcome each is booked under
+_DURATION_STAGE = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend_compile",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_load",
+}
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+_EVENT_OUTCOME = {
+    "/jax/compilation_cache/cache_hits": "cache_hit",
+    "/jax/compilation_cache/cache_misses": "cache_miss",
+}
+# the entry's name for compile events that belong to no captured
+# program: the random-weights init, `jit_convert_element_type` and the
+# other helpers a thread compiles before its first `capture`
+OTHER_PROGRAM = "other"
+
 log = logging.getLogger("ome.perf.ledger")
+
+# jax.monitoring keeps listeners for the life of the process and
+# cannot drop one by owner: the two callbacks are registered once and
+# (three with the scalar one) forward to whichever ledger called
+# `listen()` last
+_listening: Optional["ProgramLedger"] = None
+_registered = False
+
+
+def _on_duration(event: str, duration: float, **_kw) -> None:
+    led = _listening
+    if led is not None and event in _DURATION_STAGE:
+        led._on_duration(_DURATION_STAGE[event], duration)
+
+
+def _on_scalar(event: str, _value, **_kw) -> None:
+    # JAX marks the START of a timed stretch with a scalar of the
+    # same name. Only tracing nests (a jitted function called inside
+    # a traced body is traced inside it, and both report): the depth
+    # says which report is the outermost
+    led = _listening
+    if led is not None and event == _TRACE_EVENT:
+        led._tl.depth += 1
+
+
+def _on_event(event: str, **_kw) -> None:
+    led = _listening
+    if led is not None and event in _EVENT_OUTCOME:
+        led._on_outcome(_EVENT_OUTCOME[event])
+
+
+class _ThreadBook(threading.local):
+    """What one thread's compile events are booked by."""
+    entry: Optional[dict] = None   # captured last on this thread
+    depth = 0        # traces under way, one inside the other
+    loaded = 0.0     # cache retrieval inside the compile under way
+    booked = 0.0     # seconds booked so far (`capture` takes what JAX
+    #                  reported from inside `_build_entry` off its wall)
+
+
+def _compile_book() -> dict:
+    return {"compile_s": dict.fromkeys(COMPILE_STAGES, 0.0),
+            "cache": None}
 
 
 def device_spec(device=None) -> Dict[str, object]:
@@ -191,6 +272,19 @@ class ProgramLedger:
         self._lock = threading.Lock()
         self._entries: "OrderedDict[str, dict]" = OrderedDict()
         self._last: Optional[dict] = None
+        self._tl = _ThreadBook()
+        # compile seconds {(stage, when): s} and cache events
+        # {outcome: n} of the process; exported through the counters
+        # below once `bind` has a registry
+        self._compile_s = {(stage, when): 0.0 for stage in COMPILE_STAGES
+                           for when in COMPILE_WHEN}
+        self._compile_events = dict.fromkeys(COMPILE_OUTCOMES, 0)
+        self._when = COMPILE_WHEN[0]
+        self._other = dict(_compile_book(), program=OTHER_PROGRAM)
+        # what the listeners themselves cost: calls and wall seconds
+        self._listener = [0, 0.0]
+        self._c_compile_s: Dict[tuple, object] = {}
+        self._c_compile_events: Dict[str, object] = {}
         self._g_flops = None
         self._g_bytes = None
         self._spec: Optional[dict] = None
@@ -217,12 +311,57 @@ class ProgramLedger:
             "HBM bytes moved per dispatch of each compiled engine "
             "program, from XLA cost_analysis (or the analytic model "
             "off-TPU)", labelnames=("program",))
+        c_seconds = registry.counter(
+            "ome_engine_compile_seconds_total",
+            "Seconds spent compiling or loading programs, by stage, "
+            "before (startup) and after (serving) the listener was "
+            "up; work of the compiling threads, not wall time",
+            labelnames=("stage", "when"))
+        c_events = registry.counter(
+            "ome_engine_compile_events_total",
+            "Persistent compile cache entries read (cache_hit) and "
+            "written after a compile (cache_miss)",
+            labelnames=("outcome",))
         if flight is not None:
             self.flight = flight
         with self._lock:
             entries = list(self._entries.values())
+            # every series exists from the bind on, at 0 or at what
+            # was compiled before it (the random-weights init)
+            for stage in COMPILE_STAGES:
+                for when in COMPILE_WHEN:
+                    child = c_seconds.labels(stage=stage, when=when)
+                    child.inc(self._compile_s[(stage, when)])
+                    self._c_compile_s[(stage, when)] = child
+            for outcome in COMPILE_OUTCOMES:
+                child = c_events.labels(outcome=outcome)
+                child.inc(self._compile_events[outcome])
+                self._c_compile_events[outcome] = child
         for e in entries:
             self._export(e)
+
+    def listen(self) -> None:
+        """Book JAX's compile events on this ledger from now on (the
+        serving process calls this once, in `main`). Costs nothing
+        where nothing compiles."""
+        global _listening, _registered
+        _listening = self
+        if not _registered:
+            import jax.monitoring
+            jax.monitoring.register_event_duration_secs_listener(
+                _on_duration)
+            jax.monitoring.register_event_listener(_on_event)
+            jax.monitoring.register_scalar_listener(_on_scalar)
+            _registered = True
+
+    def mark_serving(self) -> None:
+        """`server.start()` returned: compile seconds from here on
+        count under `when="serving"`."""
+        self._when = COMPILE_WHEN[1]
+
+    @property
+    def bound(self) -> bool:
+        return self._g_flops is not None
 
     def device_spec(self) -> Dict[str, object]:
         if self._spec is None:
@@ -252,14 +391,23 @@ class ProgramLedger:
             entry = self._entries.get(key)
             if entry is not None:
                 entry["dispatches"] += 1
-                self._last = entry
+                self._last = self._tl.entry = entry
                 return entry
+        # `introspect` is the wall time of building the entry less
+        # what JAX reported from inside it (booked on the entry, under
+        # its own stages, as it arrived)
+        tl = self._tl
+        booked = tl.booked
+        t0 = time.monotonic()
         entry = self._build_entry(key, name, static_desc, fn, args,
                                   static_kwargs, model)
+        took = time.monotonic() - t0
+        own = max(took - (tl.booked - booked), 0.0)
         with self._lock:
             entry = self._entries.setdefault(key, entry)
             entry["dispatches"] += 1
-            self._last = entry
+            self._last = tl.entry = entry
+        self._book(entry, "introspect", own)
         self._export(entry)
         if self.flight is not None:
             self.flight.record(
@@ -294,7 +442,12 @@ class ProgramLedger:
             "platform": spec["platform"],
             "dispatches": 0,
             "captured_unix": time.time(),
+            # seconds by COMPILE_STAGES and "hit" | "miss" | None of
+            # the persistent cache (a miss anywhere wins), `listen()`
+            **_compile_book(),
         }
+        # compile events from here on are this program's
+        self._tl.entry = entry
         if self._resolved_mode() == "full" and fn is not None:
             self._introspect(entry, fn, args, static_kwargs)
         entry["expected_ms"] = roofline_ms(
@@ -376,6 +529,70 @@ class ProgramLedger:
                         "— keeping the analytic model estimate",
                         stage, program, exc)
 
+    # -- compile events ------------------------------------------------
+
+    def _on_duration(self, stage: str, seconds: float) -> None:
+        t0 = time.perf_counter()
+        tl = self._tl
+        nested = False
+        if stage == "trace":
+            nested = tl.depth > 1   # inside a trace that reports it too
+            tl.depth = max(tl.depth - 1, 0)
+        elif stage == "cache_load":
+            tl.loaded += seconds
+        elif stage == "backend_compile":
+            # JAX times the cache's retrieval inside this one
+            seconds = max(seconds - tl.loaded, 0.0)
+            tl.loaded = 0.0
+        if not nested:
+            tl.booked += seconds
+            self._book(tl.entry or self._other, stage, seconds)
+        self._listener[0] += 1
+        self._listener[1] += time.perf_counter() - t0
+
+    def _on_outcome(self, outcome: str) -> None:
+        t0 = time.perf_counter()
+        entry = self._tl.entry or self._other
+        with self._lock:
+            self._compile_events[outcome] += 1
+            child = self._c_compile_events.get(outcome)
+            if entry["cache"] != "miss":   # a miss anywhere wins
+                entry["cache"] = outcome[len("cache_"):]
+        if child is not None:
+            child.inc()
+        self._listener[0] += 1
+        self._listener[1] += time.perf_counter() - t0
+
+    def _book(self, entry: dict, stage: str, seconds: float) -> None:
+        key = (stage, self._when)
+        with self._lock:
+            entry["compile_s"][stage] += seconds
+            self._compile_s[key] += seconds
+            child = self._c_compile_s.get(key)
+            cache = entry["cache"]
+        if child is not None:
+            child.inc(seconds)
+            if self.flight is not None:
+                self.flight.record(
+                    PROGRAM_COMPILED, program=entry["program"],
+                    stage=stage, seconds=round(seconds, 6), cache=cache)
+
+    def compile_totals(self) -> dict:
+        """{seconds: {stage: {when: s}}, events: {outcome: n},
+        other: the book of events no program owns, listeners: what
+        the callbacks cost}: the /debug/programs body beside the
+        entries."""
+        with self._lock:
+            seconds = {stage: {when: self._compile_s[(stage, when)]
+                               for when in COMPILE_WHEN}
+                       for stage in COMPILE_STAGES}
+            return {"seconds": seconds,
+                    "events": dict(self._compile_events),
+                    "other": dict(self._other,
+                                  compile_s=dict(self._other["compile_s"])),
+                    "listeners": {"calls": self._listener[0],
+                                  "seconds": self._listener[1]}}
+
     # -- reads ---------------------------------------------------------
 
     def last_dispatch(self) -> Optional[dict]:
@@ -388,7 +605,8 @@ class ProgramLedger:
         """Entry copies in first-compile order (the /debug/programs
         body)."""
         with self._lock:
-            return [dict(e) for e in self._entries.values()]
+            return [dict(e, compile_s=dict(e["compile_s"]))
+                    for e in self._entries.values()]
 
     def summary(self) -> List[dict]:
         """Compact {program, expected_ms, source} list — rides along

@@ -1452,6 +1452,31 @@ def _parse_selector(s: str) -> Dict[str, str]:
 
 
 def main(argv=None) -> int:
+    # the first statement: the `interpreter` phase ends here, and
+    # everything up to the listener is `listen`
+    from ..telemetry.startup import StartupTimeline
+    startup = StartupTimeline()
+    with startup.phase("listen"):
+        srv = _start(argv)
+    startup.ready()
+    startup.publish(
+        srv.router.registry.gauge(
+            "ome_router_startup_phase_seconds",
+            "Seconds of each start-up phase; the phases tile process "
+            "creation to ready", labelnames=("phase",)),
+        srv.router.registry.gauge(
+            "ome_router_startup_seconds",
+            "Seconds from process creation to the listener up"))
+    try:
+        threading.Event().wait()
+    except KeyboardInterrupt:
+        srv.stop()
+    return 0
+
+
+def _start(argv=None) -> "RouterServer":
+    """Parse the command line, build the router and start its
+    listener."""
     p = argparse.ArgumentParser(prog="ome-router")
     p.add_argument("--backend", action="append", default=[],
                    help="engine URL (repeatable); pool prefix with "
@@ -1577,11 +1602,7 @@ def main(argv=None) -> int:
                  args.slo_spec, args.slo_interval)
     log.info("router on :%d over %d backends (policy=%s)", srv.port,
              len(backends), args.policy)
-    try:
-        threading.Event().wait()
-    except KeyboardInterrupt:
-        srv.stop()
-    return 0
+    return srv
 
 
 if __name__ == "__main__":

@@ -99,3 +99,44 @@ def scoped(name: str):
                 return fn(*args, **kwargs)
         return inner
     return deco
+
+
+# -- start-up and compilation (metric label values, not capture names) --
+# Appended below `scoped` on purpose: its line numbers are part of
+# every traced operation's location, and with metadata in the compile
+# cache's key (engine/serve.py) a moved line is a new key.
+
+# ome_engine_startup_phase_seconds{phase=...} / ome_router_...: the
+# phases of a process's start, in order; defined where the router,
+# which never imports JAX, can reach them
+from .startup import STARTUP_PHASES  # noqa: E402,F401
+
+# ome_engine_compile_seconds_total{stage=...}: where compiling a
+# program spends its time, as JAX's monitoring events report it
+# (perf/ledger.py books them, by program, on /debug/programs too)
+#   trace            jaxpr_trace_duration of the outermost traced
+#                    function (a jitted function called inside a
+#                    traced body reports too; its time is in the outer)
+#   lower            jaxpr_to_mlir_module_duration: jaxpr -> StableHLO
+#   backend_compile  backend_compile_duration less the cache retrieval
+#                    inside it: XLA's compile on a miss; on a hit what
+#                    is left is the hashing of the cache key
+#   cache_load       cache_retrieval_time_sec: an executable read from
+#                    the persistent cache and loaded
+#   introspect       the wall time of ProgramLedger._build_entry at a
+#                    program's first dispatch LESS what JAX reported
+#                    from inside it (that is the program's one trace,
+#                    lowering and compile, booked above: the dispatch
+#                    reuses them): the cost analyses, the compiled
+#                    text, the pass over its instructions
+# Disjoint on a thread, so they add up to seconds of work.
+COMPILE_STAGES = ("trace", "lower", "backend_compile", "cache_load",
+                  "introspect")
+# {when=...}: before `server.start()` returned, or after
+COMPILE_WHEN = ("startup", "serving")
+# ome_engine_compile_events_total{outcome=...}: a persistent-cache
+# entry read, or one written after a compile
+COMPILE_OUTCOMES = ("cache_hit", "cache_miss")
+# flight event of one compile stage of one program, once the scheduler
+# holds the ledger: {program, stage, seconds, cache}
+PROGRAM_COMPILED = "program_compiled"
