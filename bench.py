@@ -430,11 +430,14 @@ def main() -> None:
             lp = per[l]
             h = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
             q = _proj(h, lp["wq"], cfg.dtype,
-                      out_dims=(cfg.num_heads, cfg.head_dim))
+                      out_dims=(cfg.num_heads, cfg.head_dim),
+                      out_major=True)
             k = _proj(h, lp["wk"], cfg.dtype,
-                      out_dims=(cfg.num_kv_heads, cfg.head_dim))
+                      out_dims=(cfg.num_kv_heads, cfg.head_dim),
+                      out_major=True)
             v = _proj(h, lp["wv"], cfg.dtype,
-                      out_dims=(cfg.num_kv_heads, cfg.head_dim))
+                      out_dims=(cfg.num_kv_heads, cfg.head_dim),
+                      out_major=True)
             q = apply_rope(q, positions, freqs)
             k = apply_rope(k, positions, freqs)
             # the slab's rows lie merged, [B, S, K * Dh] (llama.KVCache)
@@ -658,11 +661,14 @@ def main() -> None:
                 lp = per[l]
                 h = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
                 q = _proj(h, lp["wq"], cfg.dtype,
-                          out_dims=(cfg.num_heads, cfg.head_dim))
+                          out_dims=(cfg.num_heads, cfg.head_dim),
+                          out_major=True)
                 k = _proj(h, lp["wk"], cfg.dtype,
-                          out_dims=(cfg.num_kv_heads, cfg.head_dim))
+                          out_dims=(cfg.num_kv_heads, cfg.head_dim),
+                          out_major=True)
                 v = _proj(h, lp["wv"], cfg.dtype,
-                          out_dims=(cfg.num_kv_heads, cfg.head_dim))
+                          out_dims=(cfg.num_kv_heads, cfg.head_dim),
+                          out_major=True)
                 q = apply_rope(q, positions, freqs)
                 k = apply_rope(k, positions, freqs)
                 if quantized:

@@ -186,9 +186,15 @@ class _Stacker:
 def convert_llama(ckpt: Checkpoint, cfg, dtype=None) -> Dict[str, Any]:
     """Map HF checkpoint names/layouts onto the llama.py param tree.
 
-    HF linear weights are [out, in] (y = W x); the model's einsums take
-    [in, out]-shaped factors, so every projection transposes, and
-    attention projections reshape the fused head dim into [heads, Dh].
+    HF linear weights are [out, in] (y = W x). The model's matrices
+    are in-major, [in, out], so those projections transpose. The
+    attention projections out of the hidden size (wq / wk / wv and
+    the output gate w_ogate) are the exception: they lie OUT-MAJOR,
+    [heads, Dh, D], which is HF's own [heads * Dh, D] with the fused
+    head dim split and NO transpose. That is the order the decode
+    step's dot reads them in, so no program re-lays them out before
+    use (llama._proj, llama._init_layer_block). wo [H, Dh, D]
+    transposes as before.
 
     DeepSeek (MLA) checkpoints additionally split kv_b_proj into the
     absorbed-path factors w_uk/w_uv, and route the first_k_dense
@@ -217,6 +223,10 @@ def convert_llama(ckpt: Checkpoint, cfg, dtype=None) -> Dict[str, Any]:
 
     def linear_in_out(name: str) -> np.ndarray:
         return take(name).T  # [out,in] -> [in,out]
+
+    def out_major(name: str, heads: int) -> np.ndarray:
+        # [heads * Dh, D] -> [heads, Dh, D]: HF's order, heads split
+        return take(name).reshape(heads, -1, D)
 
     for li in range(L):
         p = f"model.layers.{li}."
@@ -285,14 +295,12 @@ def convert_llama(ckpt: Checkpoint, cfg, dtype=None) -> Dict[str, Any]:
         elif hybrid:
             # gated attention: q_proj gives, per head, a query and a
             # gate of head_dim each
-            qg = take(p + "self_attn.q_proj.weight").T.reshape(
-                D, H, 2 * Dh)
-            st.put("wq", i, qg[..., :Dh])
-            st.put("w_ogate", i, qg[..., Dh:])
-            st.put("wk", i,
-                   take(p + "self_attn.k_proj.weight").T.reshape(D, K, Dh))
-            st.put("wv", i,
-                   take(p + "self_attn.v_proj.weight").T.reshape(D, K, Dh))
+            qg = take(p + "self_attn.q_proj.weight").reshape(
+                H, 2 * Dh, D)
+            st.put("wq", i, qg[:, :Dh])
+            st.put("w_ogate", i, qg[:, Dh:])
+            st.put("wk", i, out_major(p + "self_attn.k_proj.weight", K))
+            st.put("wv", i, out_major(p + "self_attn.v_proj.weight", K))
             st.put("wo", i,
                    take(p + "self_attn.o_proj.weight").T.reshape(H, Dh, D))
         elif mla:
@@ -308,8 +316,7 @@ def convert_llama(ckpt: Checkpoint, cfg, dtype=None) -> Dict[str, Any]:
                            cfg.q_lora_rank, H, qk))
             else:
                 st.put("wq", i,
-                       take(p + "self_attn.q_proj.weight").T.reshape(
-                           D, H, qk))
+                       out_major(p + "self_attn.q_proj.weight", H))
             st.put("wkv_a", i, linear_in_out(
                 p + "self_attn.kv_a_proj_with_mqa.weight"))
             st.put("kv_a_norm", i,
@@ -326,25 +333,21 @@ def convert_llama(ckpt: Checkpoint, cfg, dtype=None) -> Dict[str, Any]:
         elif p + "self_attn.qkv_proj.weight" in ckpt:
             # phi3: fused qkv — rows are [H*Dh | K*Dh | K*Dh]
             qkv = take(p + "self_attn.qkv_proj.weight")
-            st.put("wq", i, qkv[:H * Dh].T.reshape(D, H, Dh))
-            st.put("wk", i,
-                   qkv[H * Dh:(H + K) * Dh].T.reshape(D, K, Dh))
-            st.put("wv", i, qkv[(H + K) * Dh:].T.reshape(D, K, Dh))
+            st.put("wq", i, qkv[:H * Dh].reshape(H, Dh, D))
+            st.put("wk", i, qkv[H * Dh:(H + K) * Dh].reshape(K, Dh, D))
+            st.put("wv", i, qkv[(H + K) * Dh:].reshape(K, Dh, D))
             st.put("wo", i,
                    take(p + "self_attn.o_proj.weight").T.reshape(H, Dh, D))
         else:
-            st.put("wq", i,
-                   take(p + "self_attn.q_proj.weight").T.reshape(D, H, Dh))
-            st.put("wk", i,
-                   take(p + "self_attn.k_proj.weight").T.reshape(D, K, Dh))
-            st.put("wv", i,
-                   take(p + "self_attn.v_proj.weight").T.reshape(D, K, Dh))
+            st.put("wq", i, out_major(p + "self_attn.q_proj.weight", H))
+            st.put("wk", i, out_major(p + "self_attn.k_proj.weight", K))
+            st.put("wv", i, out_major(p + "self_attn.v_proj.weight", K))
             st.put("wo", i,
                    take(p + "self_attn.o_proj.weight").T.reshape(H, Dh, D))
         if p + "self_attn.gate_proj.weight" in ckpt:
             # afmoe: the output gate is a projection of its own
-            st.put("w_ogate", i, take(
-                p + "self_attn.gate_proj.weight").T.reshape(D, H, Dh))
+            st.put("w_ogate", i,
+                   out_major(p + "self_attn.gate_proj.weight", H))
         if getattr(cfg, "attn_bias", False):
             st.put("bq", i,
                    take(p + "self_attn.q_proj.bias").reshape(H, Dh))

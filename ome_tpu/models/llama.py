@@ -43,16 +43,43 @@ def _w(p: Params, name: str, dtype=None) -> jax.Array:
     return w
 
 
-def _proj(x: jax.Array, w, dtype, out_dims=None, flatten: int = 1):
+# the stacked leaves that lie out-major, [L, heads, Dh, D] (`_proj`)
+OUT_MAJOR = ("wq", "wk", "wv", "w_ogate")
+
+
+def to_out_major(w):
+    """[.., D, heads, Dh] -> [.., heads, Dh, D]: a projection out of
+    the hidden size from the in-major order (in which the random
+    weights are drawn) to the order it is stored in."""
+    return jnp.moveaxis(w, -3, -1)
+
+
+def _proj(x: jax.Array, w, dtype, out_dims=None, flatten: int = 1,
+          out_major: bool = False):
     """Contract x's trailing `flatten` dims with weight `w`.
+
+    `w` lies one of two ways. In-major, [K.., N..]: the contraction
+    dims first, the output channels behind them (wo, the MLP's
+    matrices, the DeltaNet leaves). `out_major`, [N.., K]: a row an
+    output channel, the contraction its LAST dim, which is how the
+    stacked attention projections wq / wk / wv / w_ogate are stored,
+    [L, H, Dh, D] (`_init_layer_block`). The dot is the same dot with
+    the operands' contraction dims named the other way; the reason is
+    the decode step: its dot has a handful of rows, the compiler reads
+    the weight with the contraction dim minor in HBM, and a leaf stored
+    [L, D, H, Dh] was re-laid out by a `copy` before every use (a
+    layer's slice inside the layer scan, or the whole stack ahead of
+    it: 1.6-2.5 ms of every step, ROADMAP A4). wo [L, H, Dh, D]
+    contracts its leading dims and never had one.
 
     int4 QTensor leaves route through the fused Pallas kernel
     (ops/int4_matmul.py) so the nibble unpack happens in VMEM and HBM
     streams packed bytes; everything else (bf16, int8, unsupported
     shapes, non-TPU) takes the dequant + einsum path, which XLA fuses
-    for int8. Callers must only pass weights whose dims up to and
-    including the pack axis are contraction dims (wq/wk/wv/wo,
-    w_gate/w_up — not expert-stacked or per-head-factored leaves).
+    for int8. An in-major int4 leaf packs its FIRST contraction dim
+    and every dim up to it is a contraction dim (w_gate / w_up; not
+    expert-stacked or per-head-factored leaves); an out-major one
+    packs its last dim (quant._LAYER_CONTRACT).
     """
     import math
     lead = x.shape[:-flatten]
@@ -65,7 +92,10 @@ def _proj(x: jax.Array, w, dtype, out_dims=None, flatten: int = 1):
     if y is None:
         wd = w.dequant(dtype or jnp.bfloat16) \
             if isinstance(w, QTensor) else w
-        y = jnp.einsum("...k,kn->...n", x2, wd.reshape(K, -1))
+        if out_major:
+            y = jnp.einsum("...k,nk->...n", x2, wd.reshape(-1, K))
+        else:
+            y = jnp.einsum("...k,kn->...n", x2, wd.reshape(K, -1))
     if out_dims:
         y = y.reshape(*y.shape[:-1], *out_dims)
     return y
@@ -216,9 +246,21 @@ def _init_layer_block(rng: jax.Array, cfg: ModelConfig, L: int,
                       moe: bool, linear: bool = False) -> Params:
     """One stacked block of L structurally-identical layers.
     `linear`: a hybrid model's Gated DeltaNet layers (the mixer's
-    leaves in place of the attention projections)."""
+    leaves in place of the attention projections).
+
+    The attention projections out of the hidden size, wq / wk / wv and
+    the output gate w_ogate, lie OUT-MAJOR, [L, heads, Dh, D]: a row an
+    output channel and the hidden size last, as wo [L, H, Dh, D] lies
+    and as a Hugging Face `q_proj.weight` [heads * Dh, D] lies
+    reshaped. That is the order in which the decode step's dot reads
+    them (`_proj`), so no program re-lays a layer's slice or the stack
+    before using it. Every other matrix is in-major, [L, K.., N..]."""
     D, H, K, Dh, F = (cfg.hidden_size, cfg.num_heads, cfg.num_kv_heads,
                       cfg.head_dim, cfg.intermediate_size)
+    # an out-major leaf is DRAWN [L, D, heads, Dh], the shape and the
+    # key order its values have always had (the benchmark's references
+    # draw the same), and re-laid after the draw, in the program that
+    # drew it: `to_out_major`
     keys = iter(jax.random.split(rng, 24))
     depth = cfg.num_layers
 
@@ -242,7 +284,7 @@ def _init_layer_block(rng: jax.Array, cfg: ModelConfig, L: int,
             layers["q_a_norm"] = norm_scale(L, cfg.q_lora_rank)
             layers["wq_b"] = norm((L, cfg.q_lora_rank, H, qk), next(keys))
         else:
-            layers["wq"] = norm((L, D, H, qk), next(keys))
+            layers["wq"] = to_out_major(norm((L, D, H, qk), next(keys)))
         layers["wkv_a"] = norm((L, D, r + cfg.qk_rope_head_dim),
                                next(keys))
         layers["kv_a_norm"] = norm_scale(L, r)
@@ -252,9 +294,9 @@ def _init_layer_block(rng: jax.Array, cfg: ModelConfig, L: int,
                             std=cfg.init_std / (2 * depth) ** 0.5)
     else:
         layers.update({
-            "wq": norm((L, D, H, Dh), next(keys)),
-            "wk": norm((L, D, K, Dh), next(keys)),
-            "wv": norm((L, D, K, Dh), next(keys)),
+            "wq": to_out_major(norm((L, D, H, Dh), next(keys))),
+            "wk": to_out_major(norm((L, D, K, Dh), next(keys))),
+            "wv": to_out_major(norm((L, D, K, Dh), next(keys))),
             "wo": norm((L, H, Dh, D), next(keys),
                        std=cfg.init_std / (2 * depth) ** 0.5),
         })
@@ -327,7 +369,7 @@ def _init_layer_block(rng: jax.Array, cfg: ModelConfig, L: int,
                     next(keys), (L, Hv), jnp.float32)))),
         })
     elif cfg.attn_output_gate:
-        layers["w_ogate"] = norm((L, D, H, Dh), next(keys))
+        layers["w_ogate"] = to_out_major(norm((L, D, H, Dh), next(keys)))
     return layers
 
 
@@ -517,9 +559,10 @@ def _lora_delta(x: jax.Array, lp: Params, name: str,
 
 def _proj_lora(x: jax.Array, lp: Params, name: str,
                adapter_ids: Optional[jax.Array], dtype,
-               out_dims=None, flatten: int = 1):
-    """_proj + the slot's adapter delta (multi-LoRA serving)."""
-    y = _proj(x, lp[name], dtype, flatten=flatten)
+               out_dims=None, flatten: int = 1, out_major: bool = False):
+    """_proj + the slot's adapter delta (multi-LoRA serving; the
+    factors are [r, K] / [r, N] however the base leaf lies)."""
+    y = _proj(x, lp[name], dtype, flatten=flatten, out_major=out_major)
     d = _lora_delta(x, lp, name, adapter_ids, flatten=flatten)
     if d is not None:
         y = y + d.reshape(y.shape)
@@ -1091,11 +1134,13 @@ def _qkv(h: jax.Array, lp: Params, cfg: ModelConfig, freqs: jax.Array,
     dense (_mha) and paged (forward_paged) attention paths.
     `rope=False` is cohere2's NoPE global layers."""
     q = _proj_lora(h, lp, "wq", adapter_ids, cfg.dtype,
-                   out_dims=(cfg.num_heads, cfg.head_dim))
+                   out_dims=(cfg.num_heads, cfg.head_dim), out_major=True)
     k = _proj_lora(h, lp, "wk", adapter_ids, cfg.dtype,
-                   out_dims=(cfg.num_kv_heads, cfg.head_dim))
+                   out_dims=(cfg.num_kv_heads, cfg.head_dim),
+                   out_major=True)
     v = _proj_lora(h, lp, "wv", adapter_ids, cfg.dtype,
-                   out_dims=(cfg.num_kv_heads, cfg.head_dim))
+                   out_dims=(cfg.num_kv_heads, cfg.head_dim),
+                   out_major=True)
     if cfg.attn_bias:
         q = q + lp["bq"]
         k = k + lp["bk"]
@@ -1135,12 +1180,17 @@ def _rows_as(rows: jax.Array, dtype, tail: Tuple[int, ...]) -> jax.Array:
     """Fresh rows [B, S, K, Dh] in a cache's dtype and row layout:
     `tail` is what follows the cache's row dimension, [K, Dh] or
     merged [K * Dh] (`KVCache`). A step's few rows are merged behind
-    a barrier: without one the compiler folds the merge into the
-    projection that made them, wants that layer's `wk` / `wv` as
-    [hidden, K * Dh] and re-lays the weights out inside the layer
-    scan, every layer of every step (chip compiler, PR 38), where a
-    handful of rows cost nothing to re-lay. A prompt's rows are more
-    than the weights' and the compiler is left to choose."""
+    a barrier: without one the compiler folded the merge into the
+    projection that made them, wanted that layer's `wk` / `wv`, then
+    stored [hidden, K, Dh], as [hidden, K * Dh] and re-laid the
+    weights out inside the layer scan, every layer of every step
+    (chip compiler, PR 38), where a handful of rows cost nothing to
+    re-lay. Since PR 41 the leaves lie [K, Dh, hidden] (`_proj`) and
+    the text compiled without the barrier re-lays nothing either
+    (chip compiler, PR 41: long-decode's `decode`); it stays until a
+    chip run prices the programs without it (ROADMAP C15). A prompt's
+    rows are more than the weights' and the compiler is left to
+    choose."""
     rows = rows.astype(dtype)
     if rows.shape[2:] == tuple(tail):
         return rows
@@ -1291,7 +1341,8 @@ def _mha(h: jax.Array, lp: Params, cfg: ModelConfig, freqs: jax.Array,
             # Qwen3-Next: a gate per head and dim, projected from the
             # same normed input as the query
             gate = _proj(h, lp["w_ogate"], cfg.dtype,
-                         out_dims=(cfg.num_heads, cfg.head_dim))
+                         out_dims=(cfg.num_heads, cfg.head_dim),
+                         out_major=True)
             attn = attn * jax.nn.sigmoid(gate.astype(jnp.float32)) \
                 .astype(attn.dtype)
         a = _proj_lora(attn, lp, "wo", adapter_ids, cfg.dtype, flatten=2)

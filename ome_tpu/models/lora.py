@@ -33,14 +33,18 @@ from .checkpoint import Checkpoint
 
 log = logging.getLogger("ome.lora")
 
+def _heads_split(d, cfg):
+    """wq / wk / wv lie out-major, [heads, Dh, D] (llama.
+    _init_layer_block): HF's own [out, in] with the heads split, no
+    transpose."""
+    return d.reshape(-1, cfg.head_dim, cfg.hidden_size)
+
+
 # HF module name -> (our stacked leaf, reshaper from [out, in] delta)
 _TARGETS = {
-    "q_proj": ("wq", lambda d, cfg: d.T.reshape(
-        cfg.hidden_size, cfg.num_heads, cfg.head_dim)),
-    "k_proj": ("wk", lambda d, cfg: d.T.reshape(
-        cfg.hidden_size, cfg.num_kv_heads, cfg.head_dim)),
-    "v_proj": ("wv", lambda d, cfg: d.T.reshape(
-        cfg.hidden_size, cfg.num_kv_heads, cfg.head_dim)),
+    "q_proj": ("wq", _heads_split),
+    "k_proj": ("wk", _heads_split),
+    "v_proj": ("wv", _heads_split),
     "o_proj": ("wo", lambda d, cfg: d.T.reshape(
         cfg.num_heads, cfg.head_dim, cfg.hidden_size)),
     "gate_proj": ("w_gate", lambda d, cfg: d.T),
@@ -98,7 +102,8 @@ def _read_adapter(adapter_dir: str):
 
 
 # multi-LoRA factor layout per target: flattened contraction width K
-# and output width N of the stacked leaf ([L, r, K] A / [L, r, N] B)
+# and output width N of the stacked leaf ([L, r, K] A / [L, r, N] B),
+# whichever way the base leaf itself lies
 def _target_dims(cfg) -> Dict[str, tuple]:
     D, H, K, Dh, F = (cfg.hidden_size, cfg.num_heads, cfg.num_kv_heads,
                       cfg.head_dim, cfg.intermediate_size)

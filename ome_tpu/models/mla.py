@@ -146,7 +146,8 @@ def mla_attention(h: jax.Array, lp: Params, cfg: ModelConfig,
         ql = rms_norm(ql, lp["q_a_norm"], cfg.rms_norm_eps)
         q = jnp.einsum("bsr,rhk->bshk", ql, _w(lp, "wq_b", cfg.dtype))
     else:
-        q = jnp.einsum("bsd,dhk->bshk", h, _w(lp, "wq", cfg.dtype))
+        # out-major [H, qk, D], as every model's wq lies (llama._proj)
+        q = jnp.einsum("bsd,hkd->bshk", h, _w(lp, "wq", cfg.dtype))
     q_nope, q_pe = q[..., :nope], q[..., nope:]
     q_pe = rope_interleaved(q_pe, positions, cfg)
 

@@ -198,7 +198,10 @@ def quantize_tensor_int4(w: jax.Array, contract_axes,
 
 # contraction axes per stacked-layer leaf ([L, ...]; axis 0 = layer)
 _LAYER_CONTRACT = {
-    "wq": (1,), "wk": (1,), "wv": (1,),   # [L, D, H, Dh]: sum over D
+    # the projections out of the hidden size lie out-major (llama.
+    # _init_layer_block): [L, heads, Dh, D], sum over the LAST dim; an
+    # int4 leaf packs that dim, a row's nibbles side by side
+    "wq": (3,), "wk": (3,), "wv": (3,),
     "wo": (2, 1),                          # [L, H, Dh, D]: sum over H,Dh
     "w_gate": (1,), "w_up": (1,),          # [L, D, F]
     "w_down": (1,),                        # [L, F, D]
@@ -213,7 +216,7 @@ _LAYER_CONTRACT = {
     "w_uv": (2,),                          # [L, H, r, v]
     # hybrid models (Qwen3-Next): the attention's output gate and the
     # DeltaNet mixer's projections; conv, router, w_b / w_a stay fp
-    "w_ogate": (1,),                       # [L, D, H, Dh]
+    "w_ogate": (3,),                       # [L, H, Dh, D], as wq
     "w_qkv": (1,), "w_z": (1,),            # [L, D, C] / [L, D, Hv*dv]
     "w_lin_out": (1,),                     # [L, Hv*dv, D]
 }

@@ -7,13 +7,26 @@ small group scales) into VMEM, unpacks with i32 shifts (Mosaic has no
 i8 vector shifts), scales per group, and feeds the MXU — HBM traffic
 is the packed 0.5 byte/weight, the decode roofline's whole point.
 
-Layout contract (models/quant.py concat-pack): the packing axis holds
-pairs (g, g+G/2) within each scale group; flattened 2D view
-`[K/2, N]` where every dim up to and including the pack axis is a
-CONTRACTION dim (callers guarantee this — true for wq/wk/wv/wo and
-the MLP gate/up projections) and the trailing dims are output
-channels. Scales flatten to `[K/G, N]` after broadcasting collapsed
-contract dims.
+Layout contract (models/quant.py concat-pack): byte j of the packing
+axis holds original rows j and K/2 + j. A leaf flattens to a 2D view
+one of two ways, told apart by where its pack axis is
+(`flatten_qtensor`):
+
+  * in-major, `[K/2, N]`: the pack axis is the leaf's FIRST dim and a
+    contraction dim, the trailing dims are output channels (the MLP's
+    gate / up projections, `[D, F]`). Scales flatten to `[K/G, N]`.
+  * out-major, `[N, K/2]`: the pack axis is the leaf's LAST dim, every
+    dim before it an output channel. This is how the attention
+    projections wq / wk / wv / w_ogate lie, `[heads, Dh, D]`
+    (llama._init_layer_block): the decode step's bf16 dot reads them
+    with the hidden size minor, and a leaf stored the other way was
+    re-laid out before every use. A row's nibbles lie side by side in
+    the lanes, the dot contracts both operands' minor dim, and the
+    scales `[N, K/G]` are re-laid to `[K/G.., N, groups]` blocks in
+    XLA ahead of the call (a sixteenth of the packed bytes).
+
+wo `[H, Dh, D]` packs Dh under H, flattens neither way, and stays int8
+(quant.quantize_params).
 
 Dispatch rules (the kernel declines with None otherwise, the caller
 takes the XLA dequant path, and the decline is noted — ops/__init__.py):
@@ -68,7 +81,7 @@ def kernel_disabled():
 
 
 def _kernel(xl_ref, xh_ref, qp_ref, sl_ref, sh_ref, o_ref, acc_ref, *,
-            gsize: int):
+            gsize: int, out_major: bool = False):
     k = pl.program_id(1)
 
     @pl.when(k == 0)
@@ -79,8 +92,6 @@ def _kernel(xl_ref, xh_ref, qp_ref, sl_ref, sh_ref, o_ref, acc_ref, *,
     # nibble extraction in i32: arithmetic shifts sign-extend
     hi = qp >> 4
     lo = (qp << 28) >> 28
-    bkp, bn = qp_ref.shape
-    ng = bkp // gsize
     # half-packed layout (models/quant.py): packed row j of this block
     # holds original rows at the SAME offset in the axis' low half (lo
     # nibble) and high half (hi nibble). The matching x slices and
@@ -89,16 +100,34 @@ def _kernel(xl_ref, xh_ref, qp_ref, sl_ref, sh_ref, o_ref, acc_ref, *,
     # (a full-tile VMEM round-trip) and no strided shuffles.
     # f32 unpack-scale measured FASTER than bf16 on v5e Mosaic (bf16
     # VPU packing overhead outweighs the halved element width)
-    wl = (lo.reshape(ng, gsize, bn).astype(jnp.float32)
-          * sl_ref[...][:, None, :]).reshape(bkp, bn).astype(jnp.bfloat16)
-    wh = (hi.reshape(ng, gsize, bn).astype(jnp.float32)
-          * sh_ref[...][:, None, :]).reshape(bkp, bn).astype(jnp.bfloat16)
-    acc_ref[...] += jax.lax.dot_general(
-        xl_ref[...], wl, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    acc_ref[...] += jax.lax.dot_general(
-        xh_ref[...], wh, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    if out_major:
+        # qp [bn, bkp]: a row an output channel, its nibbles along the
+        # lanes; sl / sh [bn, ng]. A group is gsize lanes (a multiple
+        # of 128) times its column of the scales, and the dot
+        # contracts both operands' minor dim
+        ng = qp_ref.shape[1] // gsize
+
+        def scaled(nib, s_ref):
+            s = s_ref[...]
+            return jnp.concatenate(
+                [nib[:, g * gsize:(g + 1) * gsize].astype(jnp.float32)
+                 * s[:, g:g + 1] for g in range(ng)],
+                axis=1).astype(jnp.bfloat16)
+        w_contract = 1
+    else:
+        bkp, bn = qp_ref.shape
+        ng = bkp // gsize
+
+        def scaled(nib, s_ref):
+            return (nib.reshape(ng, gsize, bn).astype(jnp.float32)
+                    * s_ref[...][:, None, :]
+                    ).reshape(bkp, bn).astype(jnp.bfloat16)
+        w_contract = 0
+    for x_ref, w in ((xl_ref, scaled(lo, sl_ref)),
+                     (xh_ref, scaled(hi, sh_ref))):
+        acc_ref[...] += jax.lax.dot_general(
+            x_ref[...], w, (((1,), (w_contract,)), ((), ())),
+            preferred_element_type=jnp.float32)
 
     @pl.when(k == pl.num_programs(1) - 1)
     def _done():
@@ -107,10 +136,11 @@ def _kernel(xl_ref, xh_ref, qp_ref, sl_ref, sh_ref, o_ref, acc_ref, *,
 
 @functools.partial(jax.jit,
                    static_argnames=("gsize", "bkp", "bn", "out_dtype",
-                                    "interpret"))
+                                    "interpret", "out_major"))
 def _mm4(x2, qp2, s2, gsize: int, bkp: int, bn: int, out_dtype,
-         interpret: bool = False):
-    """x2 [m, K] @ half-packed qp2 [K/2, N] with scales s2 [K/G, N].
+         interpret: bool = False, out_major: bool = False):
+    """x2 [m, K] @ half-packed qp2 [K/2, N] with scales s2 [K/G, N]
+    (`out_major`: qp2 [N, K/2], s2 [N, K/G]).
 
     Grid steps walk the PACKED rows in blocks of bkp; each step reads
     the two matching x column-blocks (low half: cols [kk*bkp, ...);
@@ -121,22 +151,37 @@ def _mm4(x2, qp2, s2, gsize: int, bkp: int, bn: int, out_dtype,
     own (ngb, ·) whatever ngb is: Mosaic asks for sublane blocks of 8
     or the whole dim, and K=2560 has 10 groups a half."""
     m, k = x2.shape
-    n = qp2.shape[1]
+    n = qp2.shape[0 if out_major else 1]
     kp = k // 2
     nkb = kp // bkp               # x/scale block offset of the high half
     ngb = bkp // gsize            # scale rows per block
-    s3 = s2.reshape(2 * nkb, ngb, n)
+    if out_major:
+        # the same view with the channels ahead of a block's groups:
+        # [N, K/G] -> [2 * nkb, N, ngb], a block's last two dims the
+        # array's own (bn a multiple of 8, ngb whole)
+        s3 = s2.reshape(n, 2 * nkb, ngb).transpose(1, 0, 2)
+        w_specs = [
+            pl.BlockSpec((bn, bkp), lambda i, kk: (i, kk)),
+            pl.BlockSpec((None, bn, ngb), lambda i, kk: (kk, i, 0)),
+            pl.BlockSpec((None, bn, ngb),
+                         lambda i, kk: (nkb + kk, i, 0)),
+        ]
+    else:
+        s3 = s2.reshape(2 * nkb, ngb, n)
+        w_specs = [
+            pl.BlockSpec((bkp, bn), lambda i, kk: (kk, i)),
+            pl.BlockSpec((None, ngb, bn), lambda i, kk: (kk, 0, i)),
+            pl.BlockSpec((None, ngb, bn),
+                         lambda i, kk: (nkb + kk, 0, i)),
+        ]
     return pl.pallas_call(
-        functools.partial(_kernel, gsize=gsize),
+        functools.partial(_kernel, gsize=gsize, out_major=out_major),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         grid=(n // bn, nkb),
         in_specs=[
             pl.BlockSpec((m, bkp), lambda i, kk: (0, kk)),
             pl.BlockSpec((m, bkp), lambda i, kk: (0, nkb + kk)),
-            pl.BlockSpec((bkp, bn), lambda i, kk: (kk, i)),
-            pl.BlockSpec((None, ngb, bn), lambda i, kk: (kk, 0, i)),
-            pl.BlockSpec((None, ngb, bn),
-                         lambda i, kk: (nkb + kk, 0, i)),
+            *w_specs,
         ],
         out_specs=pl.BlockSpec((m, bn), lambda i, kk: (0, i)),
         scratch_shapes=[pltpu.VMEM((m, bn), jnp.float32)],
@@ -148,18 +193,29 @@ def _mm4(x2, qp2, s2, gsize: int, bkp: int, bn: int, out_dtype,
 
 
 def flatten_qtensor(qt) -> Optional[tuple]:
-    """(qp2 [K/2, N], s2 [K/G, N], K, N, G) — 2D views of a packed
-    leaf whose pre-pack dims are all contraction dims; None if the
-    shapes don't flatten cleanly."""
+    """(qp2, s2, K, N, G, out_major): 2D views of a packed leaf.
+    In-major, qp2 [K/2, N] and s2 [K/G, N]: the pack axis is the
+    leaf's first dim; out-major, qp2 [N, K/2] and s2 [N, K/G]: it is
+    the leaf's last (wq / wk / wv / w_ogate, [heads, Dh, D]). None if
+    the leaf lies neither way or its shapes don't flatten cleanly."""
     q, s = qt.q, qt.s
     if getattr(qt, "bits", 8) != 4:
         return None
     a = qt.axis % q.ndim
     pre, post = q.shape[:a], q.shape[a + 1:]
+    if not post and q.ndim > 1:
+        # the pack axis is the minor dim: a row's nibbles are
+        # contiguous, whatever the output channels' dims are
+        kp, n, n_groups = q.shape[a], int(np.prod(pre)), s.shape[a]
+        gsize = 2 * kp // n_groups
+        if gsize < 2 or gsize % 2 or s.shape[:a] != pre:
+            return None
+        return (q.reshape(n, kp), s.reshape(n, n_groups), 2 * kp, n,
+                gsize, True)
     if int(np.prod(pre)) != 1:
         # the half-packed layout is contiguous in the flattened
-        # contraction only when the pack axis is OUTERMOST (true for
-        # every kernel-eligible leaf: quant.py packs axes[0])
+        # contraction only when the pack axis is OUTERMOST or
+        # innermost (quant.py packs axes[0])
         return None
     kp = q.shape[a]
     n = int(np.prod(post))
@@ -177,7 +233,7 @@ def flatten_qtensor(qt) -> Optional[tuple]:
         return None
     qp2 = q.reshape(kp, n)
     s2 = s_full.reshape(int(np.prod(pre)) * n_groups, n)
-    return qp2, s2, k, n, gsize
+    return qp2, s2, k, n, gsize, False
 
 
 def _pick_bkp(kp: int, gsize: int) -> int:
@@ -223,7 +279,7 @@ def int4_matmul(x: jax.Array, qt, out_dtype=jnp.bfloat16,
     if flat is None:
         return decline("leaf does not flatten to the half-packed "
                        "[K/2, N] layout")
-    qp2, s2, k, n, gsize = flat
+    qp2, s2, k, n, gsize, out_major = flat
     if x.shape[-1] != k:
         return decline(f"x contracts {x.shape[-1]}, weight {k}")
     kp = k // 2
@@ -247,7 +303,7 @@ def int4_matmul(x: jax.Array, qt, out_dtype=jnp.bfloat16,
     if pad:
         x2 = jnp.pad(x2, ((0, pad), (0, 0)))
     y = _mm4(x2.astype(jnp.bfloat16), qp2, s2, gsize, bkp, bn,
-             out_dtype, interpret)
+             out_dtype, interpret, out_major)
     if pad:
         y = y[:m]
     return y.reshape(*lead, n)

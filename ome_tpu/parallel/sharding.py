@@ -30,9 +30,9 @@ _LAYER_RULES: Dict[str, tuple] = {
     "bq": ("tp", None),          # qwen2 attention biases: heads on tp
     "bk": ("tp", None),          # [H|K, Dh] — follows wq/wk/wv
     "bv": ("tp", None),
-    "wq": (None, "tp", None),      # [D, H, Dh]
-    "wk": (None, "tp", None),
-    "wv": (None, "tp", None),
+    "wq": ("tp", None, None),      # [H, Dh, D]: out-major, heads first
+    "wk": ("tp", None, None),      # [K, Dh, D]  (llama._proj)
+    "wv": ("tp", None, None),
     "wo": ("tp", None, None),      # [H, Dh, D]
     "w_gate": (None, "tp"),        # [D, F]
     "w_up": (None, "tp"),

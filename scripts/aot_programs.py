@@ -59,7 +59,7 @@ ONLY = None         # --only: the prefix of the programs to compile
 
 
 def report(name: str, lowered) -> None:
-    if ONLY and not name.startswith(ONLY):
+    if ONLY and not name.startswith(tuple(ONLY.split(","))):
         return
     t0 = time.time()
     compiled = lowered.compile()
@@ -98,7 +98,8 @@ def main() -> int:
                          "look for what its temporaries are")
     ap.add_argument("--only", default=None,
                     help="compile only the programs whose name starts "
-                         "with this")
+                         "with this (or with one of these, comma-"
+                         "separated: `prefill,decode`)")
     args = ap.parse_args()
     global TEXT_DIR, ONLY
     TEXT_DIR, ONLY = args.text_dir, args.only

@@ -130,11 +130,14 @@ def run_noattn(cfg, quant):
         def body(x, lp):
             h = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
             q = _proj(h, lp["wq"], cfg.dtype,
-                      out_dims=(cfg.num_heads, cfg.head_dim))
+                      out_dims=(cfg.num_heads, cfg.head_dim),
+                      out_major=True)
             k = _proj(h, lp["wk"], cfg.dtype,
-                      out_dims=(cfg.num_kv_heads, cfg.head_dim))
+                      out_dims=(cfg.num_kv_heads, cfg.head_dim),
+                      out_major=True)
             v = _proj(h, lp["wv"], cfg.dtype,
-                      out_dims=(cfg.num_kv_heads, cfg.head_dim))
+                      out_dims=(cfg.num_kv_heads, cfg.head_dim),
+                      out_major=True)
             # attention skipped: feed q straight to the output proj so
             # every weight still streams but no KV traffic happens
             a = _proj(q + 0 * (k.sum() + v.sum()), lp["wo"], cfg.dtype,
@@ -332,11 +335,14 @@ def _unrolled_q8kv_step(cfg, per, top, tok, kq, vq, ksc, vsc, index):
         lp = per[l]
         h = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
         q = _proj(h, lp["wq"], cfg.dtype,
-                  out_dims=(cfg.num_heads, cfg.head_dim))
+                  out_dims=(cfg.num_heads, cfg.head_dim),
+                  out_major=True)
         k = _proj(h, lp["wk"], cfg.dtype,
-                  out_dims=(cfg.num_kv_heads, cfg.head_dim))
+                  out_dims=(cfg.num_kv_heads, cfg.head_dim),
+                  out_major=True)
         v = _proj(h, lp["wv"], cfg.dtype,
-                  out_dims=(cfg.num_kv_heads, cfg.head_dim))
+                  out_dims=(cfg.num_kv_heads, cfg.head_dim),
+                  out_major=True)
         q = apply_rope(q, positions, freqs)
         k = apply_rope(k, positions, freqs)
         kq8, ks8 = quantize_kv_block(k)   # [B,1,K,D], [B,K,1]

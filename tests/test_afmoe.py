@@ -39,6 +39,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(ROOT, "benchmark"))
 from reference import window_moe as ref  # noqa: E402
 
+from _parent_proj import stored  # noqa: E402
+
 W = 8
 HF = dict(
     architectures=["AfmoeForCausalLM"], model_type="afmoe",
@@ -194,8 +196,9 @@ def test_reference_makes_the_served_weights(cut):
                          (p, {k: w[k] for k in ("embed", "lm_head",
                                                 "final_norm")})):
         for name, leaf in theirs.items():
-            np.testing.assert_array_equal(np.asarray(mine[name]),
-                                          np.asarray(leaf), err_msg=name)
+            np.testing.assert_array_equal(
+                np.asarray(mine[name]), np.asarray(stored(name, leaf)),
+                err_msg=name)
     assert llama.param_count(p) == sum(
         x.size for x in jax.tree.leaves(w))
 
@@ -722,7 +725,9 @@ def test_a_checkpoint_under_the_published_names_loads(tmp_path):
             t[pre + theirs + ".weight"] = np.asarray(blk[ours][j])
         for ours, theirs in (("wq", "q"), ("wk", "k"), ("wv", "v"),
                              ("w_ogate", "gate")):
-            lin(pre + f"self_attn.{theirs}_proj.weight", blk[ours][j])
+            # out-major [heads, Dh, D]: HF's [out, in] with heads split
+            t[pre + f"self_attn.{theirs}_proj.weight"] = np.asarray(
+                blk[ours][j], np.float32).reshape(-1, 64)
         t[pre + "self_attn.o_proj.weight"] = np.asarray(
             blk["wo"][j]).reshape(-1, 64).T
         if i < 2:

@@ -194,6 +194,31 @@ def test_int4_matmul_compiles_or_declines(one_chip, monkeypatch, k, n):
     assert declined or "tpu_custom_call" in c.as_text()
 
 
+@pytest.mark.parametrize("k,heads", [(2560, 32), (2560, 8), (4096, 32),
+                                     (2048, 32)])
+def test_int4_matmul_takes_an_out_major_leaf(one_chip, monkeypatch, k,
+                                             heads):
+    """The attention projections lie [heads, Dh, D] and an int4 leaf
+    of them packs D, its LAST dim (`llama._proj`): Mosaic accepts the
+    kernel's out-major form (a row's nibbles along the lanes, both
+    operands contracted on their minor dim, the scales a block's
+    [channels, groups]) at the hidden sizes of the served models, 10
+    groups a nibble half in one k-step at 2560, two k-steps at
+    4096."""
+    monkeypatch.setattr(device, "on_tpu", lambda: True)
+
+    def f(x, q, s):
+        y = int4_matmul.int4_matmul(
+            x, QTensor(q=q, s=s, bits=4, axis=-1))
+        assert y is not None, "the kernel declined an out-major leaf"
+        return y
+
+    c = _compile(f, one_chip((B, k), jnp.bfloat16),
+                 one_chip((heads, D, k // 2), jnp.int8),
+                 one_chip((heads, D, k // 128), jnp.float32))
+    assert "tpu_custom_call" in c.as_text()
+
+
 @pytest.mark.parametrize("merged", [False, True],
                          ids=["heads_apart", "merged_rows"])
 @pytest.mark.parametrize("sq,skv", [(1, 2048), (2048, 2048)])
@@ -253,6 +278,16 @@ def test_int4_quantizer_keeps_no_float32_copy(one_chip):
 LAYERS, N_BLOCKS = 36, 198             # the qwen3-4b cells' depth, pool
 
 
+def _qwen3_4b():
+    """Qwen3-4B's ModelConfig at the cells' depth and length."""
+    from ome_tpu.models.config import ModelConfig
+    return ModelConfig(vocab_size=151936, hidden_size=2560,
+                       num_layers=LAYERS, num_heads=H, num_kv_heads=K,
+                       head_dim=D, intermediate_size=9728,
+                       max_seq_len=2048, qk_norm=True,
+                       tie_word_embeddings=True, dtype=jnp.bfloat16)
+
+
 @pytest.fixture(scope="module")
 def decode_paged(topo):
     """`_decode_paged` as engine/core.py builds it (`forward_paged`
@@ -262,11 +297,11 @@ def decode_paged(topo):
     scale-plane shape or None). The whole depth, because it costs
     nothing (the 20 s are the vocabulary-wide sort of `sample`) and
     because under 12 layers the compiler hoists relayout copies of
-    the stacked attention weights out of the loop (ROADMAP A4), which
-    would be read here as the pool's."""
+    the stacked attention weights out of the loop (until PR 41 stored
+    them as the dot reads them), which would be read here as the
+    pool's."""
     from ome_tpu.engine import core
     from ome_tpu.models import llama
-    from ome_tpu.models.config import ModelConfig
     from ome_tpu.telemetry import scopes
 
     sharding = SingleDeviceSharding(topo.devices[0])
@@ -274,11 +309,7 @@ def decode_paged(topo):
     def struct(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
-    cfg = ModelConfig(vocab_size=151936, hidden_size=2560,
-                      num_layers=LAYERS, num_heads=H, num_kv_heads=K,
-                      head_dim=D, intermediate_size=9728,
-                      max_seq_len=2048, qk_norm=True,
-                      tie_word_embeddings=True, dtype=jnp.bfloat16)
+    cfg = _qwen3_4b()
 
     @scopes.scoped("decode")
     def _decode_paged(params, k, v, ks, vs, lengths, table, tokens, key,
@@ -389,20 +420,21 @@ def test_decode_paged_kernel_takes_the_pool_whole(decode_paged,
 
 
 # the two periodic window / global cells: configuration -> (heads, what
-# a decode step's temporaries may be: the parent of PR 38 read 0.541 GB
-# and 0.626 GB, chip compiler, and merged rows read the same to the MB)
-WINDOW_CELLS = {"trinity-mini-ep4": (32, 545_000_000),
-                "smallthinker-21b-a3b-ep4": (28, 630_000_000)}
+# a decode step's temporaries may be: until PR 41 they read 0.541 GB
+# and 0.626 GB (chip compiler, PR 38), nearly all of it the stacks of
+# the attention projections re-laid ahead of the layer scan; stored as
+# the dot reads them (`llama._proj`) they read 0.015 GB and 0.060 GB)
+WINDOW_CELLS = {"trinity-mini-ep4": (32, 30_000_000),
+                "smallthinker-21b-a3b-ep4": (28, 80_000_000)}
 
 
-@pytest.fixture(scope="module", params=sorted(WINDOW_CELLS))
-def decode_window(topo, request):
+@pytest.fixture(scope="module")
+def slab_decode(topo):
     """The engine's own `decode` program for a benchmark configuration
-    of the periodic window / global family (benchmark/configs/
-    trinity-mini-ep4.json: `long-doc`; smallthinker-21b-a3b-ep4.json:
-    `long-decode`) at the cell's slots and length, compiled for the
-    described chip: (compiled, global slab shape, ring shape, the
-    configuration's name, its ModelConfig)."""
+    that runs the slab path (benchmark/configs/<name>.json) at its
+    cell's slots and length, compiled for the described chip once a
+    configuration: name -> (compiled, the slots' state as shapes, its
+    ModelConfig)."""
     import json
 
     from ome_tpu.engine.core import InferenceEngine
@@ -410,35 +442,49 @@ def decode_window(topo, request):
     from ome_tpu.models.config import ModelConfig
     from ome_tpu.perf.ledger import ProgramLedger
 
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "benchmark", "configs",
-                           request.param + ".json")) as f:
-        file = json.load(f)
-    cfg = ModelConfig.from_hf_config(
-        {k: v for k, v in file.items()
-         if k not in ("source", "reduced", "assumed", "benchmark")}
-    ).replace(moe_impl="ragged")
-    serve = file["benchmark"]["serve_args"]
-    slots = serve[serve.index("--max-slots") + 1]
-    max_seq = serve[serve.index("--max-seq") + 1]
     sharding = SingleDeviceSharding(topo.devices[0])
 
     def struct(a):
         return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding)
 
-    params = jax.tree.map(struct, jax.eval_shape(
-        lambda k: llama.init_params(k, cfg), jax.random.PRNGKey(0)))
-    with pytest.MonkeyPatch.context() as mp:
-        mp.setattr(device, "on_tpu", lambda: True)
-        eng = InferenceEngine(params, cfg, max_slots=slots, max_seq=max_seq,
-                              ledger=ProgramLedger("off"))
-        state = jax.tree.map(struct, jax.eval_shape(eng.new_state))
-        ints = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=sharding)
-        floats = jax.ShapeDtypeStruct((slots,), jnp.float32,
-                                      sharding=sharding)
-        key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=sharding)
-        compiled = eng.programs["decode"].lower(
-            params, state, floats, ints, floats, key).compile()
+    @functools.lru_cache(maxsize=None)
+    def compiled(name):
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        with open(os.path.join(root, "benchmark", "configs",
+                               name + ".json")) as f:
+            file = json.load(f)
+        cfg = ModelConfig.from_hf_config(
+            {k: v for k, v in file.items()
+             if k not in ("source", "reduced", "assumed", "benchmark")}
+        ).replace(moe_impl="ragged")
+        serve = file["benchmark"]["serve_args"]
+        slots = serve[serve.index("--max-slots") + 1]
+        max_seq = serve[serve.index("--max-seq") + 1]
+        params = jax.tree.map(struct, jax.eval_shape(
+            lambda k: llama.init_params(k, cfg), jax.random.PRNGKey(0)))
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(device, "on_tpu", lambda: True)
+            eng = InferenceEngine(params, cfg, max_slots=slots,
+                                  max_seq=max_seq,
+                                  ledger=ProgramLedger("off"))
+            state = jax.tree.map(struct, jax.eval_shape(eng.new_state))
+            ints = struct(jax.ShapeDtypeStruct((slots,), jnp.int32))
+            floats = struct(jax.ShapeDtypeStruct((slots,), jnp.float32))
+            key = struct(jax.ShapeDtypeStruct((2,), jnp.uint32))
+            c = eng.programs["decode"].lower(
+                params, state, floats, ints, floats, key).compile()
+        return c, state, cfg
+
+    return compiled
+
+
+@pytest.fixture(scope="module", params=sorted(WINDOW_CELLS))
+def decode_window(slab_decode, request):
+    """The `decode` program of a configuration of the periodic window
+    / global family (trinity-mini-ep4: `long-doc`;
+    smallthinker-21b-a3b-ep4: `long-decode`): (compiled, global slab
+    shape, ring shape, the configuration's name, its ModelConfig)."""
+    compiled, state, cfg = slab_decode(request.param)
     return compiled, state.k.shape, state.wk.shape, request.param, cfg
 
 
@@ -450,13 +496,13 @@ def test_decode_leaves_both_caches_where_they_are(decode_window):
     index in its scalar prefetch: every attention call's operands are
     the WHOLE stacked arrays with a row's K heads merged in the lanes
     (the kernel's key block is a dense [rows, K * D] tile of them),
-    the program's temporaries are no more than the parent's (about
-    one ring's size: as xs/ys of the scan it kept a second slab,
-    3.2 GB), and no `copy`, `dynamic-slice` or
+    the program's temporaries are a fraction of one ring's size (as
+    xs/ys of the scan it kept a second slab, 3.2 GB), and no `copy`,
+    `dynamic-slice` or
     `dynamic-update-slice` gives a slab, a ring or a layer of
     either."""
     compiled, slab, ring, name, cfg = decode_window
-    heads, parent_temp = WINDOW_CELLS[name]
+    heads, most_temp = WINDOW_CELLS[name]
     text = compiled.as_text()
     calls = [line for line in text.splitlines()
              if "tpu_custom_call" in line
@@ -480,7 +526,7 @@ def test_decode_leaves_both_caches_where_they_are(decode_window):
     assert sum("/attn_window/" in c for c in calls) \
         == (head + P) * (P - 1) // P
     temp = compiled.memory_analysis().temp_size_in_bytes
-    assert temp <= parent_temp < 1.1 * math.prod(ring) * 2, temp
+    assert temp <= most_temp < 0.15 * math.prod(ring) * 2, temp
     held = [slab, slab[1:], ring, ring[1:]]
     shapes = "|".join(",".join(str(d) for d in h) for h in held)
     moved = [line.strip()[:160] for line in text.splitlines()
@@ -527,6 +573,105 @@ def test_decode_names_both_attention_kinds(decode_window):
     for scope in ("moe_router", "moe_experts") + (("moe_shared",)
                                                   if shared else ()):
         assert any(f"/mlp/{scope}/" in p for p in paths), scope
+
+
+# -- the stacked attention projections lie as the decode dot reads them --
+
+
+def _relaid_projections(text, cfg, layers):
+    """The `copy` / `copy-start` / `copy-done` lines of a compiled
+    text that re-lay `wq` / `wk` / `wv` / `w_ogate`: the result is a
+    stack or a layer's slice of one in the order it is stored ([L,
+    heads, Dh, D]) but with another dimension minor, or has the shape
+    of the order they were in ([L, D, heads, Dh]). (A flattened view
+    has the shape of other leaves, a shared expert's or a DeltaNet
+    mixer's, and is held where it once appeared:
+    `test_decode_writes_a_steps_rows_in_place`.) A copy that keeps
+    the stored order is the compiler's
+    prefetch of a layer's slice into its other memory space (`wo`,
+    which lies the same way, always had three a step) and re-lays
+    nothing."""
+    D, Dh = cfg.hidden_size, cfg.head_dim
+    stored, other = set(), set()
+    for heads in (cfg.num_heads, cfg.num_kv_heads):
+        for lead in ((), (1,), (layers,)):
+            stored.add(lead + (heads, Dh, D))
+            other.add(lead + (D, heads, Dh))
+    out = []
+    for line in text.splitlines():
+        m = re.search(r"= \w+\[([\d,]+)\]\{([\d,]*)\S* "
+                      r"copy(-start|-done)?\(", line)
+        if not m:
+            continue
+        shape = tuple(int(d) for d in m.group(1).split(","))
+        order = [int(d) for d in m.group(2).split(",")]
+        if shape in other or (shape in stored
+                              and order != sorted(order, reverse=True)):
+            out.append(line.strip()[:160])
+    return out
+
+
+@pytest.mark.parametrize("name", [
+    "qwen3-4b", "qwen3-next-80b-a3b-ep4", "trinity-mini-ep4",
+    "smallthinker-21b-a3b-ep4"])
+def test_decode_relays_no_attention_projection(decode_paged, slab_decode,
+                                               name):
+    """`wq` / `wk` / `wv` / `w_ogate` are stored [L, heads, Dh, D],
+    the order the decode step's dot reads them in (`llama._proj`), so
+    the compiled decode program of each of the benchmark's four
+    configurations holds no `copy` whose result is a layer's slice of
+    one of them (the paged scan's `copy bf16[1,2560,32,128]`, 1.9 ms
+    a step) or a whole stack (the window / global and hybrid scans'
+    `copy bf16[24,2560,28,128]` ahead of the loop, 1.6 ms): ROADMAP
+    A4, ledger PR 39 `breakdown.device_ops`."""
+    if name == "qwen3-4b":
+        compiled, layers, cfg = decode_paged("bf16")[0], LAYERS, _qwen3_4b()
+    else:
+        compiled, _, cfg = slab_decode(name)
+        # a hybrid model stacks its full-attention layers alone
+        layers = (cfg.kv_cache_layers if cfg.is_hybrid
+                  else cfg.num_layers - cfg.first_k_dense)
+    relaid = _relaid_projections(compiled.as_text(), cfg, layers)
+    assert not relaid, relaid
+
+
+def test_decode_multi_paged_keeps_no_copy_of_the_projections(topo):
+    """`decode_multi_paged[n=4]` of the qwen3-4b cells (the engine's
+    own program at 16 slots over the pool of 198 blocks): its 1.14 GB
+    of temporaries (chip compiler, PR 28-30) were the re-laid stacks
+    of `wq` / `wk` / `wv`, hoisted out of the four-step loop. Stored
+    as the dot reads them, nothing is re-laid and the temporaries are
+    a fraction of one stack."""
+    from ome_tpu.engine.core import DecodeState, InferenceEngine
+    from ome_tpu.models import llama
+    from ome_tpu.perf.ledger import ProgramLedger
+
+    sharding = SingleDeviceSharding(topo.devices[0])
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    cfg = _qwen3_4b()
+    params = jax.tree.map(
+        lambda a: S(a.shape, a.dtype),
+        jax.eval_shape(lambda k: llama.init_params(k, cfg),
+                       jax.random.PRNGKey(0)))
+    i32, f32 = jnp.int32, jnp.float32
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(device, "on_tpu", lambda: True)
+        eng = InferenceEngine(params, cfg, max_slots=B, max_seq=2048,
+                              kv_block=BS, kv_blocks=N_BLOCKS,
+                              ledger=ProgramLedger("off"))
+        pool = S((LAYERS, N_BLOCKS, BS, K, D), cfg.dtype)
+        state = DecodeState(k=pool, v=pool, lengths=S((B,), i32),
+                            tokens=S((B,), i32), adapters=S((B,), i32))
+        compiled = eng.programs["decode_multi_paged"].lower(
+            params, state, S((B, eng.max_blocks), i32), S((B,), f32),
+            S((B,), i32), S((B,), f32), S((2,), jnp.uint32),
+            S((B,), i32), S((B, 4), i32), n=4).compile()
+    assert not _relaid_projections(compiled.as_text(), cfg, LAYERS)
+    one_stack = LAYERS * H * D * cfg.hidden_size * 2      # wq: 755 MB
+    assert compiled.memory_analysis().temp_size_in_bytes < one_stack / 4
 
 
 def test_flash_decode_reads_a_layer_of_a_stacked_slab(one_chip):

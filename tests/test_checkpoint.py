@@ -62,6 +62,66 @@ def test_multi_shard_checkpoint_via_index(tmp_path):
     assert c.read("w2").shape == (3,)
 
 
+# -- a Hugging Face-named projection through the loader and `_proj` -------
+
+
+@pytest.mark.parametrize("arch", ["split", "fused_qkv", "gate_proj"])
+def test_a_hf_named_projection_gives_x_times_w_through_proj(tmp_path, arch):
+    """A Hugging Face `q_proj.weight` is [heads * Dh, D] and means
+    `y = x @ W.T`. The loader stores it [heads, Dh, D] (out-major: no
+    transpose, `checkpoint.convert_llama`) and `llama._proj` contracts
+    its last dim, so the two together give HF's `y`, heads split, for
+    the spellings the loader reads: separate q / k / v (llama), the
+    fused `qkv_proj` (phi3) and an output gate's `gate_proj` (afmoe);
+    Qwen3-Next's `q_proj` of a query and a gate a head is held against
+    the published model in tests/test_qwen3_next.py."""
+    D, H, K, Dh, V = 32, 4, 2, 8, 64
+    rng = np.random.RandomState(0)
+    t = {"model.embed_tokens.weight": rng.randn(V, D),
+         "model.norm.weight": np.ones(D),
+         "lm_head.weight": rng.randn(V, D)}
+    pre = "model.layers.0."
+    hf = dict(hidden_size=D, num_hidden_layers=1, num_attention_heads=H,
+              num_key_value_heads=K, head_dim=Dh, intermediate_size=48,
+              vocab_size=V, max_position_embeddings=64,
+              tie_word_embeddings=False, rms_norm_eps=1e-6)
+    q, k, v = (rng.randn(n * Dh, D) for n in (H, K, K))
+    gate = rng.randn(H * Dh, D)
+    want = {"wq": q, "wk": k, "wv": v}
+    for n in ("input_layernorm", "post_attention_layernorm"):
+        t[pre + n + ".weight"] = np.ones(D)
+    for n, shape in (("gate_proj", (48, D)), ("up_proj", (48, D)),
+                     ("down_proj", (D, 48))):
+        t[pre + "mlp." + n + ".weight"] = rng.randn(*shape)
+    t[pre + "self_attn.o_proj.weight"] = rng.randn(D, H * Dh)
+    if arch == "fused_qkv":
+        hf.update(architectures=["Phi3ForCausalLM"], model_type="phi3")
+        t[pre + "self_attn.qkv_proj.weight"] = np.concatenate([q, k, v])
+    else:
+        t[pre + "self_attn.k_proj.weight"] = k
+        t[pre + "self_attn.v_proj.weight"] = v
+        t[pre + "self_attn.q_proj.weight"] = q
+        hf.update(architectures=["LlamaForCausalLM"], model_type="llama")
+    if arch == "gate_proj":
+        t[pre + "self_attn.gate_proj.weight"] = gate
+        want["w_ogate"] = gate
+    ck.save_safetensors(str(tmp_path / "model.safetensors"),
+                        {n: np.asarray(a, np.float32) for n, a in t.items()})
+    cfg = ModelConfig.from_hf_config(hf).replace(dtype=jnp.float32)
+    params = ck.convert_llama(ck.Checkpoint(str(tmp_path)), cfg,
+                              dtype=jnp.float32)
+    x = rng.randn(2, 5, D).astype(np.float32)
+    for name, w in want.items():
+        heads = w.shape[0] // Dh
+        leaf = params["layers"][name]
+        assert leaf.shape == (1, heads, Dh, D), (name, leaf.shape)
+        got = llama._proj(jnp.asarray(x), jnp.asarray(leaf[0]), jnp.float32,
+                          out_dims=(heads, Dh), out_major=True)
+        np.testing.assert_allclose(
+            np.asarray(got), (x @ w.T).reshape(2, 5, heads, Dh),
+            atol=1e-5, err_msg=name)
+
+
 # -- transformers equivalence ----------------------------------------------
 
 transformers = pytest.importorskip("transformers")

@@ -11,12 +11,13 @@ from ome_tpu.models.quant import quantize_tensor_int4
 from ome_tpu.ops.int4_matmul import flatten_qtensor, int4_matmul
 
 
-def _check(x, w, contract_axes, group):
+def _check(x, w, contract_axes, group, out_major=False):
     qt = quantize_tensor_int4(jnp.asarray(w), contract_axes,
                               group=group)
     K = x.shape[-1]
-    want = x.astype(np.float32) @ np.asarray(
-        qt.dequant(jnp.float32)).reshape(K, -1)
+    deq = np.asarray(qt.dequant(jnp.float32))
+    want = x.astype(np.float32) @ (deq.reshape(-1, K).T if out_major
+                                   else deq.reshape(K, -1))
     got = int4_matmul(jnp.asarray(x), qt, jnp.float32, interpret=True)
     assert got is not None, "kernel unexpectedly fell back"
     np.testing.assert_allclose(np.asarray(got), want, rtol=2e-2,
@@ -33,6 +34,18 @@ def test_kernel_matches_dequant_gate_layout(k):
     _check(rng.standard_normal((16, k), dtype=np.float32),
            rng.standard_normal((k, 256), dtype=np.float32),
            contract_axes=(0,), group=128)
+
+
+@pytest.mark.parametrize("k,heads", [(1024, 2), (2560, 4), (4096, 2)])
+def test_kernel_matches_dequant_out_major_layout(k, heads):
+    # wq-style [heads, Dh, K], pack axis LAST (llama._proj's out-major
+    # leaves): a row's nibbles along the lanes, the scales [N, K/G]
+    # re-laid to a block's [N, groups]; 10 groups a half in one k-step
+    # at K=2560, two k-steps of 8 at K=4096
+    rng = np.random.default_rng(4)
+    _check(rng.standard_normal((16, k), dtype=np.float32),
+           rng.standard_normal((heads, 128, k), dtype=np.float32),
+           contract_axes=(2,), group=128, out_major=True)
 
 
 def test_wo_layout_falls_back_and_dequants_right():
@@ -88,20 +101,26 @@ def test_flattened_views_dequantize_exactly():
     params = llama.init_params(jax.random.PRNGKey(0), cfg)
     q4 = quantize_params(params, mode="int4", group=128)
     # wo stays int8 under mode="int4" (its pack axis sits under H) —
-    # the kernel-eligible leaves are the leading-axis packed ones
+    # the kernel-eligible leaves pack their first dim (in-major: the
+    # MLP's) or their last (out-major: the attention projections)
     for name in ("wq", "wk", "wv", "w_gate", "w_up"):
         qt = jax.tree.map(lambda a: a[0], q4["layers"][name])
         flat = flatten_qtensor(qt)
         assert flat is not None, name
-        qp2, s2, K, N, gsize = flat
-        deq = np.asarray(qt.dequant(jnp.float32)).reshape(K, N)
+        qp2, s2, K, N, gsize, out_major = flat
+        assert out_major == (name in llama.OUT_MAJOR), name
+        deq = np.asarray(qt.dequant(jnp.float32))
         # reconstruct from the 2D views exactly as the kernel does:
         # low nibbles = rows [0, K/2), high nibbles = rows [K/2, K)
+        # of the contraction, which is the views' first dim in-major
+        # and their last out-major
+        ax = 1 if out_major else 0
+        deq = deq.reshape((N, K) if out_major else (K, N))
         qp = np.asarray(qp2).astype(np.int32)
         lo = (qp << 28) >> 28
         hi = qp >> 4
-        w = np.concatenate([lo, hi], axis=0)
-        rebuilt = w * np.repeat(np.asarray(s2), gsize, axis=0)
+        w = np.concatenate([lo, hi], axis=ax)
+        rebuilt = w * np.repeat(np.asarray(s2), gsize, axis=ax)
         np.testing.assert_allclose(rebuilt, deq, rtol=1e-6)
     from ome_tpu.models.quant import QTensor
     assert isinstance(q4["layers"]["wo"], QTensor)

@@ -76,7 +76,8 @@ def test_merge_applies_exact_delta(tmp_path):
 
     assert merge_lora(params, cfg, adapter) == 2
 
-    want_q = wq_before + (scale * (B_q @ A_q)).T.reshape(32, 4, 8)
+    # wq lies out-major [H, Dh, D]: the delta [out, in] with heads split
+    want_q = wq_before + (scale * (B_q @ A_q)).reshape(4, 8, 32)
     np.testing.assert_allclose(params["layers"]["wq"][0], want_q,
                                atol=1e-5)
     want_d = wdown_before + (scale * (B_d @ A_d)).T
@@ -270,3 +271,45 @@ def test_unknown_adapter_fails_request_not_scheduler(tmp_path):
     assert bad.finish_reason == "error"
     assert ok.finish_reason in ("stop", "length")
     assert sched.healthy
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_per_slot_lora_on_out_major_projections_matches_the_parent_form(
+        tmp_path, dtype):
+    """Per-slot LoRA on `wq` (stored [L, heads, Dh, D] since PR 41; the
+    factors stay [r, K] / [r, N]): one engine serving the base model
+    and two adapters in one batch gives, slot by slot, the logits of
+    the same engine built on the parent's form of the projections
+    (`_parent_proj.parent_form`): to the bit in bfloat16, to 1e-5 in
+    float32."""
+    import jax
+
+    from _parent_proj import parent_form
+    from ome_tpu.engine.core import InferenceEngine
+    base = _mk_base(tmp_path)
+    a1 = _mk_named_adapter(tmp_path, "a1", seed=11)
+    a2 = _mk_named_adapter(tmp_path, "a2", seed=22)
+    dt = jnp.dtype(dtype)
+
+    def logits():
+        params, cfg = ck.load_params(base, dtype=dt, device_put=False)
+        params = jax.tree.map(jnp.asarray, params)
+        eng = InferenceEngine(params, cfg, max_slots=4, max_seq=32,
+                              prefill_buckets=[8], lora_slots=3,
+                              lora_rank=8)
+        eng.register_adapter("a1", a1)
+        eng.register_adapter("a2", a2)
+        toks = jnp.asarray([[5, 6, 7, 8]] * 3, jnp.int32)
+        lg, _ = llama.forward(eng.params, cfg, toks,
+                              adapter_ids=jnp.asarray([0, 1, 2]))
+        return np.asarray(lg.astype(jnp.float32))
+
+    got = logits()
+    with parent_form():
+        want = logits()
+    assert np.abs(got[0] - got[1]).max() > 1e-3      # the adapters act
+    assert np.abs(got[1] - got[2]).max() > 1e-3
+    if dtype == "bfloat16":
+        np.testing.assert_array_equal(got, want)
+    else:
+        np.testing.assert_allclose(got, want, atol=1e-5)

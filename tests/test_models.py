@@ -1,10 +1,18 @@
 """Model correctness tests: causality, decode/prefill consistency,
 MoE routing, parameter accounting."""
 
+import dataclasses
+import importlib
+import json
+import os
+import sys
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
+from _parent_proj import in_major, parent_form, stored
 from ome_tpu.models import config as cfgs
 from ome_tpu.models import llama
 
@@ -162,3 +170,121 @@ class TestAccounting:
         for _ in range(5):
             l1, params = step(params)
         assert l1 < l0
+
+
+# -- the attention projections lie out-major (PR 41) ---------------------
+
+_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+# the benchmark's four families at the toy size its own rehearsals run:
+# family -> (fixture directory, configuration, reference module,
+# reference's groups of leaves -> the program's blocks)
+FAMILIES = {
+    "dense": ("fixture", "tiny-qwen3", "dense_gqa", {None: "layers"}),
+    "hybrid": ("fixture_hybrid", "tiny-qwen3-next", "hybrid_gdn_moe",
+               {"full": "layers", "linear": "linear_layers"}),
+    "window": ("fixture_window", "tiny-afmoe", "window_moe",
+               {"moe": "layers", "dense": "dense_layers"}),
+    "preroute": ("fixture_preroute", "tiny-smallthinker", "preroute_moe",
+                 {"layers": "layers"}),
+}
+
+
+def _family(name, dtype):
+    fixture, config, ref, groups = FAMILIES[name]
+    with open(os.path.join(_ROOT, "tests", "benchmark", fixture,
+                           "benchmark", "configs", config + ".json")) as f:
+        file = json.load(f)
+    hf = {k: v for k, v in file.items()
+          if k not in ("source", "reduced", "assumed", "benchmark")}
+    cfg = cfgs.ModelConfig.from_hf_config(hf).replace(dtype=dtype)
+    if cfg.is_moe:
+        cfg = cfg.replace(moe_impl="ragged")
+    params = jax.jit(
+        lambda: llama.init_params(jax.random.PRNGKey(0), cfg))()
+    sys.path.insert(0, os.path.join(_ROOT, "benchmark"))
+    try:
+        ref = importlib.import_module("reference." + ref)
+    finally:
+        sys.path.pop(0)
+    return hf, cfg, params, ref, groups
+
+
+@pytest.mark.parametrize("family", sorted(FAMILIES))
+def test_init_params_draws_what_the_benchmarks_reference_draws(family):
+    """`--random-weights` at the one seed gives every leaf the values
+    the configuration's plain reference draws from its own code
+    (benchmark/reference/*: `normal(key, (L, D, heads, Dh))` for the
+    attention projections): an out-major leaf is drawn in that shape
+    and key order and re-laid after the draw, never drawn in a new
+    shape, so `check.py`'s reference still scores the served model."""
+    hf, cfg, params, ref, groups = _family(family, jnp.bfloat16)
+    w = ref.init_weights(hf)
+    seen = set()
+    for group, block in groups.items():
+        theirs = w if group is None else w[group]
+        for name, mine in params[block].items():
+            want = stored(name, theirs[name])
+            assert mine.shape == want.shape, (block, name)
+            assert (np.asarray(mine.astype(jnp.float32))
+                    == np.asarray(want.astype(jnp.float32))).all(), name
+            seen.add(name)
+    assert set(llama.OUT_MAJOR) & seen >= {"wq", "wk", "wv"}
+    D = cfg.hidden_size
+    for block in groups.values():
+        for name in set(llama.OUT_MAJOR) & set(params[block]):
+            heads = cfg.num_heads if name in ("wq", "w_ogate") \
+                else cfg.num_kv_heads
+            assert params[block][name].shape[1:] == (heads, cfg.head_dim, D)
+
+
+@pytest.mark.parametrize("family", sorted(FAMILIES))
+def test_out_major_projections_give_the_parents_logits_to_the_bit(family):
+    """bfloat16, every family of the benchmark: one full pass, then a
+    prompt and six decode steps through the cache (the slab and, where
+    the family has one, the ring and the recurrent state), with the
+    projections stored out-major and on the parent's form of the same
+    leaves: the same bits."""
+    hf, cfg, params, _, _ = _family(family, jnp.bfloat16)
+    toks = jnp.asarray(np.random.RandomState(3).randint(
+        0, cfg.vocab_size, (1, 20)))
+
+    def passes():
+        full, _ = llama.forward(params, cfg, toks)
+        out = [full]
+        cache = llama.KVCache.create(cfg, 1, 32)
+        cache = dataclasses.replace(
+            cache, index=jnp.zeros((1,), jnp.int32))
+        n = jnp.asarray([14], jnp.int32)
+        lg, cache = llama.forward(params, cfg, toks[:, :14], cache=cache,
+                                  valid_len=n)
+        out.append(lg)
+        for t in range(14, 20):
+            lg, cache = llama.forward(params, cfg, toks[:, t:t + 1],
+                                      cache=cache)
+            out.append(lg)
+        return [np.asarray(x.astype(jnp.float32)) for x in out]
+
+    got = passes()
+    with parent_form():
+        want = passes()
+    assert float(np.std(want[0])) > 0.01
+    for a, b in zip(got, want):
+        np.testing.assert_array_equal(a, b)
+
+
+def test_parent_form_is_the_in_major_dot():
+    """The helper the comparisons above stand on: under `parent_form`
+    an out-major leaf reaches the in-major einsum as [D, heads, Dh]."""
+    w = jax.random.normal(jax.random.PRNGKey(0), (64, 4, 16))
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 3, 64))
+    want = jnp.einsum("bsd,dhk->bshk", x, w)
+    lay = llama.to_out_major(w)
+    assert lay.shape == (4, 16, 64)
+    np.testing.assert_array_equal(np.asarray(in_major(lay)), np.asarray(w))
+    got = llama._proj(x, lay, jnp.float32, out_dims=(4, 16), out_major=True)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
+    with parent_form():
+        old = llama._proj(x, lay, jnp.float32, out_dims=(4, 16),
+                          out_major=True)
+    np.testing.assert_array_equal(np.asarray(old), np.asarray(
+        llama._proj(x, w, jnp.float32, out_dims=(4, 16))))

@@ -48,12 +48,13 @@ class TestShardingRules:
         params = llama.init_params(jax.random.PRNGKey(0), cfg)
         staged = sharding.stack_to_stages(params, 2)
         shp = sharding.shard_params(staged, mesh8, pipeline=True)
-        wq = shp["layers"]["wq"]  # [pp, l, D, H, Dh], pp+tp sharded
+        wq = shp["layers"]["wq"]  # [pp, l, H, Dh, D], pp+tp sharded
         n_shards = len({s.device for s in wq.addressable_shards})
         assert n_shards == 8  # spread over all devices (dp replicates)
         shard_shape = wq.addressable_shards[0].data.shape
         assert shard_shape[0] == 1  # pp split
-        assert shard_shape[3] == cfg.num_heads // 2  # tp split on heads
+        assert shard_shape[2] == cfg.num_heads // 2  # tp split on heads
+        assert shard_shape[3:] == (cfg.head_dim, cfg.hidden_size)
 
     def test_stack_unstack_roundtrip(self):
         cfg = cfgs.tiny_test()

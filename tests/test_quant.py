@@ -221,3 +221,53 @@ def test_fp8_roundtrip_and_forward():
     # same byte footprint as int8 weights
     q8 = quantize_params(params, mode="int8")
     assert quantized_bytes(qp) == quantized_bytes(q8)
+
+
+# -- out-major attention projections (PR 41) -----------------------------
+
+
+@pytest.mark.parametrize("mode", ["int8", "int4"])
+def test_out_major_leaves_quantize_to_the_parent_forms_numbers(mode):
+    """`wq` / `wk` / `wv` lie [L, heads, Dh, D] and reduce over their
+    LAST dim (`_LAYER_CONTRACT`): the scales span the same values as
+    the parent's [L, D, heads, Dh] leaf reduced over D, an int4 leaf's
+    groups are the same 64 of D, so the dequantized leaf is the
+    parent's, re-laid, to the bit; and the logits through the
+    out-major dot are the parent form's (float32: up to the order of
+    a sum)."""
+    from _parent_proj import in_major, parent_form
+    cfg = tiny_test().replace(dtype=jnp.float32)
+    params = llama.init_params(jax.random.PRNGKey(0), cfg)
+    q = quantize_params(params, mode=mode, group=64)
+    quantize = quantize_tensor if mode == "int8" else \
+        (lambda w, axes: quantize_tensor_int4(w, axes, group=64))
+    for name in ("wq", "wk", "wv"):
+        leaf = q["layers"][name]
+        assert isinstance(leaf, QTensor) and leaf.shape \
+            == params["layers"][name].shape
+        assert leaf.bits == (8 if mode == "int8" else 4)
+        parent = quantize(in_major(params["layers"][name]), (1,))
+        np.testing.assert_array_equal(
+            np.asarray(in_major(leaf.dequant(jnp.float32))),
+            np.asarray(parent.dequant(jnp.float32)))
+        if mode == "int4":
+            # packed along D, the minor dim: what the kernel's
+            # out-major form reads (ops/int4_matmul.py)
+            assert leaf.axis == -1 and leaf.q.shape[-1] \
+                == cfg.hidden_size // 2
+    tok = jnp.asarray([[1, 2, 3, 4, 5, 6, 7, 8]], jnp.int32)
+    got, _ = llama.forward(q, cfg, tok)
+    with parent_form():
+        want, _ = llama.forward(q, cfg, tok)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=1e-5)
+    low = jax.tree.map(
+        lambda a: a.astype(jnp.bfloat16) if a.dtype == jnp.float32
+        and a.ndim > 1 else a, params)
+    qb = quantize_params(low, mode=mode, group=64)
+    cb = cfg.replace(dtype=jnp.bfloat16)
+    got, _ = llama.forward(qb, cb, tok)
+    with parent_form():
+        want, _ = llama.forward(qb, cb, tok)
+    np.testing.assert_array_equal(np.asarray(got.astype(jnp.float32)),
+                                  np.asarray(want.astype(jnp.float32)))

@@ -86,3 +86,35 @@ def test_tp4_kv_head_sharding_layout():
     K = cfg.num_kv_heads
     assert state.k.shape[3:] == (K * cfg.head_dim,)
     assert all(sh[3:] == (K // 4 * cfg.head_dim,) for sh in shard_shapes)
+
+
+@pytest.mark.parametrize("tp", [2, 4])
+def test_tp_shards_the_out_major_projections_on_their_heads(tp):
+    """`wq` / `wk` / `wv` lie [L, heads, Dh, D] (llama._proj) and `tp`
+    stays on the heads, now their first dim behind the layers: a chip
+    holds whole heads' rows, the sharded forward gives the single
+    device's logits, and those are the parent form's (the same leaves
+    put back [L, D, heads, Dh] and contracted in-major)."""
+    import jax.numpy as jnp
+    from _parent_proj import parent_form
+    from ome_tpu.parallel.mesh import MeshConfig, build_mesh
+    from ome_tpu.parallel.sharding import shard_params
+
+    cfg = tiny_test().replace(dtype=jnp.float32)
+    params = llama.init_params(jax.random.PRNGKey(0), cfg)
+    sharded = shard_params(params, build_mesh(MeshConfig(tp=tp)))
+    for name, heads in (("wq", cfg.num_heads), ("wk", cfg.num_kv_heads),
+                        ("wv", cfg.num_kv_heads)):
+        leaf = sharded["layers"][name]
+        assert leaf.shape[1:] == (heads, cfg.head_dim, cfg.hidden_size)
+        assert {s.data.shape[1:] for s in leaf.addressable_shards} \
+            == {(heads // tp, cfg.head_dim, cfg.hidden_size)}
+    tok = jnp.asarray([[1, 2, 3, 4, 5, 6, 7, 8]], jnp.int32)
+    ref, _ = jax.jit(lambda p, t: llama.forward(p, cfg, t))(params, tok)
+    got, _ = jax.jit(lambda p, t: llama.forward(p, cfg, t))(sharded, tok)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=1e-5)
+    with parent_form():
+        want, _ = jax.jit(lambda p, t: llama.forward(p, cfg, t))(
+            sharded, tok)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=1e-5)
