@@ -1045,7 +1045,17 @@ def _hybrid_scan(params: Params, cfg: ModelConfig, x: jax.Array, freqs,
     stacked blocks (`linear_layers`, `layers`) and the body unrolls
     one period. KV rows exist for the full layers only; the DeltaNet
     layers carry `cache.rec` (zeros when there is no cache: every
-    sequence then starts from an empty state)."""
+    sequence then starts from an empty state).
+
+    Both kinds of per-slot state are the scan's CARRY, as
+    `_alt_window_scan`'s caches are: the full layers' slabs
+    `cache.k / v` [G, B, Smax, K * Dh] whole, period g's layer handed
+    a `SlabLayer(k, v, g)` that writes its rows in place and reads
+    them by layer index (`_mha`), and the recurrent state, each layer
+    updating its own rows. As scanned input and output either would
+    be sliced out a period, stacked back and copied whole after the
+    loop, every step. The layers' weights are scanned input (they are
+    only read) and the experts' stacks closed over (`split`)."""
     L, P = cfg.num_layers, cfg.full_attn_interval
     G = L // P
     B = x.shape[0]
@@ -1076,11 +1086,8 @@ def _hybrid_scan(params: Params, cfg: ModelConfig, x: jax.Array, freqs,
         return dict(lp, **stacks, expert_layer=layer) if stacks else lp
 
     def body(carry, per):
-        # the recurrent state rides the carry whole and each layer
-        # updates its own rows in place; as scanned input and output
-        # it would be copied in and out every step
-        x, rec = carry
-        g, lin_g, full_g, c = per
+        x, rec, ck, cv = carry
+        g, lin_g, full_g = per
         counts = jnp.zeros((3,), jnp.uint32)
         for j in range(P - 1):
             li = g * (P - 1) + j
@@ -1099,22 +1106,26 @@ def _hybrid_scan(params: Params, cfg: ModelConfig, x: jax.Array, freqs,
                            rec["conv"], tail_j.astype(rec["conv"].dtype),
                            li, 0)}
             counts = counts + _moe_counts(st)
+        slab = SlabLayer(ck, cv, g) if cache is not None else None
         x, nc, st = _layer(x, with_experts(full_g, full_experts, g), cfg,
-                           freqs, positions, kv_len, c, index,
+                           freqs, positions, kv_len, slab, index,
                            adapter_ids=adapter_ids, moe_stats=True)
+        if nc is not None:
+            ck, cv = nc
         counts = counts + _moe_counts(st)
-        return (x, rec), (nc, counts)
+        return (x, rec, ck, cv), counts
 
-    xs = (jnp.arange(G, dtype=jnp.int32), jax.tree.map(group, lin), full,
-          (cache.k, cache.v) if cache is not None else None)
-    (x, rec), (nc, counts) = lax.scan(body, (x, rec), xs)
+    xs = (jnp.arange(G, dtype=jnp.int32), jax.tree.map(group, lin), full)
+    carry = (x, rec) + ((cache.k, cache.v) if cache is not None
+                        else (None, None))
+    (x, rec, ck, cv), counts = lax.scan(body, carry, xs)
     if cache is None:
         return x, None
     S = positions.shape[1]
     stats = cache.stats
     if stats is not None:
         stats = stats + jnp.sum(counts, axis=0, dtype=jnp.uint32)
-    return x, KVCache(k=nc[0], v=nc[1], index=cache.index + S,
+    return x, KVCache(k=ck, v=cv, index=cache.index + S,
                       rec=rec, stats=stats)
 
 
@@ -1161,8 +1172,10 @@ def _qkv(h: jax.Array, lp: Params, cfg: ModelConfig, freqs: jax.Array,
 
 class SlabLayer(NamedTuple):
     """One layer's place in the stacked slabs that a layer scan
-    carries (`_alt_window_scan`), where `_layer` and `_mha` otherwise
-    take one layer's (k, v): the layer's rows are written in place
+    carries (`_alt_window_scan`: the global layers' slabs and the
+    window layers' rings; `_hybrid_scan`: the full-attention layers'
+    slabs), where `_layer` and `_mha` otherwise take one layer's
+    (k, v): the layer's rows are written in place
     and read through the index, and nothing slices a layer out (a
     slice of a carried slab is a copy of it, ops/paged.py)."""
     k: jax.Array                # [L, B, Smax, K * Dh] (or [.., K, Dh],

@@ -478,6 +478,37 @@ def slab_decode(topo):
     return compiled
 
 
+def _flash_decode_calls(text):
+    """(line, its operands' dims as the kernel constrains them) of
+    each `flash_decode` call of a compiled text."""
+    out = []
+    for line in text.splitlines():
+        if "tpu_custom_call" in line \
+                and re.search(r"%flash_decode(\.\d+)? = ", line):
+            operands = re.search(
+                r"operand_layout_constraints=\{(.*?)\}\}, ", line).group(1)
+            out.append((line, re.findall(r"\w+\[([\d,]*)\]", operands)))
+    return out
+
+
+def _moved(text, held):
+    """The `copy`, `dynamic-slice` and `dynamic-update-slice` lines of
+    a compiled text whose result has one of the shapes `held`."""
+    shapes = "|".join(",".join(str(d) for d in h) for h in held)
+    return [line.strip()[:160] for line in text.splitlines()
+            if re.search(rf"= \w+\[({shapes})\]\S* (copy|dynamic-slice|"
+                         r"dynamic-update-slice)\(", line)]
+
+
+def _kv_write_scatters(text):
+    """The result shape of each scatter under `kv_write` that updates
+    a bf16 array in place."""
+    return [re.search(r"= bf16\[([\d,]*)\]", line).group(1)
+            for line in text.splitlines()
+            if re.search(r"ROOT %scatter\S* = bf16\[", line)
+            and "/kv_write/" in line]
+
+
 @pytest.fixture(scope="module", params=sorted(WINDOW_CELLS))
 def decode_window(slab_decode, request):
     """The `decode` program of a configuration of the periodic window
@@ -504,9 +535,7 @@ def test_decode_leaves_both_caches_where_they_are(decode_window):
     compiled, slab, ring, name, cfg = decode_window
     heads, most_temp = WINDOW_CELLS[name]
     text = compiled.as_text()
-    calls = [line for line in text.splitlines()
-             if "tpu_custom_call" in line
-             and re.search(r"%flash_decode(\.\d+)? = ", line)]
+    calls = _flash_decode_calls(text)
     # the unrolled layers of the head and the period's 4 in the scan
     P = cfg.sliding_pattern
     head = -(-cfg.first_k_dense // P) * P
@@ -514,24 +543,17 @@ def test_decode_leaves_both_caches_where_they_are(decode_window):
     kinds = {"attn_window": ring, "attn_global": slab}
     B, K, D = slab[1], cfg.num_kv_heads, cfg.head_dim
     assert slab[3:] == ring[3:] == (K * D,)
-    for line in calls:
+    for line, dims in calls:
         kind = next(k for k in kinds if f"/{k}/attn/" in line)
-        operands = re.search(r"operand_layout_constraints=\{(.*?)\}\}, ",
-                             line).group(1)
-        dims = re.findall(r"\w+\[([\d,]*)\]", operands)
         whole = ",".join(str(d) for d in kinds[kind])
         assert whole.endswith(f",{K * D}")
         assert dims == [f"{B},3", f"{B},{K},{heads // K},{D}", whole,
                         whole], dims
-    assert sum("/attn_window/" in c for c in calls) \
+    assert sum("/attn_window/" in line for line, _ in calls) \
         == (head + P) * (P - 1) // P
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp <= most_temp < 0.15 * math.prod(ring) * 2, temp
-    held = [slab, slab[1:], ring, ring[1:]]
-    shapes = "|".join(",".join(str(d) for d in h) for h in held)
-    moved = [line.strip()[:160] for line in text.splitlines()
-             if re.search(rf"= \w+\[({shapes})\]\S* (copy|dynamic-slice|"
-                          r"dynamic-update-slice)\(", line)]
+    moved = _moved(text, [slab, slab[1:], ring, ring[1:]])
     assert not moved, moved
 
 
@@ -543,15 +565,12 @@ def test_decode_writes_a_steps_rows_in_place(decode_window):
     `wk` / `wv` slice was copied to [hidden, K * D] every step)."""
     compiled, slab, ring, name, cfg = decode_window
     text = compiled.as_text()
-    scatters = [line for line in text.splitlines()
-                if re.search(r"ROOT %scatter\S* = bf16\[", line)
-                and "/kv_write/" in line]
+    scatters = _kv_write_scatters(text)
     P = cfg.sliding_pattern
     head = -(-cfg.first_k_dense // P) * P
     assert len(scatters) == 2 * (head + P)
-    shapes = {",".join(str(d) for d in s) for s in (slab, ring)}
-    for line in scatters:
-        assert re.search(r"= bf16\[([\d,]*)\]", line).group(1) in shapes
+    assert set(scatters) <= {",".join(str(d) for d in s)
+                             for s in (slab, ring)}, scatters
     K, D, hidden = cfg.num_kv_heads, cfg.head_dim, cfg.hidden_size
     relaid = [line.strip()[:160] for line in text.splitlines()
               if re.search(rf"= bf16\[{hidden},{K * D}\]\S* "
@@ -573,6 +592,44 @@ def test_decode_names_both_attention_kinds(decode_window):
     for scope in ("moe_router", "moe_experts") + (("moe_shared",)
                                                   if shared else ()):
         assert any(f"/mlp/{scope}/" in p for p in paths), scope
+
+
+# what the hybrid cell's decode step may keep in temporaries: it reads
+# 0.367 GB (chip compiler, PR 44), none of it rows: the head re-laid
+# `bf16[2048,37984]` 0.156, the DeltaNet layers' `w_z` stack
+# `copy bf16[9,2048,4096]` 0.151, and the period's weight slices
+# (ROADMAP A4's remainder). One slab is 0.403 GB, and a step that
+# stacked both anew and sliced a layer out read 1.52 GB
+HYBRID_CELL_TEMP = 380_000_000
+
+
+def test_hybrid_decode_carries_its_slab(slab_decode):
+    """The full-attention layers' slabs of `qwen3-next-80b-a3b-ep4`
+    (long-batch: [3, 32, 4096, 2 * 256] for K and for V) are the layer
+    scan's carry beside the recurrent state (`llama._hybrid_scan`):
+    no `copy`, `dynamic-slice` or `dynamic-update-slice` of the
+    compiled `decode` gives a slab or a layer of one (as xs / ys of
+    the scan it held two of each, 7.4 ms of a 23.4 ms step: ledger,
+    PR 41); the period's one `flash_decode` call takes the WHOLE
+    stacked arrays and reads its layer through the scalar prefetch;
+    a step's rows go in by two scatters in place; and the program's
+    temporaries hold no second slab."""
+    compiled, state, cfg = slab_decode("qwen3-next-80b-a3b-ep4")
+    slab = state.k.shape
+    B, K, D = slab[1], cfg.num_kv_heads, cfg.head_dim
+    assert slab == state.v.shape
+    assert (slab[0], slab[3:]) == (cfg.kv_cache_layers, (K * D,))
+    text = compiled.as_text()
+    moved = _moved(text, [slab, slab[1:]])
+    assert not moved, moved
+    whole = ",".join(str(d) for d in slab)
+    (line, dims), = _flash_decode_calls(text)
+    assert "/layers/while/body/" in line
+    assert dims == [f"{B},3", f"{B},{K},{cfg.num_heads // K},{D}", whole,
+                    whole], dims
+    assert _kv_write_scatters(text) == [whole, whole]
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp <= HYBRID_CELL_TEMP < math.prod(slab) * 2, temp
 
 
 # -- the stacked attention projections lie as the decode dot reads them --

@@ -331,6 +331,43 @@ def test_two_slots_of_different_length_decode_as_each_alone(cut, engine):
     assert engine.moe_counters() == counts       # read twice: no drift
 
 
+@pytest.mark.parametrize("merged", [True, False],
+                         ids=["merged", "heads_apart"])
+def test_a_decode_step_changes_its_own_rows_and_no_others(cut, merged):
+    """The full layers' slabs ride the layer scan's carry and a step
+    writes its rows in place (`_hybrid_scan`): a cached decode step
+    through `llama.forward` at per-slot lengths that differ changes,
+    in `k` and in `v`, exactly the rows [layer, slot, index[slot]],
+    in both full layers, and every other row of every layer comes
+    back equal to the bit."""
+    cfg, p, _ = cut
+    B, S = 3, 48
+    index = np.asarray([5, 0, 31], np.int32)
+    rng = np.random.RandomState(7)
+    cache = llama.KVCache.create(cfg, B, S, merged=merged)
+    assert cache.k.shape[0] == cfg.kv_cache_layers == 2
+    before = {n: rng.standard_normal(getattr(cache, n).shape)
+              .astype(np.float32) for n in ("k", "v")}
+    cache = llama.KVCache(
+        k=jnp.asarray(before["k"]), v=jnp.asarray(before["v"]),
+        index=jnp.asarray(index),
+        rec=jax.tree.map(lambda a: jnp.asarray(
+            rng.standard_normal(a.shape) * 0.1, a.dtype), cache.rec))
+    toks = jnp.asarray(_tokens(B, seed=3)[:, None], jnp.int32)
+    _, after = llama.forward(p, cfg, toks, cache=cache)
+    np.testing.assert_array_equal(np.asarray(after.index), index + 1)
+    written = np.zeros((cfg.kv_cache_layers, B, S), bool)
+    written[:, np.arange(B), index] = True
+    for n in ("k", "v"):
+        got = np.asarray(getattr(after, n))
+        assert got.shape == before[n].shape
+        changed = (got != before[n]).reshape(written.shape + (-1,))
+        # a fresh row differs from the noise it replaced in every
+        # lane; no other row differs in any
+        assert changed[written].all(), n
+        assert not changed[~written].any(), n
+
+
 def test_a_slots_state_is_counted_and_dense_models_have_none(engine):
     cfg = engine.cfg
     per_slot = cfg.linear_layers * (4 * 16 * 16 * 4 + 3 * 128 * 4)
