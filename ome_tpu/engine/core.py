@@ -757,9 +757,7 @@ class InferenceEngine:
         self.prefill_buckets = prefill_buckets
         # an expert layer that holds a share of its experts counts
         # what it is hit by (llama.ragged_experts), on the device
-        self._counts_experts = bool(
-            (cfg.is_hybrid or cfg.alt_sliding_window) and cfg.is_moe
-            and cfg.moe_impl == "ragged")
+        self._counts_experts = llama.counts_experts(cfg)
         self._moe_stats_dev: Optional[jax.Array] = None
         self._moe_seen = [0, 0, 0]
         self._moe_totals = {"layer_steps": 0, "experts_hit": 0,

@@ -18,7 +18,8 @@ Name mapping covers the Llama superset the model implements: llama /
 mistral / qwen2 (attention bias) / qwen3 (qk-norm) / gemma2 (softcap)
 dense models, mixtral / qwen2-moe / deepseek-style MoE with shared
 experts, qwen3-next (Gated DeltaNet layers beside gated attention),
-afmoe and smallthinker (periodic window / global attention).
+afmoe and smallthinker (periodic window / global attention),
+pangu_ultra_moe (latent attention inside sandwich norms).
 """
 
 from __future__ import annotations
@@ -312,8 +313,8 @@ def convert_llama(ckpt: Checkpoint, cfg, dtype=None) -> Dict[str, Any]:
                 st.put("q_a_norm", i,
                        take(p + "self_attn.q_a_layernorm.weight"))
                 st.put("wq_b", i,
-                       take(p + "self_attn.q_b_proj.weight").T.reshape(
-                           cfg.q_lora_rank, H, qk))
+                       take(p + "self_attn.q_b_proj.weight").reshape(
+                           H, qk, cfg.q_lora_rank))
             else:
                 st.put("wq", i,
                        out_major(p + "self_attn.q_proj.weight", H))
@@ -501,6 +502,17 @@ SUPPORTED_ARCHITECTURES = frozenset({
     # that reads the layer's input, ReLU-gated experts (PowerInfer
     # SmallThinker)
     "SmallThinkerForCausalLM",
+    # latent attention (models/mla.py, DeepSeek's tensor names) inside
+    # four norms a block (`input_layernorm`, `post_attention_layernorm`
+    # on the attention's OUTPUT, `pre_mlp_layernorm`,
+    # `post_mlp_layernorm`), leading dense layers, a plain sigmoid
+    # router (`mlp.gate.weight`, no correction bias) over routed and
+    # shared experts (openPangu-Ultra-MoE). Served: the forward pass
+    # that gives a token's logits. NOT served: the checkpoint's one
+    # multi-token-prediction module (`num_nextn_predict_layers`: the
+    # layer behind the last, a draft source), whose tensors are never
+    # read
+    "PanguUltraMoEForCausalLM",
     # decoder embedding models (engine/embed.py): bare AutoModel
     # checkpoints whose tensors lack the "model." prefix
     "MistralModel", "Qwen2Model", "Qwen3Model",

@@ -206,8 +206,17 @@ class ModelConfig:
 
     @property
     def kv_cache_k_dim(self) -> int:
+        """Lanes of a cached row's k part. A latent row `[c | k_pe]`
+        wider than one tile of 128 lanes is PADDED to whole tiles (576
+        -> 640): the chip lays an array whose minor dimension is no
+        multiple of 128 with its rows minor instead, and every decode
+        step then copies the slab into the row-major order the kernel
+        reads (chip compiler, PR 46: 2.5 GB a step at 24 x 16 384
+        rows; row-major tiles pad to 640 in HBM anyway). The padding
+        lanes are zero and read by nothing (models/mla.py)."""
         if self.mla:
-            return self.kv_lora_rank + self.qk_rope_head_dim
+            w = self.kv_lora_rank + self.qk_rope_head_dim
+            return -(-w // 128) * 128 if w > 128 else w
         return self.head_dim
 
     @property
@@ -250,8 +259,11 @@ class ModelConfig:
             cfg = dict(cfg, rope_scaling=dict(sc_raw,
                                               rope_type="longrope"))
         deepseek = arch.startswith("Deepseek")
+        pangu = arch == "PanguUltraMoEForCausalLM"
         mla_kw = {}
-        if deepseek:
+        if pangu:
+            mla_kw = _pangu_ultra_fields(cfg)
+        elif deepseek:
             # DeepSeek-V2/V3 family (Kimi-K2 ships the V3 architecture):
             # MLA attention + first-k-dense MoE + its routing flavor
             v3 = arch.startswith("DeepseekV3")
@@ -284,7 +296,7 @@ class ModelConfig:
         # q and k both scale, so logits scale by att^2 — fold it into
         # the query scale (KV cache stays unscaled). MLA models apply
         # their own mscale (models/mla.py) and skip this.
-        if not deepseek:
+        if not (deepseek or pangu):
             att = _rope_attention_factor(
                 cfg.get("rope_scaling"),
                 cfg.get("max_position_embeddings", 8192))
@@ -468,6 +480,53 @@ def _afmoe_fields(cfg: Dict[str, Any]) -> Dict[str, Any]:
         norm_topk_prob=bool(cfg.get("route_norm", True)),
         routed_scaling_factor=cfg.get("route_scale", 1.0),
         # a cut config: `num_experts` counts the experts held here
+        # (see the Qwen3Next branch)
+        num_experts_total=cfg.get("ep_num_experts_total", 0) or 0,
+        expert_offset=cfg.get("ep_expert_offset", 0) or 0)
+
+
+def _pangu_ultra_fields(cfg: Dict[str, Any]) -> Dict[str, Any]:
+    """openPangu-Ultra-MoE (`pangu_ultra_moe`): latent attention under
+    DeepSeek's key names (`q_lora_rank`, `kv_lora_rank`, the three
+    head dims; models/mla.py), `first_k_dense_replace` leading dense
+    layers, then `n_routed_experts` routed experts beside
+    `n_shared_experts` shared ones under a plain float32 sigmoid
+    router: the top `num_experts_per_tok` scores, normalised, times
+    `routed_scaling_factor`, with no selection bias and no groups
+    (config.json has no `n_group`, `topk_group`, `scoring_func` or
+    correction-bias key); `sandwich_norm`: four RMSNorms a block, the
+    attention's and the MLP's outputs normed before the residual adds
+    (its depth scaling is an initialisation of those norms' weights).
+    `num_nextn_predict_layers` (a multi-token-prediction module that
+    drafts a second token) is no part of the forward pass and is read
+    by nothing. Raises for the variants that are not implemented."""
+    def refuse(what):
+        raise ValueError(f"PanguUltraMoE: {what} is not implemented")
+
+    if cfg.get("rope_scaling"):
+        refuse("rope_scaling")
+    if (cfg.get("n_group") or 1) > 1 or (cfg.get("topk_group") or 1) > 1:
+        refuse("group-limited routing (n_group / topk_group over 1)")
+    if cfg.get("scoring_func", "sigmoid") != "sigmoid":
+        refuse(f"scoring_func {cfg['scoring_func']!r}")
+    if not cfg.get("norm_topk_prob", True):
+        refuse("norm_topk_prob false")
+    if cfg.get("attention_bias"):
+        refuse("attention_bias")
+    if not cfg.get("q_lora_rank"):
+        refuse("a query projection without q_lora_rank")
+    return dict(
+        mla=True,
+        q_lora_rank=cfg["q_lora_rank"],
+        kv_lora_rank=cfg.get("kv_lora_rank", 512),
+        qk_nope_head_dim=cfg.get("qk_nope_head_dim", 128),
+        qk_rope_head_dim=cfg.get("qk_rope_head_dim", 64),
+        v_head_dim=cfg.get("v_head_dim", 128),
+        post_block_norms=bool(cfg.get("sandwich_norm", True)),
+        router_scoring="sigmoid_v3", router_bias=False,
+        n_group=0, topk_group=0, norm_topk_prob=True,
+        routed_scaling_factor=cfg.get("routed_scaling_factor", 1.0),
+        # a cut config: `n_routed_experts` counts the experts held here
         # (see the Qwen3Next branch)
         num_experts_total=cfg.get("ep_num_experts_total", 0) or 0,
         expert_offset=cfg.get("ep_expert_offset", 0) or 0)

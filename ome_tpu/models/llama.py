@@ -43,8 +43,9 @@ def _w(p: Params, name: str, dtype=None) -> jax.Array:
     return w
 
 
-# the stacked leaves that lie out-major, [L, heads, Dh, D] (`_proj`)
-OUT_MAJOR = ("wq", "wk", "wv", "w_ogate")
+# the stacked leaves that lie out-major, [L, heads, Dh, D] (`_proj`;
+# a latent model's second query projection [L, heads, qk, q_rank] too)
+OUT_MAJOR = ("wq", "wk", "wv", "w_ogate", "wq_b")
 
 
 def to_out_major(w):
@@ -282,7 +283,9 @@ def _init_layer_block(rng: jax.Array, cfg: ModelConfig, L: int,
         if cfg.q_lora_rank:
             layers["wq_a"] = norm((L, D, cfg.q_lora_rank), next(keys))
             layers["q_a_norm"] = norm_scale(L, cfg.q_lora_rank)
-            layers["wq_b"] = norm((L, cfg.q_lora_rank, H, qk), next(keys))
+            # out-major as wq is, [L, H, qk, q_rank] (`OUT_MAJOR`)
+            layers["wq_b"] = to_out_major(
+                norm((L, cfg.q_lora_rank, H, qk), next(keys)))
         else:
             layers["wq"] = to_out_major(norm((L, D, H, qk), next(keys)))
         layers["wkv_a"] = norm((L, D, r + cfg.qk_rope_head_dim),
@@ -862,6 +865,18 @@ def moe_mlp_ragged(x: jax.Array, p: Params, cfg: ModelConfig,
     weight-bound. Serving-path default (models/config.py moe_impl).
     `routed`: see `moe_mlp_dense`."""
     B, S, D = x.shape
+    chunks = _moe_token_chunks(B * S, cfg.experts_per_token, D,
+                               jnp.dtype(x.dtype).itemsize) \
+        if routed is None else 1
+    if chunks > 1:
+        def one(xc):
+            out, stats = moe_mlp_ragged(xc[None], p, cfg, with_stats=True)
+            return out[0], jnp.stack(stats)
+        out, stats = lax.map(one, x.reshape(chunks, -1, D))
+        stats = tuple(jnp.sum(stats, axis=0))   # (a long prompt's: the
+        # experts hit are counted a chunk; only decode steps are read)
+        out = out.reshape(B, S, D)
+        return (out, stats) if with_stats else out
     if routed is None:
         routed = moe_decide(x, p, cfg, ragged=True)
     with jax.named_scope("moe_experts"):
@@ -869,6 +884,29 @@ def moe_mlp_ragged(x: jax.Array, p: Params, cfg: ModelConfig,
                                     p, cfg)
     out = out.reshape(B, S, D).astype(x.dtype)
     return (out, stats) if with_stats else out
+
+
+# the ragged dispatch gathers EVERY routed pair's token, T * k rows of
+# the hidden size, held here or not (static shapes: all of them may
+# land here), and its grouped matmuls hand back as many. Past
+# `_MOE_PAIRS_LIMIT` bytes of them a layer runs its tokens in chunks
+# of at most `_MOE_PAIRS_CHUNK`: at hidden 7680 and top-8 a 16 384-
+# token prompt's pairs are 2 GB a copy and three copies live at once
+# (chip compiler, PR 46: 4.5 GB of temporaries beside 12.4 GB held).
+# Each chunk reads the experts it hits again, so chunks stay large.
+_MOE_PAIRS_LIMIT = 1 << 30
+_MOE_PAIRS_CHUNK = 1 << 29
+
+
+def _moe_token_chunks(tokens: int, k: int, D: int, itemsize: int) -> int:
+    """Chunks (a power of two that divides `tokens`) an expert layer
+    runs its tokens in; 1 under `_MOE_PAIRS_LIMIT`."""
+    pairs = tokens * k * D * itemsize
+    n = 1
+    if pairs > _MOE_PAIRS_LIMIT:
+        while pairs // n > _MOE_PAIRS_CHUNK and tokens % (2 * n) == 0:
+            n *= 2
+    return n
 
 
 def moe_mlp(x: jax.Array, p: Params, cfg: ModelConfig,
@@ -919,7 +957,8 @@ def _layer(x: jax.Array, lp: Params, cfg: ModelConfig, freqs: jax.Array,
     """One transformer block. cache_kv: ([B,Smax,K,Dh], [B,Smax,K,Dh])
     (or merged rows, `KVCache`), or a `SlabLayer`: the whole stacked
     slabs a layer scan carries and the layer of them that is this
-    block's (`_mha`, whose `attn_scope` is too).
+    block's (`_mha`, whose `attn_scope` is too; a latent model's
+    `mla.mla_attention`).
     `window` overrides cfg.sliding_window (the gemma2 pair-scan passes
     the per-layer value; None = global attention). `moe` overrides
     cfg.is_moe (DeepSeek's first_k_dense leading dense layers).
@@ -941,10 +980,9 @@ def _layer(x: jax.Array, lp: Params, cfg: ModelConfig, freqs: jax.Array,
     with jax.named_scope("qkv"):
         h = block_norm(x, lp, "attn_norm", cfg)
     if cfg.mla:
-        from .mla import mla_attention
-        with jax.named_scope("attn"):
-            a, new_cache = mla_attention(h, lp, cfg, positions, kv_len,
-                                         cache_kv, cache_index)
+        from .mla import mla_attention  # (writes its own scopes)
+        a, new_cache = mla_attention(h, lp, cfg, positions, kv_len,
+                                     cache_kv, cache_index)
     else:
         a, new_cache = _mha(h, lp, cfg, freqs, positions, kv_len,
                             cache_kv, cache_index, window, uo,
@@ -1129,6 +1167,77 @@ def _hybrid_scan(params: Params, cfg: ModelConfig, x: jax.Array, freqs,
                       rec=rec, stats=stats)
 
 
+def counts_experts(cfg: ModelConfig) -> bool:
+    """This model's layer scan adds to `KVCache.stats` what its expert
+    layers are hit by: the ragged dispatch under a scan that carries
+    the counters (`_hybrid_scan`, `_alt_window_scan`, `_latent_scan`).
+    The engine keeps the counters for such a model."""
+    return bool(cfg.is_moe and cfg.moe_impl == "ragged"
+                and (cfg.is_hybrid or cfg.alt_sliding_window or cfg.mla))
+
+
+def _latent_scan(params: Params, cfg: ModelConfig, x: jax.Array, freqs,
+                 positions, kv_len, cache: Optional[KVCache],
+                 adapter_ids: Optional[jax.Array] = None):
+    """The layers of a latent-attention (MLA) model: the leading dense
+    layers' block (`first_k_dense`), then the expert layers', each a
+    scan over its stacked leaves.
+
+    The latent slab `cache.k` [L, B, Smax, rank + rope] is both scans'
+    CARRY, as `_hybrid_scan`'s and `_alt_window_scan`'s slabs are:
+    layer i is handed `SlabLayer(k, v, i)`, writes its rows in place
+    and the decode kernel reads the slab by layer index
+    (models/mla.py). As scanned input and output the slab would be
+    sliced out a layer, stacked back and copied whole after each
+    loop, and the dense block's slab CONCATENATED onto the expert
+    block's, every step. The zero-width v plane rides along untouched.
+    The layers' weights are scanned input (they are only read) and a
+    ragged model's experts closed over whole, addressed by layer
+    (`expert_compute`)."""
+    n_dense = cfg.first_k_dense if "dense_layers" in params else 0
+    EXPERTS = ("we_gate", "we_up", "we_down")
+    main, stacks = params["layers"], {}
+    if cfg.is_moe and cfg.moe_impl == "ragged":
+        stacks = {k: main[k] for k in EXPERTS}
+        main = {k: v for k, v in main.items() if k not in EXPERTS}
+    index = cache.index if cache is not None else None
+    counting = cache is not None and cache.stats is not None
+
+    def block(carry, leaves, first: int, moe):
+        """Layers first .. first + n - 1, the n stacked in `leaves`."""
+        def body(carry, per):
+            x, ck, counts = carry
+            lp, j = per
+            if moe is not False and stacks:
+                lp = dict(lp, **stacks, expert_layer=j)
+            slab = SlabLayer(ck, cache.v, first + j) \
+                if cache is not None else None
+            x, nc, st = _layer(x, lp, cfg, freqs, positions, kv_len, slab,
+                               index, moe=moe, adapter_ids=adapter_ids,
+                               moe_stats=True)
+            if nc is not None:
+                ck = nc[0]
+            if counting:
+                counts = counts + _moe_counts(st)
+            return (x, ck, counts), None
+
+        n = jax.tree.leaves(leaves)[0].shape[0]
+        carry, _ = lax.scan(body, carry,
+                            (leaves, jnp.arange(n, dtype=jnp.int32)))
+        return carry
+
+    carry = (x, cache.k if cache is not None else None,
+             jnp.zeros((3,), jnp.uint32) if counting else None)
+    if n_dense:
+        carry = block(carry, params["dense_layers"], 0, False)
+    x, ck, counts = block(carry, main, n_dense, None)
+    if cache is None:
+        return x, None
+    return x, KVCache(k=ck, v=cache.v,
+                      index=cache.index + positions.shape[1],
+                      stats=cache.stats + counts if counting else None)
+
+
 def _moe_counts(stats) -> jax.Array:
     """[layer-steps, experts hit, pairs landed] of one expert layer."""
     if stats is None:
@@ -1174,10 +1283,11 @@ class SlabLayer(NamedTuple):
     """One layer's place in the stacked slabs that a layer scan
     carries (`_alt_window_scan`: the global layers' slabs and the
     window layers' rings; `_hybrid_scan`: the full-attention layers'
-    slabs), where `_layer` and `_mha` otherwise take one layer's
-    (k, v): the layer's rows are written in place
-    and read through the index, and nothing slices a layer out (a
-    slice of a carried slab is a copy of it, ops/paged.py)."""
+    slabs; `_latent_scan`: the latent rows), where `_layer`, `_mha`
+    and `mla.mla_attention` otherwise take one layer's (k, v): the
+    layer's rows are written in place and read through the index, and
+    nothing slices a layer out (a slice of a carried slab is a copy of
+    it, ops/paged.py)."""
     k: jax.Array                # [L, B, Smax, K * Dh] (or [.., K, Dh],
     v: jax.Array                # `KVCache`), all layers
     layer: Any                  # this block's: an int or a traced index
@@ -1429,41 +1539,31 @@ def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
             x, new_cache = _alt_window_scan(params, cfg, x, freqs,
                                             positions, kv_len, cache,
                                             adapter_ids, valid_len)
+    elif cfg.mla:
+        with jax.named_scope("layers"):
+            x, new_cache = _latent_scan(params, cfg, x, freqs, positions,
+                                        kv_len, cache, adapter_ids)
     else:
-        # DeepSeek first_k_dense: leading dense-MLP layers scan as
-        # their own block; the cache's layer dim covers both blocks
-        n_dense = cfg.first_k_dense if "dense_layers" in params else 0
+        if "dense_layers" in params:
+            # (every architecture with leading dense layers is latent
+            # or periodic; the block scan this branch had for them
+            # concatenated the two blocks' slabs every step)
+            raise ValueError(
+                "leading dense layers (first_k_dense) are implemented "
+                "for latent-attention and periodic window / global "
+                "models only (_latent_scan, _alt_window_scan)")
 
-        def scan_block(x, block, ck, cv, moe):
-            def body(x, per_layer):
-                lp, layer_cache = per_layer
-                x, nc = _layer(x, lp, cfg, freqs, positions, kv_len,
-                               layer_cache, index, moe=moe,
-                               adapter_ids=adapter_ids)
-                return x, nc
-
-            carry_cache = (ck, cv) if cache is not None else None
-            with jax.named_scope("layers"):
-                x, nc = lax.scan(body, x, (block, carry_cache))
+        def body(x, per_layer):
+            lp, layer_cache = per_layer
+            x, nc = _layer(x, lp, cfg, freqs, positions, kv_len,
+                           layer_cache, index, adapter_ids=adapter_ids)
             return x, nc
 
-        if cache is not None:
-            dk, dv = cache.k[:n_dense], cache.v[:n_dense]
-            mk, mv = cache.k[n_dense:], cache.v[n_dense:]
-        else:
-            dk = dv = mk = mv = None
-        if n_dense:
-            x, dnc = scan_block(x, params["dense_layers"], dk, dv,
-                                moe=False)
-        x, mnc = scan_block(x, params["layers"], mk, mv, moe=None)
-        if cache is not None:
-            nk, nv = mnc
-            if n_dense:
-                nk = jnp.concatenate([dnc[0], nk], axis=0)
-                nv = jnp.concatenate([dnc[1], nv], axis=0)
-            new_cache = KVCache(k=nk, v=nv, index=cache.index + S)
-        else:
-            new_cache = None
+        carry_cache = (cache.k, cache.v) if cache is not None else None
+        with jax.named_scope("layers"):
+            x, nc = lax.scan(body, x, (params["layers"], carry_cache))
+        new_cache = KVCache(k=nc[0], v=nc[1], index=cache.index + S) \
+            if cache is not None else None
 
     if logits_at is not None:
         x = jnp.take_along_axis(x, logits_at[:, None, None], axis=1)

@@ -210,7 +210,7 @@ _LAYER_CONTRACT = {
     "ws_gate": (1,), "ws_up": (1,), "ws_down": (1,),
     # MLA projections (models/mla.py); norms/biases stay fp
     "wq_a": (1,),                          # [L, D, q_rank]
-    "wq_b": (1,),                          # [L, q_rank, H, qk]
+    "wq_b": (3,),                          # [L, H, qk, q_rank], as wq
     "wkv_a": (1,),                         # [L, D, r+rope]
     "w_uk": (2,),                          # [L, H, nope, r]
     "w_uv": (2,),                          # [L, H, r, v]

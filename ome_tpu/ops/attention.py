@@ -247,3 +247,122 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array,
             (q.shape[0], q.shape[1], k.shape[1]))
     return xla_attention(q, k, v, mask=mask, scale=scale,
                          logit_softcap=logit_softcap, sinks=sinks)
+
+
+# -- latent attention (MLA: models/mla.py) ---------------------------------
+
+
+def _latent_backend(backend: Optional[str], B: int, Sq: int, H: int,
+                    Skv: int) -> str:
+    """As `attention` chooses, but a head-sharded trace takes the
+    einsums: GSPMD cannot partition a Mosaic kernel and the latent
+    kernels have no per-device wrapper."""
+    if backend is None:
+        backend = _auto_backend(B, Sq, H, Skv)
+    if backend == "pallas" and _tp_mesh.get() is not None:
+        return "xla"
+    return backend
+
+
+def xla_latent_decode(q_lat: jax.Array, q_pe: jax.Array, rows: jax.Array,
+                      lo: jax.Array, hi: jax.Array, scale: float
+                      ) -> jax.Array:
+    """The einsum path of `latent_decode`: a [B, H, S] array of scores
+    over every cached row, those outside [lo, hi) masked."""
+    rank, rope = q_lat.shape[-1], q_pe.shape[-1]
+    c, k_pe = rows[..., :rank], rows[..., rank:rank + rope]
+    scores = (jnp.einsum("bhr,btr->bht", q_lat, c,
+                         preferred_element_type=jnp.float32)
+              + jnp.einsum("bhp,btp->bht", q_pe, k_pe,
+                           preferred_element_type=jnp.float32)) * scale
+    t = jnp.arange(rows.shape[1], dtype=jnp.int32)[None, None, :]
+    seen = (t >= lo[:, None, None]) & (t < hi[:, None, None])
+    probs = jax.nn.softmax(jnp.where(seen, scores, NEG_INF), axis=-1)
+    return jnp.einsum("bht,btr->bhr", probs.astype(c.dtype), c)
+
+
+def latent_decode(q_lat: jax.Array, q_pe: jax.Array, rows: jax.Array,
+                  positions: jax.Array, kv_len: Optional[jax.Array], *,
+                  rank: int, scale: float, layer=None,
+                  backend: Optional[str] = None) -> jax.Array:
+    """A decode step's latent attention: the absorbed queries q_lat
+    [B, H, rank] and q_pe [B, H, rope] of each slot against its cached
+    rows `[c | k_pe]` up to its position; returns [B, H, rank], still
+    in latent space. rows: [B, S, W] (or [B, S, 1, W]), W >= rank +
+    rope, the lanes behind `k_pe` padding no one reads; with `layer`, the stacked [L, B, S, ..] of a layer scan
+    that carries the slab, which the kernel reads where it lies and
+    every other path takes the layer out of first."""
+    B, H, _ = q_lat.shape
+    stacked = layer is not None
+    if rows.ndim - stacked == 4:    # the one latent "head" apart
+        rows = rows.reshape(rows.shape[:-2] + (-1,))
+    S = rows.shape[-2]
+    pos = positions[:, 0]
+    hi = pos + 1 if kv_len is None else \
+        jnp.minimum(pos + 1, jnp.broadcast_to(kv_len, (B,)))
+    lo = jnp.zeros_like(hi)
+    backend = _latent_backend(backend, B, 1, H, S)
+    if backend in ("pallas", "pallas_interpret"):
+        from . import flash
+        out = flash.latent_decode(
+            q_lat, q_pe.astype(q_lat.dtype), rows, lo, hi, scale=scale,
+            layer=layer, interpret=(backend == "pallas_interpret"))
+        if out is not None:
+            return out
+        note_decline("latent_decode",
+                     f"q{tuple(q_lat.shape)} rows{tuple(rows.shape)} "
+                     f"outside the kernel's coverage")
+    if stacked:
+        rows = _layer_of(rows, layer)
+    return xla_latent_decode(q_lat, q_pe, rows, lo, hi, scale)
+
+
+def xla_latent_prefill(q_nope, q_pe, k_nope, k_pe, v, base, kv_hi,
+                       scale: float) -> jax.Array:
+    """The einsum path of `latent_prefill`: a whole [B, H, Sq, S]
+    array of scores under the causal and length mask."""
+    scores = (jnp.einsum("bhsk,bhtk->bhst", q_nope, k_nope,
+                         preferred_element_type=jnp.float32)
+              + jnp.einsum("bhsp,btp->bhst", q_pe, k_pe,
+                           preferred_element_type=jnp.float32)) * scale
+    q_pos = base[:, None] + jnp.arange(q_nope.shape[2], dtype=jnp.int32)
+    t = jnp.arange(k_nope.shape[2], dtype=jnp.int32)
+    seen = (t[None, None, :] <= q_pos[:, :, None]) \
+        & (t[None, None, :] < kv_hi[:, None, None])
+    probs = jax.nn.softmax(jnp.where(seen[:, None], scores, NEG_INF),
+                           axis=-1)
+    return jnp.einsum("bhst,bhtv->bhsv", probs.astype(v.dtype), v)
+
+
+def latent_prefill(q_nope: jax.Array, q_pe: jax.Array, k_nope: jax.Array,
+                   k_pe: jax.Array, v: jax.Array, positions: jax.Array,
+                   kv_len: Optional[jax.Array], *, scale: float,
+                   backend: Optional[str] = None) -> jax.Array:
+    """A prompt's latent attention over MATERIALISED heads, head-major:
+    q_nope [B, H, Sq, nope], q_pe [B, H, Sq, rope], k_nope [B, H, S,
+    nope], the one k_pe [B, S, rope] all heads share, v [B, H, S, dv];
+    returns [B, H, Sq, dv]. With `kv_len` ([B] valid rows) the keys
+    are a cache's rows, row t at position t, and the queries stand at
+    `positions` (contiguous per row); with None the keys are the
+    queries' own rows (plain causal)."""
+    B, H, Sq, _ = q_nope.shape
+    S = k_nope.shape[2]
+    if kv_len is None:
+        base = jnp.zeros((B,), jnp.int32)
+        kv_hi = jnp.full((B,), S, jnp.int32)
+    else:
+        base = positions[:, 0].astype(jnp.int32)
+        kv_hi = jnp.broadcast_to(kv_len, (B,)).astype(jnp.int32)
+    backend = _latent_backend(backend, B, Sq, H, S)
+    if backend in ("pallas", "pallas_interpret"):
+        from . import flash
+        out = flash.latent_prefill(
+            q_nope, q_pe, k_nope, k_pe, v, base, kv_hi, scale=scale,
+            interpret=(backend == "pallas_interpret"))
+        if out is not None:
+            return out
+        note_decline("latent_prefill",
+                     f"q{tuple(q_nope.shape)} k{tuple(k_nope.shape)} "
+                     f"outside the kernel's coverage")
+    return xla_latent_prefill(q_nope, q_pe, k_nope, k_pe, v, base, kv_hi,
+                              scale)

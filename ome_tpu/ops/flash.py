@@ -174,6 +174,30 @@ def _decode_kernel(lim_ref, q_ref, k_ref, v_ref, *refs, bs: int,
         o_ref[0] = (acc_ref[:] / l).reshape(K, G, D).astype(o_ref.dtype)
 
 
+def _decode_walk(lo, hi, layer, B: int, bs: int):
+    """What both decode kernels walk a slot's rows by: the scalar-
+    prefetch `limits` [B, 2] (lo, hi; a third column the layer, the
+    same for every row, where the cache is a STACKED slab read by
+    layer index) and the block index map over a `[(L,) B, S, lanes]`
+    cache. The walk starts at the sliding window's first valid block
+    and clamps at the last block holding a valid row: repeated indices
+    make Pallas skip the DMA for both the pre-window head
+    (long-context sliding window) and the cache tail (short
+    sequences)."""
+    stacked = layer is not None
+    limits = [lo.astype(jnp.int32), hi.astype(jnp.int32)]
+    if stacked:
+        limits.append(jnp.broadcast_to(jnp.asarray(layer, jnp.int32),
+                                       (B,)))
+
+    def kv_index(b, s, lim):
+        first, last = _decode_block_range(lim[b, 0], lim[b, 1], bs)
+        at = (b, jnp.minimum(first + s, last), 0)
+        return (lim[b, 2],) + at if stacked else at
+
+    return jnp.stack(limits, axis=1), kv_index
+
+
 def _flash_decode(q, k, v, lo, hi, scale, softcap, interpret,
                   k_scale=None, v_scale=None, layer=None):
     """k, v: [B, S, K * D], a row's K heads side by side in the lanes
@@ -196,22 +220,8 @@ def _flash_decode(q, k, v, lo, hi, scale, softcap, interpret,
     quantized = k_scale is not None
     stacked = layer is not None
     assert not (stacked and quantized)
-    limits = [lo.astype(jnp.int32), hi.astype(jnp.int32)]
-    if stacked:
-        # third column: the layer, the same for every row
-        limits.append(jnp.broadcast_to(jnp.asarray(layer, jnp.int32),
-                                       (B,)))
-    limits = jnp.stack(limits, axis=1)                   # [B, 2 or 3]
+    limits, kv_index = _decode_walk(lo, hi, layer, B, bs)    # [B, 2 or 3]
     qh = q.reshape(B, K, G, D)
-
-    # walk blocks starting at the sliding-window's first valid block and
-    # clamp at the last block holding a valid row: repeated indices make
-    # Pallas skip the DMA for both the pre-window head (long-context
-    # sliding window) and the cache tail (short sequences).
-    def kv_index(b, s, lim):
-        first, last = _decode_block_range(lim[b, 0], lim[b, 1], bs)
-        at = (b, jnp.minimum(first + s, last), 0)
-        return (lim[b, 2],) + at if stacked else at
 
     # the layer's dimension is squeezed out of the block: the kernel
     # sees [1, bs, K * D] either way, bs whole rows as they lie in HBM
@@ -633,3 +643,285 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                              interpret, layer=layer)
     return _flash_prefill(q, k, v, base, kv_hi, scale, logit_softcap,
                           sliding_window, interpret)
+
+
+# -- latent attention (MLA) kernels ----------------------------------------
+#
+# A latent model (models/mla.py) caches ONE row a token, `[c | k_pe]`
+# of `rank + rope` lanes (512 + 64), which every head reads as its key
+# and, in its first `rank` lanes, as its value. Decode never leaves
+# latent space (the queries come absorbed, `[q_lat | q_pe]`); a prompt
+# materialises per-head keys and values, whose widths differ (nope +
+# rope against v_head_dim) and of which the rotary part is shared.
+
+
+def _latent_decode_kernel(lim_ref, q_ref, qpe_ref, kv_ref, o_ref, m_ref,
+                          l_ref, acc_ref, *, bs: int, rank: int,
+                          scale: float):
+    """q_ref [1, H, rank], qpe_ref [1, H, rope]: a slot's absorbed
+    queries; kv_ref [1, bs, W]: bs cached rows `[c | k_pe | padding]`,
+    brought from HBM once and used twice: the `rank + rope` lanes
+    against the queries for the scores, the first `rank` for the
+    weighted sum."""
+    rope = qpe_ref.shape[-1]
+    s = pl.program_id(1)
+    ns = pl.num_programs(1)
+
+    @pl.when(s == 0)
+    def _():
+        m_ref[:] = jnp.full_like(m_ref, M_INIT)
+        l_ref[:] = jnp.zeros_like(l_ref)
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+
+    lo = lim_ref[pl.program_id(0), 0]
+    hi = lim_ref[pl.program_id(0), 1]
+    first, last = _decode_block_range(lo, hi, bs)
+    start = jnp.minimum(first + s, last) * bs   # as the index map's
+    given = (first + s <= last) & (start < hi) & (start + bs > lo)
+    whole = (start >= lo) & (start + bs <= hi)
+
+    def update(masked: bool):
+        c = kv_ref[0, :, :rank]                 # [bs, rank]
+        x = lax.dot_general(q_ref[0], c, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+        x = x + lax.dot_general(
+            qpe_ref[0], kv_ref[0, :, rank:rank + rope],
+            (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        x = x * scale                           # [H, bs]
+        if masked:
+            col = start + lax.broadcasted_iota(jnp.int32, x.shape, 1)
+            valid = (col >= lo) & (col < hi)
+            x = jnp.where(valid, x, M_INIT)
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, jnp.max(x, axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(x - _lanes(m_new, bs))
+        if masked:
+            p = jnp.where(valid, p, 0.0)
+        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
+        pv = lax.dot_general(p.astype(c.dtype), c, (((1,), (0,)), ((), ())),
+                             preferred_element_type=jnp.float32)
+        acc_ref[...] = acc_ref[...] * _lanes(alpha, rank) + pv
+        m_ref[...] = m_new
+
+    @pl.when(given & whole)
+    def _():
+        update(False)
+
+    @pl.when(given & jnp.logical_not(whole))
+    def _():
+        update(True)
+
+    @pl.when(s == ns - 1)
+    def _():
+        l = jnp.maximum(l_ref[...], 1e-30)
+        o_ref[0] = (acc_ref[...] / _lanes(l, rank)).astype(o_ref.dtype)
+
+
+def latent_decode(q_lat: jax.Array, q_pe: jax.Array, rows: jax.Array,
+                  lo: jax.Array, hi: jax.Array, *, scale: float,
+                  layer=None, interpret: bool = False
+                  ) -> Optional[jax.Array]:
+    """One absorbed query a head and slot against the slot's cached
+    latent rows [lo, hi): q_lat [B, H, rank], q_pe [B, H, rope]; rows
+    [B, S, W] (`[c | k_pe]` and, where W > rank + rope, padding to
+    whole lane tiles: a slab engine's merged rows), or with `layer`
+    the STACKED slab [L, B, S, W] of a layer scan that carries it,
+    read where it lies (`_flash_decode`). Online
+    softmax in float32. Returns [B, H, rank] in latent space (`w_uv`
+    lifts it), or None for shapes the kernel does not cover."""
+    B, H, rank = q_lat.shape
+    rope = q_pe.shape[-1]
+    S, width = rows.shape[-2], rows.shape[-1]
+    stacked = layer is not None
+    bs = _pick_block(S, (512, 256, 128))
+    if bs is None or H % 8 or rank % 128 or width < rank + rope \
+            or rows.ndim != 3 + stacked:
+        return None
+    limits, kv_index = _decode_walk(lo, hi, layer, B, bs)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(B, S // bs),
+        in_specs=[
+            pl.BlockSpec((1, H, rank), lambda b, s, lim: (b, 0, 0)),
+            pl.BlockSpec((1, H, rope), lambda b, s, lim: (b, 0, 0)),
+            pl.BlockSpec(((None,) if stacked else ()) + (1, bs, width),
+                         kv_index),
+        ],
+        out_specs=pl.BlockSpec((1, H, rank), lambda b, s, lim: (b, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((H, 128), jnp.float32),
+            pltpu.VMEM((H, 128), jnp.float32),
+            pltpu.VMEM((H, rank), jnp.float32),
+        ],
+    )
+    return pl.pallas_call(
+        functools.partial(_latent_decode_kernel, bs=bs, rank=rank,
+                          scale=scale),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, H, rank), q_lat.dtype),
+        interpret=interpret,
+        name="latent_decode",
+    )(limits, q_lat, q_pe, rows)
+
+
+def _latent_prefill_kernel(lim_ref, q_ref, qpe_ref, k_ref, kpe_ref, v_ref,
+                           o_ref, m_ref, l_ref, acc_ref, *, bq: int,
+                           bs: int, g: int, scale: float):
+    """q_ref [1, g, bq, nope], qpe_ref [1, g, bq, rope]: g heads'
+    queries; k_ref [1, g, bs, nope], v_ref [1, g, bs, dv]: their keys
+    and values; kpe_ref [1, bs, rope]: the rotary key all heads share.
+    Running softmax state a row a (head, query row), head-major."""
+    b, qi, ki = pl.program_id(0), pl.program_id(2), pl.program_id(3)
+    nk = pl.num_programs(3)
+    dv = v_ref.shape[-1]
+
+    @pl.when(ki == 0)
+    def _():
+        m_ref[:] = jnp.full_like(m_ref, M_INIT)
+        l_ref[:] = jnp.zeros_like(l_ref)
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+
+    base = lim_ref[b, 0]             # absolute position of q row 0
+    kv_hi = lim_ref[b, 1]            # valid key rows
+    start, some, whole = _prefill_block_kind(base, kv_hi, qi, ki, bq, bs,
+                                             None)
+
+    def update(valid=None):
+        kpe = kpe_ref[0]             # [bs, rope]
+        for h in range(g):
+            rows = pl.ds(h * bq, bq)
+            x = lax.dot_general(
+                q_ref[0, h], k_ref[0, h], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            x = x + lax.dot_general(
+                qpe_ref[0, h], kpe, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            x = x * scale                                   # [bq, bs]
+            if valid is not None:
+                x = jnp.where(valid, x, M_INIT)
+            m_prev = m_ref[rows, :]
+            m_new = jnp.maximum(m_prev, jnp.max(x, axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(x - _lanes(m_new, bs))
+            if valid is not None:
+                p = jnp.where(valid, p, 0.0)
+            l_ref[rows, :] = alpha * l_ref[rows, :] \
+                + jnp.sum(p, axis=1, keepdims=True)
+            vb = v_ref[0, h]
+            pv = lax.dot_general(
+                p.astype(vb.dtype), vb, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)         # [bq, dv]
+            acc_ref[rows, :] = acc_ref[rows, :] * _lanes(alpha, dv) + pv
+            m_ref[rows, :] = m_new
+
+    @pl.when(whole)
+    def _():
+        update()
+
+    @pl.when(some & jnp.logical_not(whole))
+    def _():
+        q_lo = base + qi * bq
+        col = start + lax.broadcasted_iota(jnp.int32, (bq, bs), 1)
+        qpos = q_lo + lax.broadcasted_iota(jnp.int32, (bq, bs), 0)
+        update((col <= qpos) & (col < kv_hi))
+
+    @pl.when(ki == nk - 1)
+    def _():
+        for h in range(g):
+            rows = pl.ds(h * bq, bq)
+            l = jnp.maximum(l_ref[rows, :], 1e-30)
+            o_ref[0, h] = (acc_ref[rows, :] / _lanes(l, dv)) \
+                .astype(o_ref.dtype)
+
+
+# heads a grid step: the shared rotary key block is fetched once for
+# them and the step's fixed cost paid once
+_LATENT_PREFILL_HEADS = 4
+
+
+def _latent_prefill_blocks(Sq: int, S: int, H: int):
+    """(bq, bs, heads a step), or None for shapes the kernel does not
+    cover. A head's keys serve its own queries alone (no group shares
+    them), so a key block's operations a byte are bq: 512 rows where
+    the GQA kernel takes 256."""
+    bq = _pick_block(Sq, (512, 256, 128, 64, 32, 16))
+    bs = _pick_block(S, (512, 256, 128, 64, 32, 16))
+    g = _pick_block(H, (_LATENT_PREFILL_HEADS, 2, 1))
+    if bq is None or bs is None:
+        return None
+    return bq, bs, g
+
+
+def latent_prefill(q_nope, q_pe, k_nope, k_pe, v, base, kv_hi, *,
+                   scale: float, interpret: bool = False
+                   ) -> Optional[jax.Array]:
+    """Causal blocked attention over a latent model's MATERIALISED
+    heads, head-major: q_nope [B, H, Sq, nope] and q_pe [B, H, Sq,
+    rope] against k_nope [B, H, S, nope], the one shared k_pe [B, S,
+    rope] (two operands: no [S, H, nope + rope] concatenation exists)
+    and v [B, H, S, dv], whose width is not the keys'. Query row i of
+    batch b stands at position base[b] + i and sees key rows
+    <= its position and < kv_hi[b]. Block kinds and skipped blocks as
+    `_flash_prefill` has them. Returns [B, H, Sq, dv], or None for
+    shapes the kernel does not cover."""
+    B, H, Sq, nope = q_nope.shape
+    S, dv, rope = k_nope.shape[2], v.shape[-1], q_pe.shape[-1]
+    blocks = _latent_prefill_blocks(Sq, S, H)
+    if blocks is None or nope % 128 or dv % 128 or rope % 8:
+        return None
+    return _latent_prefill_call(q_nope, q_pe, k_nope, k_pe, v, base, kv_hi,
+                                blocks=blocks, scale=scale,
+                                interpret=interpret)
+
+
+# a jit of its own, as `_prefill_call` is
+@functools.partial(jax.jit, static_argnames=("blocks", "scale", "interpret"))
+def _latent_prefill_call(q_nope, q_pe, k_nope, k_pe, v, base, kv_hi, *,
+                         blocks, scale, interpret):
+    B, H, Sq, nope = q_nope.shape
+    S, dv, rope = k_nope.shape[2], v.shape[-1], q_pe.shape[-1]
+    bq, bs, g = blocks
+    limits = jnp.stack(
+        [base.astype(jnp.int32), kv_hi.astype(jnp.int32)], axis=1)
+
+    def key_block(b, hg, qi, ki, lim):
+        first, last = _prefill_block_range(lim[b, 0], lim[b, 1], qi, bq,
+                                           bs, None)
+        return jnp.minimum(first + ki, last)
+
+    def q_index(b, hg, qi, ki, lim):
+        return (b, hg, qi, 0)
+
+    def kv_index(b, hg, qi, ki, lim):
+        return (b, hg, key_block(b, hg, qi, ki, lim), 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(B, H // g, Sq // bq, _prefill_key_steps(S, bq, bs, None)),
+        in_specs=[
+            pl.BlockSpec((1, g, bq, nope), q_index),
+            pl.BlockSpec((1, g, bq, rope), q_index),
+            pl.BlockSpec((1, g, bs, nope), kv_index),
+            pl.BlockSpec((1, bs, rope),
+                         lambda b, hg, qi, ki, lim:
+                         (b, key_block(b, hg, qi, ki, lim), 0)),
+            pl.BlockSpec((1, g, bs, dv), kv_index),
+        ],
+        out_specs=pl.BlockSpec((1, g, bq, dv), q_index),
+        scratch_shapes=[
+            pltpu.VMEM((g * bq, 128), jnp.float32),
+            pltpu.VMEM((g * bq, 128), jnp.float32),
+            pltpu.VMEM((g * bq, dv), jnp.float32),
+        ],
+    )
+    return pl.pallas_call(
+        functools.partial(_latent_prefill_kernel, bq=bq, bs=bs, g=g,
+                          scale=scale),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, H, Sq, dv), q_nope.dtype),
+        interpret=interpret,
+        name="latent_prefill",
+    )(limits, q_nope, q_pe, k_nope, k_pe, v)

@@ -49,7 +49,7 @@ _LAYER_RULES: Dict[str, tuple] = {
     # latent cache itself is replicated — kv_cache_heads == 1)
     "wq_a": (None, None),           # [D, q_rank]
     "q_a_norm": (None,),
-    "wq_b": (None, "tp", None),     # [q_rank, H, qk_dim]
+    "wq_b": ("tp", None, None),     # [H, qk_dim, q_rank] (out-major)
     "wkv_a": (None, None),          # [D, r + rope]
     "kv_a_norm": (None,),
     "w_uk": ("tp", None, None),     # [H, nope, r]

@@ -29,7 +29,7 @@ from typing import Dict, Optional
 # set, so label cardinality is bounded by construction (the
 # metrics-label-cardinality lint pattern)
 HBM_TENANTS = ("weights", "kv_cache", "recurrent_state", "window_ring",
-               "prefix_cache", "workspace")
+               "latent_rows", "prefix_cache", "workspace")
 
 
 def kv_capacity_bytes(engine) -> int:
@@ -87,7 +87,8 @@ class HbmAccountant:
             "byte model), kv_cache (pool/slab capacity), "
             "recurrent_state (a hybrid model's per-slot DeltaNet "
             "state), window_ring (a periodic window / global model's "
-            "per-slot rings), prefix_cache (its byte counter), "
+            "per-slot rings), latent_rows (a latent-attention model's "
+            "slab of one row a token), prefix_cache (its byte counter), "
             "workspace (the residual)",
             labelnames=("tenant",))
         self._tenants = {t: fam.labels(tenant=t) for t in HBM_TENANTS}
@@ -132,6 +133,11 @@ class HbmAccountant:
         """Refresh the gauges (one /metrics scrape). Returns the
         partition dict (tests assert the arithmetic on it)."""
         kv = kv_capacity_bytes(engine) if engine is not None else 0
+        # a latent-attention model's slab holds one row `[c | k_pe]` a
+        # token for all heads, not keys and values: a tenant of its own
+        latent = 0
+        if getattr(getattr(engine, "cfg", None), "mla", False):
+            kv, latent = 0, kv
         # the slots' other state: a hybrid model's DeltaNet layers
         # hold a float32 matrix a head and a conv tail, not rows
         state_fn = getattr(engine, "state_bytes", None)
@@ -141,7 +147,7 @@ class HbmAccountant:
         pc = getattr(engine, "prefix_cache", None)
         pcb = int(getattr(pc, "bytes", 0) or 0)
         stats = self._read_stats()
-        tenant_sum = self.weight_bytes + kv + rs + ring + pcb
+        tenant_sum = self.weight_bytes + kv + rs + ring + latent + pcb
         if stats:
             in_use = float(stats.get("bytes_in_use", tenant_sum))
             limit = float(stats.get("bytes_limit", 0) or 0)
@@ -152,7 +158,7 @@ class HbmAccountant:
         part = {"bytes_in_use": in_use, "bytes_limit": limit,
                 "peak_bytes": peak, "weights": float(self.weight_bytes),
                 "kv_cache": float(kv), "recurrent_state": float(rs),
-                "window_ring": float(ring),
+                "window_ring": float(ring), "latent_rows": float(latent),
                 "prefix_cache": float(pcb), "workspace": workspace}
         self._g_in_use.set(in_use)
         self._g_limit.set(limit)
@@ -169,6 +175,7 @@ class HbmAccountant:
                     bytes_limit=int(limit),
                     weights=int(self.weight_bytes), kv_cache=int(kv),
                     recurrent_state=rs, window_ring=ring,
+                    latent_rows=latent,
                     prefix_cache=pcb,
                     workspace=int(workspace))
             self._last_peak = peak

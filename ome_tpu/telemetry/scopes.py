@@ -76,9 +76,9 @@ KERNELS = ("paged_attention", "flash_decode", "flash_prefill",
 #               rows a slot holds read; a full-length row written and
 #               every row read (models/llama.py `_mha`)
 SUBPHASES = ("gdn_mixer", "gdn_state", "moe_router", "moe_experts",
-             "moe_shared", "attn_window", "attn_global")
-# `name=` of Pallas kernels written after KERNELS was copied: none yet
-SUBKERNELS = ()
+             "moe_shared", "attn_window", "attn_global", "attn_latent")
+# `name=` of Pallas kernels written after KERNELS was copied (below)
+SUBKERNELS = ("latent_decode", "latent_prefill")
 
 # ome_engine_step_phase_seconds{phase=...} label values, each also a
 # `sched.<phase>` span (scheduler._phase)
@@ -140,3 +140,23 @@ COMPILE_OUTCOMES = ("cache_hit", "cache_miss")
 # flight event of one compile stage of one program, once the scheduler
 # holds the ledger: {program, stage, seconds, cache}
 PROGRAM_COMPILED = "program_compiled"
+
+
+# -- latent attention (PR 46; described here, below `scoped`, for the
+# reason above) --
+#   attn_latent   (SUBPHASES) a latent-attention (MLA) layer, AROUND
+#               its `kv_write` and `attn` inside `layers`, as
+#               `attn_global` is: the slot's one row `[c | k_pe]`
+#               written into the stacked slab in place, and the kernel
+#               over the rows the slot holds (a prompt: over its
+#               materialised heads). The projections, the absorbed
+#               query and a prompt's materialised keys and values sit
+#               under `qkv`, `w_uv`'s lift and `wo` under `o_proj`
+#               (models/mla.py)
+#   latent_decode, latent_prefill   (SUBKERNELS) the two kernels'
+#               `name=` (ops/flash.py): a decode step's absorbed
+#               queries over a slot's cached rows, each block read once
+#               as key and as value; a prompt's causal blocked
+#               attention at query / key width nope + rope and value
+#               width v_head_dim. The benchmark reads all three from
+#               benchmark/latent_kinds.py

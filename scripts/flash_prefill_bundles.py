@@ -24,6 +24,9 @@ mask's arithmetic.
     python scripts/flash_prefill_bundles.py --kernel decode --heads 28
     python scripts/flash_prefill_bundles.py --kernel decode --heads 28 \
         --tree /root/scratch/parent --apart       # the kernel before PR 38
+    python scripts/flash_prefill_bundles.py --kernel latent_decode --heads 128
+    python scripts/flash_prefill_bundles.py --kernel latent_prefill --heads 32 \
+        --body whole                  # a latent (MLA) model's two kernels
 
 `--kernel decode` compiles the decode kernel over a stacked slab of
 `--rows` rows for 8 slots (`--apart`: the slab as `[L, B, S, K, D]`,
@@ -63,7 +66,7 @@ def _compile(args):
     from jax.sharding import SingleDeviceSharding
     sys.path.insert(0, args.tree or ROOT)
     from ome_tpu.ops import flash
-    if args.body != "both" and args.kernel == "prefill":
+    if args.body != "both" and args.kernel.endswith("prefill"):
         kind = flash._prefill_block_kind
 
         def only(*a):
@@ -80,6 +83,39 @@ def _compile(args):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
 
     S, D = args.rows, args.dim
+    ints = struct((1,), jnp.int32)
+    if args.kernel == "latent_decode":
+        # a latent model's absorbed queries over its stacked slab of
+        # padded rows `[c 512 | k_pe 64 | 64]` (--heads 128)
+        slots, layers = 8, 2
+        ints = struct((slots,), jnp.int32)
+
+        def step(q_lat, q_pe, rows, lo, hi, layer):
+            out = flash.latent_decode(q_lat, q_pe, rows, lo, hi,
+                                      scale=192 ** -0.5, layer=layer)
+            assert out is not None, "the kernel declined the shape"
+            return out
+
+        jax.jit(step).lower(
+            struct((slots, args.heads, 512), jnp.bfloat16),
+            struct((slots, args.heads, 64), jnp.bfloat16),
+            struct((layers, slots, S, 640), jnp.bfloat16), ints, ints,
+            struct((), jnp.int32)).compile()
+        return
+    if args.kernel == "latent_prefill":
+        # a group of --heads materialised heads: keys 128 + the shared
+        # 64, values 128
+        def g(q_nope, q_pe, k_nope, k_pe, v, base, kv_hi):
+            out = flash.latent_prefill(q_nope, q_pe, k_nope, k_pe, v, base,
+                                       kv_hi, scale=192 ** -0.5)
+            assert out is not None, "the kernel declined the shape"
+            return out
+
+        wide = struct((1, args.heads, S, 128), jnp.bfloat16)
+        jax.jit(g).lower(wide, struct((1, args.heads, S, 64), jnp.bfloat16),
+                         wide, struct((1, S, 64), jnp.bfloat16), wide, ints,
+                         ints).compile()
+        return
     if args.kernel == "decode":
         slots, layers = 8, 2
         rows = (args.kv_heads, D) if args.apart else (args.kv_heads * D,)
@@ -110,7 +146,8 @@ def _compile(args):
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--kernel", default="prefill",
-                    choices=("prefill", "decode"))
+                    choices=("prefill", "decode", "latent_decode",
+                             "latent_prefill"))
     ap.add_argument("--tree", default=None,
                     help="take ome_tpu from this checkout, not this one")
     ap.add_argument("--apart", action="store_true",
@@ -135,7 +172,8 @@ def main():
     child = subprocess.run([sys.executable, __file__] + sys.argv[1:],
                            env=env, capture_output=True, text=True)
     try:
-        name = "flash_" + args.kernel
+        name = args.kernel if args.kernel.startswith("latent") \
+            else "flash_" + args.kernel
         found = [f for f in glob.glob(
             f"{out}/*{name}*final_bundles.txt")
             if "schedule-analysis" not in f]

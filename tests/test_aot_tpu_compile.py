@@ -770,3 +770,61 @@ def test_flash_decode_at_a_group_of_seven(one_chip, layers, rows):
                  one_chip((slots,), jnp.int32), one_chip((), jnp.int32))
     assert c.as_text().count("tpu_custom_call") == 1
     assert c.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+# -- latent attention (MLA): the two kernels of ops/flash.py at
+# openpangu-ultra-moe-718b-ep16's widths (128 heads, a cached row of
+# 512 + 64 numbers in 640 lanes, 24 slots x 16 384 rows x 5 layers) ---
+
+
+@pytest.mark.parametrize("lanes,copied", [(640, False), (576, True)])
+def test_latent_decode_reads_a_layer_of_a_stacked_slab(one_chip, lanes,
+                                                       copied):
+    """The kernel handed the stacked latent slab and a traced layer
+    index: one Mosaic call and nothing beside it when a row is whole
+    lane tiles (640: what `ModelConfig.kv_cache_k_dim` pads 576 to).
+    At 576 lanes the chip lays the slab rows-minor and the call takes
+    a row-major COPY of it, 2.5 GB a step: the reason for the padding,
+    held here so that a compiler that stops doing it is noticed."""
+    slots, heads, rank, rope, L, S = 24, 128, 512, 64, 5, 16384
+    slab = one_chip((L, slots, S, lanes), jnp.bfloat16)
+
+    def f(q_lat, q_pe, rows, lo, hi, layer):
+        out = flash.latent_decode(q_lat, q_pe, rows, lo, hi,
+                                  scale=192 ** -0.5, layer=layer)
+        assert out is not None
+        return out
+
+    c = _compile(f, one_chip((slots, heads, rank), jnp.bfloat16),
+                 one_chip((slots, heads, rope), jnp.bfloat16), slab,
+                 one_chip((slots,), jnp.int32), one_chip((slots,), jnp.int32),
+                 one_chip((), jnp.int32))
+    assert c.as_text().count("tpu_custom_call") == 1
+    temp = c.memory_analysis().temp_size_in_bytes
+    if copied:
+        assert temp >= math.prod(slab.shape) * 2, temp
+    else:
+        assert temp < 1 << 20, temp
+
+
+@pytest.mark.parametrize("S,heads", [(16384, 32), (8192, 64), (4096, 128)])
+def test_latent_prefill_at_the_cells_shapes(one_chip, S, heads):
+    """A prompt's materialised heads, a group at a time as
+    `mla._prefill_head_group` cuts them at the cell's three buckets:
+    query / key width 128 + 64 (two operands), value width 128,
+    accepted by Mosaic with four heads a grid step."""
+    from ome_tpu.models.mla import _prefill_head_group
+    assert _prefill_head_group(S, 128, 128) == heads
+    assert flash._latent_prefill_blocks(S, S, heads) == (512, 512, 4)
+
+    def f(q_nope, q_pe, k_nope, k_pe, v, base, kv_hi):
+        out = flash.latent_prefill(q_nope, q_pe, k_nope, k_pe, v, base,
+                                   kv_hi, scale=192 ** -0.5)
+        assert out is not None
+        return out
+
+    wide = one_chip((1, heads, S, 128), jnp.bfloat16)
+    c = _compile(f, wide, one_chip((1, heads, S, 64), jnp.bfloat16), wide,
+                 one_chip((1, S, 64), jnp.bfloat16), wide,
+                 one_chip((1,), jnp.int32), one_chip((1,), jnp.int32))
+    assert c.as_text().count("tpu_custom_call") == 1

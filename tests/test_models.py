@@ -175,7 +175,7 @@ class TestAccounting:
 # -- the attention projections lie out-major (PR 41) ---------------------
 
 _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-# the benchmark's four families at the toy size its own rehearsals run:
+# the benchmark's five families at the toy size its own rehearsals run:
 # family -> (fixture directory, configuration, reference module,
 # reference's groups of leaves -> the program's blocks)
 FAMILIES = {
@@ -186,6 +186,8 @@ FAMILIES = {
                {"moe": "layers", "dense": "dense_layers"}),
     "preroute": ("fixture_preroute", "tiny-smallthinker", "preroute_moe",
                  {"layers": "layers"}),
+    "latent": ("fixture_latent", "tiny-pangu", "latent_moe",
+               {"moe": "layers", "dense": "dense_layers"}),
 }
 
 
@@ -228,6 +230,13 @@ def test_init_params_draws_what_the_benchmarks_reference_draws(family):
             assert (np.asarray(mine.astype(jnp.float32))
                     == np.asarray(want.astype(jnp.float32))).all(), name
             seen.add(name)
+    if cfg.mla:     # a latent model's one out-major leaf: [H, qk, q_rank]
+        assert set(llama.OUT_MAJOR) & seen == {"wq_b"}
+        qk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+        for block in groups.values():
+            assert params[block]["wq_b"].shape[1:] == (
+                cfg.num_heads, qk, cfg.q_lora_rank)
+        return
     assert set(llama.OUT_MAJOR) & seen >= {"wq", "wk", "wv"}
     D = cfg.hidden_size
     for block in groups.values():
