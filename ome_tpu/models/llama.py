@@ -712,12 +712,14 @@ class Dispatch(NamedTuple):
     """What the ragged dispatch DECIDES from the router's choice
     alone, before any expert's weights or any activation is touched:
     the T * k (token, expert) pairs sorted by expert, with the pairs
-    of experts that are not held here behind every group."""
+    of experts that are not held here behind every group, and where
+    each pair went, by which its result is fetched back."""
     token_of: jax.Array     # [T*k] source token of each sorted pair
     counts: jax.Array       # [held] pairs of each held expert
     sorted_ids: jax.Array   # [T*k] held expert of each sorted pair
-    w_sorted: jax.Array     # [T*k] the router's weight of each
-    mine: jax.Array         # [T*k] the pair's expert is held here
+    place: jax.Array        # [k, T] sorted row of token t's j-th pair
+    weights: jax.Array      # [k, T] the router's weight of each pair
+    mine: jax.Array         # [k, T] the pair's expert is held here
 
 
 class Routed(NamedTuple):
@@ -742,7 +744,9 @@ def expert_dispatch(weights: jax.Array, idx: jax.Array, held: int,
     expert, for a device that holds experts lo .. lo + held - 1 of
     those the router chose among. A pair routed to an expert that is
     not held sorts behind every group, so it belongs to no group's
-    rows."""
+    rows. `place` is the inverse of that sort: the row at which a
+    token's j-th pair's result will lie (`expert_compute` fetches it
+    back from there)."""
     k = idx.shape[-1]
     ids = idx.reshape(-1) - lo
     mine = (ids >= 0) & (ids < held)
@@ -754,16 +758,21 @@ def expert_dispatch(weights: jax.Array, idx: jax.Array, held: int,
         token_of=order // k,
         counts=counts,
         sorted_ids=jnp.minimum(jnp.take(local_ids, order), held - 1),
-        w_sorted=jnp.take(weights.reshape(-1), order, axis=0),
-        mine=jnp.take(mine, order))
+        place=jnp.argsort(order).reshape(-1, k).T,
+        weights=weights.reshape(-1, k).T,
+        mine=mine.reshape(-1, k).T)
 
 
 def expert_compute(xf: jax.Array, plan: Dispatch, p: Params,
                    cfg: ModelConfig):
     """Compute: gather the sorted pairs' tokens from `xf` [T, D], run
     them as grouped matmuls over the experts held
-    (lax.ragged_dot -> TPU grouped GEMM) and add each pair's result,
-    times its weight, to its token. Returns (out [T, D], (held experts
+    (lax.ragged_dot -> TPU grouped GEMM), fetch each token's k results
+    back by `plan.place` and sum them, each times its weight, in
+    float32 (a gather and a reduction: a TPU scatters rows at a small
+    fraction of the rate at which it gathers them, and a scatter-add
+    of the pairs into `[T, D]` was 29 % of a 16 384-token prefill at
+    hidden 7680; ledger, PR 46). Returns (out [T, D], (held experts
     hit, pairs that landed here)).
 
     `p["expert_layer"]` (a traced layer index, set by a layer scan
@@ -774,10 +783,9 @@ def expert_compute(xf: jax.Array, plan: Dispatch, p: Params,
     and nothing else; a layer's experts sliced out of the stack
     first would be copied whole, hit or not, every step (1.2 GB a
     layer at 128 experts of 2048 x 512, chip compiler, PR 27)."""
-    T, D = xf.shape
     layer = p.get("expert_layer")
     held = _held_experts(p)
-    token_of, counts, sorted_ids, w_sorted, mine = plan
+    token_of, counts, sorted_ids, place, weights, mine = plan
 
     def experts(name):
         w = p[name]
@@ -791,7 +799,10 @@ def expert_compute(xf: jax.Array, plan: Dispatch, p: Params,
         return w.reshape(-1, *w.shape[2:])
 
     we_gate = experts("we_gate")
-    xs = jnp.take(xf, token_of, axis=0)                  # [T*k, D]
+    # `token_of` (a permutation's entries over k) and `place` (a
+    # permutation) are in bounds: neither gather fills or wraps
+    xs = xf.at[token_of].get(mode="promise_in_bounds",
+                             wrap_negative_indices=False)  # [T*k, D]
     group_sizes = counts
     if we_gate.shape[0] != held:
         # the whole stack: this layer's groups among empty ones
@@ -809,12 +820,15 @@ def expert_compute(xf: jax.Array, plan: Dispatch, p: Params,
     if cfg.moe_bias:
         out_sorted = out_sorted + jnp.take(p["we_down_b"], sorted_ids,
                                            axis=0)
+    back = out_sorted.at[place].get(
+        mode="promise_in_bounds", unique_indices=True,
+        wrap_negative_indices=False)                     # [k, T, D]
     # rows behind the last group hold whatever the grouped matmul
     # leaves there: select, do not multiply by zero
-    contrib = jnp.where(
-        mine[:, None],
-        out_sorted * w_sorted[:, None].astype(out_sorted.dtype), 0)
-    out = jnp.zeros((T, D), contrib.dtype).at[token_of].add(contrib)
+    held_back = jnp.where(mine[..., None], back, 0)
+    out = jnp.sum(held_back.astype(jnp.float32)
+                  * weights[..., None].astype(jnp.float32), axis=0)
+    out = out.astype(out_sorted.dtype)
     return out, (jnp.sum(counts > 0), jnp.sum(counts))
 
 
@@ -859,11 +873,16 @@ def moe_mlp_ragged(x: jax.Array, p: Params, cfg: ModelConfig,
                    routed: Optional[Routed] = None):
     """Dropless ragged dispatch over the experts held here: O(k/E) of
     the dense path's expert FLOPs with NO capacity dropping, static
-    [T*k] shapes, so it jits cleanly. The sort/gather/scatter costs
-    bandwidth proportional to activations (tiny next to expert
-    weights), which is the right trade on TPU where the MoE block is
-    weight-bound. Serving-path default (models/config.py moe_impl).
-    `routed`: see `moe_mlp_dense`."""
+    [T*k] shapes, so it jits cleanly. The sorts and the two gathers
+    (tokens out to their pairs' rows, results back to their tokens)
+    move T * k rows of the hidden size: tiny next to the expert
+    weights in a decode step, which is weight-bound; NOT in a long
+    prompt, where the pairs are 0.5 GB a layer and the rows must move
+    by gathers: added back by a scatter-add they cost 24 ms a chunk
+    of 4096 tokens at hidden 7680, twelve times the grouped matmul
+    whose result they were, 29 % of a 16 384-token prefill (ledger,
+    PR 46; `expert_compute`). Serving-path default (models/config.py
+    moe_impl). `routed`: see `moe_mlp_dense`."""
     B, S, D = x.shape
     chunks = _moe_token_chunks(B * S, cfg.experts_per_token, D,
                                jnp.dtype(x.dtype).itemsize) \

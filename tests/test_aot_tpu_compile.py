@@ -428,13 +428,13 @@ WINDOW_CELLS = {"trinity-mini-ep4": (32, 30_000_000),
                 "smallthinker-21b-a3b-ep4": (28, 80_000_000)}
 
 
-@pytest.fixture(scope="module")
-def slab_decode(topo):
-    """The engine's own `decode` program for a benchmark configuration
-    that runs the slab path (benchmark/configs/<name>.json) at its
-    cell's slots and length, compiled for the described chip once a
-    configuration: name -> (compiled, the slots' state as shapes, its
-    ModelConfig)."""
+def _cell_engine(name, struct, mp, layers=None):
+    """The engine of a benchmark configuration that runs the slab path
+    (benchmark/configs/<name>.json) at its cell's slots and length,
+    on shapes: (engine, params, the slots' state, its ModelConfig, the
+    cell's prefill buckets). `layers` cuts the depth (a period is
+    enough to see what a layer's program holds). `mp` steers the code
+    that asks the device."""
     import json
 
     from ome_tpu.engine.core import InferenceEngine
@@ -442,6 +442,37 @@ def slab_decode(topo):
     from ome_tpu.models.config import ModelConfig
     from ome_tpu.perf.ledger import ProgramLedger
 
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           name + ".json")) as f:
+        file = json.load(f)
+    if layers:  # and the lists that hold an entry a layer
+        full = file["num_hidden_layers"]
+        file = {k: v[:layers] if isinstance(v, list) and len(v) == full
+                else v for k, v in file.items()}
+        file["num_hidden_layers"] = layers
+    cfg = ModelConfig.from_hf_config(
+        {k: v for k, v in file.items()
+         if k not in ("source", "reduced", "assumed", "benchmark")}
+    ).replace(moe_impl="ragged")
+    serve = file["benchmark"]["serve_args"]
+    slots = serve[serve.index("--max-slots") + 1]
+    max_seq = serve[serve.index("--max-seq") + 1]
+    params = jax.tree.map(struct, jax.eval_shape(
+        lambda k: llama.init_params(k, cfg), jax.random.PRNGKey(0)))
+    mp.setattr(device, "on_tpu", lambda: True)
+    eng = InferenceEngine(params, cfg, max_slots=slots, max_seq=max_seq,
+                          ledger=ProgramLedger("off"))
+    state = jax.tree.map(struct, jax.eval_shape(eng.new_state))
+    return eng, params, state, cfg, file["benchmark"]["prefill_buckets"]
+
+
+@pytest.fixture(scope="module")
+def slab_decode(topo):
+    """The engine's own `decode` program for a benchmark configuration
+    that runs the slab path, compiled for the described chip once a
+    configuration: name -> (compiled, the slots' state as shapes, its
+    ModelConfig)."""
     sharding = SingleDeviceSharding(topo.devices[0])
 
     def struct(a):
@@ -449,25 +480,9 @@ def slab_decode(topo):
 
     @functools.lru_cache(maxsize=None)
     def compiled(name):
-        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-        with open(os.path.join(root, "benchmark", "configs",
-                               name + ".json")) as f:
-            file = json.load(f)
-        cfg = ModelConfig.from_hf_config(
-            {k: v for k, v in file.items()
-             if k not in ("source", "reduced", "assumed", "benchmark")}
-        ).replace(moe_impl="ragged")
-        serve = file["benchmark"]["serve_args"]
-        slots = serve[serve.index("--max-slots") + 1]
-        max_seq = serve[serve.index("--max-seq") + 1]
-        params = jax.tree.map(struct, jax.eval_shape(
-            lambda k: llama.init_params(k, cfg), jax.random.PRNGKey(0)))
         with pytest.MonkeyPatch.context() as mp:
-            mp.setattr(device, "on_tpu", lambda: True)
-            eng = InferenceEngine(params, cfg, max_slots=slots,
-                                  max_seq=max_seq,
-                                  ledger=ProgramLedger("off"))
-            state = jax.tree.map(struct, jax.eval_shape(eng.new_state))
+            eng, params, state, cfg, _ = _cell_engine(name, struct, mp)
+            slots = state.lengths.shape[0]
             ints = struct(jax.ShapeDtypeStruct((slots,), jnp.int32))
             floats = struct(jax.ShapeDtypeStruct((slots,), jnp.float32))
             key = struct(jax.ShapeDtypeStruct((2,), jnp.uint32))
@@ -630,6 +645,82 @@ def test_hybrid_decode_carries_its_slab(slab_decode):
     assert _kv_write_scatters(text) == [whole, whole]
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp <= HYBRID_CELL_TEMP < math.prod(slab) * 2, temp
+
+
+# -- an expert layer fetches its pairs' results back: no scatter-add --------
+
+
+def _moe_scatters(text):
+    """The result shape of each scatter under `moe_experts`: the
+    routed pairs added into their tokens' rows one read-modify-write
+    at a time (24 ms a chunk of 4096 tokens at hidden 7680 where the
+    rows' bytes take 0.6: ledger, PR 46)."""
+    return [re.search(r"= (\w+\[[\d,]*\])", line).group(1)
+            for line in text.splitlines()
+            if re.search(r"ROOT %scatter\S* = ", line)
+            and "/moe_experts/" in line]
+
+
+def _pair_rows(text, pairs, hidden):
+    """The operations of a compiled text (fusion bodies left out)
+    whose result is an array of the routed pairs at the hidden size,
+    `[T * k, D]`: each is that many bytes written and read again."""
+    inner = set(re.findall(r"(?:calls|to_apply)=%([\w.\-]+)", text))
+    out, body = [], None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) \(.*\{$", line)
+        if head:
+            body = head.group(1)
+        elif body not in inner and re.search(
+                rf"^\s+(ROOT )?%\S+ = \w+\[{pairs},{hidden}\]\S* "
+                r"(?!parameter|get-tuple-element|bitcast)", line):
+            out.append(line.strip()[:120])
+    return out
+
+
+def test_prefill_gathers_an_expert_layers_pairs_back(topo):
+    """No program adds the routed pairs into `[T, D]` by a scatter:
+    `llama.expert_compute` fetches each token's k results by the
+    inverse of the dispatch's sort (`Dispatch.place`) and sums them.
+    The compiled 8192-bucket prefill of `smallthinker-21b-a3b-ep4` at
+    its cell's widths, one period deep (four expert layers, all in
+    the scan's body): no scatter under `moe_experts`, and a layer
+    keeps three arrays of `[T * k, D]` (the gathered tokens, the
+    grouped matmul's result, the results fetched back) where the
+    scatter-add kept four (the tokens, `jnp.take`'s fill of them, the
+    result, the weighted `contrib`: chip compiler, PR 46)."""
+    sharding = SingleDeviceSharding(topo.devices[0])
+
+    def struct(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding)
+
+    def of(shape, dtype):
+        return struct(jax.ShapeDtypeStruct(shape, dtype))
+
+    bucket = 8192
+    with pytest.MonkeyPatch.context() as mp:
+        eng, params, _, cfg, buckets = _cell_engine(
+            "smallthinker-21b-a3b-ep4", struct, mp, layers=4)
+        assert bucket in buckets
+        one_i, one_f = of((1,), jnp.int32), of((1,), jnp.float32)
+        text = eng._prefill_fn.lower(
+            params, of((1, bucket), jnp.int32), one_i, one_f, one_i,
+            one_f, of((2,), jnp.uint32), one_i,
+            bucket=bucket).compile().as_text()
+    assert "/moe_experts/" in text
+    assert _moe_scatters(text) == []
+    rows = _pair_rows(text, bucket * cfg.experts_per_token,
+                      cfg.hidden_size)
+    assert len(rows) == 3 * 4, rows
+
+
+@pytest.mark.parametrize("name", sorted(WINDOW_CELLS)
+                         + ["qwen3-next-80b-a3b-ep4"])
+def test_decode_gathers_an_expert_layers_pairs_back(slab_decode, name):
+    """The same in a cell's whole `decode`, where T is the slots."""
+    text = slab_decode(name)[0].as_text()
+    assert "/moe_experts/" in text
+    assert _moe_scatters(text) == []
 
 
 # -- the stacked attention projections lie as the decode dot reads them --

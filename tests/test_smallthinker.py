@@ -309,7 +309,9 @@ def test_forward_matches_the_reference(cut, n):
 
 def test_the_program_in_bfloat16_would_fail_the_tolerance(cut):
     """The tolerance's other side: the program in bfloat16 differs
-    from the reference by hundreds of times ATOL."""
+    from the reference by about a hundred times ATOL (0.00508 with
+    the experts' results added up in bfloat16, 0.00479 since they are
+    summed in float32 and rounded once)."""
     cfg, p, w = cut
     toks = _tokens(29)
     low = jax.tree.map(lambda a: a.astype(jnp.bfloat16)
@@ -317,7 +319,7 @@ def test_the_program_in_bfloat16_would_fail_the_tolerance(cut):
     lg, _ = llama.forward(low, cfg.replace(dtype=jnp.bfloat16),
                           jnp.asarray(toks[None]))
     want = ref.logits(w, CUT, toks, 0, 29)
-    assert float(jnp.abs(lg[0] - want).max()) > 100 * ATOL
+    assert float(jnp.abs(lg[0] - want).max()) > 50 * ATOL
 
 
 @pytest.mark.parametrize("n0", [5, 8, 19])
@@ -752,16 +754,22 @@ def test_a_checkpoint_under_the_published_names_loads(tmp_path):
 # read on the parent's form of the attention projections
 # (`_parent_proj.parent_form`: in float32 XLA's CPU dot adds a one-row
 # product's terms in another order once the weight lies out-major),
-# and the out-major dots are held beside them to 1e-5
+# and the out-major dots are held beside them to 1e-5. Since PR 47
+# the two sparse families' are read on that PR's tree: an expert layer
+# sums a token's k weighted results as one expression where it
+# scatter-added them, XLA's CPU backend contracts the products into
+# the sum, and the last bit of some logits moved (largest difference
+# 1.8e-7 on logits up to 0.58 / 0.56; the parent's digests were
+# 91910728b8d3... and d4c7792d80ae...)
 PARENT_DIGESTS = {
     "afmoe":
-        "91910728b8d363b247dc7f8f9dc8858e2cae00bfd327ac6e036ea9e78bee97b4",
+        "a89ded87ea0173502e653b2fb39197d49b62c6c41d5f58d1e8d9a65cc23fa8a9",
     "cohere2":
         "a81a257fe2ae10650454f8ee0ea3fd2c5fb2427b5187e1bb603d30a287820c23",
     "gemma2":
         "38479a844e3b74def031432390a49bab8f925684db12915ca22558c4df290bca",
     "gpt_oss":
-        "d4c7792d80ae275eaca030a1d4455d7a8ff62e856189f88cc597e86eab70935a",
+        "be7515fa40d84cd0b7406486f8df62de4d0e1c78f4b2d843185cb7e8b71c4969",
 }
 
 
