@@ -31,9 +31,9 @@ import numpy as np
 from jax import lax
 
 from .. import device
-from ..models import llama
+from ..models import llama, mla
 from ..models.config import ModelConfig
-from ..ops.attention import prefill_block_kinds
+from ..ops.attention import latent_prefill_block_kinds, prefill_block_kinds
 from ..ops.paged import TRASH_BLOCK
 from ..telemetry.scopes import scoped
 from . import sampling
@@ -614,23 +614,35 @@ def slot_state_refusals(cfg: ModelConfig, **asked) -> List[str]:
 
 
 def prefill_attn_block_kinds(cfg: ModelConfig, rows: int, kv_rows: int,
-                             base: int = 0) -> Dict[str, int]:
+                             base: int = 0, valid: Optional[int] = None
+                             ) -> Dict[str, int]:
     """Grid steps by kind (ops/flash.py: none / whole / edge) of the
-    attention kernel calls in ONE prefill of `rows` prompt rows at
-    positions `base` on over `kv_rows` cache rows, summed over the
-    model's layers by their window; all zero where the prompt takes
-    XLA's attention (off the chip, or float32 logits under
-    `_XLA_PREFILL_CAP`) or the kernel declines. Host arithmetic on
-    shapes: nothing is read from the device."""
+    attention kernel calls in ONE prefill of `rows` prompt rows, the
+    first `valid` of them real (None: all; the rest a bucket's
+    padding), at positions `base` on over `kv_rows` cache rows: summed
+    over the model's layers by their window, or over a latent model's
+    layers and the groups of heads its prompt materialises at a time
+    (`latent_prefill`'s steps, each a group of heads of its own); all
+    zero where the prompt takes XLA's attention (off the chip, or
+    float32 logits under `_XLA_PREFILL_CAP`) or the kernel declines.
+    Host arithmetic on shapes: nothing is read from the device."""
     total = {"none": 0, "whole": 0, "edge": 0}
     if cfg.attn_sinks:           # sinks take XLA's attention
         return total
-    for window, layers in cfg.attn_layer_windows:
-        kinds = prefill_block_kinds(
-            rows, kv_rows, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim,
-            base, window)
+    calls = [(layers, prefill_block_kinds(
+        rows, kv_rows, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim,
+        base, window, valid)) for window, layers in cfg.attn_layer_windows]
+    if cfg.mla:
+        G = mla._prefill_head_group(kv_rows, cfg.num_heads,
+                                    cfg.qk_nope_head_dim)
+        calls.append((cfg.num_layers * (cfg.num_heads // G),
+                      latent_prefill_block_kinds(
+                          rows, kv_rows, G, cfg.qk_nope_head_dim,
+                          cfg.qk_rope_head_dim, cfg.v_head_dim, base,
+                          valid)))
+    for n_calls, kinds in calls:
         for kind, n in (kinds or {}).items():
-            total[kind] += layers * n
+            total[kind] += n_calls * n
     return total
 
 
@@ -768,7 +780,6 @@ class InferenceEngine:
         # over the prefills run (`_count_attn_blocks`); plain ints the
         # scheduler mirrors at scrape, as it does the prefix cache's
         self.prefill_attn_blocks = {"none": 0, "whole": 0, "edge": 0}
-        self._attn_block_kinds: Dict[tuple, Dict[str, int]] = {}
         self.prefix_cache = PrefixCache(
             prefix_cache_bytes,
             host_capacity_bytes=prefix_host_bytes,
@@ -859,7 +870,8 @@ class InferenceEngine:
             cache = llama.KVCache(k=k0, v=v0, index=prefix_len)
             logits, new_cache = llama.forward(params, cfg_, padded,
                                               cache=cache,
-                                              logits_at=suffix_len - 1)
+                                              logits_at=suffix_len - 1,
+                                              valid_len=suffix_len)
             tok = sample(logits[:, 0], key, temperature, top_k, top_p)
             # (suffix prefill stays base-model-only: adapter requests
             # bypass the prefix cache — their KV depends on the
@@ -1714,7 +1726,7 @@ class InferenceEngine:
                 tokens=sbucket, kv_rows=bucket)
             tok, k, v = self._prefill_suffix_fn(*args, **kw)
             rec = []
-            self._count_attn_blocks(sbucket, bucket, plen)
+            self._count_attn_blocks(sbucket, bucket, plen, len(suffix))
         else:
             bucket = _bucketize(len(ids), self.prefill_buckets)
             padded = np.asarray(
@@ -1730,7 +1742,7 @@ class InferenceEngine:
                 name, f"bucket={bucket}", fn, args,
                 dict(bucket=bucket), tokens=bucket, kv_rows=bucket)
             tok, k, v, *rec = fn(*args, bucket=bucket)
-            self._count_attn_blocks(bucket, bucket, 0)
+            self._count_attn_blocks(bucket, bucket, 0, len(ids))
         if aid == 0:
             self.prefix_cache.put(ids, k, v, len(ids), bucket)
         # multi-host: int() on an array spanning non-addressable
@@ -1740,12 +1752,14 @@ class InferenceEngine:
         # true_len as a third element; insert() takes the tuple whole
         return int(host_value(tok)), (k, v, *rec), len(ids), bucket
 
-    def _count_attn_blocks(self, rows: int, kv_rows: int, base: int):
-        shape = (rows, kv_rows, base)
-        kinds = self._attn_block_kinds.get(shape)
-        if kinds is None:
-            kinds = self._attn_block_kinds[shape] = \
-                prefill_attn_block_kinds(self.cfg, *shape)
+    def _count_attn_blocks(self, rows: int, kv_rows: int, base: int,
+                           valid: int):
+        """A prefill's attention grid steps onto the tallies, by the
+        prompt's true length `valid` (a quarter of a millisecond of
+        host arithmetic, after the program's dispatch and before its
+        token is waited for)."""
+        kinds = prefill_attn_block_kinds(self.cfg, rows, kv_rows, base,
+                                         valid)
         for kind, n in kinds.items():
             self.prefill_attn_blocks[kind] += n
 

@@ -1529,9 +1529,12 @@ def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
     `valid_len` ([B] int32) says how many leading positions of each
     row are real (a right-padded prefill bucket; 0 for a frozen slot
     of the multi-token decode loop). KV rows hide a padded tail
-    behind `kv_len`; a hybrid model's recurrent state cannot, so its
-    DeltaNet layers leave their state untouched from there on. No
-    other model reads it.
+    behind `kv_len`, and a prompt's attention (S > 1) is told so:
+    its valid rows end at `cache.index + valid_len`, a query row past
+    them is padding whose output is unspecified (`ops.attention`),
+    and the prefill kernels do no work for blocks of such rows. A
+    hybrid model's recurrent state cannot hide a tail, so its
+    DeltaNet layers leave their state untouched from there on.
     Returns (logits [B, S, vocab], updated cache or None).
     """
     B, S = tokens.shape
@@ -1544,7 +1547,10 @@ def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
     x = _embed(params, cfg, tokens)
     freqs = _rope_frequencies(cfg)
 
-    kv_len = jnp.broadcast_to(cache.index + S, (B,)) \
+    # a decode step (S == 1) keeps every row: a frozen slot's
+    # `valid_len` 0 is the multi-token loop's, not a padded tail
+    rows = S if valid_len is None or S == 1 else valid_len
+    kv_len = jnp.broadcast_to(cache.index + rows, (B,)) \
         if cache is not None else None
     index = cache.index if cache is not None else None
 

@@ -113,19 +113,36 @@ def _auto_backend(B: int, Sq: int, H: int, Skv: int) -> str:
 
 
 def prefill_block_kinds(Sq: int, Skv: int, H: int, K: int, D: int,
-                        base: int, window: Optional[int]):
+                        base: int, window: Optional[int],
+                        valid: Optional[int] = None):
     """Grid steps by kind ({"none", "whole", "edge"}:
     flash.prefill_block_kinds) of the kernel call that `attention`
     makes for one sequence's `Sq` prompt rows at positions `base`
-    on, over `Skv` cache rows of which `base + Sq` are valid (what
-    llama.forward passes); None where that call takes XLA's
-    attention or the kernel declines the shape. Host arithmetic."""
+    on, the first `valid` of them real (None: all), over `Skv` cache
+    rows of which `base + valid` are valid (what llama.forward
+    passes); None where that call takes XLA's attention or the kernel
+    declines the shape. Host arithmetic."""
     if Sq == 1 or H % K or \
             not _auto_backend(1, Sq, H, Skv).startswith("pallas"):
         return None
     from . import flash
-    return flash.prefill_block_kinds(Sq, Skv, K, H // K, D, base,
-                                     base + Sq, window)
+    return flash.prefill_block_kinds(
+        Sq, Skv, K, H // K, D, base, base + (Sq if valid is None else valid),
+        window)
+
+
+def latent_prefill_block_kinds(Sq: int, Skv: int, H: int, nope: int,
+                               rope: int, dv: int, base: int,
+                               valid: Optional[int] = None):
+    """The same of the kernel call that `latent_prefill` makes for H
+    heads of one sequence (flash.latent_prefill_block_kinds: a step
+    is a group of heads, a query block and a key block)."""
+    if not _latent_backend(None, 1, Sq, H, Skv).startswith("pallas") \
+            or nope % 128 or dv % 128 or rope % 8:
+        return None
+    from . import flash
+    return flash.latent_prefill_block_kinds(
+        Sq, Skv, H, base, base + (Sq if valid is None else valid))
 
 
 def make_causal_mask(q_pos: jax.Array, kv_pos: jax.Array,
@@ -198,7 +215,11 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array,
     layer out first.
     positions: [B, Sq] absolute query positions (contiguous per row);
     None disables causal masking entirely (bidirectional attention).
-    kv_len: [B] valid KV rows for fixed-capacity caches.
+    kv_len: [B] valid KV rows for fixed-capacity caches. The output
+    of a query row at a position >= kv_len is unspecified: the row is
+    padding (a right-padded prompt's tail, whose own key is no valid
+    row); the prefill kernel skips query blocks made only of them and
+    hands back zeros, XLA's path some value under the same mask.
     backend: None (auto), "xla", "pallas", or "pallas_interpret" (the
     Pallas kernels run interpreted on CPU — for numerics tests).
     sinks: [H] gpt_oss attention-sink logits — handled by the XLA
@@ -344,7 +365,8 @@ def latent_prefill(q_nope: jax.Array, q_pe: jax.Array, k_nope: jax.Array,
     returns [B, H, Sq, dv]. With `kv_len` ([B] valid rows) the keys
     are a cache's rows, row t at position t, and the queries stand at
     `positions` (contiguous per row); with None the keys are the
-    queries' own rows (plain causal)."""
+    queries' own rows (plain causal). The output of a query row at a
+    position >= kv_len is unspecified, as `attention`'s is."""
     B, H, Sq, _ = q_nope.shape
     S = k_nope.shape[2]
     if kv_len is None:

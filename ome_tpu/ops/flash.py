@@ -30,7 +30,11 @@ Pallas kernel pair designed around the TPU memory system:
     **none**: no (row, column) pair is seen: nothing runs. Under a
     sliding window the grid's key dimension holds only as many steps
     as a query block's windows can reach (`_prefill_key_steps`), not
-    one a key block of the sequence;
+    one a key block of the sequence. A query block whose first row
+    stands at or past the valid length (`kv_len`: the padded tail of
+    a prompt in its bucket) is `none` at every step: the kernel does
+    the triangle of the prompt, not of its bucket, and such a block's
+    output is zeros;
     **whole**: every pair is seen (the causal edge, the cache's
     length and the window's lower edge all pass outside the block):
     the dots and the softmax update, with no iota, compare or select;
@@ -61,6 +65,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -334,32 +339,51 @@ def flash_decode_quantized(q: jax.Array, kq: jax.Array, vq: jax.Array,
 # host's count of grid steps by kind (`prefill_block_kinds`).
 
 
-def _traced(*xs) -> bool:
-    return not all(isinstance(x, int) for x in xs)
+def _lib(*xs):
+    """What computes on `xs`: None for Python integers, numpy for a
+    host's arrays (`prefill_block_kinds`: a whole grid at once), else
+    jax.numpy (traced: the kernels and their index maps)."""
+    if all(isinstance(x, int) for x in xs):
+        return None
+    if all(isinstance(x, (int, np.ndarray, np.generic)) for x in xs):
+        return np
+    return jnp
 
 
 def _div(a, b):
     """a // b for a >= 0. Below 0 the traced quotient rounds to 0 and
     the integer one down: every caller clamps it to 0 next."""
-    return lax.div(a, b) if _traced(a, b) else a // b
+    return lax.div(a, b) if _lib(a, b) is jnp else a // b
 
 
 def _min(a, b):
-    return jnp.minimum(a, b) if _traced(a, b) else min(a, b)
+    lib = _lib(a, b)
+    return lib.minimum(a, b) if lib else min(a, b)
 
 
 def _max(a, b):
-    return jnp.maximum(a, b) if _traced(a, b) else max(a, b)
+    lib = _lib(a, b)
+    return lib.maximum(a, b) if lib else max(a, b)
+
+
+def _where(c, a, b):
+    lib = _lib(c, a, b)
+    return lib.where(c, a, b) if lib else (a if c else b)
 
 
 def _prefill_block_range(base, kv_hi, qi, bq, bs, window):
     """[first, last] KV block indices a q block can attend — the same
-    mapping the prefill BlockSpec index maps use."""
+    mapping the prefill BlockSpec index maps use. A query block whose
+    first row stands at or past `kv_hi` is padding (its own keys are
+    not valid rows): its range is the one block the last valid row
+    lies in, where the query block before it ended, so every step of
+    it repeats that index and fetches nothing."""
     causal_last = _div(base + (qi + 1) * bq - 1, bs)
     len_last = _max(_div(kv_hi - 1, bs), 0)
     last = _min(causal_last, len_last)
     first = 0 if window is None else \
         _max(_div(base + qi * bq - window + 1, bs), 0)
+    first = _where(base + qi * bq >= kv_hi, last, first)
     return _min(first, last), _max(last, 0)
 
 
@@ -380,14 +404,18 @@ def _prefill_block_kind(base, kv_hi, qi, ki, bq, bs, window):
     the step was given (the index map's clamp, undone); `some`: a
     (row, column) pair of it is seen; `whole`: every pair is: the
     causal edge, the cache's length and the window's lower edge all
-    pass outside the block, so the mask would change nothing."""
+    pass outside the block, so the mask would change nothing. A query
+    block whose first row stands at or past `kv_hi` holds padded rows
+    only (a right-padded prompt's tail: no row's own key is valid) and
+    has nothing at any step; the block that `kv_hi` crosses keeps its
+    padded rows under the mask as every other row."""
     first, last = _prefill_block_range(base, kv_hi, qi, bq, bs, window)
     start = _min(first + ki, last) * bs
     q_lo = base + qi * bq            # absolute position of first q row
     q_hi = q_lo + bq - 1
     # `first + ki <= last` keeps clamped (repeated, DMA-skipped) steps
     # from double-counting the boundary block
-    given = first + ki <= last
+    given = (first + ki <= last) & (q_lo < kv_hi)
     some = given & (start <= q_hi) & (start < kv_hi)
     whole = given & (start + bs - 1 <= q_lo) & (start + bs <= kv_hi)
     if window is not None:
@@ -497,26 +525,35 @@ def _prefill_blocks(Sq: int, S: int, G: int, D: int):
     return bq, bs
 
 
+def _count_kinds(n_q: int, n_k: int, bq: int, bs: int, base: int,
+                 kv_hi: int, window: Optional[int]):
+    """A (query blocks, key steps) grid's steps by kind, the whole
+    grid through `_prefill_block_kind` at once on the host."""
+    _, some, whole = _prefill_block_kind(
+        base, kv_hi, np.arange(n_q)[:, None], np.arange(n_k)[None, :], bq,
+        bs, window)
+    work, whole = (int(np.count_nonzero(np.broadcast_to(x, (n_q, n_k))))
+                   for x in (some, whole))
+    return {"none": n_q * n_k - work, "whole": whole, "edge": work - whole}
+
+
 def prefill_block_kinds(Sq: int, S: int, K: int, G: int, D: int,
                         base: int, kv_hi: int, window: Optional[int]):
     """Grid steps of one sequence's `flash_prefill` call by kind,
-    {"none", "whole", "edge"}, on the host and in Python integers from
-    the kernel's own tests; None where the kernel declines the shape.
+    {"none", "whole", "edge"}, on the host from the kernel's own
+    tests; None where the kernel declines the shape.
     `whole` steps run the softmax with no mask arithmetic, `edge`
     steps build the mask, `none` steps do nothing (their key block's
-    DMA is skipped too): whole / (whole + edge) is how often the cheap
-    body engages, none what is left of the grid."""
+    DMA is skipped too; every step of a query block past `kv_hi`, a
+    padded prompt's tail, is one): whole / (whole + edge) is how often
+    the cheap body engages, none what is left of the grid."""
     blocks = _prefill_blocks(Sq, S, G, D)
     if blocks is None:
         return None
     bq, bs = blocks
-    kinds = {"none": 0, "whole": 0, "edge": 0}
-    for qi in range(Sq // bq):
-        for ki in range(_prefill_key_steps(S, bq, bs, window)):
-            _, some, whole = _prefill_block_kind(base, kv_hi, qi, ki, bq,
-                                                 bs, window)
-            kinds["whole" if whole else "edge" if some else "none"] += K
-    return kinds
+    kinds = _count_kinds(Sq // bq, _prefill_key_steps(S, bq, bs, window),
+                         bq, bs, base, kv_hi, window)
+    return {kind: K * n for kind, n in kinds.items()}
 
 
 def _kv_heads(k, D: int, stacked: bool = False) -> int:
@@ -616,7 +653,12 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     positions: [B, Sq] absolute query positions, assumed contiguous per
     row (base + arange — what the model forward produces); None means
     non-causal full attention (not covered here -> None).
-    kv_len: [B] valid KV rows (None = all Skv rows valid).
+    kv_len: [B] valid KV rows (None = all Skv rows valid). The output
+    of a query row at a position >= kv_len is unspecified: such a row
+    is padding (its own key is no valid row), and the prefill kernel
+    does no work for query blocks made only of them (they come back
+    zero; a padded row beside real ones comes back as the mask leaves
+    it).
     """
     if positions is None:
         return None  # non-causal: XLA path
@@ -864,9 +906,11 @@ def latent_prefill(q_nope, q_pe, k_nope, k_pe, v, base, kv_hi, *,
     rope] (two operands: no [S, H, nope + rope] concatenation exists)
     and v [B, H, S, dv], whose width is not the keys'. Query row i of
     batch b stands at position base[b] + i and sees key rows
-    <= its position and < kv_hi[b]. Block kinds and skipped blocks as
-    `_flash_prefill` has them. Returns [B, H, Sq, dv], or None for
-    shapes the kernel does not cover."""
+    <= its position and < kv_hi[b]; the output of a query row at a
+    position >= kv_hi[b] is unspecified (padding: a query block made
+    only of such rows does no work and comes back zero). Block kinds
+    and skipped blocks as `_flash_prefill` has them. Returns [B, H,
+    Sq, dv], or None for shapes the kernel does not cover."""
     B, H, Sq, nope = q_nope.shape
     S, dv, rope = k_nope.shape[2], v.shape[-1], q_pe.shape[-1]
     blocks = _latent_prefill_blocks(Sq, S, H)
@@ -875,6 +919,21 @@ def latent_prefill(q_nope, q_pe, k_nope, k_pe, v, base, kv_hi, *,
     return _latent_prefill_call(q_nope, q_pe, k_nope, k_pe, v, base, kv_hi,
                                 blocks=blocks, scale=scale,
                                 interpret=interpret)
+
+
+def latent_prefill_block_kinds(Sq: int, S: int, H: int, base: int,
+                               kv_hi: int):
+    """Grid steps by kind of one sequence's `latent_prefill` call
+    over H heads, as `prefill_block_kinds` counts `flash_prefill`'s:
+    a step is a (group of heads, query block, key block); None where
+    the blocks do not fit the shape."""
+    blocks = _latent_prefill_blocks(Sq, S, H)
+    if blocks is None:
+        return None
+    bq, bs, g = blocks
+    kinds = _count_kinds(Sq // bq, _prefill_key_steps(S, bq, bs, None),
+                         bq, bs, base, kv_hi, None)
+    return {kind: H // g * n for kind, n in kinds.items()}
 
 
 # a jit of its own, as `_prefill_call` is

@@ -191,13 +191,14 @@ FAMILIES = {
 }
 
 
-def _family(name, dtype):
+def _family(name, dtype, **widths):
     fixture, config, ref, groups = FAMILIES[name]
     with open(os.path.join(_ROOT, "tests", "benchmark", fixture,
                            "benchmark", "configs", config + ".json")) as f:
         file = json.load(f)
     hf = {k: v for k, v in file.items()
           if k not in ("source", "reduced", "assumed", "benchmark")}
+    hf.update(widths)
     cfg = cfgs.ModelConfig.from_hf_config(hf).replace(dtype=dtype)
     if cfg.is_moe:
         cfg = cfg.replace(moe_impl="ragged")
@@ -297,3 +298,83 @@ def test_parent_form_is_the_in_major_dot():
                           out_major=True)
     np.testing.assert_array_equal(np.asarray(old), np.asarray(
         llama._proj(x, w, jnp.float32, out_dims=(4, 16))))
+
+
+# -- a right-padded prompt attends over its true length (PR 48) ----------
+
+
+def _kernel_sized(family):
+    """The family's toy model with heads the prefill kernels take
+    (ops/flash.py: 128 lanes a head), float32."""
+    widths = dict(qk_nope_head_dim=128, v_head_dim=128) \
+        if family == "latent" else dict(head_dim=128)
+    _, cfg, params, _, _ = _family(family, jnp.float32, **widths)
+    return cfg, params
+
+
+def _per_slot_cache(cfg, rows):
+    cache = llama.KVCache.create(cfg, 1, rows)
+    return dataclasses.replace(cache, index=jnp.zeros((1,), jnp.int32))
+
+
+@pytest.mark.parametrize("backend", ["xla", "pallas_interpret"])
+@pytest.mark.parametrize("family", sorted(FAMILIES))
+def test_a_padded_prompt_is_the_unpadded_one(family, backend, monkeypatch):
+    """Every layer scan of `forward`: a prompt of 40 tokens right-padded
+    to a bucket of 96 (three query blocks of 32: real, crossed by the
+    length, padding) with `valid_len` gives the last real row's logits
+    and leaves the first 40 cache rows, the rings and the recurrent
+    state as the unpadded prompt does, by XLA's attention and by the
+    kernels (which the unpadded 40 rows do not fit: XLA's there)."""
+    from ome_tpu.engine.core import prefill_attn_block_kinds
+    monkeypatch.setenv("OME_ATTN_BACKEND", backend)
+    cfg, params = _kernel_sized(family)
+    T, S = 40, 96
+    kinds = prefill_attn_block_kinds(cfg, S, S, 0, T)
+    assert (kinds["none"] > 0) == (backend != "xla"), kinds
+    toks = np.random.RandomState(5).randint(1, cfg.vocab_size, (1, T))
+    padded = np.zeros((1, S), np.int32)
+    padded[:, :T] = toks
+    n = jnp.asarray([T], jnp.int32)
+    got, have = llama.forward(params, cfg, jnp.asarray(padded),
+                              cache=_per_slot_cache(cfg, S),
+                              logits_at=n - 1, valid_len=n)
+    want, kept = llama.forward(params, cfg, jnp.asarray(toks),
+                               cache=_per_slot_cache(cfg, S))
+    assert float(np.std(np.asarray(want[0, T - 1]))) > 1e-3
+    np.testing.assert_allclose(np.asarray(got[0, 0]),
+                               np.asarray(want[0, T - 1]), atol=2e-4)
+    for name in ("k", "v"):         # [L, B, rows, ..]: the real rows
+        np.testing.assert_allclose(
+            np.asarray(getattr(have, name))[:, :, :T],
+            np.asarray(getattr(kept, name))[:, :, :T], atol=2e-4,
+            err_msg=name)
+    state = {name: getattr(have, name, None) for name in ("wk", "wv", "rec")}
+    assert any(x is not None for x in state.values()) == (
+        family in ("hybrid", "window", "preroute")), family
+    for name, x in state.items():
+        if x is None:
+            continue
+        for a, b in zip(jax.tree.leaves(x),
+                        jax.tree.leaves(getattr(kept, name))):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       atol=2e-4, err_msg=name)
+
+
+@pytest.mark.parametrize("family", ["dense", "latent"])
+def test_a_decode_step_keeps_every_row_whatever_valid_len_says(family):
+    """S == 1 is the multi-token loop's step, whose `valid_len` 0 / 1
+    freezes a slot and pads nothing: the step attends over `index + 1`
+    rows as with no `valid_len` at all, to the bit (these two families
+    read `valid_len` nowhere else)."""
+    _, cfg, params, _, _ = _family(family, jnp.float32)
+    toks = jnp.asarray(np.random.RandomState(6).randint(
+        1, cfg.vocab_size, (2, 9)))
+    cache = llama.KVCache.create(cfg, 2, 16)
+    cache = dataclasses.replace(cache, index=jnp.zeros((2,), jnp.int32))
+    _, cache = llama.forward(params, cfg, toks[:, :8], cache=cache)
+    want, kept = llama.forward(params, cfg, toks[:, 8:], cache=cache)
+    got, have = llama.forward(params, cfg, toks[:, 8:], cache=cache,
+                              valid_len=jnp.asarray([0, 1], jnp.int32))
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    np.testing.assert_array_equal(np.asarray(have.k), np.asarray(kept.k))
